@@ -1,4 +1,4 @@
-# Native runtime components (C++). `make` builds build/librtpu.so; the
+# Native runtime components (C++). `make` builds build/librtpu-<sha>.so; the
 # Python side also builds it on demand (ray_tpu/core/native.py).
 #
 # Sanitizer targets (the race-detection story for the native plane —

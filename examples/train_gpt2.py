@@ -1,6 +1,13 @@
 """Train GPT-2 with JaxTrainer: gang actors + mesh data parallelism.
 
 Usage: python examples/train_gpt2.py [--steps 30] [--model tiny|small]
+                                     [--tpus-per-worker 1]
+
+With ``--tpus-per-worker N`` each gang worker leases N chips from the
+raylet and opens exactly those (the path ``chip_smoke.py`` proves); this
+process must then not touch a jax backend itself — one process per chip.
+On the chip ``--model small`` trains at the published sequence length
+(1024); on the CPU it is cut to 128 so the example stays quick.
 """
 
 import os
@@ -20,32 +27,27 @@ def train_loop(config):
     import jax
     import jax.numpy as jnp
     import optax
-
-    from ray_tpu.models import GPT2, GPT2Config
-    from ray_tpu.models.gpt2 import loss_fn
+    from flax.core import meta
 
     from ray_tpu.core import device_telemetry
+    from ray_tpu.models import GPT2, GPT2Config
+    from ray_tpu.models.gpt2 import make_train_step
 
     cfg = (GPT2Config.tiny(dtype=jnp.float32)
            if config["model"] == "tiny" else GPT2Config.gpt2_small())
     model = GPT2(cfg)
     rng = jax.random.PRNGKey(session.get_world_rank())
-    seq = min(cfg.max_seq_len, 128)
-    params = model.init_params(rng, batch=1, seq=seq)
+    on_chip = jax.default_backend() == "tpu"
+    seq = cfg.max_seq_len if on_chip else min(cfg.max_seq_len, 128)
+    params = meta.unbox(model.init_params(rng, batch=1, seq=seq))
     tx = optax.adamw(3e-4, weight_decay=0.01)
     opt_state = tx.init(params)
-
-    @jax.jit
-    def step(params, opt_state, tokens):
-        loss, grads = jax.value_and_grad(
-            lambda p: loss_fn(model, p, tokens))(params)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss
 
     # device-plane wiring: compile telemetry on the jitted step, MFU /
     # phase attribution via the session's step monitor (rides the
     # result rows back to the driver as the "device" sibling key)
-    step = device_telemetry.instrument_step(step, name="train_gpt2.step")
+    step = device_telemetry.instrument_step(
+        make_train_step(model, tx), name="train_gpt2.step")
     mon = session.step_monitor()
     mon.flops_per_token = cfg.flops_per_token()
 
@@ -72,6 +74,7 @@ def main():
     parser.add_argument("--model", default="tiny",
                         choices=("tiny", "small"))
     parser.add_argument("--num-workers", type=int, default=1)
+    parser.add_argument("--tpus-per-worker", type=int, default=0)
     args = parser.parse_args()
 
     ray_tpu.init(ignore_reinit_error=True)
@@ -80,7 +83,8 @@ def main():
         train_loop_config={"steps": args.steps, "batch": args.batch,
                            "model": args.model},
         scaling_config=ScalingConfig(num_workers=args.num_workers,
-                                     cpus_per_worker=1))
+                                     cpus_per_worker=1,
+                                     tpus_per_worker=args.tpus_per_worker))
     result = trainer.fit()
     assert result.error is None, result.error
     print(f"final loss: {result.metrics['loss']:.4f} "

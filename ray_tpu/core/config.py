@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import json
 import os
+import tempfile
 from dataclasses import dataclass, field, fields
 from typing import Any, Dict
 
@@ -225,7 +226,9 @@ class Config:
     tpu_resource_name: str = "TPU"
 
     # ---- misc ------------------------------------------------------------
-    session_root: str = "/tmp/ray_tpu"
+    #: under the system temp dir, so a TMPDIR given to the process is
+    #: honored (``/tmp/ray_tpu`` where none is)
+    session_root: str = os.path.join(tempfile.gettempdir(), "ray_tpu")
     log_to_driver: bool = True
     event_stats: bool = True
     task_events_buffer_size: int = 10000

@@ -64,26 +64,29 @@ __all__ = ["instrument_step", "is_instrumented", "compile_count",
            "add_device_seconds", "reset_for_tests"]
 
 
-def peak_flops_per_chip() -> float:
-    """Best-effort peak bf16 FLOPs of the attached chip (the MFU
-    denominator).  CPU hosts get the v5e figure so CPU-smoke MFU
-    numbers stay comparable across bench runs."""
-    try:
-        import jax
+#: peak dense bf16 FLOP/s per chip by ``device_kind`` substring (Google
+#: Cloud TPU documentation, per-generation system architecture pages)
+_PEAK_BF16_FLOPS = {
+    "v5 lite": 197e12, "v5e": 197e12, "v5litepod": 197e12,
+    "v4": 275e12,
+    "v5p": 459e12,
+    "v6 lite": 918e12, "v6e": 918e12,
+}
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # noqa: BLE001 — no backend: assume v5e-class
-        return 197e12
-    table = {
-        "v5 lite": 197e12, "v5e": 197e12, "v5litepod": 197e12,
-        "v4": 275e12,
-        "v5p": 459e12,
-        "v6 lite": 918e12, "v6e": 918e12,
-    }
-    for key, val in table.items():
+
+def peak_flops_per_chip() -> Optional[float]:
+    """Peak bf16 FLOP/s of the chip this process has opened (the MFU
+    denominator), or None for a device that is not in the table — the
+    CPU included.  MFU is then left out; it is never computed against
+    another chip's peak.  Opens the jax backend: call it only from the
+    process that owns the device."""
+    import jax
+
+    kind = jax.devices()[0].device_kind.lower()
+    for key, val in _PEAK_BF16_FLOPS.items():
         if key in kind:
             return val
-    return 197e12
+    return None
 
 
 # ---------------------------------------------------------------------------
@@ -261,7 +264,9 @@ class StepMonitor:
     ``serve`` feeds ``ray_tpu_serve_decode_device_frac{deployment}``,
     every plane feeds the ``ray_tpu_step_phase_seconds`` histograms
     and the goodput gauge.  MFU needs ``flops_per_token`` from the
-    engine (0 disables it — goodput and phase fractions still work).
+    engine (0 disables it — goodput and phase fractions still work)
+    and a known peak for the device: on one the peaks table does not
+    know, ``stats()["mfu"]`` is None and the gauge is not set.
     """
 
     PHASES = ("data_wait", "host", "device", "sync")
@@ -273,8 +278,12 @@ class StepMonitor:
         self.name = name or plane
         self.deployment = deployment
         self.flops_per_token = float(flops_per_token)
-        self.peak_flops = float(peak_flops) if peak_flops \
-            else peak_flops_per_chip()
+        # None: not given — the device is asked once, at the first step
+        # whose MFU can be computed (loops set flops_per_token after
+        # construction, and asking opens the backend)
+        self.peak_flops: Optional[float] = float(peak_flops) \
+            if peak_flops else None
+        self._peak_asked = bool(peak_flops)
         self._lock = threading.Lock()
         self._window: "deque[Tuple[float, float, float, float, float]]" \
             = deque(maxlen=max(8, window))
@@ -315,7 +324,7 @@ class StepMonitor:
         elif self.plane == "serve" and self.deployment:
             _tm.serve_decode_device_frac(self.deployment, dev_frac)
 
-    def _derive_locked(self) -> Tuple[float, float, float, float]:
+    def _derive_locked(self) -> Tuple[Optional[float], float, float, float]:
         wait = host = dev = sync = tok = 0.0
         for dw, h, d, s, t in self._window:
             wait += dw
@@ -327,8 +336,15 @@ class StepMonitor:
         if wall <= 0:
             return 0.0, 0.0, 0.0, 0.0
         goodput = tok / wall
-        mfu = (goodput * self.flops_per_token / self.peak_flops) \
-            if self.flops_per_token > 0 else 0.0
+        if self.flops_per_token <= 0:
+            mfu: Optional[float] = 0.0
+        else:
+            if not self._peak_asked:
+                self._peak_asked = True
+                self.peak_flops = peak_flops_per_chip()
+            # no peak known for this device (the CPU included): no MFU
+            mfu = goodput * self.flops_per_token / self.peak_flops \
+                if self.peak_flops else None
         return mfu, goodput, dev / wall, wait / wall
 
     def stats(self) -> Dict[str, Any]:

@@ -929,7 +929,12 @@ class GcsServer:
                 # departed driver
                 del self.subscribers[channel]
         node_id = conn.context.get("node_id")
-        if node_id is not None and node_id in self.nodes:
+        # only the node's CURRENT link counts: a raylet whose health
+        # report timed out (a head stalled past the 5 s RPC timeout)
+        # re-registers on a new connection and then closes the old one —
+        # reading that close as death killed live nodes under load
+        if node_id is not None and node_id in self.nodes \
+                and self._node_conns.get(node_id) is conn:
             self._mark_node_dead(node_id, "raylet connection lost")
         actor_id = conn.context.get("actor_id")
         if actor_id is not None:
@@ -1545,9 +1550,23 @@ class GcsServer:
                 asyncio.get_running_loop().create_task(self._schedule_pg(pg))
 
     async def _health_check_loop(self) -> None:
+        period = self.config.health_report_period_s
+        last_tick = time.monotonic()
         while True:
-            await asyncio.sleep(self.config.health_report_period_s)
+            await asyncio.sleep(period)
             now = time.monotonic()
+            stall, last_tick = now - last_tick - period, now
+            if stall > period:
+                # THIS loop woke late — the process or the whole host
+                # was stalled (a loaded CI box; a worker opening its
+                # TPU chips freezes a small VM for seconds).  Reports
+                # sent meanwhile are still queued behind this callback,
+                # so the silence is ours, not the nodes': credit it.
+                logger.warning("health checker stalled %.2fs; not "
+                               "counted against any node", stall)
+                for node in self.nodes.values():
+                    node.last_heartbeat += stall
+                continue
             for node in list(self.nodes.values()):
                 if node.alive and (now - node.last_heartbeat
                                    > self.config.health_timeout_s):

@@ -1,15 +1,23 @@
 """ctypes loader for the native (C++) runtime components.
 
 The native sources live in ``src/`` at the repo root and are compiled to a
-shared library on first use (cached by source mtime), or ahead of time via
-``make`` / ``python -m ray_tpu.core.native``.  ctypes rather than an
-extension module keeps the build a single ``g++`` invocation with no
-Python-dev dependency (pybind11 is unavailable in this environment).
+shared library on first use, or ahead of time via ``make`` /
+``python -m ray_tpu.core.native``.  The library is named by the CONTENT
+of its sources and build flags (``build/librtpu-<sha>.so``): a copied or
+checked-out tree carries arbitrary mtimes, so a library is current iff
+its name matches, and a clean tree builds it once, under a file lock,
+however many daemons and workers start at the same moment.  ctypes
+rather than an extension module keeps the build a single ``g++``
+invocation with no Python-dev dependency (pybind11 is unavailable in
+this environment).
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,34 +25,43 @@ import threading
 _REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
 _SRC_DIR = os.path.join(_REPO_ROOT, "src")
 _BUILD_DIR = os.path.join(_REPO_ROOT, "build")
-_LIB_PATH = os.path.join(_BUILD_DIR, "librtpu.so")
 _SOURCES = ["object_store.cc", "sched_core.cc"]
+_FLAGS = ["-std=c++17", "-O2", "-g", "-fPIC", "-shared", "-Wall", "-Wextra",
+          "-pthread"]
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 
 
-def _needs_build() -> bool:
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    return any(
-        os.path.getmtime(os.path.join(_SRC_DIR, s)) > lib_mtime for s in _SOURCES
-    )
+def lib_path() -> str:
+    digest = hashlib.sha256(" ".join(_FLAGS).encode())
+    for s in _SOURCES:
+        with open(os.path.join(_SRC_DIR, s), "rb") as f:
+            digest.update(f.read())
+    return os.path.join(_BUILD_DIR, f"librtpu-{digest.hexdigest()[:16]}.so")
 
 
 def build() -> str:
+    """Build the library for the sources as they are, unless it is
+    already there.  Safe to call from any number of processes at once."""
+    path = lib_path()
+    if os.path.exists(path):
+        return path
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    cmd = [
-        "g++", "-std=c++17", "-O2", "-g", "-fPIC", "-shared",
-        "-Wall", "-Wextra",
-        *[os.path.join(_SRC_DIR, s) for s in _SOURCES],
-        "-o", _LIB_PATH + ".tmp",
-        "-pthread",
-    ]
-    subprocess.run(cmd, check=True, capture_output=True, text=True)
-    os.replace(_LIB_PATH + ".tmp", _LIB_PATH)
-    return _LIB_PATH
+    with open(os.path.join(_BUILD_DIR, ".librtpu.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if os.path.exists(path):  # built while we waited for the lock
+            return path
+        tmp = f"{path}.{os.getpid()}.tmp"
+        subprocess.run(
+            ["g++", *_FLAGS,
+             *[os.path.join(_SRC_DIR, s) for s in _SOURCES], "-o", tmp],
+            check=True, capture_output=True, text=True)
+        os.replace(tmp, path)
+        for stale in glob.glob(os.path.join(_BUILD_DIR, "librtpu*.so")):
+            if stale != path:  # other source revisions (still mapped
+                os.unlink(stale)  # where loaded; the name is just gone)
+    return path
 
 
 def load() -> ctypes.CDLL:
@@ -54,9 +71,7 @@ def load() -> ctypes.CDLL:
     with _lock:
         if _lib is not None:
             return _lib
-        if _needs_build():
-            build()
-        lib = ctypes.CDLL(_LIB_PATH)
+        lib = ctypes.CDLL(build())
         u64 = ctypes.c_uint64
         p_u64 = ctypes.POINTER(u64)
         buf = ctypes.c_char_p  # 28-byte id blobs pass as bytes
@@ -86,27 +101,22 @@ def load() -> ctypes.CDLL:
                                                   ctypes.c_char_p, u64]
         lib.rtpu_store_stats.restype = None
         lib.rtpu_store_stats.argtypes = [ctypes.c_void_p, p_u64, p_u64, p_u64]
-        try:
-            # telemetry extensions (absent from a stale pre-built .so;
-            # stats_ex callers fall back to the basic stats)
-            lib.rtpu_store_stats_ex.restype = u64
-            lib.rtpu_store_stats_ex.argtypes = [ctypes.c_void_p, p_u64, u64]
-            lib.rtpu_store_bucket_used.restype = u64
-            lib.rtpu_store_bucket_used.argtypes = [ctypes.c_void_p, p_u64,
-                                                   u64]
-            lib.rtpu_store_shard_contention.restype = u64
-            lib.rtpu_store_shard_contention.argtypes = [ctypes.c_void_p,
-                                                        p_u64, u64]
-            lib.rtpu_store_spill_candidates.restype = u64
-            lib.rtpu_store_spill_candidates.argtypes = [
-                ctypes.c_void_p, ctypes.c_char_p, p_u64, u64, u64]
-            lib.rtpu_store_create_sharded.restype = ctypes.c_void_p
-            lib.rtpu_store_create_sharded.argtypes = [ctypes.c_char_p,
-                                                      u64, u64]
-            lib.rtpu_store_used.restype = u64
-            lib.rtpu_store_used.argtypes = [ctypes.c_void_p]
-        except AttributeError:
-            pass
+        lib.rtpu_store_stats_ex.restype = u64
+        lib.rtpu_store_stats_ex.argtypes = [ctypes.c_void_p, p_u64, u64]
+        lib.rtpu_store_bucket_used.restype = u64
+        lib.rtpu_store_bucket_used.argtypes = [ctypes.c_void_p, p_u64,
+                                               u64]
+        lib.rtpu_store_shard_contention.restype = u64
+        lib.rtpu_store_shard_contention.argtypes = [ctypes.c_void_p,
+                                                    p_u64, u64]
+        lib.rtpu_store_spill_candidates.restype = u64
+        lib.rtpu_store_spill_candidates.argtypes = [
+            ctypes.c_void_p, ctypes.c_char_p, p_u64, u64, u64]
+        lib.rtpu_store_create_sharded.restype = ctypes.c_void_p
+        lib.rtpu_store_create_sharded.argtypes = [ctypes.c_char_p,
+                                                  u64, u64]
+        lib.rtpu_store_used.restype = u64
+        lib.rtpu_store_used.argtypes = [ctypes.c_void_p]
 
         f64p = ctypes.POINTER(ctypes.c_double)
         i64p = ctypes.POINTER(ctypes.c_int64)

@@ -33,14 +33,8 @@ class SharedMemoryStore:
         default): N concurrent writers doing create/seal/get/release
         only contend when their object ids hash to the same shard."""
         self._lib = native.load()
-        create_sharded = getattr(self._lib, "rtpu_store_create_sharded",
-                                 None)
-        if create_sharded is not None:
-            self._handle = create_sharded(path.encode(), capacity,
-                                          max(0, int(shards)))
-        else:  # stale pre-built .so
-            self._handle = self._lib.rtpu_store_create(path.encode(),
-                                                       capacity)
+        self._handle = self._lib.rtpu_store_create_sharded(
+            path.encode(), capacity, max(0, int(shards)))
         if not self._handle:
             raise OSError(f"failed to create object store at {path}")
         self.path = path
@@ -239,10 +233,7 @@ class SharedMemoryStore:
         """Allocated bytes, lock-free (atomic read in the native
         store) — the per-allocation spill-pressure probe.  stats()
         additionally counts objects, which sweeps every shard mutex."""
-        fn = getattr(self._lib, "rtpu_store_used", None)
-        if fn is None:
-            return self.stats()["used"]
-        return fn(self._handle)
+        return self._lib.rtpu_store_used(self._handle)
 
     def stats(self) -> Dict[str, int]:
         used = ctypes.c_uint64()
@@ -265,53 +256,38 @@ class SharedMemoryStore:
         """Arena telemetry: basic stats plus slab-bucket reuse hit/miss
         counters, doomed-object counts, and bucket occupancy (the
         observability half of the per-client allocator)."""
-        fn = getattr(self._lib, "rtpu_store_stats_ex", None)
-        if fn is None:
-            return self.stats()
         out = (ctypes.c_uint64 * len(self._STATS_EX_FIELDS))()
-        n = fn(self._handle, out, len(self._STATS_EX_FIELDS))
+        n = self._lib.rtpu_store_stats_ex(self._handle, out,
+                                          len(self._STATS_EX_FIELDS))
         return {name: out[i]
                 for i, name in enumerate(self._STATS_EX_FIELDS[:n])}
 
     def spill_candidates(self, max_ids: int = 64, max_pins: int = 1
-                         ) -> Optional[List[Tuple[ObjectID, int]]]:
+                         ) -> List[Tuple[ObjectID, int]]:
         """Sealed objects whose pin count is at most ``max_pins``,
         oldest last-pin first, as (id, payload size) — the raylet's
         LRU-by-last-pin spill queue (its own primary pin keeps
         pin_count at 1, so max_pins=1 means no client is reading).
-        Unsealed and client-pinned objects never appear.  Returns
-        None on a stale pre-built .so without the symbol — NOT an
-        empty list, and not the unpinned LRU queue (primaries always
-        hold the raylet's pin, so an LRU-based answer would make the
-        spill sweep silently spill nothing); the caller falls back to
-        its own primary table."""
-        fn = getattr(self._lib, "rtpu_store_spill_candidates", None)
-        if fn is None:
-            return None
+        Unsealed and client-pinned objects never appear."""
         ids = ctypes.create_string_buffer(ObjectID.SIZE * max_ids)
         sizes = (ctypes.c_uint64 * max_ids)()
-        n = fn(self._handle, ids, sizes, max_ids, max_pins)
+        n = self._lib.rtpu_store_spill_candidates(
+            self._handle, ids, sizes, max_ids, max_pins)
         raw = ids.raw
         return [(ObjectID(raw[i * ObjectID.SIZE:(i + 1) * ObjectID.SIZE]),
                  sizes[i]) for i in range(n)]
 
     def shard_contention(self) -> List[int]:
         """Cumulative contended-lock count per metadata shard."""
-        fn = getattr(self._lib, "rtpu_store_shard_contention", None)
-        if fn is None:
-            return []
         out = (ctypes.c_uint64 * 64)()
-        n = fn(self._handle, out, 64)
+        n = self._lib.rtpu_store_shard_contention(self._handle, out, 64)
         return list(out[:n])
 
     def bucket_occupancy(self) -> List[Tuple[int, int]]:
         """Per-bucket live allocation bytes, nonzero buckets only, as
         (bucket index, bytes) — arena occupancy by producing client."""
-        fn = getattr(self._lib, "rtpu_store_bucket_used", None)
-        if fn is None:
-            return []
         out = (ctypes.c_uint64 * 64)()
-        n = fn(self._handle, out, 64)
+        n = self._lib.rtpu_store_bucket_used(self._handle, out, 64)
         return [(i, out[i]) for i in range(n) if out[i]]
 
     def close(self) -> None:

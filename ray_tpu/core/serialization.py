@@ -142,12 +142,79 @@ def _rebuild_bytearray(buf) -> bytearray:
     return bytearray(buf)
 
 
-def _rebuild_jax_array(shape, dtype, buf):
-    import jax  # the putter had jax imported; readers reconstruct lazily
+def _rebuild_jax_array(shape, dtype, weak_type, buf):
     import numpy as _np
 
     arr = _np.frombuffer(buf, dtype=dtype).reshape(shape)
-    return jax.numpy.asarray(arr)
+    xb = sys.modules.get("jax._src.xla_bridge")
+    if xb is None or not xb.backends_are_initialized():
+        # a reader that holds no backend gets numpy: putting the value
+        # on a device would OPEN one — on a TPU host, the chip the
+        # worker that produced it still holds
+        return arr
+    import jax
+    from jax._src.lax import lax as _lax
+
+    out = jax.numpy.asarray(arr)
+    return _lax._convert_element_type(out, weak_type=True) \
+        if weak_type else out
+
+
+def _jax_array_wire(value, jax_mod, np_mod) -> Optional["_BufferWire"]:
+    """Host bytes of a fully addressable jax array as a _BufferWire, or
+    None (multi-host shards not visible here; any layout oddity)."""
+    try:
+        devices = value.devices()
+        if len(devices) == 1 and next(iter(devices)).platform == "cpu":
+            # single-device CPU: np.asarray aliases the XLA host
+            # buffer — zero copies before the arena write
+            np_view = np_mod.asarray(value)
+        elif getattr(value, "is_fully_addressable", False):
+            # DEVICE (non-CPU) or multi-shard arrays: one DMA/gather
+            # into a host staging array that then rides out-of-band.
+            # KV pages and weight shards take this path.
+            np_view = np_mod.ascontiguousarray(jax_mod.device_get(value))
+        else:
+            return None
+        if not np_view.flags["C_CONTIGUOUS"]:
+            return None
+        # ship the payload as raw uint8 (extended dtypes like bfloat16
+        # don't speak the buffer protocol) and reinterpret on rebuild
+        return _BufferWire(
+            _rebuild_jax_array,
+            (np_view.shape, np_view.dtype,
+             bool(getattr(value, "weak_type", False))),
+            np_view.reshape(-1).view(np_mod.uint8))
+    except Exception:  # noqa: BLE001
+        return None
+
+
+def _reduce_jax_array(value):
+    """dispatch_table entry for jax arrays that do not take the flat
+    fast path (small, or nested in a container): jax's own reduce
+    rebuilds with a device put, which initialises a backend in every
+    reader — see _rebuild_jax_array."""
+    wire = _jax_array_wire(value, sys.modules["jax"], sys.modules["numpy"])
+    return wire.__reduce__() if wire is not None else value.__reduce__()
+
+
+_jax_reducer_installed = False
+
+
+def _install_jax_reducer() -> None:
+    global _jax_reducer_installed
+    if _jax_reducer_installed or "jax" not in sys.modules:
+        return
+    try:
+        from jax._src.array import ArrayImpl
+    except ImportError:  # jax still mid-import on another thread
+        return
+    import collections
+
+    _RefAwarePickler.dispatch_table = collections.ChainMap(
+        {ArrayImpl: _reduce_jax_array},
+        cloudpickle.CloudPickler.dispatch_table)
+    _jax_reducer_installed = True
 
 
 class _BufferWire:
@@ -201,40 +268,13 @@ def _serialize_buffer_fast(value: Any) -> Optional["SerializedObject"]:
                             buffer_callback=buffers.append)
         return SerializedObject(meta, buffers, [])
     jax_mod = sys.modules.get("jax")
-    if jax_mod is not None and isinstance(value, jax_mod.Array):
-        try:
-            if getattr(value, "weak_type", False):
-                return None  # jnp.asarray would strengthen the type
-            if np_mod is None:
-                return None
-            devices = value.devices()
-            if len(devices) == 1 \
-                    and next(iter(devices)).platform == "cpu":
-                # single-device CPU: np.asarray aliases the XLA host
-                # buffer — zero copies before the arena write
-                np_view = np_mod.asarray(value)
-            elif getattr(value, "is_fully_addressable", False):
-                # DEVICE (non-CPU) or multi-shard arrays: one DMA/
-                # gather into a host staging array that then rides
-                # out-of-band, instead of the old cloudpickle fallback
-                # (device_get + a second wholesale copy into the pickle
-                # stream).  KV pages and weight shards take this path.
-                np_view = np_mod.ascontiguousarray(
-                    jax_mod.device_get(value))
-            else:
-                return None  # multi-host shards not visible here
-        except Exception:  # noqa: BLE001 — any layout oddity: fall back
+    if jax_mod is not None and np_mod is not None \
+            and isinstance(value, jax_mod.Array):
+        wire = _jax_array_wire(value, jax_mod, np_mod)
+        if wire is None or wire.buf.nbytes < _INBAND_LIMIT:
             return None
-        if (np_view is None or np_view.nbytes < _INBAND_LIMIT
-                or not np_view.flags["C_CONTIGUOUS"]):
-            return None
-        # ship the payload as raw uint8 (extended dtypes like bfloat16
-        # don't speak the buffer protocol) and reinterpret on rebuild
-        meta = pickle.dumps(
-            _BufferWire(_rebuild_jax_array,
-                        (np_view.shape, np_view.dtype),
-                        np_view.reshape(-1).view(np_mod.uint8)),
-            protocol=5, buffer_callback=buffers.append)
+        meta = pickle.dumps(wire, protocol=5,
+                            buffer_callback=buffers.append)
         return SerializedObject(meta, buffers, [])
     return None
 
@@ -269,6 +309,7 @@ def serialize(value: Any) -> SerializedObject:
     fast = _serialize_buffer_fast(value)
     if fast is not None:
         return fast
+    _install_jax_reducer()
     buffers: List = []
     contained: List = []
     sink = io.BytesIO()

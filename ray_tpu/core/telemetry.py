@@ -956,16 +956,17 @@ def step_goodput(plane: str, per_s: float) -> None:
            ("plane",)).set_key(_planekey(plane), per_s)
 
 
-def train_step_quality(mfu: float, data_wait_frac: float) -> None:
+def train_step_quality(mfu: Optional[float], data_wait_frac: float) -> None:
     """Train-plane step efficiency: model FLOPs utilization and the
     fraction of step wall time spent waiting on input data (the
     starved-accelerator signal the autoscaler and `ray-tpu top` read
     via the train:mfu / train:step_data_wait_frac recording rules)."""
     if not enabled():
         return
-    _gauge("ray_tpu_train_mfu",
-           "rolling model-FLOPs utilization of the train step loop"
-           ).set_key(_EMPTY_KEY, mfu)
+    if mfu is not None:  # None: no peak known for this device
+        _gauge("ray_tpu_train_mfu",
+               "rolling model-FLOPs utilization of the train step loop"
+               ).set_key(_EMPTY_KEY, mfu)
     _gauge("ray_tpu_train_step_data_wait_frac",
            "fraction of train step wall time spent waiting for input "
            "data (prefetch handoff)").set_key(_EMPTY_KEY, data_wait_frac)

@@ -67,12 +67,9 @@ def main() -> None:
         faulthandler.dump_traceback_later(
             float(os.environ["RAY_TPU_WORKER_FAULTHANDLER"]), repeat=True)
 
-    # Workers never own TPU chips unless a task leases them; keep jax (if
-    # user code imports it) off the real accelerator by default so that N
-    # workers on one host don't fight over the chip.  Training workers
-    # explicitly clear this (see ray_tpu.train).
-    os.environ.setdefault("JAX_PLATFORMS", os.environ.get(
-        "RAY_TPU_WORKER_JAX_PLATFORMS", "cpu"))
+    # JAX_PLATFORMS (and, for a TPU lease, the visible chips) were decided
+    # by the raylet before this interpreter started (node.plain_worker_env
+    # / node.tpu_worker_env); nothing is re-pinned here.
 
     from ray_tpu.core.ids import JobID, NodeID
     from ray_tpu.core.worker import CoreWorker

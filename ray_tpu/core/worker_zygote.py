@@ -13,10 +13,10 @@ The raylet talks to it over a line-oriented stdin/stdout protocol:
 Safety: the zygote imports only thread-free modules (threads, event
 loops, and sockets all start inside ``CoreWorker.__init__`` AFTER the
 fork), and ``ray_tpu.core.ids`` re-seeds its entropy pool via
-``os.register_at_fork``.  TPU-capable workers do NOT fork from here —
-they need the accelerator plugin's sitecustomize, which only runs at
-real interpreter start — so the raylet uses this path only for plain
-(CPU) pool workers.
+``os.register_at_fork``.  Workers for a TPU lease do NOT fork from
+here — their platform selection and visible chips have to be in the
+environment when the interpreter starts — so the raylet uses this path
+only for plain (CPU) pool workers.
 """
 
 from __future__ import annotations

@@ -14,6 +14,7 @@ Design notes:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -144,7 +145,12 @@ class Block(nn.Module):
 
             attn = _attention_reference(q, k, v, True, head_dim ** -0.5)
         else:
-            attn = flash_attention(q, k, v, causal=True)
+            from ray_tpu.parallel.mesh import get_global_mesh
+
+            # same binding rule as "ring": under a multi-device mesh the
+            # kernel runs per (batch, head) shard
+            attn = flash_attention(q, k, v, causal=True,
+                                   mesh=get_global_mesh())
         attn = attn.reshape(batch, seq, cfg.embed_dim)
         attn = _dense(cfg.embed_dim, cfg, "attn_proj",
                       ("heads", "embed"))(attn)
@@ -236,3 +242,21 @@ def loss_fn(model: GPT2, params, tokens: jax.Array,
     return chunked_lm_loss(x[:, :-1], wte, tokens[:, 1:],
                            chunk=head_chunk, compute_dtype=compute,
                            logits_dtype=head_logits_dtype)
+
+
+def make_train_step(model: GPT2, tx, head_logits_dtype: Any = None):
+    """The jitted train step the GPT-2 entry points share: chunked-head
+    loss, gradients, one ``tx`` (optax) update.  Params and optimizer
+    state are donated so XLA updates them in place (saves an HBM copy
+    of the full state per step)."""
+    import optax
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def train_step(params, opt_state, tokens):
+        loss, grads = jax.value_and_grad(
+            lambda p: loss_fn(model, p, tokens,
+                              head_logits_dtype=head_logits_dtype))(params)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    return train_step

@@ -33,19 +33,6 @@ from jax.sharding import Mesh
 AXIS_ORDER = ("dp", "fsdp", "pp", "sp", "tp", "ep")
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """Version-portable ``shard_map``: ``jax.shard_map`` where it exists
-    (and takes ``check_vma``), else the pre-0.6 experimental entry point
-    (whose equivalent knob is ``check_rep``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **kwargs)
-
-
 @dataclass
 class MeshConfig:
     """Declarative parallelism layout (the ScalingConfig analog for

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.parallel.mesh import shard_map as _shard_map
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

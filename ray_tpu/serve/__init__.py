@@ -329,6 +329,19 @@ class Deployment:
         version = ray_tpu.get(controller.deploy.remote(
             self.name, blob, init_args, init_kwargs, self.config), timeout=60)
         _wait_for_replicas(controller, self.name, self.config, version)
+        # the controller is ready; THIS process's router learns of it by
+        # long poll.  Return only once it has — a first call racing the
+        # poll would otherwise burn assign()'s short unknown-deployment
+        # grace and fail on a loaded box.
+        router = _get_router()
+        deadline = time.monotonic() + 30.0
+        while not (router.known(self.name) and router.known(
+                router.prefill_for(self.name) or self.name)):
+            if time.monotonic() > deadline:
+                raise TimeoutError(
+                    f"deployment {self.name} never reached this "
+                    f"process's routing table")
+            time.sleep(0.02)
         return DeploymentHandle(self.name)
 
     def get_handle(self) -> DeploymentHandle:

@@ -1421,7 +1421,7 @@ class SummaryCache:
             return
         try:
             os.makedirs(os.path.dirname(self.path), exist_ok=True)
-            tmp = self.path + ".tmp"
+            tmp = f"{self.path}.{os.getpid()}.tmp"  # no shared tmp
             with open(tmp, "wb") as f:
                 pickle.dump({"version": CACHE_VERSION, "specs": self._fp,
                              "modules": self._entries}, f,

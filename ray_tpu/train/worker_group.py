@@ -69,21 +69,30 @@ class TrainWorker:
     def setup_jax(self, coordinator: Optional[str], use_tpu: bool) -> bool:
         """Initialize the jax runtime for this worker.
 
-        On TPU hosts, clears the CPU pin set by the worker bootstrap so
-        jax grabs the chips; multi-host gangs rendezvous at the rank-0
-        coordinator (the torch TCP-store analog).
+        The platform was selected when the raylet spawned this process
+        for its lease (jax reads ``JAX_PLATFORMS`` at import, which
+        unpickling this class already triggered), so a TPU gang only
+        CHECKS that it really opened the chip — a mis-pinned worker
+        would otherwise train on the CPU and report a loss.  Multi-host
+        gangs rendezvous at the rank-0 coordinator (the torch TCP-store
+        analog).
         """
-        if use_tpu:
-            os.environ.pop("JAX_PLATFORMS", None)
-        else:
-            os.environ["JAX_PLATFORMS"] = "cpu"
-        if coordinator is not None and self.world_size > 1 and use_tpu:
-            import jax
+        if not use_tpu:
+            return True
+        import jax
 
+        if coordinator is not None and self.world_size > 1:
             jax.distributed.initialize(
                 coordinator_address=coordinator,
                 num_processes=self.world_size,
                 process_id=self.world_rank)
+        backend = jax.default_backend()
+        if backend != "tpu":
+            seen = {k: v for k, v in os.environ.items()
+                    if k.startswith(("JAX_", "TPU_", "XLA_", "RAY_TPU_"))}
+            raise RuntimeError(
+                f"train worker {self.world_rank} leased TPU chips but jax "
+                f"opened {backend!r}; environment: {seen}")
         return True
 
     def setup_tensorflow(self, cluster_workers: List[str]) -> bool:
@@ -165,6 +174,15 @@ class WorkerGroup:
     def start(self) -> None:
         bundles = [self.scaling.worker_resources()
                    for _ in range(self.scaling.num_workers)]
+        want = sum(b.get("TPU", 0) for b in bundles)
+        have = ray_tpu.cluster_resources().get("TPU", 0) if want else 0
+        if want > have:
+            # infeasible, not busy: fail now instead of waiting out the
+            # placement timeout for chips no node of the cluster has
+            raise RuntimeError(
+                f"training gang needs {want:g} TPU chips but the cluster "
+                f"has {have:g} (chips are counted from /dev/accel* or "
+                f"/dev/vfio; RAY_TPU_CHIPS overrides)")
         self.pg = placement_group(bundles,
                                   strategy=self.scaling.placement_strategy)
         if not self.pg.wait(120):

@@ -1,0 +1,164 @@
+"""The chip's compiler, asked from the CPU sandbox.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (``topologies.get_topology_desc``): what it
+refuses here — a kernel over its fast-memory limit, an unaligned slice,
+a Mosaic kernel GSPMD cannot partition, a step that does not fit 16 GB —
+it would refuse on the chip.  Nothing runs, so these say nothing about
+results or times.
+
+All such compiles live in THIS file, and the topology is described
+inside a module-scoped fixture: only one process may load libtpu, so a
+second file (another xdist worker) or an import-time call would make
+the suite's workers disagree.  The kernel entry points are called
+directly with ``interpret=False`` — the public wrappers ask
+``jax.default_backend()``, which is the CPU here, and would take the
+jnp reference.
+"""
+
+import dataclasses
+import importlib
+import os
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+fa = importlib.import_module("ray_tpu.ops.flash_attention")
+fused = importlib.import_module("ray_tpu.ops.fused")
+
+V5E_HBM_BYTES = 16 * 1024 ** 3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without a chip: keep it off around these
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _attn_grads(family: str):
+    """d(sum(attention))/d(q, k, v): the forward and both backward
+    kernels of one family, default 1024 blocks, compiled not interpreted."""
+    def grads(q, k, v):
+        scale = q.shape[-1] ** -0.5
+
+        def loss(q, k, v):
+            if family == "native":
+                out = fa._flash_nl(q, k, v, True, scale, 1024, 1024, False)
+            else:
+                out = fa._flash(q, k, v, True, scale, 1024, 1024, False,
+                                "pallas")
+            return out.astype(jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    return grads
+
+
+@pytest.mark.parametrize("family", ["native", "head_major"])
+@pytest.mark.parametrize("shape", [(32, 1024, 12, 64), (1, 32768, 12, 64)],
+                         ids=["gpt2_batch32", "long_context_32k"])
+def test_flash_kernels_compile_for_v5e(one_chip, family, shape):
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(_attn_grads(family)).lower(x, x, x).compile()
+    # forward, dK/dV and dQ: three Mosaic kernels, none interpreted
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+def test_fused_rmsnorm_compiles_at_llama_width(one_chip):
+    x = jax.ShapeDtypeStruct((8, 2048, 4096), jnp.bfloat16,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((4096,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda x, w: fused._rmsnorm(x, w, 1e-6, False)).lower(x, w).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _gpt2_step_shapes(place, **cut):
+    """The shared GPT-2 124M train step with abstract arguments;
+    ``place(shape_struct, partition_spec)`` attaches the sharding."""
+    import optax
+
+    from ray_tpu.models import GPT2, GPT2Config
+    from ray_tpu.models.gpt2 import make_train_step
+    from ray_tpu.parallel.sharding import FSDP_RULES, flax_sharding
+
+    cfg = dataclasses.replace(GPT2Config.gpt2_small(), **cut)
+    model = GPT2(cfg)
+    tx = optax.adamw(6e-4, weight_decay=0.01)
+    boxed = jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0), batch=1))
+    plain, specs = flax_sharding(boxed, FSDP_RULES)
+    params = jax.tree.map(place, plain, specs)
+    opt_state = jax.eval_shape(tx.init, params)
+    tokens = place(jax.ShapeDtypeStruct((32, cfg.max_seq_len), jnp.int32),
+                   P(("dp", "fsdp"), None))
+    return make_train_step(model, tx), (params, opt_state, tokens)
+
+
+def _lower_as_on_tpu(step, args):
+    # the model's flash dispatch asks jax.default_backend(); steer it in
+    # the test, as the chip would answer
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        return step.lower(*args).compile()
+
+
+def test_gpt2_124m_train_step_fits_one_v5e(one_chip):
+    def place(a, _spec):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    step, args = _gpt2_step_shapes(place)
+    compiled = _lower_as_on_tpu(step, args)
+    # 12 layers x (forward, dK/dV, dQ)
+    assert compiled.as_text().count("tpu_custom_call") == 36
+    mem = compiled.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 0.9 * V5E_HBM_BYTES, f"{total / 2**30:.2f} GiB"
+
+
+def test_gpt2_fsdp_step_partitions_over_four_v5e(topo):
+    """Mosaic kernels cannot be partitioned by GSPMD: under a mesh the
+    model has to run them per shard (flash_attention(mesh=...)).
+    Published widths, depth cut to 2: partitioning does not depend on
+    depth, and the full-depth compile costs every suite run a minute
+    (it was made once, by hand: PERF.md, PR 22)."""
+    from ray_tpu.parallel import MeshConfig, build_mesh
+    from ray_tpu.parallel.mesh import use_mesh
+
+    mesh = build_mesh(MeshConfig(fsdp=4), devices=topo.devices)
+
+    def place(a, spec):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    step, args = _gpt2_step_shapes(place, num_layers=2)
+    with use_mesh(mesh):
+        compiled = _lower_as_on_tpu(step, args)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 6
+    assert "all-gather" in text and "reduce-scatter" in text
+    mem = compiled.memory_analysis()  # bytes on each device
+    assert mem.temp_size_in_bytes < 0.9 * V5E_HBM_BYTES
