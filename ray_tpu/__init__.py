@@ -230,6 +230,11 @@ def shutdown() -> None:
             _serve._stop_router()
         core = _worker_mod.global_worker_or_none()
         if core is not None:
+            if _head_proc is not None and _owns_head:
+                # the span table dies with the head: leave the timeline
+                # in the session directory for timeline() after the run
+                from ray_tpu.experimental.state.api import leave_timeline
+                leave_timeline(core)
             core.shutdown()
         if _head_proc is not None and _owns_head:
             _head_proc.terminate()
@@ -394,6 +399,8 @@ def get_tpu_ids() -> List[int]:
 
 
 def timeline(filename: Optional[str] = None) -> List[Dict[str, Any]]:
-    """Chrome-trace export of task events (reference ``ray.timeline``)."""
+    """Chrome-trace export of task events and runtime spans (reference
+    ``ray.timeline``); after ``shutdown()``, what the last session of
+    this process left (docs/observability.md)."""
     from ray_tpu.experimental.state.api import timeline as _timeline
     return _timeline(filename)

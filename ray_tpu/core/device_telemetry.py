@@ -59,7 +59,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 from ray_tpu.core import telemetry as _tm
 
 __all__ = ["instrument_step", "is_instrumented", "compile_count",
-           "compile_stats", "StepMonitor", "RankSkewWindow",
+           "compile_stats", "record_xla_phases", "StepMonitor", "RankSkewWindow",
            "peak_flops_per_chip", "device_seconds",
            "add_device_seconds", "reset_for_tests"]
 
@@ -167,6 +167,43 @@ def instrument_step(fn: Callable, name: str) -> Callable:
     wrapped._rtpu_step_name = name
     wrapped.__wrapped__ = fn
     return wrapped
+
+
+#: jax's own compile phases (``jax.monitoring`` time-span events, each
+#: with ``fun_name`` and true start/end) -> span name under cat ``xla``
+_XLA_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+#: tracing one 36-layer step fires ~10^4 sub-millisecond events for the
+#: ``jnp`` helpers it calls; those would push every older span out of
+#: the bounded buffer, so only phases at least this long become spans
+_XLA_PHASE_MIN_S = 0.001
+_xla_phases_on = False
+
+
+def _on_xla_phase(event: str, start: float, end: float, **kwargs) -> None:
+    name = _XLA_PHASES.get(event)
+    if name is not None and end - start >= _XLA_PHASE_MIN_S:
+        _tm.record_span("xla", name, start, end,
+                        fun_name=str(kwargs.get("fun_name")))
+
+
+def record_xla_phases() -> None:
+    """From now on this process's trace / lower / backend-compile
+    phases (a backend compile, or the persistent cache's answer) are
+    ``xla:*`` timeline spans that name the jitted function — what jax
+    itself measured, beside :func:`instrument_step`'s guess from input
+    signatures.  Idempotent; imports jax, opens no backend."""
+    global _xla_phases_on
+    with _compile_lock:
+        if _xla_phases_on:
+            return
+        _xla_phases_on = True
+    import jax
+
+    jax.monitoring.register_event_time_span_listener(_on_xla_phase)
 
 
 def is_instrumented(fn: Callable) -> bool:

@@ -227,6 +227,7 @@ class GcsServer:
         # not re-applied (exactly-once at the fold, like the WAL dedup)
         self._task_event_seq: Dict[str, int] = {}
         self._metric_seq: Dict[str, int] = {}
+        self._span_seq: Dict[str, int] = {}
         # ring-buffer overflow accounting (satellite: silent event loss):
         # job hex -> events evicted unread, plus burst-logging state
         self._task_event_drops: Dict[str, int] = {}
@@ -1919,6 +1920,15 @@ class GcsServer:
         }
 
     async def handle_report_spans(self, conn, data):
+        seq = data.get("seq")
+        if seq is not None:
+            # a reporter whose call timed out sends the SAME batch again
+            # under the same seq (worker._flush_telemetry): append it
+            # once, whichever delivery got through
+            src = data.get("source") or ""
+            if self._span_seq.get(src, -1) >= seq:
+                return True
+            self._span_seq[src] = seq
         self._spans.extend(data.get("spans", []))
         return True
 

@@ -366,9 +366,9 @@ class Raylet:
         # store path -> (mmap, base address, ctypes export)
         self._peer_arenas: Dict[str, tuple] = {}
 
-        # worker pool: spawned-but-unregistered procs as
-        # (proc, tpu_ids or None for a plain worker, spawn_token)
-        self._spawned_procs: List[Tuple[Any, Any, Any]] = []
+        # worker pool: spawned-but-unregistered procs as (proc, tpu_ids
+        # or None for a plain worker, spawn_token, wall time of the spawn)
+        self._spawned_procs: List[Tuple[Any, Any, Any, float]] = []
         self.workers: Dict[WorkerID, WorkerHandle] = {}
         self._idle: List[WorkerHandle] = []
         self._starting = 0
@@ -1131,7 +1131,7 @@ class Raylet:
                     self._on_worker_dead(w, f"exit code {w.proc.returncode}")
             # workers that died before registering (startup crash)
             for entry in list(self._spawned_procs):
-                proc, tpu_ids, token = entry
+                proc, tpu_ids, token, _ = entry
                 if proc.poll() is not None:
                     self._spawned_procs.remove(entry)
                     self._dec_starting(tpu_ids)
@@ -1325,13 +1325,14 @@ class Raylet:
         # and takes the posix_spawn path, never forking the raylet.
         if safe_die_with_parent():
             env["RAY_TPU_PDEATHSIG"] = str(os.getpid())
+        t_spawn = time.time()
         proc = subprocess.Popen(
             cmd, env=env, stdout=out, stderr=err, close_fds=False)
         # log monitor maps these files to the worker pid for prefixes
         self._log_pids[log_base + ".out"] = proc.pid
         self._log_pids[log_base + ".err"] = proc.pid
         # handle registered later in handle_register_worker; remember proc
-        self._spawned_procs.append((proc, tpu_ids, None))
+        self._spawned_procs.append((proc, tpu_ids, None, t_spawn))
 
     def _start_env_worker(self, lease: "PendingLease") -> None:
         """Spawn a worker under an isolated runtime env (venv / conda /
@@ -1403,6 +1404,7 @@ class Raylet:
         fut = loop.run_in_executor(None, build_and_spawn)
 
         def _done(f):
+            t_spawn = time.time()  # the env is built, the Popen made
             try:
                 proc = f.result()
             except Exception as e:  # noqa: BLE001 — report to leases
@@ -1420,7 +1422,7 @@ class Raylet:
             self._log_pids[log_base + ".out"] = proc.pid
             self._log_pids[log_base + ".err"] = proc.pid
             self._env_spawn_hash[token] = env_hash
-            self._spawned_procs.append((proc, None, token))
+            self._spawned_procs.append((proc, None, token, t_spawn))
 
         fut.add_done_callback(_done)
 
@@ -1430,6 +1432,7 @@ class Raylet:
             self._zygote = _ZygoteClient(self.session_dir)
         loop = asyncio.get_running_loop()
         zygote = self._zygote
+        t_spawn = time.time()
 
         def _fork():
             # failpoint: the zygote fork fails — the raylet must fall
@@ -1469,7 +1472,7 @@ class Raylet:
                     self._dec_starting(None)
                     self._maybe_schedule()  # freed pool capacity
                     return
-            self._spawned_procs.append((handle, None, None))
+            self._spawned_procs.append((handle, None, None, t_spawn))
 
         fut.add_done_callback(_done)
 
@@ -1502,7 +1505,7 @@ class Raylet:
         # workers register with a namespaced pid), host pid otherwise
         reg_token = data.get("spawn_token")
         for entry in list(self._spawned_procs):
-            proc, tpu_ids, token = entry
+            proc, tpu_ids, token, t_spawn = entry
             # with a spawn token, match on it EXCLUSIVELY: a container
             # worker's namespaced pid can collide with an unrelated
             # pending proc entry, mis-adopting the handle and corrupting
@@ -1513,6 +1516,10 @@ class Raylet:
                 worker.tpu_ids = tpu_ids
                 self._spawned_procs.remove(entry)
                 self._dec_starting(tpu_ids)
+                # spawn -> this registration: the interpreter's start,
+                # its imports and the worker's connect (``worker:boot``)
+                _tm.record_span("lease", "spawn", t_spawn, time.time(),
+                                pid=worker.pid, tpu=len(tpu_ids or ()))
                 break
         # isolated-env workers are born bound to their env
         env_hash = data.get("env_hash") \

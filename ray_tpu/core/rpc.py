@@ -129,8 +129,9 @@ IDEMPOTENT_METHODS = frozenset({
     # existing directory entries instead of re-registering
     "register_actor_batch",
     "object_release", "return_worker", "cancel_lease", "cancel_task",
-    # report_spans is deliberately NOT here: its handler appends, so a
-    # retry-after-send would duplicate spans (flush loops drop instead)
+    # report_spans is deliberately NOT here: its handler appends.  A
+    # worker's flush sends an unacknowledged batch again itself, under
+    # the same seq, and the handler drops the replay
     "report_metrics", "report_task_events", "drain_node", "reattach_job",
     # transfer bookkeeping: pull_start re-pins idempotently (the holder
     # keeps one pin per link), pull_end/location updates converge

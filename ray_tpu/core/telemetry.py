@@ -27,7 +27,10 @@ up in one Perfetto track without per-consumer correction.
 
 from __future__ import annotations
 
+import contextvars
+import itertools
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -65,6 +68,7 @@ def _reset_for_tests() -> None:
     _bytes_sent = 0
     _bytes_received = 0
     _spans.clear()
+    _current_span.set(None)
 
 
 # ---------------------------------------------------------------------------
@@ -1218,21 +1222,114 @@ def spans_enabled() -> bool:
     return enabled()
 
 
-def record_span(cat: str, name: str, start: float, end: float,
-                **args: Any) -> None:
-    """Buffer one completed span (wall-clock seconds, local clock; the
-    GCS offset is applied at drain time).  Bounded: the oldest spans
-    drop when the buffer outpaces the flush loop."""
-    if not enabled():
-        return
+#: this process's id, read once: ``os.getpid()`` is a system call, and
+#: on a sandboxed host that alone costs microseconds a span
+_pid = os.getpid()
+
+
+def _refresh_pid() -> None:
+    global _pid
+    _pid = os.getpid()
+
+
+os.register_at_fork(after_in_child=_refresh_pid)  # zygote-forked workers
+
+
+def _buffer(row: Dict[str, Any]) -> None:
     global _spans, _span_cap_applied
     if not _span_cap_applied:
         _span_cap_applied = True
         cap = _span_cap()
         if cap != _spans.maxlen:
             _spans = deque(_spans, maxlen=cap)
-    _spans.append({"cat": cat, "name": name, "start": start, "end": end,
-                   "pid": os.getpid(), "args": args})
+    _spans.append(row)
+
+
+def record_span(cat: str, name: str, start: float, end: float,
+                **args: Any) -> None:
+    """Buffer one completed span (wall-clock seconds, local clock; the
+    GCS offset is applied at drain time).  Bounded: the oldest spans
+    drop when the buffer outpaces the flush loop.  For spans learned
+    after the fact; code that brackets its own work uses :class:`span`."""
+    if not enabled():
+        return
+    _buffer({"cat": cat, "name": name, "start": start, "end": end,
+             "pid": _pid, "args": args})
+
+
+_span_ids = itertools.count(1)
+#: id of the innermost open ``span()`` of this thread (or asyncio task)
+_current_span: "contextvars.ContextVar[Optional[int]]" = \
+    contextvars.ContextVar("ray_tpu_span", default=None)
+
+
+class span:
+    """``with span("train", "poll", results=3) as s: ...`` — one span
+    at a layer boundary, on two clocks at once.
+
+    Buffers what :func:`record_span` buffers plus ``id``, ``tid`` (the
+    thread) and ``parent`` (the ``id`` of the enclosing ``span()`` of
+    the same thread, ``None`` at the top), so a layer's self time is
+    its duration minus its children's.  Counts known only at the end
+    go in through ``s.args`` (``s.args["bytes"] = n``).
+
+    A site on a path that can run once a task gives ``min_s``: a span
+    shorter than that is not buffered (its annotation still is), so
+    that a task-heavy job does not push the rare long rows out of the
+    bounded buffers.  The body may lower ``s.min_s`` once it knows the
+    span matters.
+
+    When ``jax`` is already imported in this process the span is also a
+    ``jax.profiler.TraceAnnotation("ray_tpu:<cat>:<name>")``: inside a
+    profiler session it lands on the host plane of the profiler's file,
+    on the clock of the device planes (outside one it is a flag test).
+    This module never imports jax itself — GCS, raylet and drivers
+    that own no chip must not open a backend — and the annotation only
+    carries the arguments known at entry.
+    """
+
+    __slots__ = ("cat", "name", "args", "min_s", "start", "_token",
+                 "_annotation")
+
+    def __init__(self, cat: str, name: str, min_s: float = 0.0,
+                 **args: Any):
+        self.cat = cat
+        self.name = name
+        self.args = args
+        self.min_s = min_s
+        self._token = None
+
+    def __enter__(self) -> "span":
+        if not enabled():
+            return self
+        self._token = _current_span.set(next(_span_ids))
+        # a jax that is only half imported yet has no ``profiler``
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        self._annotation = None
+        if profiler is not None:
+            self._annotation = profiler.TraceAnnotation(
+                f"ray_tpu:{self.cat}:{self.name}", **self.args)
+            self._annotation.__enter__()
+        self.start = time.time()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        token = self._token
+        if token is None:
+            return
+        end = time.time()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        self._token = None
+        me = _current_span.get()
+        _current_span.reset(token)
+        if end - self.start < self.min_s:
+            return
+        _buffer({"cat": self.cat, "name": self.name, "start": self.start,
+                 "end": end, "pid": _pid,
+                 # the Thread object remembers its native id: no syscall
+                 "tid": threading.current_thread().native_id, "id": me,
+                 "parent": _current_span.get(), "args": self.args})
 
 
 def drain_spans(source: str) -> List[Dict[str, Any]]:

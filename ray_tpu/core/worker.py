@@ -109,6 +109,10 @@ PLASMA_MARKER = b"__RTPU_IN_PLASMA__"
 #: during reply commit — is swallowed instead of killing the exec loop.
 INTERRUPT_WINDOW = threading.local()
 
+#: an inline reply faster than this leaves no ``worker:reply`` rows (a
+#: task-heavy job posts one reply a task; ``task_exec`` already has it)
+_REPLY_SPAN_MIN_S = 0.001
+
 
 def _renv_hash(runtime_env: Optional[Dict[str, Any]]) -> Optional[str]:
     if not runtime_env:
@@ -224,16 +228,6 @@ class CoreWorker:
                  job_id: Optional[JobID] = None,
                  config: Optional[Config] = None):
         assert mode in ("driver", "worker")
-        _trace = os.environ.get("RAY_TPU_BOOT_TRACE")
-        _t0 = time.perf_counter()
-
-        def _mark(label):
-            if _trace:
-                import sys as _sys
-                _sys.stderr.write(f"BOOT cw.{label} "
-                                  f"{1000 * (time.perf_counter() - _t0):.1f}"
-                                  f"ms\n")
-                _sys.stderr.flush()
         self.mode = mode
         self.gcs_address = gcs_address
         self.raylet_address = raylet_address
@@ -247,9 +241,7 @@ class CoreWorker:
         _flight.init(mode, session_dir, self.config)
 
         self.memory_store = MemoryStore()
-        _mark("pre_store")
         self.store_client = StoreClient(store_path, store_capacity)
-        _mark("store")
         self.reference_counter = ReferenceCounter(
             on_free=self._on_object_freed,
             on_borrow_added=self._on_borrow_added,
@@ -262,7 +254,6 @@ class CoreWorker:
         self._loop_thread = threading.Thread(
             target=self._loop.run_forever, name="rtpu-io", daemon=True)
         self._loop_thread.start()
-        _mark("loop_thread")
 
         self._ctx = _TaskContext()
         self._address_cache: Optional[OwnerAddress] = None
@@ -342,6 +333,14 @@ class CoreWorker:
         # seq as its first attempt for the replay guard to drop it
         self._task_event_report_seq = 0
         self._metrics_report_seq = 0
+        # the span batch the GCS has not acknowledged yet, as (seq,
+        # rows): sent again under the same seq, so that a flush that
+        # times out (an io loop held up by a 3 GB reply) loses nothing
+        # and a delivery that did arrive is not appended twice
+        self._unsent_spans: Optional[Tuple[int, list]] = None
+        #: the flush loop's tick and a flush_telemetry() never overlap
+        self._flush_lock = asyncio.Lock()
+        self._span_report_seq = 0
         self._reg_batch_seq = 0
         # task_id bin -> submit monotonic time (dispatch-latency metric)
         self._dispatch_ts: Dict[bytes, float] = {}
@@ -418,7 +417,6 @@ class CoreWorker:
         self._waiting_for_deps: Dict[bytes, tuple] = {}
         self._dep_waiters: Dict[ObjectID, list] = {}
 
-        _mark("pre_async_init")
         # load env-armed failpoints up front: site checks (and the actor
         # fast-path gate) then reduce to one empty-dict truth test
         _fp.armed()
@@ -426,7 +424,6 @@ class CoreWorker:
         # (25 Hz), zero cost on the task hot path itself
         _prof.set_task_info_provider(lambda: dict(self._executing_info))
         self._run(self._async_init())
-        _mark("async_init")
         set_global_worker(self)
 
     # ------------------------------------------------------------------
@@ -1075,7 +1072,8 @@ class CoreWorker:
         view = self.store_client.view(lease["offset"], lease["size"])
         pin = _Pin(release=lambda b=object_id.binary():
                    self._post(self._release_plasma(b)))
-        value, _ = _deserialize_pinned(view, pin)
+        with _tm.span("worker", "get.deserialize", bytes=lease["size"]):
+            value, _ = _deserialize_pinned(view, pin)
         if pin.count == 0:
             # no out-of-band buffers alias the mapping; release immediately
             await self._release_plasma(object_id.binary())
@@ -3442,13 +3440,9 @@ class CoreWorker:
         MetricsAgent).  Batches registry deltas + runtime spans to the
         GCS every ``metrics_report_period_s`` with drop-don't-block
         semantics: an unreachable GCS costs the window's deltas only."""
-        from ray_tpu.util import metrics as metrics_mod
-
         period = max(0.25, getattr(self.config,
                                    "metrics_report_period_s", 5.0))
         synced_conn = None  # re-probe on failure AND after a reconnect
-        source = f"{self.mode}-{self._worker_id_hex[:8]}"
-        wid_tags = {"wid": self._worker_id_hex[:8]}
         while not self._shutdown:
             # an active profiling window flushes at >= 1 Hz so a short
             # `ray-tpu profile --duration 2` sees its samples arrive
@@ -3469,45 +3463,85 @@ class CoreWorker:
                 if await _tm.measure_clock_offset(conn) is not None:
                     synced_conn = conn
             try:
-                records: list = []
-                spans: list = []
-                if _tm.enabled():
-                    _tm.set_gauge("ray_tpu_task_backlog",
-                                  "tasks queued owner-side awaiting "
-                                  "lease/dispatch",
-                                  self._queued_task_depth(), wid_tags)
-                    fstats = _flight.stats()
-                    if fstats is not None:
-                        _tm.flight_frames(fstats["frames_recorded"])
-                    _tm.presample()
-                    records = metrics_mod.flush_all()
-                    spans = _tm.drain_spans(source)
-                profile = _prof.drain()
-                if records:
-                    self._metrics_report_seq += 1
-                    await conn.call("report_metrics",
-                                    {"records": records, "source": source,
-                                     "seq": self._metrics_report_seq},
-                                    timeout=2.0)
-                if spans:
-                    await conn.call("report_spans", {"spans": spans},
-                                    timeout=2.0)
-                tspans = _trace.drain(source)
-                if tspans:
-                    await conn.call("report_trace_spans",
-                                    {"spans": tspans}, timeout=2.0)
-                if profile:
-                    node = self.node_id.hex()
-                    for rec in profile:
-                        rec["node"] = node
-                        rec["source"] = source
-                    await conn.call("report_profile",
-                                    {"records": profile}, timeout=2.0)
+                await self._flush_telemetry(conn)
             except (rpc.ConnectionLost, rpc.RpcError,
                     asyncio.TimeoutError, OSError):
                 pass  # dropped: counters re-accumulate next window
             except Exception:
                 logger.exception("metrics flush iteration failed")
+
+    async def _flush_telemetry(self, conn) -> None:
+        """One flush of this process's registry deltas, spans, trace
+        spans and profile records to the GCS, one at a time: a second
+        flush that drained a new batch while the first still resends
+        its own would have that batch forgotten by the first."""
+        async with self._flush_lock:
+            await self._flush_telemetry_locked(conn)
+
+    async def _flush_telemetry_locked(self, conn) -> None:
+        from ray_tpu.util import metrics as metrics_mod
+
+        source = f"{self.mode}-{self._worker_id_hex[:8]}"
+
+        async def send_unsent_spans():
+            if self._unsent_spans is not None:
+                seq, spans = self._unsent_spans
+                await conn.call(
+                    "report_spans",
+                    {"spans": spans, "source": source, "seq": seq},
+                    timeout=2.0)
+                self._unsent_spans = None
+
+        await send_unsent_spans()  # the batch a failed flush left
+        records: list = []
+        if _tm.enabled():
+            _tm.set_gauge("ray_tpu_task_backlog",
+                          "tasks queued owner-side awaiting "
+                          "lease/dispatch",
+                          self._queued_task_depth(),
+                          {"wid": self._worker_id_hex[:8]})
+            fstats = _flight.stats()
+            if fstats is not None:
+                _tm.flight_frames(fstats["frames_recorded"])
+            _tm.presample()
+            records = metrics_mod.flush_all()
+            spans = _tm.drain_spans(source)
+            if spans:
+                self._span_report_seq += 1
+                self._unsent_spans = (self._span_report_seq, spans)
+        profile = _prof.drain()
+        if records:
+            self._metrics_report_seq += 1
+            await conn.call("report_metrics",
+                            {"records": records, "source": source,
+                             "seq": self._metrics_report_seq},
+                            timeout=2.0)
+        await send_unsent_spans()
+        tspans = _trace.drain(source)
+        if tspans:
+            await conn.call("report_trace_spans",
+                            {"spans": tspans}, timeout=2.0)
+        if profile:
+            node = self.node_id.hex()
+            for rec in profile:
+                rec["node"] = node
+                rec["source"] = source
+            await conn.call("report_profile",
+                            {"records": profile}, timeout=2.0)
+
+    def flush_telemetry(self, timeout: float = 2.0) -> None:
+        """Flush now, without waiting for the period: for a process that
+        knows it is about to be stopped (a training gang's worker after
+        its loop ended, a driver in ``shutdown()``).  Best effort and
+        bounded; not for the io loop's own thread."""
+        conn = self.gcs_conn
+        if conn is None or conn.closed:
+            return
+        try:
+            self._run(asyncio.wait_for(self._flush_telemetry(conn),
+                                       timeout), timeout=timeout + 0.5)
+        except Exception:  # noqa: BLE001 — dropped, like a lost period
+            pass
 
     # ------------------------------------------------------------------
     # task execution (worker mode)
@@ -4178,23 +4212,37 @@ class CoreWorker:
 
     def _post_return(self, object_id: ObjectID, value: Any,
                      spec: TaskSpec) -> Tuple[bytes, str, Any]:
-        ser = serialize(value)
-        if ser.total_size() <= self.config.max_direct_call_object_size:
-            return (object_id.binary(), "inline", ser.to_bytes())
-        # large return: store in this node's shm; owner learns the location
-        async def _store():
+        # once a return value and once a streamed item: a reply leaves
+        # rows only when it is stored or slow, the rest is in task_exec
+        with _tm.span("worker", "reply", min_s=_REPLY_SPAN_MIN_S,
+                      fn=spec.function_descriptor) as sp:
+            with _tm.span("worker", "reply.serialize",
+                          min_s=_REPLY_SPAN_MIN_S):
+                ser = serialize(value)
             size = ser.total_size()
-            reply = await self.raylet_conn.call(
-                "object_create",
-                {"object_id": object_id.binary(), "size": size})
-            view = self.store_client.view(reply["offset"], size)
-            ser.write_to(view)
-            await self.raylet_conn.call("object_seal", {
-                "object_id": object_id.binary(),
-                "owner_address": spec.owner_address,
-            })
-        self._run(_store())
-        return (object_id.binary(), "plasma", tuple(self.raylet_address))
+            sp.args["bytes"] = size
+            if size <= self.config.max_direct_call_object_size:
+                sp.args["path"] = "inline"
+                return (object_id.binary(), "inline", ser.to_bytes())
+            # large return: store in this node's shm; owner learns the
+            # location
+            sp.args["path"] = "plasma"
+            sp.min_s = 0.0
+
+            async def _store():
+                reply = await self.raylet_conn.call(
+                    "object_create",
+                    {"object_id": object_id.binary(), "size": size})
+                view = self.store_client.view(reply["offset"], size)
+                ser.write_to(view)
+                await self.raylet_conn.call("object_seal", {
+                    "object_id": object_id.binary(),
+                    "owner_address": spec.owner_address,
+                })
+            with _tm.span("worker", "reply.store", bytes=size):
+                self._run(_store())
+            return (object_id.binary(), "plasma",
+                    tuple(self.raylet_address))
 
     def _resolve_args(self, spec: TaskSpec) -> Tuple[list, dict]:
         resolved: List[Any] = []

@@ -34,18 +34,10 @@ def _install_cancel_sigint_handler() -> None:
 
 def main() -> None:
     import time
-    t0 = time.perf_counter()
-    trace = os.environ.get("RAY_TPU_BOOT_TRACE")
-
-    def mark(label):
-        if trace:
-            sys.stderr.write(
-                f"BOOT {label} {1000 * (time.perf_counter() - t0):.1f}ms\n")
-            sys.stderr.flush()
+    t_entry = time.time()
 
     from ray_tpu.core.node import maybe_arm_pdeathsig
     maybe_arm_pdeathsig()
-    mark("pdeathsig")
     parser = argparse.ArgumentParser()
     parser.add_argument("--raylet", required=True)
     parser.add_argument("--gcs", required=True)
@@ -74,7 +66,7 @@ def main() -> None:
     from ray_tpu.core.ids import JobID, NodeID
     from ray_tpu.core.worker import CoreWorker
     _install_cancel_sigint_handler()
-    mark("imports")
+    t_imported = time.time()
 
     def parse_addr(s: str):
         host, port = s.rsplit(":", 1)
@@ -90,7 +82,14 @@ def main() -> None:
         session_dir=args.session_dir,
         job_id=JobID.from_hex(args.job_id) if args.job_id else None,
     )
-    mark("core_worker_ready")
+    # the worker's boot as one timeline span, recorded now that its
+    # telemetry (config, flush loop) exists, with the entry stamp
+    from ray_tpu.core import telemetry
+    t_ready = time.time()
+    telemetry.record_span(
+        "worker", "boot", t_entry, t_ready,
+        imports_ms=round(1e3 * (t_imported - t_entry), 1),
+        connect_ms=round(1e3 * (t_ready - t_imported), 1))
     try:
         worker.run_exec_loop()
     finally:

@@ -15,6 +15,8 @@ Also home of the chrome-trace ``timeline`` export (reference
 from __future__ import annotations
 
 import json
+import os
+import time
 from collections import defaultdict
 from typing import Any, Dict, List, Optional
 
@@ -221,10 +223,32 @@ def task_event_drops() -> Dict[str, Any]:
 def timeline(filename: Optional[str] = None) -> List[Dict[str, Any]]:
     """Chrome-trace (``chrome://tracing`` / Perfetto) export of task
     events (reference ``ray timeline``, profiling.h events), merged
-    with the runtime's object-transfer and RPC-retry spans.  Span
+    with the runtime's spans (object transfers, RPC retry chains, and
+    the ``telemetry.span()`` sites of the training path).  Span
     sources clock-correct against the GCS before reporting, so
-    cross-host rows line up on one Perfetto timebase."""
-    events = _core().gcs_call("get_task_events", {"limit": 100_000})
+    cross-host rows line up on one Perfetto timebase.
+
+    With no cluster connected it returns what ``ray_tpu.shutdown()``
+    left of this process's last session (``<session_dir>/
+    timeline.json``), or ``[]`` when there is none: the use of
+    ``ray-tpu timeline`` for a job that has ended."""
+    if worker_mod.global_worker_or_none() is None:
+        trace = _last_session_timeline()
+    else:
+        events = _core().gcs_call("get_task_events", {"limit": 100_000})
+        try:
+            spans = list_spans()
+        except Exception:  # noqa: BLE001 — pre-telemetry GCS: tasks only
+            spans = []
+        trace = _chrome_trace(events, spans)
+    if filename:
+        with open(filename, "w") as f:
+            json.dump(trace, f)
+    return trace
+
+
+def _chrome_trace(events: List[Dict[str, Any]],
+                  spans: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
     # pair RUNNING -> FINISHED/FAILED per (task, attempt)
     starts: Dict[tuple, Dict[str, Any]] = {}
     trace: List[Dict[str, Any]] = []
@@ -242,21 +266,61 @@ def timeline(filename: Optional[str] = None) -> List[Dict[str, Any]]:
                 "tid": ev["task_id"][:8],
                 "args": {"state": ev["state"], "attempt": ev.get("attempt")},
             })
-    try:
-        spans = list_spans()
-    except Exception:  # noqa: BLE001 — pre-telemetry GCS: tasks only
-        spans = []
     for span in spans:
+        args = dict(span.get("args") or {})
+        args["os_pid"] = span.get("pid")
+        if "id" in span:  # a telemetry.span(): it nests within its
+            # process, so a reader can take children from self time
+            args["span_id"] = span["id"]
+            args["parent_id"] = span.get("parent")
         trace.append({
             "name": span.get("name", "span"), "ph": "X",
             "cat": span.get("cat", "runtime"),
             "ts": span["start"] * 1e6,
             "dur": max(0.0, (span["end"] - span["start"]) * 1e6),
             "pid": span.get("source", "runtime"),
-            "tid": span.get("cat", "runtime"),
-            "args": dict(span.get("args") or {}),
+            # rows of one thread nest in one Perfetto track
+            "tid": span.get("tid", span.get("cat", "runtime")),
+            "args": args,
         })
-    if filename:
-        with open(filename, "w") as f:
-            json.dump(trace, f)
     return trace
+
+
+#: where ``leave_timeline`` wrote this process's last session
+_last_timeline_path: Optional[str] = None
+
+
+def _last_session_timeline() -> List[Dict[str, Any]]:
+    if _last_timeline_path is None:
+        return []
+    try:
+        with open(_last_timeline_path) as f:
+            return json.load(f)
+    except (OSError, ValueError):
+        return []
+
+
+def leave_timeline(core, timeout: float = 2.0) -> None:
+    """Called by ``ray_tpu.shutdown()`` in the process that owns the
+    head, before the head stops: send this driver's own spans (they
+    wait up to a flush period otherwise) and write the timeline to
+    ``<session_dir>/timeline.json``.  Best effort: bounded by
+    ``timeout`` in all, silent on failure."""
+    global _last_timeline_path
+    _last_timeline_path = None
+    t_end = time.monotonic() + timeout
+
+    def left() -> float:
+        return max(0.1, t_end - time.monotonic())
+
+    try:
+        core.flush_telemetry(timeout / 2)
+        events = core.gcs_call("get_task_events", {"limit": 100_000},
+                               timeout=left())
+        spans = core.gcs_call("get_spans", {}, timeout=left())
+        path = os.path.join(core.session_dir, "timeline.json")
+        with open(path, "w") as f:
+            json.dump(_chrome_trace(events, spans), f)
+        _last_timeline_path = path
+    except Exception:  # noqa: BLE001 — a shutdown never fails on this
+        pass

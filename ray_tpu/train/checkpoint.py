@@ -22,6 +22,7 @@ import tempfile
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from ray_tpu.core import telemetry as _tm
 from ray_tpu.train.config import CheckpointConfig
 
 
@@ -32,6 +33,9 @@ class Checkpoint:
             raise ValueError("exactly one of data/directory required")
         self._data = data
         self._dir = directory
+        #: short id that travels with the object (it pickles with it):
+        #: the spans of one save, in worker and driver, share it
+        self.id = os.urandom(4).hex()
 
     # -- constructors -----------------------------------------------------
     @classmethod
@@ -45,12 +49,16 @@ class Checkpoint:
     @classmethod
     def from_pytree(cls, pytree: Any,
                     metrics: Optional[Dict[str, Any]] = None) -> "Checkpoint":
+        import jax
         from flax import serialization
 
-        return cls(data={
-            "pytree_msgpack": serialization.to_bytes(pytree),
-            "metrics": metrics or {},
-        })
+        ckpt = cls(data={"metrics": metrics or {}})
+        with _tm.span("train", "ckpt.from_pytree", ckpt=ckpt.id) as sp:
+            blob = serialization.to_bytes(pytree)
+            sp.args.update(bytes=len(blob),
+                           leaves=len(jax.tree_util.tree_leaves(pytree)))
+        ckpt._data["pytree_msgpack"] = blob
+        return ckpt
 
     # -- accessors --------------------------------------------------------
     _MANIFEST = ".pickled_keys.json"
@@ -184,20 +192,25 @@ class CheckpointManager:
 
     def register(self, checkpoint: Checkpoint,
                  metrics: Optional[Dict[str, Any]] = None) -> str:
-        self._counter += 1
-        path = os.path.join(self.directory, f"checkpoint_{self._counter:06d}")
-        checkpoint.to_directory(path)
-        metrics = dict(metrics or checkpoint.metrics)
-        with open(os.path.join(path, ".metrics.json"), "w") as f:
-            json.dump({k: v for k, v in metrics.items()
-                       if isinstance(v, (int, float, str, bool))}, f)
-        if self.storage_uri:
-            from ray_tpu.air import storage
-            storage.upload_dir(path, storage.join(
-                self.storage_uri, os.path.basename(path)))
-        score = self._score(metrics)
-        self._entries.append((score, path, metrics))
-        self._enforce_retention()
+        with _tm.span("train", "ckpt.register", ckpt=checkpoint.id) as sp:
+            self._counter += 1
+            path = os.path.join(self.directory,
+                                f"checkpoint_{self._counter:06d}")
+            checkpoint.to_directory(path)
+            metrics = dict(metrics or checkpoint.metrics)
+            with open(os.path.join(path, ".metrics.json"), "w") as f:
+                json.dump({k: v for k, v in metrics.items()
+                           if isinstance(v, (int, float, str, bool))}, f)
+            if self.storage_uri:
+                from ray_tpu.air import storage
+                storage.upload_dir(path, storage.join(
+                    self.storage_uri, os.path.basename(path)))
+            score = self._score(metrics)
+            self._entries.append((score, path, metrics))
+            self._enforce_retention()
+            sp.args.update(path=path, bytes=sum(
+                len(v) for v in (checkpoint._data or {}).values()
+                if isinstance(v, bytes)))
         return path
 
     def _score(self, metrics: Dict[str, Any]) -> float:

@@ -9,11 +9,13 @@ process-global bound by the TrainWorker actor around the loop.
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 from typing import Any, Dict, Optional
 
 from ray_tpu.core import device_telemetry as _dt
+from ray_tpu.core import telemetry as _tm
 from ray_tpu.train.checkpoint import Checkpoint
 
 _session: Optional["_TrainSession"] = None
@@ -38,15 +40,19 @@ class _TrainSession:
 
     def report(self, metrics: Dict[str, Any],
                checkpoint: Optional[Checkpoint] = None) -> None:
-        row: Dict[str, Any] = {"metrics": dict(metrics),
-                               "checkpoint": checkpoint,
-                               "rank": self.world_rank}
-        # device stats ride as a SIBLING of metrics so result consumers
-        # comparing metrics dicts are unaffected
-        dev = self.step_monitor.stats()
-        if dev["steps"]:
-            row["device"] = dev
-        self.result_queue.put(row)
+        # a span only when a checkpoint rides: plain metric reports can
+        # come every step
+        with (_tm.span("train", "report", ckpt=checkpoint.id)
+              if checkpoint is not None else contextlib.nullcontext()):
+            row: Dict[str, Any] = {"metrics": dict(metrics),
+                                   "checkpoint": checkpoint,
+                                   "rank": self.world_rank}
+            # device stats ride as a SIBLING of metrics so result
+            # consumers comparing metrics dicts are unaffected
+            dev = self.step_monitor.stats()
+            if dev["steps"]:
+                row["device"] = dev
+            self.result_queue.put(row)
 
 
 def _set_session(session: Optional[_TrainSession]) -> None:

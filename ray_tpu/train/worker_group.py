@@ -20,9 +20,13 @@ import os
 import queue
 import socket
 import threading
+import time
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu.core import device_telemetry as _dt
+from ray_tpu.core import telemetry as _tm
+from ray_tpu.core import worker as worker_mod
 from ray_tpu.train import session as session_mod
 from ray_tpu.train.config import ScalingConfig
 from ray_tpu.util.placement_group import (
@@ -77,16 +81,22 @@ class TrainWorker:
         gangs rendezvous at the rank-0 coordinator (the torch TCP-store
         analog).
         """
-        if not use_tpu:
-            return True
-        import jax
+        _dt.record_xla_phases()
+        # every gang leaves this span, a CPU one too (then ~0 s): what
+        # opening the chips costs, apart from the rest of gang bring-up
+        with _tm.span("train", "chip_open", backend=None, devices=0) as sp:
+            if not use_tpu:
+                return True
+            import jax
 
-        if coordinator is not None and self.world_size > 1:
-            jax.distributed.initialize(
-                coordinator_address=coordinator,
-                num_processes=self.world_size,
-                process_id=self.world_rank)
-        backend = jax.default_backend()
+            if coordinator is not None and self.world_size > 1:
+                jax.distributed.initialize(
+                    coordinator_address=coordinator,
+                    num_processes=self.world_size,
+                    process_id=self.world_rank)
+            backend = jax.default_backend()
+            sp.args.update(backend=backend,
+                           devices=jax.local_device_count())
         if backend != "tpu":
             seen = {k: v for k, v in os.environ.items()
                     if k.startswith(("JAX_", "TPU_", "XLA_", "RAY_TPU_"))}
@@ -137,12 +147,23 @@ class TrainWorker:
         """Drain queued results; reports liveness and errors."""
         assert self._session is not None
         results: List[Dict[str, Any]] = []
+        t_call = time.time()
         try:
             results.append(self._session.result_queue.get(timeout=timeout))
-            while True:
-                results.append(self._session.result_queue.get_nowait())
         except queue.Empty:
             pass
+        if results:
+            # the span starts at the first row: the wait on an empty
+            # queue is the loop's time, not the checkpoint's
+            with _tm.span("train", "next_results",
+                          waited_s=round(time.time() - t_call, 6)) as sp:
+                try:
+                    while True:
+                        results.append(
+                            self._session.result_queue.get_nowait())
+                except queue.Empty:
+                    pass
+                sp.args.update(_rows(results))
         error = None
         if self._session.error is not None:
             import traceback
@@ -155,6 +176,12 @@ class TrainWorker:
             "error": error,
         }
 
+    def flush_telemetry(self) -> bool:
+        """Send what the flush period still holds (up to 5 s of spans):
+        the driver asks once, before it kills the gang."""
+        worker_mod.global_worker().flush_telemetry()
+        return True
+
     def shutdown_jax(self) -> bool:
         try:
             import jax
@@ -163,6 +190,14 @@ class TrainWorker:
         except Exception:
             pass
         return True
+
+
+def _rows(results: List[Dict[str, Any]]) -> Dict[str, Any]:
+    """Span arguments for a batch of reported rows: how many, and the
+    ids of the checkpoints that ride in it."""
+    return {"results": len(results),
+            "ckpts": [r["checkpoint"].id for r in results
+                      if r.get("checkpoint") is not None]}
 
 
 class WorkerGroup:
@@ -183,29 +218,35 @@ class WorkerGroup:
                 f"training gang needs {want:g} TPU chips but the cluster "
                 f"has {have:g} (chips are counted from /dev/accel* or "
                 f"/dev/vfio; RAY_TPU_CHIPS overrides)")
-        self.pg = placement_group(bundles,
-                                  strategy=self.scaling.placement_strategy)
-        if not self.pg.wait(120):
+        with _tm.span("train", "gang.place", bundles=len(bundles),
+                      tpu=want):
+            self.pg = placement_group(
+                bundles, strategy=self.scaling.placement_strategy)
+            placed = self.pg.wait(120)
+        if not placed:
             remove_placement_group(self.pg)
             raise RuntimeError(
                 f"could not place training gang: {bundles} "
                 f"({self.scaling.placement_strategy})")
         actor_cls = ray_tpu.remote(TrainWorker)
         self.workers = []
-        for rank in range(self.scaling.num_workers):
-            strategy = PlacementGroupSchedulingStrategy(
-                placement_group=self.pg,
-                placement_group_bundle_index=rank)
-            worker = actor_cls.options(
-                num_cpus=self.scaling.cpus_per_worker,
-                num_tpus=self.scaling.tpus_per_worker or None,
-                resources=self.scaling.resources_per_worker or None,
-                scheduling_strategy=strategy,
-                max_concurrency=4,  # run + poll concurrently
-            ).remote(rank, self.scaling.num_workers)
-            self.workers.append(worker)
-        # barrier: all actors alive
-        ray_tpu.get([w.__ray_ready__() for w in self.workers], timeout=300)
+        with _tm.span("train", "gang.spawn",
+                      workers=self.scaling.num_workers):
+            for rank in range(self.scaling.num_workers):
+                strategy = PlacementGroupSchedulingStrategy(
+                    placement_group=self.pg,
+                    placement_group_bundle_index=rank)
+                worker = actor_cls.options(
+                    num_cpus=self.scaling.cpus_per_worker,
+                    num_tpus=self.scaling.tpus_per_worker or None,
+                    resources=self.scaling.resources_per_worker or None,
+                    scheduling_strategy=strategy,
+                    max_concurrency=4,  # run + poll concurrently
+                ).remote(rank, self.scaling.num_workers)
+                self.workers.append(worker)
+            # barrier: all actors alive
+            ray_tpu.get([w.__ray_ready__() for w in self.workers],
+                        timeout=300)
 
     def setup_backend(self, backend: str = "jax") -> None:
         if backend == "torch":
@@ -234,19 +275,29 @@ class WorkerGroup:
     def run(self, fn: Callable, config: Dict[str, Any],
             dataset_shards: Optional[List[Any]] = None,
             resume_checkpoint=None) -> None:
-        ray_tpu.get([
-            w.run.remote(fn, config,
-                         dataset_shards[i] if dataset_shards else None,
-                         resume_checkpoint)
-            for i, w in enumerate(self.workers)
-        ], timeout=300)
+        with _tm.span("train", "gang.run"):
+            ray_tpu.get([
+                w.run.remote(fn, config,
+                             dataset_shards[i] if dataset_shards else None,
+                             resume_checkpoint)
+                for i, w in enumerate(self.workers)
+            ], timeout=300)
 
     def poll(self, timeout: float = 1.0) -> List[Dict[str, Any]]:
-        return ray_tpu.get(
-            [w.next_results.remote(timeout) for w in self.workers],
-            timeout=max(60.0, timeout * 10))
+        with _tm.span("train", "poll") as sp:
+            polls = ray_tpu.get(
+                [w.next_results.remote(timeout) for w in self.workers],
+                timeout=max(60.0, timeout * 10))
+            sp.args.update(_rows([r for p in polls for r in p["results"]]))
+        return polls
 
     def shutdown(self) -> None:
+        if self.workers and _tm.enabled():
+            try:  # a killed worker takes its unflushed spans with it
+                ray_tpu.get([w.flush_telemetry.remote()
+                             for w in self.workers], timeout=3.0)
+            except Exception:
+                pass
         for w in self.workers:
             try:
                 ray_tpu.kill(w)
