@@ -6,9 +6,10 @@ msgpack artifacts), or an ObjectRef, and converted between forms.  The
 manager implements keep-K + score-attribute retention
 (``CheckpointConfig``, reference ``air/config.py:513``).
 
-JAX pytrees serialize with flax's msgpack (no pickle for tensors);
-``save_pytree`` / ``load_pytree`` are the convenience entry points used by
-``JaxTrainer`` workers.
+JAX pytrees are saved in flax's msgpack format (no pickle for tensors):
+``Checkpoint.from_pytree`` writes it, byte for byte what
+``flax.serialization.to_bytes`` gives, in one pass into one host buffer,
+and ``flax.serialization.from_bytes`` reads it (``to_pytree``).
 """
 
 from __future__ import annotations
@@ -19,11 +20,154 @@ import pickle
 import re
 import shutil
 import tempfile
+import struct
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+import numpy as np
+
 from ray_tpu.core import telemetry as _tm
 from ray_tpu.train.config import CheckpointConfig
+
+
+#: the key of a pytree's msgpack payload: ``bytes`` when it was read from
+#: a directory, the flat ``uint8`` array ``from_pytree`` filled otherwise
+_PYTREE = "pytree_msgpack"
+
+#: most bytes one numpy copy moves: a dispatch that waits for the
+#: interpreter waits for one such piece, not for a whole leaf
+_COPY_BYTES = 64 << 20
+
+
+def _is_raw(key: str, value: Any) -> bool:
+    """Whether a checkpoint's value goes to a directory as it is (anything
+    else is pickled there)."""
+    return isinstance(value, bytes) or (
+        key == _PYTREE and isinstance(value, np.ndarray))
+
+
+def _to_host(pytree: Any) -> Any:
+    """The pytree's flax state dict with every ``jax.Array`` leaf on the
+    host: all the transfers are started before the first is waited for."""
+    import jax
+    from flax import serialization
+
+    # under a key, so that a bare array is a dict's value like the rest
+    root = {"": serialization.to_state_dict(pytree)}
+    found = []
+
+    def walk(node: dict) -> None:
+        for key, value in node.items():
+            if isinstance(value, jax.Array):
+                found.append((node, key, value))
+            elif isinstance(value, dict):
+                walk(value)
+
+    walk(root)
+    for _, _, value in found:
+        value.copy_to_host_async()
+    for node, key, value in found:
+        node[key] = np.asarray(value)
+    return root[""]
+
+
+#: msgpack's ``fixext`` type bytes, by the length of the body
+_FIXEXT = {1: 0xd4, 2: 0xd5, 4: 0xd6, 8: 0xd7, 16: 0xd8}
+
+
+def _ext_header(code: int, n: int) -> bytes:
+    if n in _FIXEXT:
+        return struct.pack(">BB", _FIXEXT[n], code)
+    if n < 1 << 8:
+        return struct.pack(">BBB", 0xc7, n, code)
+    if n < 1 << 16:
+        return struct.pack(">BHB", 0xc8, n, code)
+    return struct.pack(">BIB", 0xc9, n, code)
+
+
+def _bin_header(n: int) -> bytes:
+    if n < 1 << 8:
+        return struct.pack(">BB", 0xc4, n)
+    if n < 1 << 16:
+        return struct.pack(">BH", 0xc5, n)
+    return struct.pack(">BI", 0xc6, n)
+
+
+def _copy_leaf(dst: np.ndarray, leaf: np.ndarray) -> None:
+    """``leaf``'s bytes in C order into the ``uint8`` slice ``dst``, in
+    pieces of ``_COPY_BYTES``."""
+    if leaf.flags.c_contiguous:
+        src = leaf.reshape(-1).view(np.uint8)
+        for i in range(0, src.size, _COPY_BYTES):
+            dst[i:i + _COPY_BYTES] = src[i:i + _COPY_BYTES]
+        return
+    rows = dst.view(leaf.dtype).reshape(leaf.shape)
+    step = max(1, _COPY_BYTES * len(leaf) // leaf.nbytes)
+    for i in range(0, len(leaf), step):
+        rows[i:i + step] = leaf[i:i + step]
+
+
+def _encode(state: Any) -> Tuple[np.ndarray, float]:
+    """What ``flax.serialization.msgpack_serialize(state)`` returns, as a
+    flat ``uint8`` array that is allocated once and into which each
+    array leaf is copied once; and the array bytes written over the
+    array bytes of the tree (1.0: no leaf was copied twice).
+
+    The framing (map headers, keys, an array leaf's ``ext 1`` around
+    ``[shape, dtype.name, bin]``, the chunked form of a leaf above
+    ``MAX_CHUNK_SIZE``) is worked out first, which gives the size; any
+    other leaf goes through flax's own packer."""
+    import msgpack
+    from flax import serialization
+
+    packer = msgpack.Packer(strict_types=True)
+    pieces: List[Any] = []  # framing (bytes) and array leaves, in order
+    copied_before = 0
+
+    def array(leaf: np.ndarray) -> None:
+        if leaf.dtype.hasobject or leaf.dtype.isalignedstruct:
+            raise ValueError("Object and structured dtypes not supported "
+                             "for serialization of ndarrays.")
+        inner = (b"\x93" + msgpack.packb(leaf.shape)
+                 + msgpack.packb(leaf.dtype.name) + _bin_header(leaf.nbytes))
+        pieces.append(_ext_header(1, len(inner) + leaf.nbytes) + inner)
+        pieces.append(leaf)
+
+    def value(node: Any, chunk: bool = True) -> None:
+        nonlocal copied_before
+        if type(node) is dict:
+            pieces.append(packer.pack_map_header(len(node)))
+            for key, item in node.items():
+                pieces.append(packer.pack(key))
+                value(item, chunk)
+        elif not isinstance(node, np.ndarray):
+            pieces.append(serialization.msgpack_serialize(node))
+        elif chunk and node.nbytes > serialization.MAX_CHUNK_SIZE:
+            flat = node.reshape(-1)  # flax's _chunk, over views
+            if not node.flags.c_contiguous:
+                copied_before += node.nbytes
+            n = max(1, int(serialization.MAX_CHUNK_SIZE / node.itemsize))
+            value({"__msgpack_chunked_array__": True,
+                   "shape": {str(i): d for i, d in enumerate(node.shape)},
+                   "chunks": {str(i): flat[at:at + n] for i, at in
+                              enumerate(range(0, flat.size, n))}},
+                  chunk=False)
+        else:
+            array(node)
+
+    value(state)
+    sizes = [p.nbytes if isinstance(p, np.ndarray) else len(p)
+             for p in pieces]
+    out = np.empty(sum(sizes), np.uint8)
+    at = tree_bytes = 0
+    for piece, n in zip(pieces, sizes):
+        if isinstance(piece, np.ndarray):
+            _copy_leaf(out[at:at + n], piece)
+            tree_bytes += n
+        else:
+            out[at:at + n] = np.frombuffer(piece, np.uint8)
+        at += n
+    return out, (1.0 + copied_before / tree_bytes if tree_bytes else 1.0)
 
 
 class Checkpoint:
@@ -49,15 +193,24 @@ class Checkpoint:
     @classmethod
     def from_pytree(cls, pytree: Any,
                     metrics: Optional[Dict[str, Any]] = None) -> "Checkpoint":
+        """The pytree as it is now, whole and encoded on return: later
+        writes to its arrays (or their donation) do not reach it."""
         import jax
-        from flax import serialization
 
         ckpt = cls(data={"metrics": metrics or {}})
         with _tm.span("train", "ckpt.from_pytree", ckpt=ckpt.id) as sp:
-            blob = serialization.to_bytes(pytree)
-            sp.args.update(bytes=len(blob),
-                           leaves=len(jax.tree_util.tree_leaves(pytree)))
-        ckpt._data["pytree_msgpack"] = blob
+            t0 = time.time()
+            with _tm.span("train", "ckpt.d2h", ckpt=ckpt.id):
+                state = _to_host(pytree)
+            t1 = time.time()
+            with _tm.span("train", "ckpt.encode", ckpt=ckpt.id):
+                blob, copies = _encode(state)
+            sp.args.update(bytes=blob.nbytes,
+                           leaves=len(jax.tree_util.tree_leaves(pytree)),
+                           d2h_ms=1e3 * (t1 - t0),
+                           encode_ms=1e3 * (time.time() - t1),
+                           copies=copies)
+        ckpt._data[_PYTREE] = blob
         return ckpt
 
     # -- accessors --------------------------------------------------------
@@ -94,7 +247,7 @@ class Checkpoint:
         os.makedirs(path, exist_ok=True)
         pickled: List[str] = []
         for key, value in self._data.items():
-            if isinstance(value, bytes):
+            if _is_raw(key, value):
                 blob = value
             else:
                 blob = pickle.dumps(value)
@@ -129,9 +282,8 @@ class Checkpoint:
         the structure)."""
         from flax import serialization
 
-        data = self.to_dict()
-        blob = data["pytree_msgpack"]
-        if not isinstance(blob, bytes):
+        blob = self.to_dict()[_PYTREE]
+        if not _is_raw(_PYTREE, blob):
             blob = pickle.loads(blob)
         return serialization.from_bytes(target, blob)
 
@@ -209,8 +361,8 @@ class CheckpointManager:
             self._entries.append((score, path, metrics))
             self._enforce_retention()
             sp.args.update(path=path, bytes=sum(
-                len(v) for v in (checkpoint._data or {}).values()
-                if isinstance(v, bytes)))
+                len(v) for k, v in (checkpoint._data or {}).items()
+                if _is_raw(k, v)))
         return path
 
     def _score(self, metrics: Dict[str, Any]) -> float:

@@ -191,17 +191,24 @@ def _loop(config):
         return jnp.tanh(x @ x.T).sum()
 
     x = jnp.ones((32, 32))
-    w = jnp.ones((2048, 2048))  # 16 MB: pickling it takes milliseconds
+    w = jnp.ones((2048, 2048))  # 16 MB: its reply goes through the store
     for i in range(2):
         session.report({"step": i, "loss": float(tiny_step(x))},
                        checkpoint=Checkpoint.from_pytree({"w": w}))
         time.sleep(0.2)
+    # a pytree's payload rides out of band, so serialising its reply is
+    # too quick to leave a row; ``bytes`` in a dict checkpoint are pickled
+    # in band, and 32 MB of them take milliseconds
+    session.report({"step": 2}, checkpoint=Checkpoint.from_dict(
+        {"blob": b"x" * (32 << 20)}))
+    time.sleep(0.2)
 
 
 @pytest.fixture(scope="module")
 def ended_run(tmp_path_factory):
-    """One one-worker CPU fit() whose loop reports two checkpoints, then
-    shutdown(): what is left is read by the tests below."""
+    """One one-worker CPU fit() whose loop reports two pytree checkpoints
+    and a dict one, then shutdown(): what is left is read by the tests
+    below."""
     from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
 
     ray_tpu.shutdown()
@@ -237,7 +244,10 @@ def _spans(ended_run, cat, name):
     ("xla", "trace", {"fun_name"}),
     ("xla", "lower", {"fun_name"}),
     ("xla", "backend_compile", {"fun_name"}),
-    ("train", "ckpt.from_pytree", {"ckpt", "bytes", "leaves"}),
+    ("train", "ckpt.from_pytree", {"ckpt", "bytes", "leaves", "d2h_ms",
+                                   "encode_ms", "copies"}),
+    ("train", "ckpt.d2h", {"ckpt"}),
+    ("train", "ckpt.encode", {"ckpt"}),
     ("train", "report", {"ckpt"}),
     ("train", "next_results", {"results", "ckpts", "waited_s"}),
     ("worker", "reply", {"fn", "bytes", "path"}),
@@ -277,6 +287,25 @@ def test_one_ckpt_id_joins_each_save_across_processes(ended_run):
         assert end(t["poll"]) <= t["ckpt.register"]["ts"]
         # worker side and driver side are different processes
         assert t["report"]["args"]["os_pid"] != t["poll"]["args"]["os_pid"]
+
+
+def test_from_pytree_names_its_two_children_and_its_copies(ended_run):
+    for top in _spans(ended_run, "train", "ckpt.from_pytree"):
+        kids = {e["name"]: e for e in ended_run["timeline"]
+                if e["cat"] == "train"
+                and e["args"].get("parent_id") == top["args"]["span_id"]
+                and e["args"]["os_pid"] == top["args"]["os_pid"]}
+        assert set(kids) == {"ckpt.d2h", "ckpt.encode"}
+        assert kids["ckpt.d2h"]["ts"] <= kids["ckpt.encode"]["ts"]
+        for arg, name in (("d2h_ms", "ckpt.d2h"),
+                          ("encode_ms", "ckpt.encode")):
+            assert kids[name]["args"]["ckpt"] == top["args"]["ckpt"]
+            assert abs(top["args"][arg] - kids[name]["dur"] / 1e3) < 20
+        assert top["args"]["copies"] == 1.0
+        assert top["args"]["bytes"] > 2048 * 2048 * 4
+        # the two are all of it
+        assert top["args"]["d2h_ms"] + top["args"]["encode_ms"] \
+            <= top["dur"] / 1e3 + 1
 
 
 def test_reply_children_nest_and_fast_inline_replies_leave_no_row(
