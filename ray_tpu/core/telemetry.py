@@ -607,6 +607,48 @@ def profiler_records_evicted(n: int) -> None:
 
 
 # ---------------------------------------------------------------------------
+# routed expert layers (models/afmoe.py)
+# ---------------------------------------------------------------------------
+
+_moe_keys: Dict[Tuple, Tuple] = {}
+
+
+def _moekey(model: str, layer: int, expert: Optional[int] = None) -> Tuple:
+    key = _moe_keys.get((model, layer, expert))
+    if key is None:
+        tags = (("model", model), ("layer", str(layer)))
+        if expert is not None:
+            tags += (("expert", str(expert)),)
+        key = _moe_keys[(model, layer, expert)] = tags
+    return key
+
+
+def moe_router_load(model: str, layer: int, load, landed_share: float,
+                    imbalance: float) -> None:
+    """What one routed expert layer saw in its last observed batch:
+    ``load[e]`` (token, choice) pairs that chose held expert ``e``, the
+    share of all pairs that landed on this layer's share of the experts,
+    and the largest load over the mean (1.0 is an even router; the
+    grouped products' time follows the sum, a straggling expert
+    parallel rank follows the largest)."""
+    if not enabled():
+        return
+    per = _gauge("ray_tpu_moe_expert_load",
+                 "(token, choice) pairs routed to a held expert in the "
+                 "last observed batch", ("model", "layer", "expert"))
+    for e, n in enumerate(load):
+        per.set_key(_moekey(model, layer, e), float(n))
+    key = _moekey(model, layer)
+    _gauge("ray_tpu_moe_landed_share",
+           "share of a batch's (token, choice) pairs that chose an expert "
+           "held by this layer's shard", ("model", "layer")).set_key(
+        key, float(landed_share))
+    _gauge("ray_tpu_moe_load_imbalance",
+           "largest held expert's load over the mean held load",
+           ("model", "layer")).set_key(key, float(imbalance))
+
+
+# ---------------------------------------------------------------------------
 # serving plane (serve/_internal.py, serve/batching.py, serve/http_proxy.py)
 # ---------------------------------------------------------------------------
 

@@ -1,0 +1,480 @@
+"""AFMoE decoder (arcee-ai Trinity family, ``model_type`` ``afmoe``) in
+flax linen, for the training path.
+
+What the block is (the public ``config.json`` keys fix the sizes; what
+they leave open is set by the family's convention and listed under
+``assumed`` in ``benchmarks/configs/trinity-mini.json``):
+
+* attention: grouped heads (``num_kv_heads`` K/V heads serve
+  ``num_heads`` query heads, inside the flash kernels, no copies), RMS
+  norm of q and k per head, RoPE on q and k in ``sliding_attention``
+  layers (window ``window``) and none in ``full_attention`` layers, the
+  output gated by ``sigmoid(W_g h)`` before ``W_o``;
+* every sub-layer between two RMS norms (``x + post(f(pre(x)))``);
+* leading dense layers with a SwiGLU MLP, then expert layers: a
+  sigmoid router over ALL published experts, the ``top_k`` largest, their
+  scores normalised over the chosen and scaled by ``route_scale``, one
+  shared SwiGLU expert for every token;
+* untied embedding and head, the embedding scaled by ``sqrt(embed_dim)``
+  (muP).
+
+**The layer is told which experts it holds** (``experts_held = (first,
+count)``, any contiguous share): it routes over all of them, computes
+its own experts' part for the tokens routed to them, adds the shared
+expert, and leaves out what absent experts would have added — nothing
+stands in for other chips.  Dispatch drops nothing at any imbalance:
+``ray_tpu.ops.grouped_matmul`` sorts the landed (token, choice) pairs by
+expert into a buffer sized for the worst case (every choice of every
+token lands here) and runs one grouped product per projection over the
+row tiles that are live, so the products' cost follows the rows that
+landed.  The ``expert`` logical axis stays on the parameters, so the same
+layer takes an ``ep`` mesh axis later; on one chip it runs without its
+exchange.
+
+Every layer runs its two parts over one sequence of the batch at a time
+(a kernel call a sequence, the activation memory of one sequence).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any, Dict, List, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.core import telemetry
+from ray_tpu.models.llama import RMSNorm
+from ray_tpu.ops import grouped_matmul as gm
+from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.fused import _rmsnorm_ref
+
+#: rows of one tile of the grouped products
+BLOCK_ROWS = 256
+
+
+@dataclasses.dataclass(frozen=True)
+class AFMoEConfig:
+    vocab_size: int = 200192
+    #: the sequence as run (the source's ``max_position_embeddings``,
+    #: 131072, only bounds it: RoPE needs no table)
+    max_seq_len: int = 8192
+    #: EXPERT layers; the leading dense layers are counted apart
+    num_layers: int = 30
+    num_dense_layers: int = 2
+    #: published index of the first expert layer as run: a cut model
+    #: skips to a whole period of the sliding/full pattern
+    expert_layer_start: Optional[int] = None
+    num_heads: int = 32
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    embed_dim: int = 2048
+    dense_dim: int = 6144
+    expert_dim: int = 1024
+    #: the router's width: all published experts, held here or not
+    num_experts: int = 128
+    top_k: int = 8
+    #: (first, count): the contiguous share of the experts held here
+    experts_held: Tuple[int, int] = (0, 128)
+    route_scale: float = 2.826
+    window: int = 2048
+    #: every n-th layer (published index + 1 divisible by n) is full
+    global_every: int = 4
+    rope_theta: float = 10000.0
+    rms_eps: float = 1e-5
+    mup: bool = True
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    #: scores, top-k and weights; float32 as the source's router
+    router_dtype: Any = jnp.float32
+    #: "" | "full": each part of a layer recomputed in the backward pass
+    remat: str = ""
+
+    @classmethod
+    def trinity_mini(cls, **kw) -> "AFMoEConfig":  # 26B, about 3B active
+        return cls(**kw)
+
+    @classmethod
+    def trinity_mini_share(cls, **kw) -> "AFMoEConfig":
+        """One chip's share of eight (``benchmarks/configs/
+        trinity-mini.json``): one dense layer and the whole period of
+        expert layers 4..7 (sliding, sliding, sliding, full), 16 of 128
+        experts, 25,024 of 200,192 vocabulary rows; every width as
+        published."""
+        defaults = dict(num_layers=4, num_dense_layers=1,
+                        expert_layer_start=4, experts_held=(0, 16),
+                        vocab_size=25024, max_seq_len=8192)
+        defaults.update(kw)
+        return cls(**defaults)
+
+    @classmethod
+    def tiny(cls, **kw) -> "AFMoEConfig":  # for tests
+        defaults = dict(vocab_size=256, max_seq_len=64, num_layers=2,
+                        num_dense_layers=1, expert_layer_start=2,
+                        num_heads=4, num_kv_heads=2, head_dim=16,
+                        embed_dim=32, dense_dim=64, expert_dim=32,
+                        num_experts=8, top_k=2, experts_held=(0, 8),
+                        window=24)
+        defaults.update(kw)
+        return cls(**defaults)
+
+    @property
+    def kv_heads(self) -> int:
+        """K/V heads as run: the published count where it divides the
+        query heads (a test that cuts the heads keeps a valid group)."""
+        return math.gcd(self.num_heads, self.num_kv_heads)
+
+    def layer_kinds(self) -> List[str]:
+        """``sliding`` or ``full`` for every layer as run, dense layers
+        first, by the published index of each."""
+        start = self.num_dense_layers if self.expert_layer_start is None \
+            else self.expert_layer_start
+        index = list(range(self.num_dense_layers)) + [
+            start + i for i in range(self.num_layers)]
+        return ["full" if (j + 1) % self.global_every == 0 else "sliding"
+                for j in index]
+
+    def plan_args(self, tokens: int) -> Dict[str, Any]:
+        """What was compiled, for the ``moe.plan`` span."""
+        return {"experts": self.num_experts,
+                "held_first": self.experts_held[0],
+                "held": self.experts_held[1], "top_k": self.top_k,
+                "row_bound": tokens * self.top_k,
+                "block_rows": BLOCK_ROWS, "window": self.window,
+                "heads": self.num_heads, "kv_heads": self.kv_heads,
+                "layers": ",".join(k[0] for k in self.layer_kinds())}
+
+
+def _rope(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary embedding of ``[B, T, H, D]`` at positions ``0 .. T-1``:
+    pairs ``(x[i], x[i + D/2])`` rotated by ``t * theta^(-2i/D)``."""
+    dim, seq = x.shape[-1], x.shape[1]
+    freqs = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * freqs[None]
+    cos = jnp.cos(angles)[None, :, None, :]
+    sin = jnp.sin(angles)[None, :, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                           axis=-1).astype(x.dtype)
+
+
+class _HeadNorm(nn.Module):
+    """RMS norm over ``head_dim`` of ``[B, T, H, D]``, one learned scale
+    for all heads (the fused kernel takes rows of the model's width; XLA
+    fuses this one into its neighbours)."""
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.param("scale", nn.with_partitioning(
+            nn.initializers.ones, (None,)), (x.shape[-1],), jnp.float32)
+        return _rmsnorm_ref(x, w, self.eps)
+
+
+def _dense(cfg: AFMoEConfig, features: int, name: str, axes: tuple):
+    return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype,
+                    kernel_init=nn.with_partitioning(
+                        nn.initializers.normal(0.02), axes), name=name)
+
+
+def _swiglu(cfg: AFMoEConfig, h, width: int, prefix: str):
+    gate = _dense(cfg, width, prefix + "gate", ("embed", "mlp"))(h)
+    up = _dense(cfg, width, prefix + "up", ("embed", "mlp"))(h)
+    return _dense(cfg, cfg.embed_dim, prefix + "down",
+                  ("mlp", "embed"))(nn.silu(gate) * up)
+
+
+def route(cfg: AFMoEConfig, h: jax.Array, w_router: jax.Array,
+          chosen: Optional[jax.Array] = None):
+    """Sigmoid scores (float32 by default) over all published experts,
+    the ``top_k`` largest, weights normalised over the chosen and scaled.
+    ``h [T, E]`` -> ``(expert ids [T, k], weights [T, k] f32, the
+    router's own choice [T, k])``.  ``chosen [T, k]``: a recorded routing
+    to replay, in place of the router's own largest (the weights are
+    still its scores')."""
+    logits = jnp.dot(h.astype(cfg.router_dtype),
+                     w_router.astype(cfg.router_dtype),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    top, own = jax.lax.top_k(scores, cfg.top_k)
+    idx = own
+    if chosen is not None:
+        idx, top = chosen, jnp.take_along_axis(scores, chosen, axis=1)
+    top = top.astype(jnp.float32)
+    return idx, cfg.route_scale * top / top.sum(-1, keepdims=True), own
+
+
+class RoutedExperts(nn.Module):
+    """The routed part of an expert layer, for the share held here."""
+    config: AFMoEConfig
+
+    @nn.compact
+    def __call__(self, h: jax.Array,
+                 chosen: Optional[jax.Array] = None) -> jax.Array:
+        cfg = self.config
+        first, held = cfg.experts_held
+        batch, seq, embed = h.shape
+        flat = h.reshape(batch * seq, embed)
+        w_router = self.param(
+            "router", nn.with_partitioning(nn.initializers.normal(0.02),
+                                           ("embed", None)),
+            (embed, cfg.num_experts), cfg.param_dtype)
+
+        def experts(name, shape, axes):
+            return self.param(
+                name, nn.with_partitioning(nn.initializers.normal(0.02),
+                                           axes), shape,
+                cfg.param_dtype).astype(cfg.dtype)
+
+        w_gate = experts("experts_gate", (held, embed, cfg.expert_dim),
+                         ("expert", "embed", "mlp"))
+        w_up = experts("experts_up", (held, embed, cfg.expert_dim),
+                       ("expert", "embed", "mlp"))
+        w_down = experts("experts_down", (held, cfg.expert_dim, embed),
+                         ("expert", "mlp", "embed"))
+
+        with jax.named_scope("moe.route"):
+            idx, weights, own = route(cfg, flat, w_router, chosen)
+            # buffers for the worst case: every pair may land here
+            plan = gm.plan_rows(idx, first, held, block_m=BLOCK_ROWS)
+        self.sow("intermediates", "expert_load", plan.sizes)
+        self.sow("intermediates", "expert_choice", own)
+        with jax.named_scope("moe.dispatch"):
+            rows = gm.dispatch(flat, plan)
+        with jax.named_scope("moe.experts"):
+            gate = gm.grouped_matmul(rows, w_gate, plan)
+            up = gm.grouped_matmul(rows, w_up, plan)
+            out = gm.grouped_matmul(nn.silu(gate) * up, w_down, plan)
+        with jax.named_scope("moe.combine"):
+            routed = gm.combine(out, weights, plan)
+        return routed.astype(cfg.dtype).reshape(batch, seq, embed)
+
+
+class AttentionPart(nn.Module):
+    """``x + post_norm(attention(pre_norm(x)))``."""
+    config: AFMoEConfig
+    kind: str      # "sliding" | "full"
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        from ray_tpu.parallel.mesh import get_global_mesh
+
+        cfg = self.config
+        batch, seq = x.shape[:2]
+        heads, kv, dim = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+        norm = functools.partial(RMSNorm, cfg.rms_eps)
+
+        h = norm(name="attn_norm")(x)
+        q = _dense(cfg, heads * dim, "wq", ("embed", "heads"))(h)
+        k = _dense(cfg, kv * dim, "wk", ("embed", "kv"))(h)
+        v = _dense(cfg, kv * dim, "wv", ("embed", "kv"))(h)
+        gate = _dense(cfg, heads * dim, "wg", ("embed", "heads"))(h)
+        q = _HeadNorm(cfg.rms_eps, name="q_norm")(
+            q.reshape(batch, seq, heads, dim))
+        k = _HeadNorm(cfg.rms_eps, name="k_norm")(
+            k.reshape(batch, seq, kv, dim))
+        v = v.reshape(batch, seq, kv, dim)
+        sliding = self.kind == "sliding"
+        if sliding:
+            q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
+        with jax.named_scope("attn." + self.kind):
+            attn = flash_attention(
+                q, k, v, causal=True, mesh=get_global_mesh(),
+                window=cfg.window if sliding else None)
+        attn = attn.reshape(batch, seq, heads * dim) * nn.sigmoid(gate)
+        attn = _dense(cfg, cfg.embed_dim, "wo", ("heads", "embed"))(attn)
+        return x + norm(name="attn_post_norm")(attn)
+
+
+class MLPPart(nn.Module):
+    """``x + post_norm(mlp(pre_norm(x)))``: the dense SwiGLU of a leading
+    layer, or the shared expert plus the routed experts held here."""
+    config: AFMoEConfig
+    routed: bool
+
+    @nn.compact
+    def __call__(self, x: jax.Array,
+                 chosen: Optional[jax.Array] = None) -> jax.Array:
+        cfg = self.config
+        h = RMSNorm(cfg.rms_eps, name="mlp_norm")(x)
+        if self.routed:
+            out = _swiglu(cfg, h, cfg.expert_dim, "shared_") + \
+                RoutedExperts(cfg, name="moe")(h, chosen)
+        else:
+            out = _swiglu(cfg, h, cfg.dense_dim, "w_")
+        return x + RMSNorm(cfg.rms_eps, name="mlp_post_norm")(out)
+
+
+class AFMoEBlock(nn.Module):
+    """One layer: its two parts, each over one sequence at a time and
+    each recomputed on its own in the backward pass under ``remat``: the
+    backward of a part then holds that part's activations for 8,192
+    tokens alone (both parts over a batch of two are 3 GiB, which the
+    training state leaves no room for beside a gradient check)."""
+    config: AFMoEConfig
+    kind: str      # "sliding" | "full"
+    routed: bool   # an expert layer, or a leading dense one
+
+    @nn.compact
+    def __call__(self, x: jax.Array,
+                 chosen: Optional[jax.Array] = None) -> jax.Array:
+        cfg = self.config
+        attn, mlp = AttentionPart, MLPPart
+        if cfg.remat == "full":
+            attn, mlp = nn.remat(attn), nn.remat(mlp)
+        attn = attn(cfg, self.kind, name="attn")
+        mlp = mlp(cfg, self.routed, name="mlp")
+        seq = x.shape[1]
+        out = []
+        for i in range(x.shape[0]):
+            h = attn(x[i:i + 1])
+            out.append(mlp(h) if chosen is None else
+                       mlp(h, chosen[i * seq:(i + 1) * seq]))
+        return jnp.concatenate(out)
+
+
+class AFMoE(nn.Module):
+    config: AFMoEConfig
+
+    @nn.compact
+    def hidden(self, tokens: jax.Array,
+               choices: Optional[List[jax.Array]] = None):
+        """Final normed hidden states (float32) and the untied head
+        ``[V, E]``: the loss runs them through the chunked LM head.
+        ``choices``: per expert layer the experts ``[B*T, k]`` to route
+        every token to, as :func:`router_choices` gives them, in place
+        of the routers' own."""
+        cfg = self.config
+        embed = self.param(
+            "embed", nn.with_partitioning(nn.initializers.normal(0.02),
+                                          ("vocab", "embed")),
+            (cfg.vocab_size, cfg.embed_dim), cfg.param_dtype)
+        head = self.param(
+            "head", nn.with_partitioning(nn.initializers.normal(0.02),
+                                         ("vocab", "embed")),
+            (cfg.vocab_size, cfg.embed_dim), cfg.param_dtype)
+        x = embed.astype(cfg.dtype)[tokens]
+        if cfg.mup:
+            x = x * jnp.asarray(math.sqrt(cfg.embed_dim), cfg.dtype)
+        # the timeline says what was compiled: one span around the trace
+        # of the layers (a call of a layer sees one sequence)
+        with telemetry.span("model", "moe.plan",
+                            **cfg.plan_args(tokens.shape[1])):
+            for n, kind in enumerate(cfg.layer_kinds()):
+                i = n - cfg.num_dense_layers
+                block = AFMoEBlock(cfg, kind, i >= 0,
+                                   name=f"h{i}" if i >= 0 else f"dense{n}")
+                x = block(x) if choices is None or i < 0 \
+                    else block(x, choices[i])
+        x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
+        return x.astype(jnp.float32), head
+
+    def __call__(self, tokens: jax.Array) -> jax.Array:
+        x, head = self.hidden(tokens)
+        return jnp.einsum("bte,ve->btv", x, head.astype(jnp.float32))
+
+    def init_params(self, rng: jax.Array, batch: int = 1,
+                    seq: Optional[int] = None):
+        seq = seq or self.config.max_seq_len
+        tokens = jnp.zeros((batch, seq), jnp.int32)
+        return self.init(rng, tokens)["params"]
+
+
+def loss_fn(model: AFMoE, params, tokens: jax.Array,
+            head_chunk: int = 2048,
+            head_logits_dtype: Any = None,
+            choices: Optional[List[jax.Array]] = None,
+            with_choices: bool = False):
+    """Next-token cross entropy over the vocabulary (slice) through the
+    chunked LM head; float32 logits unless ``head_logits_dtype`` says
+    otherwise.  The source's auxiliary load-balance term is left out
+    (``assumed`` in the configuration).  ``choices``: a recorded routing
+    to replay (:meth:`AFMoE.hidden`).  ``with_choices``: also what every
+    expert layer's router chose ITSELF on the way, ``[B*T, k]`` a layer,
+    as :func:`router_choices` gives it."""
+    from ray_tpu.ops.fused import chunked_lm_loss
+
+    out = model.apply({"params": params}, tokens, choices,
+                      method=AFMoE.hidden,
+                      mutable=["intermediates"] if with_choices else False)
+    (x, head), state = out if with_choices else (out, None)
+    compute = jnp.bfloat16 if model.config.dtype == jnp.bfloat16 else None
+    loss = chunked_lm_loss(x[:, :-1], head, tokens[:, 1:],
+                           chunk=head_chunk, compute_dtype=compute,
+                           logits_dtype=head_logits_dtype)
+    return (loss, _own_choices(model, state)) if with_choices else loss
+
+
+def _own_choices(model: AFMoE, state) -> List[jax.Array]:
+    # sown once a call, and a call sees one sequence
+    return [jnp.concatenate(
+        state["intermediates"][f"h{i}"]["mlp"]["moe"]["expert_choice"])
+        for i in range(model.config.num_layers)]
+
+
+def make_train_step(model: AFMoE, tx):
+    """The donated ``(params, opt_state, tokens) -> (params, opt_state,
+    loss)`` step, as GPT-2's."""
+    import optax
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def train_step(params, opt_state, tokens):
+        loss, grads = jax.value_and_grad(
+            lambda p: loss_fn(model, p, tokens))(params)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    return train_step
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def router_stats(model: AFMoE, params, tokens: jax.Array
+                 ) -> Dict[str, jax.Array]:
+    """What a routed layer must tell its operator, per expert layer (in
+    order): ``load [L, held]`` (token, choice) pairs that chose each held
+    expert; ``landed_share [L]`` of all pairs that land here (an even
+    router gives ``held / experts``); ``imbalance [L]`` largest load over
+    mean load.  For a training loop to pass to
+    :func:`report_router_stats` and ``session.report``."""
+    cfg = model.config
+    _, state = model.apply({"params": params}, tokens,
+                           method=AFMoE.hidden, mutable=["intermediates"])
+    layers = state["intermediates"]
+    # sown once a call, and a call sees one sequence
+    load = jnp.stack([
+        sum(layers[f"h{i}"]["mlp"]["moe"]["expert_load"])
+        for i in range(cfg.num_layers)]).astype(jnp.float32)
+    pairs = tokens.shape[0] * tokens.shape[1] * cfg.top_k
+    return {"load": load, "landed_share": load.sum(-1) / pairs,
+            "imbalance": load.max(-1) / jnp.maximum(load.mean(-1), 1e-9)}
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def router_choices(model: AFMoE, params, tokens: jax.Array
+                   ) -> List[jax.Array]:
+    """The experts every expert layer's router chose, ``[B*T, k]`` a
+    layer: for comparing a near tie with a reference's own choice."""
+    _, state = model.apply({"params": params}, tokens,
+                           method=AFMoE.hidden, mutable=["intermediates"])
+    return _own_choices(model, state)
+
+
+def report_router_stats(stats: Dict[str, Any], model_name: str = "afmoe"
+                        ) -> Dict[str, float]:
+    """Host side: the stats as gauges, and as flat scalars for
+    ``session.report``."""
+    import numpy as np
+
+    out: Dict[str, float] = {}
+    for layer, (load, share, imb) in enumerate(zip(
+            np.asarray(stats["load"]), np.asarray(stats["landed_share"]),
+            np.asarray(stats["imbalance"]))):
+        telemetry.moe_router_load(model_name, layer, load.tolist(),
+                                  float(share), float(imb))
+        out[f"moe/h{layer}/landed_share"] = float(share)
+        out[f"moe/h{layer}/imbalance"] = float(imb)
+    return out

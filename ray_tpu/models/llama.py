@@ -111,16 +111,21 @@ class LlamaBlock(nn.Module):
                 v_cache, v.astype(v_cache.dtype), (0, cache_len, 0, 0))
             new_cache = (k, v, cache_len + seq)
 
-        repeat = cfg.num_heads // cfg.num_kv_heads
-        if repeat > 1:
-            k = jnp.repeat(k, repeat, axis=2)
-            v = jnp.repeat(v, repeat, axis=2)
-
         if kv_cache is not None:
-            # decode path: mask positions beyond cache_len + seq
+            # decode path: mask positions beyond cache_len + seq; the
+            # cached K/V heads are copied out to the query heads here
+            repeat = cfg.num_heads // cfg.num_kv_heads
+            if repeat > 1:
+                k = jnp.repeat(k, repeat, axis=2)
+                v = jnp.repeat(v, repeat, axis=2)
             attn = _decode_attention(q, k, v, positions, head_dim)
         else:
-            attn = flash_attention(q, k, v, causal=True)
+            from ray_tpu.parallel.mesh import get_global_mesh
+
+            # grouped heads inside the kernel: query head h reads K/V
+            # head h // group, no copies of K and V
+            attn = flash_attention(q, k, v, causal=True,
+                                   mesh=get_global_mesh())
         attn = attn.reshape(batch, seq, cfg.num_heads * head_dim)
         x = x + dense(cfg.embed_dim, "wo", ("heads", "embed"))(attn)
 

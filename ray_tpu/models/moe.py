@@ -179,8 +179,11 @@ class MoEBlock(nn.Module):
             attn = _attention_reference(heads(q), heads(k), heads(v),
                                         True, head_dim ** -0.5)
         else:
+            from ray_tpu.parallel.mesh import get_global_mesh
+
+            # under a multi-device mesh the kernel runs per shard
             attn = flash_attention(heads(q), heads(k), heads(v),
-                                   causal=True)
+                                   causal=True, mesh=get_global_mesh())
         attn = attn.reshape(B, T, cfg.embed_dim)
         x = x + _dense(cfg.embed_dim, g, "attn_proj",
                        ("heads", "embed"))(attn)

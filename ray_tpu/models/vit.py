@@ -86,8 +86,11 @@ class EncoderBlock(nn.Module):
             attn = _attention_reference(heads(q), heads(k), heads(v),
                                         False, head_dim ** -0.5)
         else:
+            from ray_tpu.parallel.mesh import get_global_mesh
+
+            # under a multi-device mesh the kernel runs per shard
             attn = flash_attention(heads(q), heads(k), heads(v),
-                                   causal=False)
+                                   causal=False, mesh=get_global_mesh())
         attn = attn.reshape(B, T, cfg.embed_dim)
         x = x + _dense(cfg.embed_dim, cfg, "attn_proj",
                        ("heads", "embed"))(attn)

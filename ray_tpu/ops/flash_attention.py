@@ -12,6 +12,18 @@ kernel per shard.
 
 Matmul operands stay in the input dtype (bf16 on TPU) with f32
 accumulation via ``preferred_element_type`` — the MXU's native mode.
+
+Two static extras, both off by default (the default traces the kernels
+body for body as before they existed):
+
+* ``window``: position ``t`` sees keys ``t - window + 1 .. t``.  Tiles
+  wholly outside the window are skipped the way tiles above the
+  diagonal are (compute gated off, index maps clamped so the DMA is
+  elided); tiles that straddle its edge are masked.
+* grouped heads: ``k``/``v`` may carry fewer heads than ``q``.  Query
+  head ``h`` reads K/V head ``h // group`` through the block index — no
+  copies of K and V in HBM — and the dK/dV kernel walks the ``group``
+  query heads of its K/V head along its sequential axis.
 """
 
 from __future__ import annotations
@@ -26,22 +38,62 @@ from jax import lax
 NEG_INF = -1e30
 
 
-def _clamp_k_tile(j, i, block_q: int, block_k: int):
+def _clamp_k_tile(j, i, block_q: int, block_k: int,
+                  window: Optional[int] = None):
     """Causal DMA elision: clamp streaming K-tile index ``j`` to the last
     tile intersecting Q-tile ``i``'s causal triangle — fully-masked grid
     steps then revisit the previous block and pallas skips the copy."""
-    return jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
+    last = jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
+    if window is None:
+        return last
+    # ... and from below to the first tile inside the window of the
+    # tile's earliest query
+    return jnp.maximum(
+        last, jnp.maximum(i * block_q - (window - 1), 0) // block_k)
 
 
-def _clamp_q_tile(j, i, block_q: int, block_k: int):
+def _clamp_q_tile(j, i, block_q: int, block_k: int,
+                  window: Optional[int] = None):
     """Causal DMA elision, reversed grid: clamp streaming Q-tile index
     ``j`` to the first tile intersecting K-tile ``i``'s causal triangle."""
     jmin = -((block_q - 1 - i * block_k) // block_q)
-    return jnp.maximum(j, jnp.maximum(jmin, 0))
+    first = jnp.maximum(j, jnp.maximum(jmin, 0))
+    if window is None:
+        return first
+    # ... and from above to the last Q tile that still sees the K
+    # tile's last key through the window
+    return jnp.minimum(
+        first, (i * block_k + block_k - 1 + window - 1) // block_q)
+
+
+def _keep_mask(q_offset, k_offset, block_q: int, block_k: int,
+               window: Optional[int] = None):
+    """[block_q, block_k] bool: key visible to query (causal, and inside
+    the window when there is one)."""
+    q_pos = q_offset + lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
+    k_pos = k_offset + lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep = jnp.logical_and(keep, q_pos - k_pos < window)
+    return keep
+
+
+def _tile_live(causal: bool, q_offset, k_offset, block_q: int,
+               block_k: int, window: Optional[int] = None):
+    """Does the (Q tile, K tile) pair hold any visible (query, key)?
+    Not above the diagonal, and not wholly before the window of the
+    tile's earliest query."""
+    live = jnp.logical_or(not causal, k_offset <= q_offset + block_q - 1)
+    if window is not None:
+        live = jnp.logical_and(
+            live, k_offset + block_k - 1 >= q_offset - (window - 1))
+    return live
 
 
 def _causal_dispatch(causal: bool, q_offset, k_offset, block_q: int,
-                     block_k: int, tile):
+                     block_k: int, tile, window: Optional[int] = None):
     """Run ``tile(apply_mask)`` under the causal tile classification:
     diagonal-straddling tiles get the (iota + compare + select) causal
     mask, fully-visible tiles skip it, fully-masked tiles run nothing.
@@ -55,16 +107,36 @@ def _causal_dispatch(causal: bool, q_offset, k_offset, block_q: int,
     straddles = jnp.logical_and(k_offset <= q_offset + block_q - 1,
                                 k_offset + block_k - 1 > q_offset)
     fully_visible = k_offset + block_k - 1 <= q_offset
+    if window is not None:
+        # the window's lower edge cuts through the tile when its first
+        # key lies before the window of the tile's LAST query
+        live = _tile_live(True, q_offset, k_offset, block_q, block_k,
+                          window)
+        edge = k_offset < q_offset + block_q - window
+        straddles = jnp.logical_and(
+            live, jnp.logical_or(straddles,
+                                 jnp.logical_and(fully_visible, edge)))
+        fully_visible = jnp.logical_and(
+            live, jnp.logical_and(fully_visible, jnp.logical_not(edge)))
     pl.when(straddles)(lambda: tile(True))
     pl.when(fully_visible)(lambda: tile(False))
 
 
-def _attention_reference(q, k, v, causal: bool, scale: float) -> jax.Array:
+def _attention_reference(q, k, v, causal: bool, scale: float,
+                         window: Optional[int] = None) -> jax.Array:
+    group = q.shape[2] // k.shape[2]
+    if group > 1:  # query head h reads K/V head h // group
+        k = jnp.repeat(k, group, axis=2)
+        v = jnp.repeat(v, group, axis=2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
         tq, tk = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((tq, tk), bool), tk - tq)
+        if window is not None:
+            mask = jnp.logical_and(
+                mask, jnp.triu(jnp.ones((tq, tk), bool),
+                               tk - tq - (window - 1)))
         s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
@@ -73,7 +145,7 @@ def _attention_reference(q, k, v, causal: bool, scale: float) -> jax.Array:
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                acc_ref, *, scale: float, causal: bool, block_q: int,
-               block_k: int):
+               block_k: int, window: Optional[int] = None):
     """Forward tile program: grid (B, H, q_tiles, k_tiles); the k axis
     is sequential ("arbitrary"), so the online-softmax stats live in
     VMEM scratch across its iterations.  Only one K/V tile is resident
@@ -97,7 +169,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     # compute is gated off here, and the K/V index maps clamp those grid
     # steps to the diagonal tile so their DMAs are skipped too (pallas
     # elides the copy when consecutive steps map to the same block)
-    @pl.when(jnp.logical_or(not causal, k_offset <= q_offset + block_q - 1))
+    @pl.when(_tile_live(causal, q_offset, k_offset, block_q, block_k,
+                        window))
     def _compute():
         q = q_ref[:]
         k = k_ref[:]
@@ -106,11 +179,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if causal:
-            q_pos = q_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            s = jnp.where(_keep_mask(q_offset, k_offset, block_q, block_k,
+                                     window), s, NEG_INF)
         m = m_ref[:][:, 0]
         l = l_ref[:][:, 0]
         m_new = jnp.maximum(m, s.max(axis=-1))
@@ -137,14 +207,20 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         ).astype(jnp.float32)[:, None]
 
 
+def _kv_head(h, group: int):
+    """Block index of the K/V head that query head ``h`` reads."""
+    return h if group == 1 else h // group
+
+
 def _flash_forward(q, k, v, causal: bool, scale: float,
                    block_q: int, block_k: int, interpret: bool,
-                   out_dtype=None):
+                   out_dtype=None, window: Optional[int] = None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     batch, seq_q, heads, dim = q.shape
     seq_k = k.shape[1]
+    group = heads // k.shape[2]
     # pallas layout: [B, H, T, D]
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -158,16 +234,18 @@ def _flash_forward(q, k, v, causal: bool, scale: float,
 
     grid = (batch, heads, seq_q // block_q, seq_k // block_k)
     kernel = functools.partial(_fa_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k)
+                               block_q=block_q, block_k=block_k,
+                               window=window)
 
     if causal:
         # above-diagonal K/V tiles are fully masked — causal touches
         # ~half the tiles' bandwidth instead of all of them
         def kv_idx(b, h, i, j):
-            return (b, h, _clamp_k_tile(j, i, block_q, block_k), 0)
+            return (b, _kv_head(h, group),
+                    _clamp_k_tile(j, i, block_q, block_k, window), 0)
     else:
         def kv_idx(b, h, i, j):
-            return (b, h, j, 0)
+            return (b, _kv_head(h, group), j, 0)
 
     out, lse = pl.pallas_call(
         kernel,
@@ -203,8 +281,11 @@ def _flash_forward(q, k, v, causal: bool, scale: float,
 
 def _fa_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                         dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
-                        causal: bool, block_q: int, block_k: int):
-    """dK/dV: grid (B, H, k_tiles, q_tiles); the q axis is sequential
+                        causal: bool, block_q: int, block_k: int,
+                        window: Optional[int] = None, q_tiles: int = 0):
+    """dK/dV: grid (B, Hkv, k_tiles, group * q_tiles); the last axis is
+    sequential — the Q tiles of each query head of the K/V head's group
+    in turn (``q_tiles`` is given when the group has more than one) —
     with the dK/dV accumulators in scratch."""
     from jax.experimental import pallas as pl
 
@@ -218,10 +299,13 @@ def _fa_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     k_offset = ik * block_k
-    q_offset = iq * block_q
+    q_offset = (iq % q_tiles if q_tiles else iq) * block_q
+    live = jnp.logical_or(not causal, q_offset + block_q - 1 >= k_offset)
+    if window is not None:
+        live = _tile_live(causal, q_offset, k_offset, block_q, block_k,
+                          window)
 
-    @pl.when(jnp.logical_or(not causal,
-                            q_offset + block_q - 1 >= k_offset))
+    @pl.when(live)
     def _compute():
         k = k_ref[:]
         v = v_ref[:]
@@ -233,11 +317,8 @@ def _fa_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if causal:
-            q_pos = q_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            s = jnp.where(_keep_mask(q_offset, k_offset, block_q, block_k,
+                                     window), s, NEG_INF)
         lse = jnp.where(lse <= NEG_INF / 2, 0.0, lse)  # [bq] clamp: keeps
         p = jnp.exp(s - lse[:, None])  # fully-masked rows at p == 0
         dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
@@ -259,7 +340,8 @@ def _fa_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dq_acc, *, scale: float, causal: bool,
-                      block_q: int, block_k: int):
+                      block_q: int, block_k: int,
+                      window: Optional[int] = None):
     """dQ: grid (B, H, q_tiles, k_tiles); k sequential, dQ in scratch."""
     from jax.experimental import pallas as pl
 
@@ -274,8 +356,8 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q_offset = iq * block_q
     k_offset = ik * block_k
 
-    @pl.when(jnp.logical_or(not causal,
-                            k_offset <= q_offset + block_q - 1))
+    @pl.when(_tile_live(causal, q_offset, k_offset, block_q, block_k,
+                        window))
     def _compute():
         q = q_ref[:]
         do = do_ref[:]
@@ -287,11 +369,8 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if causal:
-            q_pos = q_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            s = jnp.where(_keep_mask(q_offset, k_offset, block_q, block_k,
+                                     window), s, NEG_INF)
         lse = jnp.where(lse <= NEG_INF / 2, 0.0, lse)  # [bq] clamp: keeps
         p = jnp.exp(s - lse[:, None])  # fully-masked rows at p == 0
         dp = jax.lax.dot_general(
@@ -308,14 +387,18 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
-                    interpret, grad_dtype=None, delta=None):
+                    interpret, grad_dtype=None, delta=None,
+                    window: Optional[int] = None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     batch, seq_q, heads, dim = q.shape
     seq_k = k.shape[1]
+    kv_heads = k.shape[2]
+    group = heads // kv_heads
     block_q = min(block_q, seq_q)
     block_k = min(block_k, seq_k)
+    n_q = seq_q // block_q
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
@@ -331,19 +414,28 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         dimension_semantics=("parallel", "parallel", "parallel",
                              "arbitrary"))
 
+    def q_of(h, j):
+        """dK/dV grid -> (query head, Q tile): K/V head ``h`` walks its
+        group's query heads one after the other along ``j``."""
+        return (h, j) if group == 1 else (h * group + j // n_q, j % n_q)
+
     # causal DMA elision (same trick as the forward)
     if causal:
         def q_idx_rev(b, h, i, j):  # dK/dV grid: i = k tile, j = q tile
-            return (b, h, _clamp_q_tile(j, i, block_q, block_k), 0)
+            hq, jq = q_of(h, j)
+            return (b, hq,
+                    _clamp_q_tile(jq, i, block_q, block_k, window), 0)
 
         def kv_idx_fwd(b, h, i, j):  # dQ grid: i = q tile, j = k tile
-            return (b, h, _clamp_k_tile(j, i, block_q, block_k), 0)
+            return (b, _kv_head(h, group),
+                    _clamp_k_tile(j, i, block_q, block_k, window), 0)
     else:
         def q_idx_rev(b, h, i, j):
-            return (b, h, j, 0)
+            hq, jq = q_of(h, j)
+            return (b, hq, jq, 0)
 
         def kv_idx_fwd(b, h, i, j):
-            return (b, h, j, 0)
+            return (b, _kv_head(h, group), j, 0)
 
     tile_q = pl.BlockSpec((None, None, block_q, dim), q_idx_rev)
     tile_k_rev = pl.BlockSpec((None, None, block_k, dim),
@@ -351,10 +443,11 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     rows_q_rev = pl.BlockSpec((None, None, block_q, 1), q_idx_rev)
     dkdv = functools.partial(_fa_bwd_dkdv_kernel, scale=scale,
                              causal=causal, block_q=block_q,
-                             block_k=block_k)
+                             block_k=block_k, window=window,
+                             q_tiles=0 if group == 1 else n_q)
     dk, dv = pl.pallas_call(
         dkdv,
-        grid=(batch, heads, seq_k // block_k, seq_q // block_q),
+        grid=(batch, kv_heads, seq_k // block_k, group * n_q),
         in_specs=[tile_q, tile_k_rev, tile_k_rev, tile_q, rows_q_rev,
                   rows_q_rev],
         out_specs=[tile_k_rev, tile_k_rev],
@@ -373,7 +466,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                               lambda b, h, i, j: (b, h, i, 0))
     dq_kernel = functools.partial(_fa_bwd_dq_kernel, scale=scale,
                                   causal=causal, block_q=block_q,
-                                  block_k=block_k)
+                                  block_k=block_k, window=window)
     dq = pl.pallas_call(
         dq_kernel,
         grid=(batch, heads, seq_q // block_q, seq_k // block_k),
@@ -438,7 +531,8 @@ def _head_sel(pack: int, dim: int, rows: int):
 
 def _fa_nl_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                   scale: float, causal: bool, block_q: int,
-                  block_k: int, pack: int, dim: int):
+                  block_k: int, pack: int, dim: int,
+                  window: Optional[int] = None):
     """Native-layout forward: grid (B, H2, q_tiles, k_tiles), k sequential.
 
     Refs are [block, pack*dim] slabs; head ``h`` of the slab lives in
@@ -471,11 +565,8 @@ def _fa_nl_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         k = k_ref[:]
         v = v_ref[:]
         if apply_mask:
-            q_pos = q_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            causal_keep = q_pos >= k_pos
+            causal_keep = _keep_mask(q_offset, k_offset, block_q, block_k,
+                                     window)
         corrs = []
         pvs = []
         for h in range(pack):
@@ -507,7 +598,8 @@ def _fa_nl_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             acc_ref[:] = (acc_ref[:] * jnp.where(sel, corrs[0], corrs[1])
                           + jnp.where(sel, pvs[0], pvs[1]))
 
-    _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile)
+    _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile,
+                     window)
 
     @pl.when(ik == n_k - 1)
     def _finish():
@@ -532,7 +624,7 @@ def _fa_nl_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
 
 def _flash_nl_forward(q, k, v, causal: bool, scale: float,
                       block_q: int, block_k: int, interpret: bool,
-                      out_dtype=None):
+                      out_dtype=None, window: Optional[int] = None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -540,10 +632,13 @@ def _flash_nl_forward(q, k, v, causal: bool, scale: float,
     seq_k = k.shape[1]
     pack = 128 // dim
     h2 = heads // pack
+    # grouped heads (one head a slab only, ``_nl_eligible``): query
+    # slab h reads K/V slab h // group
+    group = heads // k.shape[2]
     # free reshapes: collapse the contiguous minor dims
     qr = q.reshape(batch, seq_q, h2 * pack * dim)
-    kr = k.reshape(batch, seq_k, h2 * pack * dim)
-    vr = v.reshape(batch, seq_k, h2 * pack * dim)
+    kr = k.reshape(batch, seq_k, k.shape[2] * dim)
+    vr = v.reshape(batch, seq_k, k.shape[2] * dim)
 
     block_q = min(block_q, seq_q)
     block_k = min(block_k, seq_k)
@@ -554,14 +649,15 @@ def _flash_nl_forward(q, k, v, causal: bool, scale: float,
     grid = (batch, h2, seq_q // block_q, seq_k // block_k)
     kernel = functools.partial(_fa_nl_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
-                               pack=pack, dim=dim)
+                               pack=pack, dim=dim, window=window)
 
     if causal:
         def kv_idx(b, h, i, j):
-            return (b, _clamp_k_tile(j, i, block_q, block_k), h)
+            return (b, _clamp_k_tile(j, i, block_q, block_k, window),
+                    _kv_head(h, group))
     else:
         def kv_idx(b, h, i, j):
-            return (b, j, h)
+            return (b, j, _kv_head(h, group))
 
     out, lse = pl.pallas_call(
         kernel,
@@ -598,8 +694,11 @@ def _flash_nl_forward(q, k, v, causal: bool, scale: float,
 def _fa_nl_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                            dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
                            causal: bool, block_q: int, block_k: int,
-                           pack: int, dim: int):
-    """NL dK/dV: grid (B, H2, k_tiles, q_tiles); q sequential."""
+                           pack: int, dim: int,
+                           window: Optional[int] = None, q_tiles: int = 0):
+    """NL dK/dV: grid (B, Hkv2, k_tiles, group * q_tiles); the last axis
+    sequential: the Q tiles of each query slab of the group in turn
+    (``q_tiles`` is given when the group has more than one)."""
     from jax.experimental import pallas as pl
 
     ik = pl.program_id(2)
@@ -612,7 +711,7 @@ def _fa_nl_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     k_offset = ik * block_k
-    q_offset = iq * block_q
+    q_offset = (iq % q_tiles if q_tiles else iq) * block_q
 
     def _tile(apply_mask: bool):
         q = q_ref[:]
@@ -620,11 +719,8 @@ def _fa_nl_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         v = v_ref[:]
         do = do_ref[:]
         if apply_mask:
-            q_pos = q_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            causal_keep = q_pos >= k_pos
+            causal_keep = _keep_mask(q_offset, k_offset, block_q, block_k,
+                                     window)
         pdos = []
         dsqs = []
         for h in range(pack):
@@ -661,7 +757,8 @@ def _fa_nl_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dv_acc[:] = dv_acc[:] + jnp.where(sel, pdos[0], pdos[1])
             dk_acc[:] = dk_acc[:] + jnp.where(sel, dsqs[0], dsqs[1])
 
-    _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile)
+    _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile,
+                     window)
 
     @pl.when(iq == n_q - 1)
     def _finish():
@@ -671,7 +768,8 @@ def _fa_nl_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _fa_nl_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dq_acc, *, scale: float, causal: bool,
-                         block_q: int, block_k: int, pack: int, dim: int):
+                         block_q: int, block_k: int, pack: int, dim: int,
+                         window: Optional[int] = None):
     """NL dQ: grid (B, H2, q_tiles, k_tiles); k sequential."""
     from jax.experimental import pallas as pl
 
@@ -692,11 +790,8 @@ def _fa_nl_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         v = v_ref[:]
         do = do_ref[:]
         if apply_mask:
-            q_pos = q_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_offset + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            causal_keep = q_pos >= k_pos
+            causal_keep = _keep_mask(q_offset, k_offset, block_q, block_k,
+                                     window)
         dsks = []
         for h in range(pack):
             mask_q = (_lane_mask(h, pack, dim, block_q, q.dtype)
@@ -726,7 +821,8 @@ def _fa_nl_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             sel = _head_sel(pack, dim, block_q)
             dq_acc[:] = dq_acc[:] + jnp.where(sel, dsks[0], dsks[1])
 
-    _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile)
+    _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile,
+                     window)
 
     @pl.when(ik == n_k - 1)
     def _finish():
@@ -734,7 +830,8 @@ def _fa_nl_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_nl_backward(q, k, v, out, lse, g, causal, scale, block_q,
-                       block_k, interpret, grad_dtype=None, delta=None):
+                       block_k, interpret, grad_dtype=None, delta=None,
+                       window: Optional[int] = None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -742,11 +839,14 @@ def _flash_nl_backward(q, k, v, out, lse, g, causal, scale, block_q,
     seq_k = k.shape[1]
     pack = 128 // dim
     h2 = heads // pack
+    kv_heads = k.shape[2]
+    group = heads // kv_heads
     block_q = min(block_q, seq_q)
     block_k = min(block_k, seq_k)
+    n_q = seq_q // block_q
     qr = q.reshape(batch, seq_q, heads * dim)
-    kr = k.reshape(batch, seq_k, heads * dim)
-    vr = v.reshape(batch, seq_k, heads * dim)
+    kr = k.reshape(batch, seq_k, kv_heads * dim)
+    vr = v.reshape(batch, seq_k, kv_heads * dim)
     gr = g.reshape(batch, seq_q, heads * dim)
     if delta is None:
         # delta_i = rowsum(dO_i * O_i), laid out [B, H2, T, pack] like
@@ -761,24 +861,35 @@ def _flash_nl_backward(q, k, v, out, lse, g, causal, scale, block_q,
         dimension_semantics=("parallel", "parallel", "parallel",
                              "arbitrary"))
 
+    def q_of(h, j):
+        """dK/dV grid -> (query slab, Q tile): K/V slab ``h`` walks its
+        group's query slabs one after the other along ``j``."""
+        return (h, j) if group == 1 else (h * group + j // n_q, j % n_q)
+
     if causal:
         def q_idx_rev(b, h, i, j):  # dK/dV grid: i = k tile, j = q tile
-            return (b, _clamp_q_tile(j, i, block_q, block_k), h)
+            hq, jq = q_of(h, j)
+            return (b, _clamp_q_tile(jq, i, block_q, block_k, window), hq)
 
         def rows_idx_rev(b, h, i, j):
-            return (b, h, _clamp_q_tile(j, i, block_q, block_k), 0)
+            hq, jq = q_of(h, j)
+            return (b, hq,
+                    _clamp_q_tile(jq, i, block_q, block_k, window), 0)
 
         def kv_idx_fwd(b, h, i, j):  # dQ grid: i = q tile, j = k tile
-            return (b, _clamp_k_tile(j, i, block_q, block_k), h)
+            return (b, _clamp_k_tile(j, i, block_q, block_k, window),
+                    _kv_head(h, group))
     else:
         def q_idx_rev(b, h, i, j):
-            return (b, j, h)
+            hq, jq = q_of(h, j)
+            return (b, jq, hq)
 
         def rows_idx_rev(b, h, i, j):
-            return (b, h, j, 0)
+            hq, jq = q_of(h, j)
+            return (b, hq, jq, 0)
 
         def kv_idx_fwd(b, h, i, j):
-            return (b, j, h)
+            return (b, j, _kv_head(h, group))
 
     slab = pack * dim
     tile_q = pl.BlockSpec((None, block_q, slab), q_idx_rev)
@@ -787,10 +898,12 @@ def _flash_nl_backward(q, k, v, out, lse, g, causal, scale, block_q,
     rows_q_rev = pl.BlockSpec((None, None, block_q, pack), rows_idx_rev)
     dkdv = functools.partial(_fa_nl_bwd_dkdv_kernel, scale=scale,
                              causal=causal, block_q=block_q,
-                             block_k=block_k, pack=pack, dim=dim)
+                             block_k=block_k, pack=pack, dim=dim,
+                             window=window,
+                             q_tiles=0 if group == 1 else n_q)
     dk, dv = pl.pallas_call(
         dkdv,
-        grid=(batch, h2, seq_k // block_k, seq_q // block_q),
+        grid=(batch, kv_heads // pack, seq_k // block_k, group * n_q),
         in_specs=[tile_q, tile_k_rev, tile_k_rev, tile_q, rows_q_rev,
                   rows_q_rev],
         out_specs=[tile_k_rev, tile_k_rev],
@@ -809,7 +922,8 @@ def _flash_nl_backward(q, k, v, out, lse, g, causal, scale, block_q,
                               lambda b, h, i, j: (b, h, i, 0))
     dq_kernel = functools.partial(_fa_nl_bwd_dq_kernel, scale=scale,
                                   causal=causal, block_q=block_q,
-                                  block_k=block_k, pack=pack, dim=dim)
+                                  block_k=block_k, pack=pack, dim=dim,
+                                  window=window)
     dq = pl.pallas_call(
         dq_kernel,
         grid=(batch, h2, seq_q // block_q, seq_k // block_k),
@@ -940,60 +1054,68 @@ def _nl_eligible(q, k, v) -> bool:
     if dim not in (64, 128):
         return False
     pack = 128 // dim
+    if q.shape[2] != k.shape[2] and pack != 1:
+        # grouped heads ride the slab index: one head a slab only
+        return False
     return q.shape[2] % pack == 0 and k.shape[2] % pack == 0
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_nl(q, k, v, causal, scale, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_nl(q, k, v, causal, scale, block_q, block_k, interpret,
+              window=None):
     out, _ = _flash_nl_forward(q, k, v, causal, scale, block_q, block_k,
-                               interpret)
+                               interpret, window=window)
     return out
 
 
-def _flash_nl_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_nl_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                  window):
     out, lse = _flash_nl_forward(q, k, v, causal, scale, block_q, block_k,
-                                 interpret)
+                                 interpret, window=window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_nl_bwd(causal, scale, block_q, block_k, interpret, res, g):
+def _flash_nl_bwd(causal, scale, block_q, block_k, interpret, window, res,
+                  g):
     q, k, v, out, lse = res
     return _flash_nl_backward(q, k, v, out, lse, g, causal, scale,
-                              block_q, block_k, interpret)
+                              block_q, block_k, interpret, window=window)
 
 
 _flash_nl.defvjp(_flash_nl_fwd, _flash_nl_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret, bwd_impl):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, causal, scale, block_q, block_k, interpret, bwd_impl,
+           window=None):
     out, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                            interpret)
+                            interpret, window=window)
     return out
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-               bwd_impl):
+               bwd_impl, window):
     out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                              interpret)
+                              interpret, window=window)
     if bwd_impl == "pallas":
         return out, (q, k, v, out, lse)
     return out, (q, k, v, None, None)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, bwd_impl,
-               res, g):
+               window, res, g):
     q, k, v, out, lse = res
     if bwd_impl == "pallas":
         return _flash_backward(q, k, v, out, lse, g, causal, scale,
-                               block_q, block_k, interpret)
+                               block_q, block_k, interpret, window=window)
     # default: XLA recompute through the reference formulation — inside
     # one big jitted step XLA fuses/remats this better than the pallas
     # backward's layout copies (measured: 58.6k vs 18.2k tok/s on the
     # GPT-2-small bench), while the pallas *forward* still provides the
     # O(T) memory inference/eval path
     _, vjp = jax.vjp(
-        lambda q_, k_, v_: _attention_reference(q_, k_, v_, causal, scale),
+        lambda q_, k_, v_: _attention_reference(q_, k_, v_, causal, scale,
+                                                window),
         q, k, v)
     return vjp(g)
 
@@ -1008,8 +1130,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     interpret: Optional[bool] = None,
                     bwd_impl: str = "pallas",
                     native: Optional[bool] = None,
-                    mesh: Optional[jax.sharding.Mesh] = None) -> jax.Array:
-    """Fused attention. Shapes ``[batch, seq, heads, head_dim]``.
+                    mesh: Optional[jax.sharding.Mesh] = None,
+                    window: Optional[int] = None) -> jax.Array:
+    """Fused attention. Shapes ``[batch, seq, heads, head_dim]``; ``k``
+    and ``v`` may carry fewer heads than ``q`` (a divisor of its count:
+    query head ``h`` reads K/V head ``h // group``, inside the kernel).
+
+    ``window`` (causal only): position ``t`` sees keys ``t - window + 1
+    .. t``; a window that covers the sequence is no window.
 
     ``mesh``: under plain jit/GSPMD over several devices pass the mesh
     (models pass ``get_global_mesh()``): a Mosaic kernel cannot be
@@ -1045,6 +1173,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if q.shape[2] % k.shape[2] or k.shape[2] != v.shape[2]:
+        raise ValueError(
+            f"query heads ({q.shape[2]}) must be a multiple of the K/V "
+            f"heads ({k.shape[2]}, {v.shape[2]})")
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("window= needs causal=True and window >= 1")
+        if window >= k.shape[1]:
+            window = None  # every key is inside it
     if native and not _nl_eligible(q, k, v):
         # validate BEFORE any backend fallback so CPU-tested code fails
         # the same way it would on the chip
@@ -1058,7 +1195,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     backend = jax.default_backend()
     if interpret is None:
         if backend != "tpu":
-            return _attention_reference(q, k, v, causal, scale)
+            return _attention_reference(q, k, v, causal, scale, window)
         interpret = False
     if mesh is not None and mesh.size > 1:
         from jax.sharding import PartitionSpec as P
@@ -1068,7 +1205,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         per_shard = functools.partial(
             flash_attention, causal=causal, scale=scale, block_q=block_q,
             block_k=block_k, interpret=interpret, bwd_impl=bwd_impl,
-            native=native)
+            native=native, window=window)
         return jax.shard_map(per_shard, mesh=mesh, in_specs=(spec,) * 3,
                              out_specs=spec, check_vma=False)(q, k, v)
     block_q, block_k = _resolve_blocks(block_q, block_k)
@@ -1077,6 +1214,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     native = _resolve_native(q, k, v, native, bwd_impl)
     if native:
         return _flash_nl(q, k, v, causal, scale, block_q, block_k,
-                         interpret)
+                         interpret, window)
     return _flash(q, k, v, causal, scale, block_q, block_k, interpret,
-                  bwd_impl)
+                  bwd_impl, window)

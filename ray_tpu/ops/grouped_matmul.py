@@ -1,0 +1,392 @@
+"""Grouped matrix products for a routed expert layer that drops nothing.
+
+A layer that holds ``E`` experts gets ``P = tokens x choices`` (token,
+choice) pairs, of which those that chose one of ITS experts "land
+here".  :func:`plan_rows` lays the landed pairs out as rows sorted by
+expert, each expert's group padded to whole row tiles of ``block_m``,
+inside a buffer of ``row_bound`` pairs: by default the worst case, every
+pair lands (``models/afmoe.py`` takes it), so no imbalance ever drops a
+row.  A caller that sizes the buffer for less reads ``fits`` and must not
+combine a plan that does not.  Every row tile then belongs to ONE expert,
+which makes the grouped product a plain tiled matmul whose weight block
+is chosen per row tile through a scalar-prefetched table:
+
+* :func:`grouped_matmul`  ``out[rows of e] = lhs[rows of e] @ rhs[e]``
+* its backward: the same kernel against ``rhs[e]^T`` for ``d lhs``, and
+  :func:`_tgmm` (``d rhs[e] = lhs[rows of e]^T @ d out[rows of e]``,
+  accumulated over the expert's row tiles in VMEM scratch).
+
+Only the ``n_live`` leading row tiles hold rows; the grid still spans
+the buffer, but a dead tile's step computes nothing and its index
+maps repeat the last live tile's blocks, so Pallas elides its DMAs: the
+cost follows the live rows.  The output rows of dead tiles are NOT
+written (whatever the buffer held); :func:`dispatch` / :func:`combine`
+never read them.
+
+``dispatch`` (rows <- tokens) and ``combine`` (tokens <- rows, weighted)
+are gathers in both directions — the plan carries the row of every pair
+and the pair of every row — because a TPU scatter-add of 10^5 rows
+serializes.
+
+On other backends (tests) the products fall back to plain ``jnp`` unless
+``interpret=True`` forces the kernels through the Pallas interpreter.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.ops.flash_attention import fit_block
+
+
+class RowPlan(NamedTuple):
+    """Where every landed (token, choice) pair sits, and back."""
+    row_pair: jax.Array     # [M]  flat pair id (token * k + choice) of a row
+    row_valid: jax.Array    # [M]  the row holds a pair
+    pair_row: jax.Array     # [T, k]  row of a pair
+    pair_valid: jax.Array   # [T, k]  the pair landed here (and was kept)
+    tile_expert: jax.Array  # [M / block_m]  local expert of a row tile
+    n_live: jax.Array       # [1]  leading row tiles that hold rows
+    sizes: jax.Array        # [E]  pairs that chose each held expert
+    fits: jax.Array         # []   every landed pair has its row
+
+
+def plan_rows(expert_idx: jax.Array, first: int, held: int, *,
+              block_m: int, row_bound: Optional[int] = None) -> RowPlan:
+    """``expert_idx [T, k]``: the experts (of all published ones) each
+    token chose.  Pairs that chose ``first .. first + held - 1`` land
+    here, sorted by expert then by pair id.  ``row_bound``: the most
+    pairs the buffer is sized for; the default ``T * k`` is the worst
+    case, so every plan fits.  With a smaller bound a plan may not
+    (``fits`` False): the pairs past the buffer have no row, and a caller
+    that combined such a plan would DROP them."""
+    tokens, k = expert_idx.shape
+    pairs = tokens * k
+    bound = pairs if row_bound is None else row_bound
+    m_tiles = -(-bound // block_m) + held  # each group pads < one tile
+    m = m_tiles * block_m
+
+    local = expert_idx.reshape(pairs).astype(jnp.int32) - first
+    here = jnp.logical_and(local >= 0, local < held)
+    key = jnp.where(here, local, held)
+    onehot = (key[:, None] == jnp.arange(held)[None]).astype(jnp.int32)
+    sizes = onehot.sum(0)                                      # [E]
+    tiles_per = (sizes + block_m - 1) // block_m
+    tile_ends = jnp.cumsum(tiles_per)
+    tile_starts = tile_ends - tiles_per
+    starts = jnp.cumsum(sizes) - sizes
+    n_live = jnp.minimum(tile_ends[-1], m_tiles)
+
+    # pair -> row: rank among the earlier pairs of the same expert
+    rank = jnp.take_along_axis(
+        jnp.cumsum(onehot, axis=0) - onehot,
+        jnp.minimum(key, held - 1)[:, None], axis=1)[:, 0]
+    pair_row = tile_starts[jnp.minimum(key, held - 1)] * block_m + rank
+    pair_valid = jnp.logical_and(here, pair_row < m)
+    pair_row = jnp.where(pair_valid, pair_row, 0)
+
+    # row -> pair: the stable sort's order, read through the padding
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    tile = jnp.arange(m_tiles, dtype=jnp.int32)
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_ends, tile, side="right"),
+        held - 1).astype(jnp.int32)
+    row_expert = jnp.repeat(tile_expert, block_m)
+    row = jnp.arange(m, dtype=jnp.int32)
+    row_rank = row - tile_starts[row_expert] * block_m
+    row_valid = jnp.logical_and(
+        row < n_live * block_m,
+        jnp.logical_and(row_rank >= 0, row_rank < sizes[row_expert]))
+    src = jnp.clip(starts[row_expert] + row_rank, 0, pairs - 1)
+    row_pair = jnp.where(row_valid, order[src], 0)
+    return RowPlan(row_pair, row_valid, pair_row.reshape(tokens, k),
+                   pair_valid.reshape(tokens, k), tile_expert,
+                   n_live.reshape(1).astype(jnp.int32), sizes,
+                   tile_ends[-1] <= m_tiles)
+
+
+# ---------------------------------------------------------------------------
+# rows <- tokens, tokens <- rows: gathers both ways
+# ---------------------------------------------------------------------------
+
+@jax.custom_vjp
+def dispatch(x: jax.Array, plan: RowPlan) -> jax.Array:
+    """``x [T, D]`` -> rows ``[M, D]``: each row its pair's token (rows
+    that hold no pair read token 0 and are never combined)."""
+    k = plan.pair_row.shape[1]
+    return x[plan.row_pair // k]
+
+
+def _dispatch_fwd(x, plan):
+    return dispatch(x, plan), plan
+
+
+def _dispatch_bwd(plan, g):
+    dx = _gather_sum(g, plan, None)
+    return dx.astype(g.dtype), None
+
+
+dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+def _gather_sum(rows, plan: RowPlan, weights):
+    """``out[t] = sum_c [valid] w[t, c] * rows[pair_row[t, c]]`` in f32,
+    one choice at a time (``[T, k, D]`` at once is the worst-case
+    buffer again)."""
+    tokens, k = plan.pair_row.shape
+    out = jnp.zeros((tokens, rows.shape[1]), jnp.float32)
+    for c in range(k):
+        part = rows[plan.pair_row[:, c]].astype(jnp.float32)
+        if weights is not None:
+            part = part * weights[:, c, None]
+        out = out + jnp.where(plan.pair_valid[:, c, None], part, 0.0)
+    return out
+
+
+@jax.custom_vjp
+def combine(rows: jax.Array, weights: jax.Array, plan: RowPlan
+            ) -> jax.Array:
+    """Rows ``[M, D]`` and the pairs' weights ``[T, k]`` (f32) ->
+    ``[T, D]`` f32: every token the weighted sum of its landed pairs'
+    rows."""
+    return _gather_sum(rows, plan, weights)
+
+
+def _combine_fwd(rows, weights, plan):
+    return combine(rows, weights, plan), (rows, weights, plan)
+
+
+def _combine_bwd(res, g):
+    rows, weights, plan = res
+    tokens, k = plan.pair_row.shape
+    w_row = weights.reshape(tokens * k)[plan.row_pair]
+    # gathered in the rows' dtype: the float32 cotangent of every row
+    # of the buffer is twice the buffer
+    w_row = jnp.where(plan.row_valid, w_row, 0.0)
+    d_rows = g.astype(rows.dtype)[plan.row_pair // k] * w_row[:, None]
+    d_w = jnp.stack([
+        jnp.where(plan.pair_valid[:, c],
+                  jnp.sum(rows[plan.pair_row[:, c]].astype(jnp.float32)
+                          * g, axis=-1), 0.0)
+        for c in range(k)], axis=1)
+    return d_rows.astype(rows.dtype), d_w.astype(weights.dtype), None
+
+
+combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+# ---------------------------------------------------------------------------
+# the kernels
+# ---------------------------------------------------------------------------
+
+def _live_tile(m, n_live_ref):
+    """Row-tile index for the index maps: a dead tile repeats the last
+    live one, so its blocks are already resident and nothing is copied."""
+    return jnp.minimum(m, jnp.maximum(n_live_ref[0] - 1, 0))
+
+
+def _gmm_kernel(te_ref, n_live_ref, lhs_ref, rhs_ref, out_ref, *,
+                transpose_rhs: bool):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(1) < n_live_ref[0])
+    def _compute():
+        contract = (((1,), (1,)), ((), ())) if transpose_rhs else \
+            (((1,), (0,)), ((), ()))
+        out_ref[:] = jax.lax.dot_general(
+            lhs_ref[:], rhs_ref[:], contract,
+            preferred_element_type=jnp.float32).astype(out_ref.dtype)
+
+
+def _gmm_pallas(lhs, rhs, tile_expert, n_live, block_m, block_n,
+                transpose_rhs, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, k = lhs.shape
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    block_n = fit_block(n, block_n)
+    if transpose_rhs:   # rhs [E, N, K]: rows of the block are outputs
+        rhs_spec = pl.BlockSpec(
+            (None, block_n, k),
+            lambda j, i, te, nl: (te[_live_tile(i, nl)], j, 0))
+    else:
+        rhs_spec = pl.BlockSpec(
+            (None, k, block_n),
+            lambda j, i, te, nl: (te[_live_tile(i, nl)], 0, j))
+    # n outermost: consecutive row tiles of one expert keep its weight
+    # block resident
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n // block_n, m // block_m),
+            in_specs=[
+                pl.BlockSpec((block_m, k),
+                             lambda j, i, te, nl: (_live_tile(i, nl), 0)),
+                rhs_spec,
+            ],
+            out_specs=pl.BlockSpec(
+                (block_m, block_n),
+                lambda j, i, te, nl: (_live_tile(i, nl), j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="grouped_matmul_t" if transpose_rhs else "grouped_matmul",
+    )(tile_expert, n_live, lhs, rhs)
+
+
+def _tgmm_kernel(te_ref, n_live_ref, lhs_ref, dout_ref, out_ref, acc_ref):
+    from jax.experimental import pallas as pl
+
+    i = pl.program_id(2)
+    last = pl.num_programs(2) - 1
+    n_live = n_live_ref[0]
+    here = te_ref[i]
+    opens = jnp.logical_or(i == 0, te_ref[jnp.maximum(i - 1, 0)] != here)
+    closes = jnp.logical_or(i == n_live - 1,
+                            te_ref[jnp.minimum(i + 1, last)] != here)
+
+    @pl.when(i < n_live)
+    def _compute():
+        @pl.when(opens)
+        def _zero():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+        acc_ref[:] = acc_ref[:] + jax.lax.dot_general(
+            lhs_ref[:], dout_ref[:], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+        @pl.when(closes)
+        def _store():
+            out_ref[:] = acc_ref[:].astype(out_ref.dtype)
+
+
+def _tgmm_pallas(lhs, dout, tile_expert, n_live, experts, block_m, block_k,
+                 block_n, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, k = lhs.shape
+    n = dout.shape[1]
+    block_k, block_n = fit_block(k, block_k), fit_block(n, block_n)
+    return pl.pallas_call(
+        _tgmm_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(k // block_k, n // block_n, m // block_m),
+            in_specs=[
+                pl.BlockSpec((block_m, block_k),
+                             lambda a, b, i, te, nl: (_live_tile(i, nl), a)),
+                pl.BlockSpec((block_m, block_n),
+                             lambda a, b, i, te, nl: (_live_tile(i, nl), b)),
+            ],
+            out_specs=pl.BlockSpec(
+                (None, block_k, block_n),
+                lambda a, b, i, te, nl: (te[_live_tile(i, nl)], a, b)),
+            scratch_shapes=[pltpu.VMEM((block_k, block_n), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((experts, k, n), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="grouped_matmul_drhs",
+    )(tile_expert, n_live, lhs, dout)
+
+
+# ---------------------------------------------------------------------------
+# plain jnp (off the TPU), tile by tile as the kernels see the rows
+# ---------------------------------------------------------------------------
+
+def _tiles_live(tile_expert, n_live):
+    return jnp.arange(tile_expert.shape[0]) < n_live[0]
+
+
+def _gmm_ref(lhs, rhs, tile_expert, n_live, block_m, transpose_rhs):
+    tiles = lhs.reshape(tile_expert.shape[0], block_m, lhs.shape[1])
+    out = jnp.einsum("tmn,tkn->tmk" if transpose_rhs else "tmk,tkn->tmn",
+                     tiles, rhs[tile_expert],
+                     preferred_element_type=jnp.float32)
+    out = jnp.where(_tiles_live(tile_expert, n_live)[:, None, None],
+                    out, 0.0)
+    return out.reshape(lhs.shape[0], -1).astype(lhs.dtype)
+
+
+def _tgmm_ref(lhs, dout, tile_expert, n_live, experts, block_m):
+    t = tile_expert.shape[0]
+    per_tile = jnp.einsum("tmk,tmn->tkn",
+                          lhs.reshape(t, block_m, -1),
+                          dout.reshape(t, block_m, -1),
+                          preferred_element_type=jnp.float32)
+    live = _tiles_live(tile_expert, n_live)
+    per_tile = jnp.where(live[:, None, None], per_tile, 0.0)
+    onehot = (tile_expert[:, None] == jnp.arange(experts)[None]) & \
+        live[:, None]
+    return jnp.einsum("te,tkn->ekn", onehot.astype(jnp.float32),
+                      per_tile).astype(lhs.dtype)
+
+
+# ---------------------------------------------------------------------------
+# the differentiable product
+# ---------------------------------------------------------------------------
+
+def _product(lhs, rhs, tile_expert, n_live, block_m, block_n,
+             transpose_rhs, interpret):
+    if interpret is None:
+        return _gmm_ref(lhs, rhs, tile_expert, n_live, block_m,
+                        transpose_rhs)
+    return _gmm_pallas(lhs, rhs, tile_expert, n_live, block_m, block_n,
+                       transpose_rhs, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _gmm(lhs, rhs, tile_expert, n_live, block_m, block_n, interpret):
+    return _product(lhs, rhs, tile_expert, n_live, block_m, block_n,
+                    False, interpret)
+
+
+def _gmm_fwd(lhs, rhs, tile_expert, n_live, block_m, block_n, interpret):
+    out = _gmm(lhs, rhs, tile_expert, n_live, block_m, block_n, interpret)
+    return out, (lhs, rhs, tile_expert, n_live)
+
+
+def _gmm_bwd(block_m, block_n, interpret, res, g):
+    lhs, rhs, tile_expert, n_live = res
+    experts = rhs.shape[0]
+    d_lhs = _product(g, rhs, tile_expert, n_live, block_m, block_n, True,
+                     interpret)
+    if interpret is None:
+        d_rhs = _tgmm_ref(lhs, g, tile_expert, n_live, experts, block_m)
+    else:
+        d_rhs = _tgmm_pallas(lhs, g, tile_expert, n_live, experts, block_m,
+                             1024, block_n, interpret)
+        # an expert with no rows was never visited: its block is
+        # whatever the buffer held
+        seen = jnp.any(
+            (tile_expert[:, None] == jnp.arange(experts)[None])
+            & _tiles_live(tile_expert, n_live)[:, None], axis=0)
+        d_rhs = jnp.where(seen[:, None, None], d_rhs, 0)
+    return d_lhs, d_rhs.astype(rhs.dtype), None, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, plan: RowPlan, *,
+                   block_n: int = 512,
+                   interpret: Optional[bool] = None) -> jax.Array:
+    """``lhs [M, K]`` rows laid out by ``plan``, ``rhs [E, K, N]`` ->
+    ``[M, N]``: every live row tile times its expert's matrix, f32
+    accumulation, output in ``lhs``'s dtype.  Rows of dead tiles are not
+    written."""
+    block_m = lhs.shape[0] // plan.tile_expert.shape[0]
+    if interpret is None and jax.default_backend() == "tpu":
+        interpret = False
+    return _gmm(lhs, rhs, plan.tile_expert, plan.n_live, block_m, block_n,
+                interpret)

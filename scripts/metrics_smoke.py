@@ -78,6 +78,11 @@ TRAFFIC_DEPENDENT = {
     "ray_tpu_serve_kv_pages_allocated_total",
     "ray_tpu_serve_kv_pages_freed_total",
     "ray_tpu_serve_kv_page_occupancy",
+    # routed expert layers report only where a training loop asks
+    # (models/afmoe.py report_router_stats)
+    "ray_tpu_moe_expert_load",
+    "ray_tpu_moe_landed_share",
+    "ray_tpu_moe_load_imbalance",
     "ray_tpu_serve_gang_bringup_seconds",
     "ray_tpu_serve_gang_shards",
     "ray_tpu_serve_gang_deaths_total",

@@ -87,6 +87,46 @@ def test_flash_kernels_compile_for_v5e(one_chip, family, shape):
     assert compiled.as_text().count("tpu_custom_call") == 3
 
 
+@pytest.mark.parametrize("window", [2048, None], ids=["sliding", "full"])
+def test_windowed_grouped_head_flash_compiles_at_afmoe_shapes(one_chip,
+                                                              window):
+    """One sequence of 8,192, 32 query heads on 4 K/V heads of 128, as
+    ``models/afmoe.py`` calls it: forward, dK/dV and dQ."""
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: fa._flash_nl(
+            q, k, v, True, 128 ** -0.5, 1024, 1024, False, window
+        ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(grads).lower(q, kv, kv).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+def test_grouped_products_compile_at_afmoe_widths(one_chip):
+    """The worst-case row buffer of one sequence (8 x 8,192 pairs and a
+    tile of padding for each of 16 experts) times 16 experts' matrices
+    of 2048 x 1024: the forward kernel, d lhs and d rhs."""
+    gm = importlib.import_module("ray_tpu.ops.grouped_matmul")
+    tiles = 8 * 8192 // 256 + 16
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def grads(lhs, rhs, tile_expert, n_live):
+        out, vjp = jax.vjp(lambda a, b: gm._gmm(
+            a, b, tile_expert, n_live, 256, 512, False), lhs, rhs)
+        return out, vjp(out)
+
+    compiled = jax.jit(grads).lower(
+        shape((tiles * 256, 2048)), shape((16, 2048, 1024)),
+        shape((tiles,), jnp.int32), shape((1,), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+
 def test_fused_rmsnorm_compiles_at_llama_width(one_chip):
     x = jax.ShapeDtypeStruct((8, 2048, 4096), jnp.bfloat16,
                              sharding=one_chip)
