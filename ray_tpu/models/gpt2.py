@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.parallel.mesh import get_global_mesh
+from ray_tpu.parallel.sharding import constrain_activation, fsdp_plan
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,6 +107,11 @@ class Block(nn.Module):
     def __call__(self, x: jax.Array, deterministic: bool = True) -> jax.Array:
         cfg = self.config
         head_dim = cfg.embed_dim // cfg.num_heads
+        # under a mesh an activation lies on its batch shard, whole along
+        # embed: a weight sharded there is gathered for its use and its
+        # gradient scattered back.  Said inside the block, so that the
+        # recomputed forward of a remat is laid out the same
+        x = constrain_activation(x, "batch", "seq", "embed")
 
         # block LNs emit cfg.dtype (statistics still accumulate f32
         # inside flax): the f32 round-trip costs 3x the HBM traffic and
@@ -124,7 +131,6 @@ class Block(nn.Module):
 
         q, k, v = heads(q), heads(k), heads(v)
         if cfg.attn_impl == "ring":
-            from ray_tpu.parallel.mesh import get_global_mesh
             from ray_tpu.parallel.ring_attention import ring_attention
 
             # under plain jit/GSPMD the sp axis is bound via the global
@@ -133,7 +139,6 @@ class Block(nn.Module):
             attn = ring_attention(q, k, v, axis_name=cfg.sp_axis,
                                   causal=True, mesh=get_global_mesh())
         elif cfg.attn_impl == "ulysses":
-            from ray_tpu.parallel.mesh import get_global_mesh
             from ray_tpu.parallel.ulysses import ulysses_attention
 
             # same binding rules as "ring": mesh when under plain
@@ -145,8 +150,6 @@ class Block(nn.Module):
 
             attn = _attention_reference(q, k, v, True, head_dim ** -0.5)
         else:
-            from ray_tpu.parallel.mesh import get_global_mesh
-
             # same binding rule as "ring": under a multi-device mesh the
             # kernel runs per (batch, head) shard
             attn = flash_attention(q, k, v, causal=True,
@@ -167,7 +170,7 @@ class Block(nn.Module):
         h = _dense(cfg.embed_dim, cfg, "mlp_down", ("mlp", "embed"))(h)
         if cfg.dropout > 0:
             h = nn.Dropout(cfg.dropout)(h, deterministic=deterministic)
-        return x + h
+        return constrain_activation(x + h, "batch", "seq", "embed")
 
 
 class GPT2(nn.Module):
@@ -190,8 +193,11 @@ class GPT2(nn.Module):
                                  (None, "embed")),
             (cfg.max_seq_len, cfg.embed_dim), cfg.param_dtype)
         seq = tokens.shape[1]
-        x = wte.astype(cfg.dtype)[tokens] + \
-            wpe.astype(cfg.dtype)[None, :seq]
+        # the lookup is a use of wte like any other: the rounded table is
+        # gathered along embed for it, not the looked-up rows exchanged
+        table = constrain_activation(wte.astype(cfg.dtype), "vocab", "embed")
+        x = table[tokens] + wpe.astype(cfg.dtype)[None, :seq]
+        x = constrain_activation(x, "batch", "seq", "embed")
         block_cls = Block
         if cfg.remat == "full":
             block_cls = nn.remat(Block, static_argnums=(2,))
@@ -206,7 +212,7 @@ class GPT2(nn.Module):
                              nn.initializers.ones, ("embed",)),
                          bias_init=nn.with_partitioning(
                              nn.initializers.zeros, ("embed",)))(x)
-        return x, wte
+        return constrain_activation(x, "batch", "seq", "embed"), wte
 
     def __call__(self, tokens: jax.Array,
                  deterministic: bool = True) -> jax.Array:
@@ -220,6 +226,20 @@ class GPT2(nn.Module):
         seq = seq or self.config.max_seq_len
         tokens = jnp.zeros((batch, seq), jnp.int32)
         return self.init(rng, tokens)["params"]
+
+
+def param_axes(config: GPT2Config):
+    """The logical axes of every parameter, as a tree like the
+    parameters'.  From an abstract init of ONE layer on a few tokens (no
+    kernel is traced); layer 0 stands for every layer."""
+    one = GPT2(dataclasses.replace(config, num_layers=1, remat="",
+                                   attn_impl="reference"))
+    boxed = jax.eval_shape(
+        lambda: one.init_params(jax.random.PRNGKey(0), seq=8))
+    axes = jax.tree.map(lambda b: tuple(b.names), boxed,
+                        is_leaf=lambda b: hasattr(b, "names"))
+    layer = axes.pop("h0")
+    return {**axes, **{f"h{i}": layer for i in range(config.num_layers)}}
 
 
 def loss_fn(model: GPT2, params, tokens: jax.Array,
@@ -241,7 +261,8 @@ def loss_fn(model: GPT2, params, tokens: jax.Array,
     compute = jnp.bfloat16 if model.config.dtype == jnp.bfloat16 else None
     return chunked_lm_loss(x[:, :-1], wte, tokens[:, 1:],
                            chunk=head_chunk, compute_dtype=compute,
-                           logits_dtype=head_logits_dtype)
+                           logits_dtype=head_logits_dtype,
+                           mesh=get_global_mesh())
 
 
 def make_train_step(model: GPT2, tx, head_logits_dtype: Any = None):
@@ -253,9 +274,14 @@ def make_train_step(model: GPT2, tx, head_logits_dtype: Any = None):
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def train_step(params, opt_state, tokens):
-        loss, grads = jax.value_and_grad(
-            lambda p: loss_fn(model, p, tokens,
-                              head_logits_dtype=head_logits_dtype))(params)
+        # under a mesh the timeline says what the step asks of it
+        with fsdp_plan(params,
+                       functools.partial(param_axes, model.config),
+                       passes=3 if model.config.remat == "full" else 2):
+            loss, grads = jax.value_and_grad(
+                lambda p: loss_fn(model, p, tokens,
+                                  head_logits_dtype=head_logits_dtype)
+            )(params)
         updates, opt_state = tx.update(grads, opt_state, params)
         return optax.apply_updates(params, updates), opt_state, loss
 

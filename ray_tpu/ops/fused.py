@@ -8,6 +8,7 @@ the kernels are forward-only with ``custom_vjp`` recompute backward).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Optional
 
 import jax
@@ -94,7 +95,8 @@ def fused_softmax_cross_entropy(logits: jax.Array,
 def chunked_lm_loss(hidden: jax.Array, emb: jax.Array, labels: jax.Array,
                     *, chunk: int = 8192,
                     compute_dtype: Any = None,
-                    logits_dtype: Any = None) -> jax.Array:
+                    logits_dtype: Any = None,
+                    mesh: Optional[jax.sharding.Mesh] = None) -> jax.Array:
     """Mean next-token cross entropy with a chunked LM head.
 
     ``hidden`` [B,T,E] (f32), ``emb`` [V,E] (tied embedding), ``labels``
@@ -103,7 +105,41 @@ def chunked_lm_loss(hidden: jax.Array, emb: jax.Array, labels: jax.Array,
     scan step (forward) and is recomputed in backward — HBM never holds
     [B,T,V], which at GPT-2-small scale is both the largest tensor and
     the dominant bandwidth cost of the naive head.
+
+    ``mesh`` (models pass ``get_global_mesh()``): where its batch axes
+    split the tokens, each device cuts the chunks inside its OWN tokens
+    (``chunk`` stays the tokens of one scan step over the whole mesh, so
+    a device's share of it), ``emb`` is gathered once for the scan, and
+    the loss is the devices' sum over the global count.  Chunks of the
+    flattened global ``[B x T]`` would mix devices, and every scan step
+    would exchange its tokens.  A head sharded along the vocabulary is
+    gathered too: correct, not Megatron's.
     """
+    n = hidden.shape[0] * hidden.shape[1]
+    axes = ()
+    if mesh is not None and mesh.size > 1:
+        from ray_tpu.parallel.sharding import MESH_RULES, P, spec_axes
+
+        tokens = MESH_RULES.activation_spec(
+            "batch", "seq", mesh=mesh, shape=hidden.shape[:2])
+        axes = spec_axes(tokens)
+    shards = math.prod(mesh.shape[a] for a in axes)
+    local_sum = functools.partial(
+        _lm_loss_sum, chunk=max(1, chunk // shards),
+        compute_dtype=compute_dtype, logits_dtype=logits_dtype)
+    if not axes:
+        return local_sum(hidden, emb, labels) / n
+    total = jax.shard_map(
+        lambda h, e, y: jax.lax.psum(local_sum(h, e, y), axes),
+        mesh=mesh, in_specs=(P(*tokens, None), P(), tokens),
+        out_specs=P(), check_vma=False)(hidden, emb, labels)
+    return total / n
+
+
+def _lm_loss_sum(hidden, emb, labels, *, chunk, compute_dtype,
+                 logits_dtype):
+    """Summed cross entropy of ``chunked_lm_loss`` over the tokens it is
+    given, ``chunk`` at a time."""
     B, T, E = hidden.shape
     V = emb.shape[0]
     flat_h = hidden.reshape(B * T, E).astype(jnp.float32)
@@ -146,4 +182,4 @@ def chunked_lm_loss(hidden: jax.Array, emb: jax.Array, labels: jax.Array,
         return carry + jnp.sum((lse - label_logit) * m), None
 
     total, _ = jax.lax.scan(body, jnp.float32(0.0), (h_c, y_c, m_c))
-    return total / n
+    return total

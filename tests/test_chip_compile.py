@@ -19,6 +19,7 @@ jnp reference.
 import dataclasses
 import importlib
 import os
+import re
 from unittest import mock
 
 import jax
@@ -136,16 +137,17 @@ def test_fused_rmsnorm_compiles_at_llama_width(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _gpt2_step_shapes(place, **cut):
-    """The shared GPT-2 124M train step with abstract arguments;
-    ``place(shape_struct, partition_spec)`` attaches the sharding."""
+def _gpt2_step_shapes(place, cfg=None):
+    """The shared GPT-2 train step (124M unless ``cfg`` says otherwise)
+    with abstract arguments; ``place(shape_struct, partition_spec)``
+    attaches the sharding."""
     import optax
 
     from ray_tpu.models import GPT2, GPT2Config
     from ray_tpu.models.gpt2 import make_train_step
     from ray_tpu.parallel.sharding import FSDP_RULES, flax_sharding
 
-    cfg = dataclasses.replace(GPT2Config.gpt2_small(), **cut)
+    cfg = cfg or GPT2Config.gpt2_small()
     model = GPT2(cfg)
     tx = optax.adamw(6e-4, weight_decay=0.01)
     boxed = jax.eval_shape(
@@ -162,7 +164,7 @@ def _lower_as_on_tpu(step, args):
     # the model's flash dispatch asks jax.default_backend(); steer it in
     # the test, as the chip would answer
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
-        return step.lower(*args).compile()
+        return step.lower(*args)
 
 
 def test_gpt2_124m_train_step_fits_one_v5e(one_chip):
@@ -170,7 +172,11 @@ def test_gpt2_124m_train_step_fits_one_v5e(one_chip):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
 
     step, args = _gpt2_step_shapes(place)
-    compiled = _lower_as_on_tpu(step, args)
+    lowered = _lower_as_on_tpu(step, args)
+    # with no mesh the step says nothing about placement
+    assert "sharding_constraint" not in lowered.as_text()
+    assert "@Sharding" not in lowered.as_text()
+    compiled = lowered.compile()
     # 12 layers x (forward, dK/dV, dQ)
     assert compiled.as_text().count("tpu_custom_call") == 36
     mem = compiled.memory_analysis()
@@ -179,12 +185,22 @@ def test_gpt2_124m_train_step_fits_one_v5e(one_chip):
     assert total < 0.9 * V5E_HBM_BYTES, f"{total / 2**30:.2f} GiB"
 
 
+#: the same compile of the step as it was before it said where
+#: activations lie (PR 28's tree, this sandbox): what GSPMD made of one
+#: table for parameters and activations (and 10 all-to-alls)
+XL_DEPTH2_BEFORE = {"all-reduce": 33, "temp_bytes": 4_911_000_000,
+                    "tpu_custom_call": 8}
+
+
 def test_gpt2_fsdp_step_partitions_over_four_v5e(topo):
     """Mosaic kernels cannot be partitioned by GSPMD: under a mesh the
-    model has to run them per shard (flash_attention(mesh=...)).
-    Published widths, depth cut to 2: partitioning does not depend on
-    depth, and the full-depth compile costs every suite run a minute
-    (it was made once, by hand: PERF.md, PR 22)."""
+    model has to run them per shard (flash_attention(mesh=...)).  And
+    the step is FSDP: activations stay on their batch shard, whole along
+    embed; weights are gathered for use, gradients scattered back.
+    GPT-2 XL's published widths, 8 sequences a device, depth cut to 2:
+    partitioning does not depend on depth, and the full-depth compile
+    costs minutes (made by hand: PERF.md, PR 30)."""
+    from ray_tpu.models import GPT2Config
     from ray_tpu.parallel import MeshConfig, build_mesh
     from ray_tpu.parallel.mesh import use_mesh
 
@@ -194,11 +210,26 @@ def test_gpt2_fsdp_step_partitions_over_four_v5e(topo):
         return jax.ShapeDtypeStruct(a.shape, a.dtype,
                                     sharding=NamedSharding(mesh, spec))
 
-    step, args = _gpt2_step_shapes(place, num_layers=2)
+    step, args = _gpt2_step_shapes(
+        place, dataclasses.replace(GPT2Config.gpt2_xl(remat="full"),
+                                   num_layers=2))
     with use_mesh(mesh):
-        compiled = _lower_as_on_tpu(step, args)
+        compiled = _lower_as_on_tpu(step, args).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 6
+    assert text.count("tpu_custom_call") \
+        == XL_DEPTH2_BEFORE["tpu_custom_call"]
     assert "all-gather" in text and "reduce-scatter" in text
+    assert text.count(" all-to-all(") <= 2
+    assert text.count(" all-reduce(") < XL_DEPTH2_BEFORE["all-reduce"]
+    # no activation lies along a width shard (1600 / 4), the embedding
+    # lookup's neither: the rounded table is gathered for it
+    assert not re.search(r"\[\d+,1024,400\]", text)
     mem = compiled.memory_analysis()  # bytes on each device
+    assert mem.temp_size_in_bytes < XL_DEPTH2_BEFORE["temp_bytes"]
     assert mem.temp_size_in_bytes < 0.9 * V5E_HBM_BYTES
+    # parameters and AdamW's moments leave as they came: donation holds
+    assert mem.alias_size_in_bytes >= mem.argument_size_in_bytes - 2 ** 20
+    (params_in, _, _), _ = compiled.input_shardings
+    assert jax.tree.all(jax.tree.map(
+        lambda a, b, x: a.is_equivalent_to(b, x.ndim),
+        params_in, compiled.output_shardings[0], args[0]))

@@ -1,5 +1,9 @@
 """Parallelism library tests on the virtual 8-device CPU mesh."""
 
+import functools
+import hashlib
+from unittest import mock
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +18,9 @@ from ray_tpu.parallel import (
     ring_attention,
     ulysses_attention,
 )
+from ray_tpu.core import telemetry
+from ray_tpu.parallel import sharding
+from ray_tpu.parallel.mesh import use_mesh
 from ray_tpu.parallel.sharding import (
     FSDP_RULES,
     TP_RULES,
@@ -281,3 +288,226 @@ def test_pipeline_real_transformer_blocks():
         stage_fn, s, x, mesh=mesh).mean()))(stacked)
     for leaf in jax.tree.leaves(grads):
         assert bool(jnp.all(jnp.isfinite(leaf)))
+
+
+# -- where activations lie (PR 30) ---------------------------------------
+
+#: a mesh for each preset: the axes the preset does not name hold one
+#: device
+PRESET_MESHES = {
+    "dp": MeshConfig(dp=8),
+    "fsdp": MeshConfig(fsdp=8),
+    "tp": MeshConfig(fsdp=4, tp=2),
+    "sp": MeshConfig(fsdp=2, sp=2, tp=2),
+    "ep": MeshConfig(fsdp=2, tp=2, ep=2),
+}
+
+
+def test_parameter_rules_are_what_they_were():
+    """The benchmark reads these as they are."""
+    assert FSDP_RULES.spec("vocab", "embed") == P(None, "fsdp")
+    assert FSDP_RULES.spec("embed", "mlp") == P("fsdp", None)
+    assert FSDP_RULES.spec("batch", None) == P(("dp", "fsdp"), None)
+    assert TP_RULES.spec("embed", "heads") == P("fsdp", "tp")
+
+
+@pytest.mark.parametrize("preset", sorted(PRESET_MESHES))
+def test_activation_rules_name_only_axes_of_the_presets_mesh(preset):
+    rules = sharding.PRESETS[preset]
+    mesh = build_mesh(PRESET_MESHES[preset])
+    live = {a for a, n in mesh.shape.items() if n > 1}
+    data = set(sharding.spec_axes(rules.spec("batch")))
+    for logical in rules.rules:
+        named = set(sharding.spec_axes(
+            rules.activation_spec(logical, mesh=mesh)))
+        assert named <= live, (logical, named)
+        if logical != "batch":
+            # an activation has a batch dimension, and a mesh axis is
+            # used once a spec
+            assert not named & data, (logical, named)
+        # the model is handed a mesh and no rules: on the preset's own
+        # mesh that is the same placement
+        assert sharding.MESH_RULES.activation_spec(logical, mesh=mesh) \
+            == rules.activation_spec(logical, mesh=mesh), logical
+        assert set(sharding.spec_axes(sharding.MESH_RULES.spec(logical))) \
+            & live == set(sharding.spec_axes(rules.spec(logical))) & live
+    assert rules.activation_spec("batch", "seq", "embed", mesh=mesh)[2] \
+        is None
+
+
+def test_activation_spec_keeps_whole_what_the_mesh_does_not_divide():
+    mesh = build_mesh(MeshConfig(fsdp=4, tp=2))
+    spec = functools.partial(TP_RULES.activation_spec,
+                             "batch", "seq", "heads", mesh=mesh)
+    assert spec() == P("fsdp", None, "tp")
+    assert spec(shape=(8, 16, 6)) == P("fsdp", None, "tp")
+    assert spec(shape=(6, 16, 5)) == P(None, None, None)
+    # without a mesh: the table's own names
+    assert FSDP_RULES.activation_spec("batch", "seq", "embed") \
+        == P(("dp", "fsdp"), None, None)
+
+
+def _tiny_gpt2(**kw):
+    import optax
+
+    from ray_tpu.models.gpt2 import GPT2, GPT2Config, make_train_step
+
+    model = GPT2(GPT2Config.tiny(dtype=jnp.float32, remat="full", **kw))
+    # a large epsilon: AdamW divides by the root of the second moment,
+    # which turns rounding in a gradient near zero into a whole step
+    tx = optax.adamw(1e-2, eps=1e-3, weight_decay=0.01)
+    boxed = model.init_params(jax.random.PRNGKey(0), batch=1)
+    tokens = np.random.default_rng(0).integers(
+        0, model.config.vocab_size, (3, 8, model.config.max_seq_len),
+        dtype=np.int32)
+    return model, tx, boxed, tokens, lambda: make_train_step(model, tx)
+
+
+def _same_placement(a, b):
+    return jax.tree.all(jax.tree.map(
+        lambda x, y: x.sharding.is_equivalent_to(y.sharding, x.ndim), a, b))
+
+
+@pytest.mark.parametrize("layout", [{"fsdp": 4}, {"dp": 2, "fsdp": 2}],
+                         ids=["fsdp4", "dp2_fsdp2"])
+def test_gpt2_step_under_fsdp_is_the_one_device_step(layout):
+    model, tx, boxed, tokens, make_step = _tiny_gpt2()
+    plain, _ = sharding.flax_sharding(boxed, FSDP_RULES)
+    # the step donates its arguments: each side gets its own copy
+    want_p = jax.tree.map(jnp.array, plain)
+    want_o = tx.init(want_p)
+    step = make_step()
+    want = []
+    for batch in tokens:
+        want_p, want_o, loss = step(want_p, want_o, batch)
+        want.append(float(loss))
+
+    mesh = build_mesh(MeshConfig(**layout), devices=jax.devices()[:4])
+    with use_mesh(mesh):
+        params, specs = sharding.place_flax_params(boxed, FSDP_RULES, mesh)
+        assert params["h0"]["mlp_up"]["kernel"].sharding.spec \
+            == P("fsdp", None)
+        # zeros_like keeps a moment's placement; AdamW's scalar count is
+        # the caller's to place
+        opt = jax.tree.map(
+            lambda x: x if x.ndim else jax.device_put(
+                x, NamedSharding(mesh, P())), tx.init(params))
+        step = make_step()
+        telemetry.drain_spans("test")
+        for batch, loss_one in zip(tokens, want):
+            was_p, was_o = params, opt
+            was = jax.tree.map(lambda x: x.sharding, (params, opt))
+            params, opt, loss = step(params, opt, jax.device_put(
+                batch, NamedSharding(mesh, FSDP_RULES.spec("batch", None))))
+            np.testing.assert_allclose(float(loss), loss_one, rtol=2e-6)
+            # state leaves as it came: donation holds, nothing recompiles
+            now = jax.tree.map(lambda x: x.sharding, (params, opt))
+            assert jax.tree.all(jax.tree.map(
+                lambda a, b, x: a.is_equivalent_to(b, x.ndim),
+                was, now, (params, opt)))
+            assert all(x.is_deleted() for x in jax.tree.leaves(was_p))
+        # one trace served the three steps: one plan span
+        assert len([r for r in telemetry.drain_spans("test")
+                    if r["name"] == "fsdp.plan"]) == 1
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6),
+        params, want_p)
+
+
+@pytest.mark.parametrize("chunk", [64, 8192],
+                         ids=["padded_tail", "chunk_over_local_tokens"])
+def test_chunked_lm_loss_under_the_mesh_is_the_unsharded_call(chunk):
+    """8 sequences of 37 tokens over fsdp=4: a device holds 74 tokens,
+    neither a multiple of its 16-token share of ``chunk=64`` nor as many
+    as its share of the default."""
+    from ray_tpu.ops.fused import chunked_lm_loss
+
+    rng = np.random.default_rng(1)
+    hidden = jnp.asarray(rng.normal(size=(8, 37, 32)), jnp.float32)
+    emb = jnp.asarray(rng.normal(size=(101, 32)), jnp.float32)
+    labels = jnp.asarray(rng.integers(0, 101, (8, 37)), jnp.int32)
+    mesh = build_mesh(MeshConfig(fsdp=4), devices=jax.devices()[:4])
+
+    def both(mesh):
+        return jax.jit(jax.value_and_grad(
+            lambda h, e: chunked_lm_loss(h, e, labels, chunk=chunk,
+                                         mesh=mesh), argnums=(0, 1)))
+
+    want, (want_h, want_e) = both(None)(hidden, emb)
+    got, (got_h, got_e) = both(mesh)(
+        jax.device_put(hidden, NamedSharding(mesh, P("fsdp"))),
+        jax.device_put(emb, NamedSharding(mesh, P(None, "fsdp"))))
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    np.testing.assert_allclose(got_h, want_h, rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(got_e, want_e, rtol=1e-5, atol=1e-6)
+    text = str(jax.make_jaxpr(both(mesh))(hidden, emb))
+    assert "shard_map" in text
+    assert "shard_map" not in str(jax.make_jaxpr(both(None))(hidden, emb))
+
+
+#: sha256 of the jaxpr text of the GPT-2 train step on ``GPT2Config.tiny``
+#: with no mesh, the flash kernels traced (not the CPU's reference), as
+#: the file traced it BEFORE it said anything about activations
+STEP_BEFORE = {
+    "": "09b991c8836ef891cbed683d1ac75064057677209de52cd59976c40a3ecc6336",
+    "full": "4882aa219589166e88dc5205f5d4e39a542547341b9f8583c2bc4b606098acc5",
+}
+
+
+def _step_jaxpr(remat, mesh=None):
+    import optax
+    from flax.core import meta
+
+    from ray_tpu.models.gpt2 import GPT2, GPT2Config, make_train_step
+
+    model = GPT2(GPT2Config.tiny(remat=remat))
+    tx = optax.adamw(1e-3)
+    params = meta.unbox(jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0))))
+    args = (params, jax.eval_shape(tx.init, params),
+            jax.ShapeDtypeStruct((4, 128), jnp.int32))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            use_mesh(mesh):
+        return str(jax.make_jaxpr(make_train_step(model, tx))(*args)), \
+            params
+
+
+@pytest.mark.parametrize("remat", sorted(STEP_BEFORE))
+def test_with_no_mesh_the_step_traces_as_it_did(remat):
+    telemetry.drain_spans("test")
+    text, _ = _step_jaxpr(remat)
+    assert "sharding_constraint" not in text and "shard_map" not in text
+    assert text.count("pallas_call") == (8 if remat else 6)
+    assert hashlib.sha256(text.encode()).hexdigest() == STEP_BEFORE[remat]
+    assert not [r for r in telemetry.drain_spans("test")
+                if r["name"] == "fsdp.plan"]
+
+
+def test_under_a_mesh_the_step_says_its_plan_once_a_trace():
+    mesh = build_mesh(MeshConfig(fsdp=4), devices=jax.devices()[:4])
+    telemetry.drain_spans("test")
+    text, params = _step_jaxpr("full", mesh)
+    rows = [r for r in telemetry.drain_spans("test")
+            if r["name"] == "fsdp.plan"]
+    assert len(rows) == 1 and rows[0]["cat"] == "parallel"
+    # the embedding's output, ln_f's, and each block's input and output,
+    # forward and recomputed; their cotangents
+    assert text.count("sharding_constraint") >= 2 + 2 * 2 * 2
+    # reckoned from the tree: what FSDP_RULES splits over fsdp
+    from ray_tpu.models.gpt2 import GPT2, GPT2Config
+
+    boxed = jax.eval_shape(lambda: GPT2(GPT2Config.tiny()).init_params(
+        jax.random.PRNGKey(0)))
+    plain, specs = sharding.flax_sharding(boxed, FSDP_RULES)
+    split = [x.size * x.dtype.itemsize for x, s in zip(
+        jax.tree.leaves(plain),
+        jax.tree.leaves(specs, is_leaf=lambda s: isinstance(s, P)))
+        if "fsdp" in s]
+    whole = len(jax.tree.leaves(plain)) - len(split)
+    assert len(split) == 2 + 2 * 10 + 2 and whole == 2 * 2
+    assert rows[0]["args"] == {
+        "mesh": "fsdp=4", "leaves_sharded": len(split),
+        "leaves_whole": whole, "gather_bytes": 3 * sum(split),
+        "scatter_bytes": sum(split),
+        "act_spec": "PartitionSpec('fsdp', None, None)"}
+    assert jax.tree.structure(params) == jax.tree.structure(plain)
