@@ -6,8 +6,8 @@
 #   make tsan   — ThreadSanitizer build of the concurrency stress
 #                 harness (src/store_stress.cc) + run
 #   make asan   — AddressSanitizer+UBSan build + run
-.PHONY: all native check check-fast test chaos bench bench-transfer bench-serve \
-	bench-serve-sharded bench-rl bench-controlplane bench-store \
+.PHONY: all native check check-fast test chaos bench-transfer bench-serve \
+	bench-serve-sharded bench-controlplane bench-store \
 	bench-ha bench-data metrics-smoke metrics-history-smoke \
 	postmortem-smoke tsan asan sanitize clean
 
@@ -74,13 +74,6 @@ chaos: native
 	  -q -m "slow or not slow" \
 	  -p no:cacheprovider -p no:randomly
 
-# Full microbenchmark suite; persists BENCH_RESULT.json and regenerates
-# the README table from it in the same run, so the committed table can
-# never lag the artifact it names (tests/test_bench_table.py enforces).
-bench: native
-	JAX_PLATFORMS=cpu python bench.py
-	python scripts/gen_bench_table.py --write
-
 # Quick transfer-plane microbench (broadcast + multi-client put) with a
 # one-line JSON delta vs the newest BENCH_r*.json baseline artifact.
 bench-transfer: native
@@ -99,12 +92,6 @@ bench-serve: native
 # JSON delta vs the newest BENCH_r*.json rows (docs/serving.md).
 bench-serve-sharded: native
 	JAX_PLATFORMS=cpu python scripts/bench_serve_sharded.py
-
-# RL-pipeline bench: decoupled PPO (env actors + centralized batched
-# inference) vs the legacy fleet, with both worker-count scaling
-# curves; one-line JSON delta vs the newest BENCH_r*.json PPO rows.
-bench-rl: native
-	JAX_PLATFORMS=cpu python scripts/bench_rl.py
 
 # Control-plane bench: actor-storm creation rate (many_actors row),
 # create+destroy churn, PG churn, and lease-grant p99 flatness 1 node

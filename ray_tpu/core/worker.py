@@ -3843,13 +3843,20 @@ class CoreWorker:
         Each task's result is PUSHED back as it completes (see
         _consume_exec_queue); the final reply carries the full list as
         the authoritative completion for bookkeeping."""
-        if self._exit_after_reply or (
-                _fp.active()
-                and _fp.failpoint("worker.push_tasks.reject")):
-            # failpoint: force the exiting-worker rejection reply — the
+        if not self._exit_after_reply and _fp.active() \
+                and _fp.failpoint("worker.push_tasks.reject"):
+            # failpoint: force the exiting-worker rejection — the
             # production trigger (a batch racing the max_calls exit
             # decision) is a sub-millisecond window no test can hit
-            # deterministically
+            # deterministically.  The worker then IS exiting: an empty
+            # batch behind whatever is queued takes the exec thread to
+            # its exit, so the raylet gets the lease back as it would
+            # (a rejecting worker that lived on kept its CPU for good,
+            # and four of them starved the owner)
+            self._exit_after_reply = True
+            self._exec_queue.put(([], self._loop.create_future(),
+                                  lambda items: None))
+        if self._exit_after_reply:
             return {"rejected": "worker exiting", "worker_exit": True}
         specs: List[TaskSpec] = pickle.loads(data["specs_blob"])
         for spec in specs:

@@ -1,7 +1,7 @@
 """GPT-2 in flax linen, TPU-first.
 
-The benchmark flagship (BASELINE.json: GPT-2 124M data-parallel on TPU).
-Design notes:
+The benchmark's dense model (``BENCHMARK.json``: GPT-2 large on one
+chip, GPT-2 XL under FSDP on four).  Design notes:
 - bfloat16 activations/params by default, float32 softmax/layernorm
   accumulation — MXU-friendly.
 - attention goes through ``ray_tpu.ops.flash_attention`` (pallas kernel on
@@ -43,9 +43,9 @@ class GPT2Config:
     sp_axis: str = "sp"
     #: activation rematerialization per block: "" (store activations),
     #: "full" (recompute everything in backward), or "dots" (save
-    #: matmul outputs, recompute elementwise).  The train step is
-    #: memory-bound (profiles/ANALYSIS.md), so trading HBM bytes for
-    #: MXU recompute can be a net win.
+    #: matmul outputs, recompute elementwise).  The benchmark's cells
+    #: run "full": GPT-2 large's training state leaves no room for a
+    #: step's activations on a 16 GB chip (``PERF.md`` section 4).
     remat: str = ""
 
     @classmethod
@@ -114,8 +114,7 @@ class Block(nn.Module):
         x = constrain_activation(x, "batch", "seq", "embed")
 
         # block LNs emit cfg.dtype (statistics still accumulate f32
-        # inside flax): the f32 round-trip costs 3x the HBM traffic and
-        # measured 35.6 -> 11.6 ms per step across the 25 LN sites
+        # inside flax): an f32 round-trip costs 3x the HBM traffic
         h = nn.LayerNorm(dtype=cfg.dtype, name="ln_1",
                          scale_init=nn.with_partitioning(
                              nn.initializers.ones, ("embed",)),
@@ -243,8 +242,7 @@ def param_axes(config: GPT2Config):
 
 
 def loss_fn(model: GPT2, params, tokens: jax.Array,
-            head_chunk: int = 8192,
-            head_logits_dtype: Any = None) -> jax.Array:
+            head_chunk: int = 8192) -> jax.Array:
     """Next-token cross entropy (labels = tokens shifted left).
 
     The LM head + softmax run in token chunks (``chunked_lm_loss``):
@@ -255,17 +253,14 @@ def loss_fn(model: GPT2, params, tokens: jax.Array,
 
     x, wte = model.apply({"params": params}, tokens, method=GPT2.hidden)
     # bf16-activation models run the head matmuls on the MXU in bf16;
-    # logits accumulate/store f32 unless the caller opts into
-    # ``head_logits_dtype=bf16`` (bench throughput mode — see the
-    # precision caveat in ops/fused.py)
+    # logits accumulate and are stored in f32
     compute = jnp.bfloat16 if model.config.dtype == jnp.bfloat16 else None
     return chunked_lm_loss(x[:, :-1], wte, tokens[:, 1:],
                            chunk=head_chunk, compute_dtype=compute,
-                           logits_dtype=head_logits_dtype,
                            mesh=get_global_mesh())
 
 
-def make_train_step(model: GPT2, tx, head_logits_dtype: Any = None):
+def make_train_step(model: GPT2, tx):
     """The jitted train step the GPT-2 entry points share: chunked-head
     loss, gradients, one ``tx`` (optax) update.  Params and optimizer
     state are donated so XLA updates them in place (saves an HBM copy
@@ -279,9 +274,7 @@ def make_train_step(model: GPT2, tx, head_logits_dtype: Any = None):
                        functools.partial(param_axes, model.config),
                        passes=3 if model.config.remat == "full" else 2):
             loss, grads = jax.value_and_grad(
-                lambda p: loss_fn(model, p, tokens,
-                                  head_logits_dtype=head_logits_dtype)
-            )(params)
+                lambda p: loss_fn(model, p, tokens))(params)
         updates, opt_state = tx.update(grads, opt_state, params)
         return optax.apply_updates(params, updates), opt_state, loss
 

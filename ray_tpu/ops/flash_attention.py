@@ -5,10 +5,18 @@ three kernels use the same structure: a 4-d grid whose last axis is
 sequential ("arbitrary" dimension semantics) streaming K/V (forward,
 dQ) or Q (dK/dV) tiles while the online-softmax statistics / gradient
 accumulators live in VMEM scratch across its iterations.  VMEM usage
-is therefore O(block), independent of sequence length — 32k-token
-fwd+bwd runs on one v5e chip (bench.py long-context detail); beyond
-one chip, ``ray_tpu.parallel.ring_attention`` composes with this
-kernel per shard.
+is therefore O(block), independent of sequence length; beyond one
+chip, ``ray_tpu.parallel.ring_attention`` composes with this kernel
+per shard.
+
+Two kernel families, chosen from the shapes alone (``_nl_eligible``):
+the native-layout kernels read ``[B, T, H, D]`` as it lies, so nothing
+is transposed around the calls, but they need whole 128-lane slabs of
+heads; the head-major kernels take any head count and dimension.  Both
+stay because the benchmark has cells on each side: 20 heads of 64
+(GPT-2 large) and 32 on 4 heads of 128 (Trinity-Mini) are eligible, 25
+heads of 64 (GPT-2 XL) cannot pack two to a slab (``PERF.md`` section
+4).
 
 Matmul operands stay in the input dtype (bf16 on TPU) with f32
 accumulation via ``preferred_element_type`` — the MXU's native mode.
@@ -36,6 +44,9 @@ import jax.numpy as jnp
 from jax import lax
 
 NEG_INF = -1e30
+
+#: block size along both sequence axes when the caller names none
+DEFAULT_BLOCK = 1024
 
 
 def _clamp_k_tile(j, i, block_q: int, block_k: int,
@@ -487,11 +498,10 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
 # Native-layout ("NL") kernels: consume [B, T, H, D] directly.
 #
 # The kernels above want [B, H, T, D]; XLA materializes layout transposes
-# around the custom-calls to provide it — ~37 ms/step (~30 GB of HBM copy
-# traffic) on the GPT-2 bench step (profiles/ANALYSIS.md, "data
-# formatting").  Round-2/4 attempts to consume [B,T,H,D] head-in-block
-# died to pallas tiling: (H=12, D=64) trailing dims pad to (16, 128), a
-# 2.7x VMEM inflation that OOMs scoped vmem at useful block sizes.
+# around the custom-calls to provide it.  Consuming [B,T,H,D] with the
+# head inside the block does not tile: (H=12, D=64) trailing dims pad to
+# (16, 128), a 2.7x VMEM inflation that overflows scoped vmem at useful
+# block sizes.
 #
 # The NL kernels sidestep the padding instead of fighting it: collapse
 # the two minor dims with a free reshape [B,T,H,D] -> [B,T,H*D] and tile
@@ -510,7 +520,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
 # multiplies.  Softmax statistics ride in per-head [block_q, 1] scratch
 # (sublane vectors — lane-broadcastable with no per-iteration relayout);
 # LSE/delta travel between forward and backward as [B, H2, T, pack]
-# (T in sublanes for the same reason; ~3 MB at the bench shape).
+# (T in sublanes for the same reason).
 #
 # Reference anchor: net-new TPU territory (SURVEY §2.5) — the reference's
 # flash attention is a CUDA kernel with its own layout constraints.
@@ -940,10 +950,9 @@ def _flash_nl_backward(q, k, v, out, lse, g, causal, scale, block_q,
 
 
 def _chunk_blocks(seq_q: int, seq_k: int):
-    """Ring-chunk block sizes: the shared env-overridable defaults,
-    shrunk to divisors of the (arbitrary) chunk lengths."""
-    block_q, block_k = _resolve_blocks(None, None)
-    return fit_block(seq_q, block_q), fit_block(seq_k, block_k)
+    """Ring-chunk block sizes: the default, shrunk to divisors of the
+    (arbitrary) chunk lengths."""
+    return fit_block(seq_q, DEFAULT_BLOCK), fit_block(seq_k, DEFAULT_BLOCK)
 
 
 def _flash_chunk_fwd(q, k, v, causal: bool, scale: float,
@@ -954,13 +963,13 @@ def _flash_chunk_fwd(q, k, v, causal: bool, scale: float,
     log-sum-exp — the pair downstream code merges across chunks with the
     standard rescaling identity.  f32 out keeps the cross-chunk
     accumulation at one rounding total (the per-tile VMEM accumulators
-    are f32 already).  Kernel-dispatched like ``flash_attention`` —
-    same RAY_TPU_FLASH_NATIVE / _BLOCK_Q/K knobs — but with no autodiff
+    are f32 already).  Kernel-dispatched like ``flash_attention`` (the
+    family from the shapes, the default block) but with no autodiff
     rule: callers own the backward (the ring builds it from
     ``_flash_chunk_bwd``)."""
     batch, seq_q, heads, dim = q.shape
     block_q, block_k = _chunk_blocks(seq_q, k.shape[1])
-    if _resolve_native(q, k, v, None):
+    if _nl_eligible(q, k, v):
         out, lse = _flash_nl_forward(q, k, v, causal, scale, block_q,
                                      block_k, interpret,
                                      out_dtype=jnp.float32)
@@ -985,7 +994,7 @@ def _flash_chunk_bwd(q, k, v, out, lse, g, causal: bool, scale: float,
     cross-chunk accumulation."""
     batch, seq_q, heads, dim = q.shape
     block_q, block_k = _chunk_blocks(seq_q, k.shape[1])
-    if _resolve_native(q, k, v, None):
+    if _nl_eligible(q, k, v):
         pack = 128 // dim
         h2 = heads // pack
 
@@ -1005,30 +1014,6 @@ def _flash_chunk_bwd(q, k, v, out, lse, g, causal: bool, scale: float,
                            else delta.transpose(0, 2, 1)[..., None])
 
 
-def _resolve_blocks(block_q, block_k):
-    """Default block sizes, with the RAY_TPU_FLASH_BLOCK_Q/K tuning
-    escape hatches applied only when the caller passed no explicit
-    size."""
-    import os
-    if block_q is None:
-        block_q = int(os.environ.get("RAY_TPU_FLASH_BLOCK_Q") or 1024)
-    if block_k is None:
-        block_k = int(os.environ.get("RAY_TPU_FLASH_BLOCK_K") or 1024)
-    return block_q, block_k
-
-
-def _resolve_native(q, k, v, native, bwd_impl="pallas"):
-    """Shared native-vs-head-major dispatch: explicit ``native`` wins,
-    otherwise auto-select eligible shapes unless RAY_TPU_FLASH_NATIVE
-    disables it or an XLA backward was requested."""
-    import os
-    if native is not None:
-        return native
-    env = os.environ.get("RAY_TPU_FLASH_NATIVE", "").lower()
-    return (env not in ("0", "false", "off")
-            and bwd_impl == "pallas" and _nl_eligible(q, k, v))
-
-
 def fit_block(seq: int, block: int) -> int:
     """Largest divisor of ``seq`` that is <= ``block`` (the pallas grids
     need the sequence to divide into whole tiles)."""
@@ -1038,7 +1023,7 @@ def fit_block(seq: int, block: int) -> int:
     return 1
 
 
-def kernel_block_for(seq: int, block: int = 1024):
+def kernel_block_for(seq: int, block: int = DEFAULT_BLOCK):
     """Fitted block size when ``seq`` divides into sublane-aligned tiles
     big enough for the flash kernels to pay off, else ``None`` — the
     shared eligibility test for sequence-parallel dispatch (ring and
@@ -1085,8 +1070,8 @@ def _flash_nl_bwd(causal, scale, block_q, block_k, interpret, window, res,
 _flash_nl.defvjp(_flash_nl_fwd, _flash_nl_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret, bwd_impl,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, scale, block_q, block_k, interpret,
            window=None):
     out, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k,
                             interpret, window=window)
@@ -1094,30 +1079,17 @@ def _flash(q, k, v, causal, scale, block_q, block_k, interpret, bwd_impl,
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-               bwd_impl, window):
+               window):
     out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
                               interpret, window=window)
-    if bwd_impl == "pallas":
-        return out, (q, k, v, out, lse)
-    return out, (q, k, v, None, None)
+    return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, bwd_impl,
-               window, res, g):
+def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res,
+               g):
     q, k, v, out, lse = res
-    if bwd_impl == "pallas":
-        return _flash_backward(q, k, v, out, lse, g, causal, scale,
-                               block_q, block_k, interpret, window=window)
-    # default: XLA recompute through the reference formulation — inside
-    # one big jitted step XLA fuses/remats this better than the pallas
-    # backward's layout copies (measured: 58.6k vs 18.2k tok/s on the
-    # GPT-2-small bench), while the pallas *forward* still provides the
-    # O(T) memory inference/eval path
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: _attention_reference(q_, k_, v_, causal, scale,
-                                                window),
-        q, k, v)
-    return vjp(g)
+    return _flash_backward(q, k, v, out, lse, g, causal, scale,
+                           block_q, block_k, interpret, window=window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -1128,7 +1100,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
-                    bwd_impl: str = "pallas",
                     native: Optional[bool] = None,
                     mesh: Optional[jax.sharding.Mesh] = None,
                     window: Optional[int] = None) -> jax.Array:
@@ -1146,29 +1117,22 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     attention being independent in both.  Inside a user ``shard_map``
     the axes are already bound: pass nothing.
 
-    On TPU runs the pallas kernel; on other backends (tests) falls back
-    to the jnp reference unless ``interpret=True`` forces the kernel
-    through the pallas interpreter.  ``bwd_impl``: "pallas" (default —
-    FlashAttention-2 dK/dV + dQ kernels, O(T) memory) or "xla"
-    (recompute through XLA fusion).  1024-blocks + pallas backward
-    measured 7.2 ms vs 20.1 ms for 128-blocks + XLA backward on the
-    GPT-2-small shapes (v5e, [32,1024,12,64]) — the tile must be large
-    enough to amortize the f32 softmax VPU work per MXU matmul.  The
-    grid streams K/V tiles with VMEM-scratch accumulators, so memory
-    stays O(block) at any sequence length (32k fwd+bwd verified on
-    v5e; see bench.py long-context detail).
+    On TPU runs the pallas kernels, forward and backward
+    (FlashAttention-2 dK/dV and dQ kernels, O(T) memory); on other
+    backends (tests) falls back to the jnp reference unless
+    ``interpret=True`` forces the kernels through the pallas
+    interpreter.  The grid streams K/V tiles with VMEM-scratch
+    accumulators, so memory stays O(block) at any sequence length.
+    ``block_q``/``block_k`` default to ``DEFAULT_BLOCK``: the tile must
+    be large enough to amortize the f32 softmax work on the VPU per MXU
+    matmul.
 
-    ``native`` selects the native-layout kernels that consume
-    ``[B, T, H, D]`` directly (head_dim 64 or 128, head count divisible
-    by ``128 // head_dim``); default auto-selects them when eligible —
-    unless ``bwd_impl="xla"`` is requested, which only the head-major
-    path honors — and ``RAY_TPU_FLASH_NATIVE=0`` forces the head-major
-    kernels for A/B.
-    Killing the layout transposes around the custom-calls measured
-    312.7 -> 276.9 ms/step on the GPT-2 bench step (MFU 45.8 -> 51.7%)
-    and 84.1 -> 80.7 ms on 32k-token fwd+bwd; the follow-up VPU cuts
-    (guard-select removal, backward lse clamp, diagonal-split causal)
-    took 32k to 73.6 ms (v5e, round 5).  Both kernel families agree to
+    ``native``: which kernel family runs follows from the shapes
+    (``_nl_eligible``: head_dim 64 or 128 and whole 128-lane slabs of
+    heads take the native-layout kernels, which read ``[B, T, H, D]``
+    with no transposes around the calls; anything else, 25 heads of 64
+    among them, takes the head-major kernels).  A test passes ``True``
+    or ``False`` to hold one family against the other; both agree to
     f32-ulp level (test_ops.py).
     """
     if scale is None:
@@ -1188,10 +1152,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         raise ValueError(
             f"native-layout flash attention needs head_dim in (64, 128) "
             f"and heads divisible by 128//head_dim; got {q.shape}")
-    if native and bwd_impl != "pallas":
-        raise ValueError(
-            "the native-layout kernels have a pallas backward only; "
-            "bwd_impl=%r requires native=False" % (bwd_impl,))
     backend = jax.default_backend()
     if interpret is None:
         if backend != "tpu":
@@ -1204,16 +1164,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                  None, "tp" if "tp" in mesh.axis_names else None, None)
         per_shard = functools.partial(
             flash_attention, causal=causal, scale=scale, block_q=block_q,
-            block_k=block_k, interpret=interpret, bwd_impl=bwd_impl,
-            native=native, window=window)
+            block_k=block_k, interpret=interpret, native=native,
+            window=window)
         return jax.shard_map(per_shard, mesh=mesh, in_specs=(spec,) * 3,
                              out_specs=spec, check_vma=False)(q, k, v)
-    block_q, block_k = _resolve_blocks(block_q, block_k)
-    # an explicit bwd_impl="xla" request keeps the head-major path — the
-    # NL family has no XLA-recompute backward to honor it with
-    native = _resolve_native(q, k, v, native, bwd_impl)
+    block_q = DEFAULT_BLOCK if block_q is None else block_q
+    block_k = DEFAULT_BLOCK if block_k is None else block_k
+    if native is None:
+        native = _nl_eligible(q, k, v)
     if native:
         return _flash_nl(q, k, v, causal, scale, block_q, block_k,
                          interpret, window)
     return _flash(q, k, v, causal, scale, block_q, block_k, interpret,
-                  bwd_impl, window)
+                  window)

@@ -163,11 +163,11 @@ def _lm_loss_sum(hidden, emb, labels, *, chunk, compute_dtype,
             # MXU path: bf16 operands, f32 accumulation by default.
             # ``logits_dtype=bf16`` opts into storing the [chunk, V]
             # block (the step's largest HBM consumer, read several
-            # times per chunk in fwd+bwd) in half width — measured +1
-            # MFU point on the v5e bench, but logits quantize at FULL
-            # magnitude before the max-subtract, so the error grows
-            # with logit scale (~0.06 per logit at |x|~16); keep the
-            # f32 default for long training runs.
+            # times per chunk in fwd+bwd) in half width: logits then
+            # quantize at FULL magnitude before the max-subtract, so
+            # the error grows with logit scale (~0.06 per logit at
+            # |x|~16).  No training path asks for it; the benchmark's
+            # ``lower_precision`` control does (``models/afmoe.py``).
             logits = jax.lax.dot_general(
                 h.astype(compute_dtype), emb_f32.astype(compute_dtype),
                 (((1,), (1,)), ((), ())),

@@ -16,7 +16,6 @@ from ray_tpu.parallel.sharding import (  # noqa: F401
     ShardingRules,
     logical_to_mesh,
     shard_params,
-    with_sharding_constraint,
 )
 from ray_tpu.parallel.ring_attention import ring_attention  # noqa: F401
 from ray_tpu.parallel.ulysses import ulysses_attention  # noqa: F401

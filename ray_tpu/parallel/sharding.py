@@ -153,16 +153,6 @@ def shard_params(params: Any, logical_specs: Any, rules: ShardingRules,
         params, specs)
 
 
-def with_sharding_constraint(x: Any, rules: ShardingRules,
-                             *logical_axes: Optional[str]) -> Any:
-    """In-jit activation sharding hint."""
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, rules.activation_spec(*logical_axes))
-    except (ValueError, RuntimeError):
-        return x  # outside jit/mesh context: no-op
-
-
 def constrain_activation(x: jax.Array, *logical_axes: Optional[str],
                          mesh: Optional[Mesh] = None) -> jax.Array:
     """Say where activation ``x`` lies on ``mesh`` (default: the global

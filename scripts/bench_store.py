@@ -6,8 +6,8 @@ the curve the sharded store metadata exists for) and a put/get round
 over a working set ~2x the arena that rotates through the raylet's
 spill tier with transparent restore — then prints ONE line of JSON
 with the measured values and their delta against the repo baseline, so
-``make bench-store`` gives a sub-two-minute signal on store work
-without paying for the full benchmark harness.
+``make bench-store`` gives a sub-two-minute signal on store work.  A
+count on the host's CPUs, not a chip measurement.
 
 Baseline resolution: the newest parseable ``BENCH_r*.json`` artifact
 (the per-round records kept next to ``BASELINE.json``); rows missing
@@ -26,6 +26,7 @@ import glob
 import json
 import os
 import re
+import statistics
 import sys
 import time
 
@@ -66,6 +67,82 @@ def load_baseline() -> dict:
     return dict(FALLBACK_BASELINE)
 
 
+def put_writer_sweep(putters, gbits: float, reps: int) -> dict:
+    """Aggregate put bandwidth at 1/2/4/8 concurrent writers: each
+    point is a median of ``reps`` timed rounds of 2 puts per writer."""
+    import ray_tpu
+
+    sweep = {}
+    for n in (1, 2, 4, 8):
+        samples = []
+        for i in range(reps):
+            if i:
+                time.sleep(1.5)
+            t0 = time.perf_counter()
+            ray_tpu.get([p.put_big.remote(2) for p in putters[:n]],
+                        timeout=600)
+            samples.append(n * 2 * gbits / (time.perf_counter() - t0))
+        sweep[str(n)] = round(statistics.median(samples), 2)
+        time.sleep(1.5)
+    return sweep
+
+
+def bench_store_spill() -> dict:
+    """Larger-than-arena put/get round: a working set ~2x the object
+    store rotates through the raylet's spill tier and restores
+    transparently on get — correctness (checksums) plus round-trip
+    bandwidth.  Runs on its own mini cluster so the deliberately tiny
+    arena can't bleed into other sections."""
+    import numpy as np
+
+    import ray_tpu
+
+    out: dict = {}
+    arena = 256 * 1024 * 1024
+    chunk = 32 * 1024 * 1024
+    n_objects = 16  # 512 MiB working set vs the 256 MiB arena
+    ray_tpu.init(_system_config={
+        "object_store_memory": arena,
+        "object_spill_threshold": 0.8,
+        "num_prestart_workers": 1,
+    })
+    try:
+        rng = np.random.default_rng(7)
+        payload = rng.integers(0, 255, chunk, dtype=np.uint8)
+        sums, refs = [], []
+        t0 = time.perf_counter()
+        for i in range(n_objects):
+            payload[:8] = i  # distinct objects, one allocation
+            refs.append(ray_tpu.put(payload))
+            sums.append(int(payload.sum()))
+        put_s = time.perf_counter() - t0
+        from ray_tpu.experimental.state import object_store_stats
+        try:
+            stats = object_store_stats()[0]
+        except Exception:  # noqa: BLE001 — accounting row is optional
+            stats = {}
+        t0 = time.perf_counter()
+        for i, ref in enumerate(refs):
+            got = ray_tpu.get(ref, timeout=120)
+            assert int(np.asarray(got).sum()) == sums[i], \
+                f"spill roundtrip corrupted object {i}"
+            del got
+        get_s = time.perf_counter() - t0
+        total_gbits = n_objects * chunk * 8 / 1e9
+        out["spill_put_gbps"] = round(total_gbits / put_s, 2)
+        out["spill_get_gbps"] = round(total_gbits / get_s, 2)
+        out["spill_roundtrip_gbps"] = round(
+            2 * total_gbits / (put_s + get_s), 2)
+        if isinstance(stats, dict) and stats.get("num_spilled"):
+            out["spill_objects_peak"] = stats["num_spilled"]
+    finally:
+        try:
+            ray_tpu.shutdown()
+        except Exception:  # noqa: BLE001
+            pass
+    return out
+
+
 def bench_sweep(mb: int, reps: int) -> dict:
     """1/2/4/8-writer aggregate put bandwidth on a default-size arena."""
     import ray_tpu
@@ -88,14 +165,11 @@ def bench_sweep(mb: int, reps: int) -> dict:
                     _rt.put(self.data)
                 return n
 
-        import bench as bench_mod
-
         gbits = mb * 1024 * 1024 * 8 / 1e9
         putters = [Putter.remote(mb) for _ in range(8)]
         ray_tpu.get([p.put_big.remote(1) for p in putters], timeout=180)
         time.sleep(3.0)
-        sweep = bench_mod.put_writer_sweep(putters, gbits, reps,
-                                           settle=time.sleep)
+        sweep = put_writer_sweep(putters, gbits, reps)
         out["put_gbps_by_writers"] = sweep
         out["put_gbps_single_client"] = sweep["1"]
         out["put_gbps_multi_client"] = sweep["4"]
@@ -120,9 +194,7 @@ def main() -> None:
     if not args.skip_sweep:
         result.update(bench_sweep(args.mb, args.reps))
     if not args.skip_spill:
-        import bench as bench_mod
-
-        result.update(bench_mod.bench_store_spill())
+        result.update(bench_store_spill())
 
     baseline = load_baseline()
     delta = {}

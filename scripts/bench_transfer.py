@@ -1,11 +1,11 @@
 """Quick object-transfer microbench: broadcast + multi-client put.
 
-Runs the two transfer-plane rows from ``bench.py`` (the 1->N broadcast
-over a 4-node virtual cluster and the 4-putter multi-client put) at a
-reduced repeat count, then prints ONE line of JSON with the measured
+Runs the two transfer-plane rows (the 1->N broadcast over a 4-node
+virtual cluster and the 4-putter multi-client put) at a reduced repeat
+count, then prints ONE line of JSON with the measured
 values and their delta against the repo baseline, so ``make
-bench-transfer`` gives a sub-two-minute signal on transfer-plane work
-without paying for the full benchmark harness.
+bench-transfer`` gives a sub-two-minute signal on transfer-plane work.
+A count on the host's CPUs, not a chip measurement.
 
 Baseline resolution: the newest parseable ``BENCH_r*.json`` artifact
 (the per-round records kept next to ``BASELINE.json``); rows missing
@@ -71,7 +71,7 @@ def bench(mb: int, consumers: int, reps: int, skip_put: bool,
     from ray_tpu.cluster_utils import Cluster
 
     out: dict = {}
-    # full default-size prestart pool (bench.py parity): with a smaller
+    # full default-size prestart pool: with a smaller
     # pool the broadcast row measures worker-spawn churn, not transfer
     # (the idle-pool trim re-spawns workers between repeats)
     c = Cluster(initialize_head=True, head_node_args={"num_cpus": 4})

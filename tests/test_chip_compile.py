@@ -66,11 +66,9 @@ def _attn_grads(family: str):
         scale = q.shape[-1] ** -0.5
 
         def loss(q, k, v):
-            if family == "native":
-                out = fa._flash_nl(q, k, v, True, scale, 1024, 1024, False)
-            else:
-                out = fa._flash(q, k, v, True, scale, 1024, 1024, False,
-                                "pallas")
+            kernels = fa._flash_nl if family == "native" else fa._flash
+            out = kernels(q, k, v, True, scale, fa.DEFAULT_BLOCK,
+                          fa.DEFAULT_BLOCK, False)
             return out.astype(jnp.float32).sum()
 
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)

@@ -99,13 +99,15 @@ def test_step_monitor_phases_telescope_to_wall_time():
     wall_total = 0.0
     for _ in range(5):
         t_prev = time.time()
-        time.sleep(0.004)                       # the input-pipeline wait
+        # phases long enough that a loaded machine's oversleep (a few
+        # ms beside five busy test workers) cannot reorder them
+        time.sleep(0.020)                       # the input-pipeline wait
         span = mon.step(data_wait_s=time.time() - t_prev)
-        time.sleep(0.003)                       # host dispatch
+        time.sleep(0.015)                       # host dispatch
         span.dispatched()
-        time.sleep(0.006)                       # device compute
+        time.sleep(0.060)                       # device compute
         span.device_done()
-        time.sleep(0.002)                       # sync / bookkeeping
+        time.sleep(0.010)                       # sync / bookkeeping
         span.done(tokens=50.0)
         wall_total += time.time() - t_prev
     st = mon.stats()
@@ -121,7 +123,7 @@ def test_step_monitor_phases_telescope_to_wall_time():
         st["goodput_per_s"] * 100.0 / 1000.0)
     assert 0.0 < st["data_wait_frac"] < 1.0
     assert 0.0 < st["device_frac"] < 1.0
-    assert st["device_frac"] > st["data_wait_frac"]  # 6ms vs 4ms
+    assert st["device_frac"] > st["data_wait_frac"]  # 60 ms vs 20 ms
 
 
 def test_step_monitor_attributes_device_seconds_to_thread():

@@ -1,0 +1,50 @@
+"""The README describes the instrument that exists: ``BENCHMARK.json``'s
+command, cells and end-to-end metrics, by name, and no table of numbers
+that a run would have to regenerate."""
+
+import json
+import os
+import re
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _read(name):
+    with open(os.path.join(ROOT, name)) as f:
+        return f.read()
+
+
+BENCHMARK = json.loads(_read("BENCHMARK.json"))
+
+
+@pytest.fixture(scope="module")
+def benchmarks_section():
+    readme = _read("README.md")
+    start = readme.index("\n## Benchmarks\n")
+    end = readme.find("\n## ", start + 1)
+    return readme[start:end if end != -1 else None]
+
+
+def test_readme_names_every_cell_and_metric(benchmarks_section):
+    names = [w["name"] for w in BENCHMARK["workloads"]] \
+        + [m["name"] for m in BENCHMARK["end_to_end"]]
+    assert names
+    missing = [n for n in names if f"`{n}`" not in benchmarks_section]
+    assert not missing, f"README's Benchmarks section does not name {missing}"
+    assert " ".join(BENCHMARK["command"]) in benchmarks_section
+    for flag in ("--workload", "--seed", "--seconds", "--trace"):
+        assert flag in benchmarks_section
+    assert "PERF.md" in benchmarks_section
+    assert "PERF_LEDGER.jsonl" in benchmarks_section
+
+
+def test_readme_publishes_no_number_of_its_own(benchmarks_section):
+    """Numbers live in ``PERF.md`` and the ledger, with their origin: a
+    rate, a time or a share printed here would go stale unseen."""
+    assert "BENCH_TABLE" not in _read("README.md")
+    measured = re.findall(
+        r"\d[\d,.]*\s*(?:%|ms\b|us\b|s\b|tokens/s|/s\b|GiB|GB|TFLOP)",
+        benchmarks_section)
+    assert not measured, measured

@@ -27,6 +27,7 @@ import time
 import pytest
 
 import ray_tpu
+from ray_tpu._test_utils import wait_for_condition
 from ray_tpu.core import rpc
 from ray_tpu.util import failpoint as fp
 
@@ -318,7 +319,9 @@ def rejecting_worker_cluster():
     with the exiting-worker reply (``worker.push_tasks.reject`` fires
     inside ``handle_push_tasks``), forcing the batch-rejection path
     deterministically — the production trigger (a batch racing the
-    max_calls exit decision) is a sub-millisecond window."""
+    max_calls exit decision) is a sub-millisecond window.  A worker
+    that rejects then exits, as the one it stands for does, so the
+    raylet takes its lease back."""
     spec = f"worker.push_tasks.reject=drop:count=1,seed={SEED}"
     os.environ["RAY_TPU_FAILPOINTS"] = spec
     fp.reload_env()
@@ -347,3 +350,10 @@ def test_rejected_batch_redispatches_elsewhere(rejecting_worker_cluster):
     burst = [g.remote(i) for i in range(24)]
     out = ray_tpu.get(burst, timeout=90)
     assert out == [i + 100 for i in range(24)]
+    # no rejecting worker kept its lease: four that did left the owner
+    # with no CPU to re-dispatch onto (the 90 s timeouts of PR 25-30)
+    # (the GCS learns a node's free resources from its health report,
+    # one a second: let the burst's own reports arrive first)
+    time.sleep(2.5)
+    wait_for_condition(
+        lambda: ray_tpu.available_resources().get("CPU") == 4.0)

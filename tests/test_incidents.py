@@ -130,7 +130,7 @@ def test_recorder_overhead_and_disabled_noop(tmp_path):
     """The hot-path bars: record() through the module facade with NO
     recorder is nanoseconds (one None test), and an enabled record stays
     in single-digit microseconds — cheap enough for task_start/finish
-    on every task (bench.py pairs this as flight_overhead_pct)."""
+    on every task."""
     saved = flt._recorder
     try:
         flt._recorder = None

@@ -1,5 +1,7 @@
 """Kernel correctness tests (pallas interpret mode on CPU)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -106,6 +108,34 @@ def test_flash_attention_native_layout_eligibility():
     with pytest.raises(ValueError):
         flash_attention(arr(4, 32), arr(4, 32), arr(4, 32),
                         interpret=True, native=True)
+
+
+@pytest.mark.parametrize("q_heads,k_heads,dim,native,env", [
+    (20, 20, 64, True, {}),    # gpt2-large: two heads to a 128-lane slab
+    (25, 25, 64, False, {}),   # gpt2-xl: 25 heads cannot pack two to a slab
+    (32, 4, 128, True, {}),    # trinity-mini: one head a slab, grouped K/V
+    (8, 4, 64, False, {}),     # grouped heads cannot share a slab
+    (20, 20, 64, True, {"RAY_TPU_FLASH_NATIVE": "0",
+                        "RAY_TPU_FLASH_BLOCK_Q": "128"}),
+], ids=["gpt2-large", "gpt2-xl", "trinity-mini", "grouped-64", "env-dead"])
+def test_flash_family_follows_the_shapes(q_heads, k_heads, dim, native, env,
+                                         monkeypatch):
+    """The shapes alone pick the kernel family and the default block: the
+    environment has no say (the ``RAY_TPU_FLASH_*`` variables are gone)."""
+    q = jnp.zeros((1, 2048, q_heads, dim), jnp.bfloat16)
+    kv = jnp.zeros((1, 2048, k_heads, dim), jnp.bfloat16)
+
+    def traced():
+        return str(jax.make_jaxpr(
+            lambda q, k, v: flash_attention(q, k, v, interpret=False)
+        )(q, kv, kv))
+
+    text = traced()
+    assert re.findall(r"name=(_flash\w*)", text) == [
+        "_flash_nl" if native else "_flash"]
+    for name, value in env.items():
+        monkeypatch.setenv(name, value)
+    assert traced() == text
 
 
 def test_rmsnorm_matches_reference():
