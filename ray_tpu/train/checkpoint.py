@@ -7,9 +7,12 @@ manager implements keep-K + score-attribute retention
 (``CheckpointConfig``, reference ``air/config.py:513``).
 
 JAX pytrees are saved in flax's msgpack format (no pickle for tensors):
-``Checkpoint.from_pytree`` writes it, byte for byte what
-``flax.serialization.to_bytes`` gives, in one pass into one host buffer,
-and ``flax.serialization.from_bytes`` reads it (``to_pytree``).
+``Checkpoint.from_pytree`` works out its framing and keeps it with the
+array leaves as a sequence of pieces, a leaf the device-to-host transfer
+left on the host carried as it is; joined, or written in order into the
+directory's one file, they are byte for byte what
+``flax.serialization.to_bytes`` gives, and
+``flax.serialization.from_bytes`` reads it (``to_pytree``).
 """
 
 from __future__ import annotations
@@ -22,7 +25,7 @@ import shutil
 import tempfile
 import struct
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import AbstractSet, Any, Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
@@ -31,24 +34,35 @@ from ray_tpu.train.config import CheckpointConfig
 
 
 #: the key of a pytree's msgpack payload: ``bytes`` when it was read from
-#: a directory, the flat ``uint8`` array ``from_pytree`` filled otherwise
+#: a directory, otherwise ``from_pytree``'s tuple of pieces (framing as
+#: ``bytes``, array leaves as flat ``uint8`` arrays) that joined are them
 _PYTREE = "pytree_msgpack"
-
-#: most bytes one numpy copy moves: a dispatch that waits for the
-#: interpreter waits for one such piece, not for a whole leaf
-_COPY_BYTES = 64 << 20
 
 
 def _is_raw(key: str, value: Any) -> bool:
     """Whether a checkpoint's value goes to a directory as it is (anything
     else is pickled there)."""
     return isinstance(value, bytes) or (
-        key == _PYTREE and isinstance(value, np.ndarray))
+        key == _PYTREE and isinstance(value, tuple))
 
 
-def _to_host(pytree: Any) -> Any:
+def _raw_pieces(value: Any) -> Tuple[Any, ...]:
+    """A raw value as the buffers that, in order, are its bytes."""
+    return value if isinstance(value, tuple) else (value,)
+
+
+def _nbytes(pieces: Tuple[Any, ...]) -> int:
+    return sum(memoryview(p).nbytes for p in pieces)
+
+
+def _to_host(pytree: Any) -> Tuple[Any, Set[int]]:
     """The pytree's flax state dict with every ``jax.Array`` leaf on the
-    host: all the transfers are started before the first is waited for."""
+    host: all the transfers are started before the first is waited for.
+    And the ``id`` of each host array that is nobody else's: jax made it
+    for the transfer (off an accelerator, or assembling shards) and keeps
+    it read-only, so neither a caller's write nor the donation of the
+    device's buffer reaches it.  On the CPU backend the host array of a
+    whole leaf IS the device's buffer, which a donation hands on."""
     import jax
     from flax import serialization
 
@@ -66,9 +80,14 @@ def _to_host(pytree: Any) -> Any:
     walk(root)
     for _, _, value in found:
         value.copy_to_host_async()
+    own = set()
     for node, key, value in found:
-        node[key] = np.asarray(value)
-    return root[""]
+        node[key] = host = np.asarray(value)
+        if not host.flags.writeable and (
+                not value.is_fully_replicated
+                or all(d.platform != "cpu" for d in value.devices())):
+            own.add(id(host))
+    return root[""], own
 
 
 #: msgpack's ``fixext`` type bytes, by the length of the body
@@ -93,81 +112,74 @@ def _bin_header(n: int) -> bytes:
     return struct.pack(">BI", 0xc6, n)
 
 
-def _copy_leaf(dst: np.ndarray, leaf: np.ndarray) -> None:
-    """``leaf``'s bytes in C order into the ``uint8`` slice ``dst``, in
-    pieces of ``_COPY_BYTES``."""
-    if leaf.flags.c_contiguous:
-        src = leaf.reshape(-1).view(np.uint8)
-        for i in range(0, src.size, _COPY_BYTES):
-            dst[i:i + _COPY_BYTES] = src[i:i + _COPY_BYTES]
-        return
-    rows = dst.view(leaf.dtype).reshape(leaf.shape)
-    step = max(1, _COPY_BYTES * len(leaf) // leaf.nbytes)
-    for i in range(0, len(leaf), step):
-        rows[i:i + step] = leaf[i:i + step]
+def _encode(state: Any, own: AbstractSet[int] = frozenset()
+            ) -> Tuple[Tuple[Any, ...], float]:
+    """What ``flax.serialization.msgpack_serialize(state)`` returns, as
+    the pieces that joined are it: the framing as ``bytes``, each array
+    leaf as a flat ``uint8`` array; and the array bytes copied on the
+    host over the array bytes of the tree.
 
-
-def _encode(state: Any) -> Tuple[np.ndarray, float]:
-    """What ``flax.serialization.msgpack_serialize(state)`` returns, as a
-    flat ``uint8`` array that is allocated once and into which each
-    array leaf is copied once; and the array bytes written over the
-    array bytes of the tree (1.0: no leaf was copied twice).
-
-    The framing (map headers, keys, an array leaf's ``ext 1`` around
-    ``[shape, dtype.name, bin]``, the chunked form of a leaf above
-    ``MAX_CHUNK_SIZE``) is worked out first, which gives the size; any
-    other leaf goes through flax's own packer."""
+    A C-contiguous leaf whose ``id`` is in ``own`` is carried: its piece
+    is a view of it.  Any other is copied once, since its owner may go on
+    to write it.  The framing (map headers, keys, an array leaf's
+    ``ext 1`` around ``[shape, dtype.name, bin]``, the chunked form of a
+    leaf above ``MAX_CHUNK_SIZE``) is ours; any other leaf goes through
+    flax's own packer."""
     import msgpack
     from flax import serialization
 
     packer = msgpack.Packer(strict_types=True)
-    pieces: List[Any] = []  # framing (bytes) and array leaves, in order
-    copied_before = 0
+    pieces: List[Any] = []
+    frame: List[bytes] = []  # framing since the last array piece
+    copied = tree_bytes = 0
 
     def array(leaf: np.ndarray) -> None:
-        if leaf.dtype.hasobject or leaf.dtype.isalignedstruct:
-            raise ValueError("Object and structured dtypes not supported "
-                             "for serialization of ndarrays.")
+        """``leaf`` is ours and C-contiguous."""
         inner = (b"\x93" + msgpack.packb(leaf.shape)
                  + msgpack.packb(leaf.dtype.name) + _bin_header(leaf.nbytes))
-        pieces.append(_ext_header(1, len(inner) + leaf.nbytes) + inner)
-        pieces.append(leaf)
+        frame.append(_ext_header(1, len(inner) + leaf.nbytes) + inner)
+        if leaf.nbytes:
+            pieces.append(b"".join(frame))
+            pieces.append(leaf.reshape(-1).view(np.uint8))
+            frame.clear()
 
-    def value(node: Any, chunk: bool = True) -> None:
-        nonlocal copied_before
+    def value(node: Any) -> None:
+        nonlocal copied, tree_bytes
         if type(node) is dict:
-            pieces.append(packer.pack_map_header(len(node)))
+            frame.append(packer.pack_map_header(len(node)))
             for key, item in node.items():
-                pieces.append(packer.pack(key))
-                value(item, chunk)
-        elif not isinstance(node, np.ndarray):
-            pieces.append(serialization.msgpack_serialize(node))
-        elif chunk and node.nbytes > serialization.MAX_CHUNK_SIZE:
-            flat = node.reshape(-1)  # flax's _chunk, over views
-            if not node.flags.c_contiguous:
-                copied_before += node.nbytes
-            n = max(1, int(serialization.MAX_CHUNK_SIZE / node.itemsize))
-            value({"__msgpack_chunked_array__": True,
-                   "shape": {str(i): d for i, d in enumerate(node.shape)},
-                   "chunks": {str(i): flat[at:at + n] for i, at in
-                              enumerate(range(0, flat.size, n))}},
-                  chunk=False)
-        else:
+                frame.append(packer.pack(key))
+                value(item)
+            return
+        if not isinstance(node, np.ndarray):
+            frame.append(serialization.msgpack_serialize(node))
+            return
+        if node.dtype.hasobject or node.dtype.isalignedstruct:
+            raise ValueError("Object and structured dtypes not supported "
+                             "for serialization of ndarrays.")
+        tree_bytes += node.nbytes
+        if id(node) not in own or not node.flags.c_contiguous:
+            copied += node.nbytes
+            node = np.array(node, order="C")
+        if node.nbytes <= serialization.MAX_CHUNK_SIZE:
             array(node)
+            return
+        flat = node.reshape(-1)  # flax's _chunk, over views
+        n = max(1, int(serialization.MAX_CHUNK_SIZE / node.itemsize))
+        frame.append(packer.pack_map_header(3)
+                     + packer.pack("__msgpack_chunked_array__")
+                     + packer.pack(True) + packer.pack("shape"))
+        value({str(i): d for i, d in enumerate(node.shape)})
+        frame.append(packer.pack("chunks")
+                     + packer.pack_map_header(-(-flat.size // n)))
+        for i, at in enumerate(range(0, flat.size, n)):
+            frame.append(packer.pack(str(i)))
+            array(flat[at:at + n])
 
     value(state)
-    sizes = [p.nbytes if isinstance(p, np.ndarray) else len(p)
-             for p in pieces]
-    out = np.empty(sum(sizes), np.uint8)
-    at = tree_bytes = 0
-    for piece, n in zip(pieces, sizes):
-        if isinstance(piece, np.ndarray):
-            _copy_leaf(out[at:at + n], piece)
-            tree_bytes += n
-        else:
-            out[at:at + n] = np.frombuffer(piece, np.uint8)
-        at += n
-    return out, (1.0 + copied_before / tree_bytes if tree_bytes else 1.0)
+    if frame:
+        pieces.append(b"".join(frame))
+    return tuple(pieces), copied / tree_bytes if tree_bytes else 0.0
 
 
 class Checkpoint:
@@ -201,16 +213,18 @@ class Checkpoint:
         with _tm.span("train", "ckpt.from_pytree", ckpt=ckpt.id) as sp:
             t0 = time.time()
             with _tm.span("train", "ckpt.d2h", ckpt=ckpt.id):
-                state = _to_host(pytree)
+                state, own = _to_host(pytree)
             t1 = time.time()
             with _tm.span("train", "ckpt.encode", ckpt=ckpt.id):
-                blob, copies = _encode(state)
-            sp.args.update(bytes=blob.nbytes,
+                pieces, copies = _encode(state, own)
+            sp.args.update(bytes=_nbytes(pieces),
                            leaves=len(jax.tree_util.tree_leaves(pytree)),
                            d2h_ms=1e3 * (t1 - t0),
                            encode_ms=1e3 * (time.time() - t1),
-                           copies=copies)
-        ckpt._data[_PYTREE] = blob
+                           copies=copies,
+                           pieces=sum(isinstance(p, np.ndarray)
+                                      for p in pieces))
+        ckpt._data[_PYTREE] = pieces
         return ckpt
 
     # -- accessors --------------------------------------------------------
@@ -247,13 +261,11 @@ class Checkpoint:
         os.makedirs(path, exist_ok=True)
         pickled: List[str] = []
         for key, value in self._data.items():
-            if _is_raw(key, value):
-                blob = value
-            else:
-                blob = pickle.dumps(value)
+            if not _is_raw(key, value):
+                value = pickle.dumps(value)
                 pickled.append(key)
             with open(os.path.join(path, key), "wb") as f:
-                f.write(blob)
+                f.writelines(_raw_pieces(value))
         with open(os.path.join(path, self._MANIFEST), "w") as f:
             json.dump(pickled, f)
         return path
@@ -285,7 +297,7 @@ class Checkpoint:
         blob = self.to_dict()[_PYTREE]
         if not _is_raw(_PYTREE, blob):
             blob = pickle.loads(blob)
-        return serialization.from_bytes(target, blob)
+        return serialization.from_bytes(target, b"".join(_raw_pieces(blob)))
 
     @property
     def metrics(self) -> Dict[str, Any]:
@@ -361,7 +373,8 @@ class CheckpointManager:
             self._entries.append((score, path, metrics))
             self._enforce_retention()
             sp.args.update(path=path, bytes=sum(
-                len(v) for k, v in (checkpoint._data or {}).items()
+                _nbytes(_raw_pieces(v))
+                for k, v in (checkpoint._data or {}).items()
                 if _is_raw(k, v)))
         return path
 

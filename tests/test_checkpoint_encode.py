@@ -1,7 +1,9 @@
-"""``Checkpoint.from_pytree`` writes flax's msgpack format itself, in one
-pass into one host buffer: the bytes must be ``flax.serialization
-.to_bytes``'s, for every kind of leaf a training loop can hand it, and
-the buffer must ride the object plane out of band."""
+"""``Checkpoint.from_pytree`` works out flax's msgpack format itself and
+keeps it as pieces, framing and leaves: joined, and in the file a
+directory gets, the bytes must be ``flax.serialization.to_bytes``'s, for
+every kind of leaf a training loop can hand it; a leaf the transfer left
+on the host must be carried, not copied, any other copied once; and the
+leaves must ride the object plane out of band."""
 
 import collections
 
@@ -19,11 +21,16 @@ from ray_tpu.train import checkpoint as checkpoint_mod
 Point = collections.namedtuple("Point", ["x", "y"])
 
 
-def _sharded():
+def _over_devices(x, spec=P("d")):
+    """``x`` split along its first axis over all the devices: jax
+    assembles the host array of such a leaf itself."""
     mesh = Mesh(np.array(jax.devices()), ("d",))
+    return jax.device_put(x, NamedSharding(mesh, spec))
+
+
+def _sharded():
     x = jnp.arange(len(jax.devices()) * 6, dtype=jnp.float32).reshape(-1, 3)
-    return {"w": jax.device_put(x, NamedSharding(mesh, P("d", None))),
-            "replicated": jax.device_put(x[:2], NamedSharding(mesh, P()))}
+    return {"w": _over_devices(x), "replicated": _over_devices(x[:2], P())}
 
 
 #: name -> (builder of the tree, flax's MAX_CHUNK_SIZE for the case)
@@ -76,47 +83,110 @@ CASES = {
 }
 
 
+def _payload(ckpt):
+    return ckpt.to_dict()["pytree_msgpack"]
+
+
+def _arrays(pieces):
+    return [p for p in pieces if isinstance(p, np.ndarray)]
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_payload_is_flax_to_bytes_byte_for_byte(case, monkeypatch):
+def test_payload_is_flax_to_bytes_byte_for_byte(case, monkeypatch, tmp_path):
     build, chunk = CASES[case]
     if chunk is not None:
         monkeypatch.setattr(serialization, "MAX_CHUNK_SIZE", chunk)
     tree = build()
     want = serialization.to_bytes(tree)
     ckpt = Checkpoint.from_pytree(tree)
-    blob = ckpt.to_dict()["pytree_msgpack"]
-    assert isinstance(blob, np.ndarray) and blob.dtype == np.uint8 \
-        and blob.ndim == 1 and blob.flags.c_contiguous
-    assert blob.tobytes() == want
-    # and flax reads it back, from the array as from bytes
-    back = ckpt.to_pytree(tree)
-    for a, b in zip(jax.tree_util.tree_leaves(tree),
-                    jax.tree_util.tree_leaves(back)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    pieces = _payload(ckpt)
+    assert isinstance(pieces, tuple)
+    for piece in pieces:
+        assert type(piece) is bytes or (
+            type(piece) is np.ndarray and piece.dtype == np.uint8
+            and piece.ndim == 1 and piece.flags.c_contiguous and piece.size)
+    # framing between two leaves is one piece
+    assert not any(type(a) is type(b) is bytes
+                   for a, b in zip(pieces, pieces[1:]))
+    assert b"".join(pieces) == want
+    # the directory's one file is the pieces in order
+    ckpt.to_directory(str(tmp_path))
+    with open(tmp_path / "pytree_msgpack", "rb") as f:
+        assert f.read() == want
+    # and flax reads it back, from the pieces as from the file
+    for held in (ckpt, Checkpoint.from_directory(str(tmp_path))):
+        back = held.to_pytree(tree)
+        for a, b in zip(jax.tree_util.tree_leaves(tree),
+                        jax.tree_util.tree_leaves(back)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_every_array_byte_is_copied_once(monkeypatch):
-    tree = {"a": jnp.ones((16, 8)), "t": np.arange(12.0).reshape(3, 4).T,
-            "s": 3}
-    state = checkpoint_mod._to_host(tree)
-    assert all(isinstance(v, (np.ndarray, int)) for v in state.values())
-    _, copies = checkpoint_mod._encode(state)
-    assert copies == 1.0
-    # a leaf that has to be flattened before it is chunked is copied twice
+def _from_pytree_args(tree):
+    """The checkpoint, and the arguments ``from_pytree`` left on its span."""
+    from ray_tpu.core import telemetry
+
+    telemetry.drain_spans("test")
+    ckpt = Checkpoint.from_pytree(tree)
+    (row,) = [r for r in telemetry.drain_spans("test")
+              if r["name"] == "ckpt.from_pytree"]
+    return ckpt, row["args"]
+
+
+def test_a_leaf_the_transfer_left_is_carried_and_any_other_copied_once(
+        monkeypatch):
+    """``copies``: array bytes copied on the host over the tree's."""
+    sharded = _sharded()["w"]  # jax assembles it into a host array of its own
+    state, own = checkpoint_mod._to_host({"w": sharded})
+    assert own == {id(state["w"])} and not state["w"].flags.writeable
+    pieces, copies = checkpoint_mod._encode(state, own)
+    assert copies == 0.0
+    (carried,) = _arrays(pieces)
+    assert np.shares_memory(carried, state["w"])
+    # through the front door: the same, and the span says so
+    ckpt, args = _from_pytree_args({"w": sharded})
+    assert args["copies"] == 0.0 and args["pieces"] == 1 \
+        and args["leaves"] == 1
+    assert args["bytes"] == len(serialization.to_bytes({"w": sharded}))
+    assert np.shares_memory(_arrays(_payload(ckpt))[0], np.asarray(sharded))
+
+    # a numpy leaf is its caller's, a whole leaf of the CPU backend is
+    # the device's own buffer: neither is in ``own``, both are copied
+    tree = {"a": jnp.ones((16, 8)), "n": np.arange(12.0), "s": 3,
+            "t": np.arange(12.0).reshape(3, 4).T}
+    state, own = checkpoint_mod._to_host(tree)
+    assert not own
+    pieces, copies = checkpoint_mod._encode(state, own)
+    assert copies == 1.0 and len(_arrays(pieces)) == 3
+    assert not any(np.shares_memory(p, leaf) for p in _arrays(pieces)
+                   for leaf in (state["a"], state["n"], state["t"]))
+    # the share, in a tree of both kinds
+    _, args = _from_pytree_args({"w": sharded, "n": np.zeros(
+        sharded.size, np.float32)})
+    assert args["copies"] == 0.5 and args["pieces"] == 2
+    # a leaf of its own that is not C-contiguous is copied all the same
+    t = np.arange(12.0).reshape(3, 4).T
+    assert checkpoint_mod._encode({"t": t}, {id(t)})[1] == 1.0
+    # flattened and chunked: still once
     monkeypatch.setattr(serialization, "MAX_CHUNK_SIZE", 32)
-    _, copies = checkpoint_mod._encode({"t": tree["t"]})
-    assert copies == 2.0
-    assert checkpoint_mod._encode({})[1] == 1.0
+    assert checkpoint_mod._encode({"t": t}, {id(t)})[1] == 1.0
+    assert checkpoint_mod._encode({})[1] == 0.0
+    assert checkpoint_mod._encode({"s": 3, "e": np.zeros((0, 3))})[1] == 0.0
 
 
-def test_a_leaf_is_copied_in_pieces(monkeypatch):
-    """No single numpy call moves more than ``_COPY_BYTES``."""
-    monkeypatch.setattr(checkpoint_mod, "_COPY_BYTES", 48)
-    tree = {"c": np.arange(100, dtype=np.float32),
-            "t": np.arange(120, dtype=np.float64).reshape(10, 12).T,
-            "wide": np.arange(64, dtype=np.float64).reshape(2, 32).T}
-    blob = Checkpoint.from_pytree(tree).to_dict()["pytree_msgpack"]
-    assert blob.tobytes() == serialization.to_bytes(tree)
+def test_the_chunks_of_a_carried_leaf_are_views_of_it(monkeypatch):
+    monkeypatch.setattr(serialization, "MAX_CHUNK_SIZE", 40)
+    tree = {"w": _sharded()["w"]}
+    state, own = checkpoint_mod._to_host(tree)
+    pieces, copies = checkpoint_mod._encode(state, own)
+    chunks = _arrays(pieces)
+    assert copies == 0.0 and len(chunks) == -(-state["w"].nbytes // 40)
+    flat = state["w"].reshape(-1).view(np.uint8)
+    at = 0
+    for chunk in chunks:
+        assert np.shares_memory(chunk, state["w"])
+        np.testing.assert_array_equal(chunk, flat[at:at + chunk.size])
+        at += chunk.size
+    assert b"".join(pieces) == serialization.to_bytes(tree)
 
 
 def test_object_leaves_are_refused_as_flax_refuses_them():
@@ -126,50 +196,81 @@ def test_object_leaves_are_refused_as_flax_refuses_them():
         Checkpoint.from_pytree({"s": {1, 2}})
 
 
-@pytest.mark.parametrize("kind", ["numpy", "jax"])
+@pytest.mark.parametrize("kind", ["numpy", "jax_deleted", "jax_donated",
+                                  "sharded_donated"])
 def test_what_is_saved_is_the_tree_at_the_call(kind):
-    """The loop goes on to write (or donate) what it saved."""
-    w = np.arange(6, dtype=np.float32)
-    tree = {"w": w if kind == "numpy" else jnp.asarray(w)}
+    """The loop goes on to write what it saved, or to donate it to the
+    next step, which writes its result where the old value lay."""
+    n = 4096 * len(jax.devices())
+    w = np.arange(n, dtype=np.float32)
+    if kind == "numpy":
+        leaf = w
+    elif kind == "sharded_donated":  # carried, not copied
+        leaf = _over_devices(w)
+    else:
+        leaf = jnp.asarray(w)
+    tree = {"w": leaf}
     ckpt = Checkpoint.from_pytree(tree)
-    before = ckpt.to_dict()["pytree_msgpack"].tobytes()
+    before = b"".join(_payload(ckpt))
+    assert before == serialization.to_bytes({"w": w.copy()})
     if kind == "numpy":
         w += 100.0
+    elif kind == "jax_deleted":
+        leaf.delete()
     else:
-        tree["w"].delete()
-    assert ckpt.to_dict()["pytree_msgpack"].tobytes() == before
+        step = jax.jit(lambda x: x + 100.0, donate_argnums=0)
+        for _ in range(3):
+            leaf, old = step(leaf), leaf
+            assert old.is_deleted()
+        np.testing.assert_array_equal(np.asarray(leaf),
+                                      np.arange(n, dtype=np.float32) + 300.0)
+    assert b"".join(_payload(ckpt)) == before
     np.testing.assert_array_equal(
-        ckpt.to_pytree({"w": np.zeros(6, np.float32)})["w"],
-        np.arange(6, dtype=np.float32))
+        ckpt.to_pytree({"w": np.zeros(n, np.float32)})["w"],
+        np.arange(n, dtype=np.float32))
 
 
 def test_the_payload_rides_out_of_band():
     from ray_tpu.core import serialization as wire
 
-    tree = {"w": np.arange(1 << 16, dtype=np.float32), "b": np.ones(3)}
+    tree = {"w": np.arange(1 << 16, dtype=np.float32), "b": np.ones(3),
+            "h": jnp.ones((128,), jnp.bfloat16),
+            # carried (a host array of jax's own) and copied alike
+            "v": _over_devices(
+                jnp.ones((len(jax.devices()), 300), jnp.bfloat16)),
+            "edge": np.zeros(512, np.uint8), "under": np.zeros(511, np.uint8)}
     ckpt = Checkpoint.from_pytree(tree, metrics={"loss": 1.0})
-    blob = ckpt.to_dict()["pytree_msgpack"]
     ser = wire.serialize([{"metrics": {"loss": 1.0}, "checkpoint": ckpt}])
-    assert len(ser.meta) < 1024
-    assert [memoryview(b).nbytes for b in ser.buffers] == [blob.nbytes]
+    assert len(ser.meta) < 2048
+    # one buffer a leaf of 512 bytes or more, as large as the leaf
+    assert [memoryview(b).nbytes for b in ser.buffers] == [
+        (1 << 16) * 4, len(jax.devices()) * 600, 512]
+    assert {bytes(b) for b in ser.buffers} <= {
+        bytes(p) for p in _arrays(_payload(ckpt))}
     (item,), is_exc = wire.deserialize(ser.to_bytes())
     assert not is_exc
     got = item["checkpoint"]
     assert got.id == ckpt.id and got.metrics == {"loss": 1.0}
-    payload = got.to_dict()["pytree_msgpack"]
-    # a view over the wire's buffer, not a copy out of a pickle
-    assert isinstance(payload, np.ndarray) and not payload.flags.owndata
-    assert payload.tobytes() == blob.tobytes()
+    payload = _payload(got)
+    assert [type(p) for p in payload] == [type(p) for p in _payload(ckpt)]
+    # views over the wire's buffers, not copies out of a pickle
+    assert all(not p.flags.owndata and not p.flags.writeable
+               for p in _arrays(payload) if p.nbytes >= 512)
+    assert b"".join(payload) == serialization.to_bytes(tree)
 
 
 def test_directory_round_trips_from_a_read_only_view(tmp_path):
-    tree = {"w": np.arange(10, dtype=np.float32), "n": 3}
+    tree = {"w": np.arange(10, dtype=np.float32), "n": 3,
+            "b": np.ones(200, jnp.bfloat16), "k": {"z": np.arange(5)}}
     ckpt = Checkpoint.from_pytree(tree, metrics={"loss": 0.5})
-    blob = ckpt.to_dict()["pytree_msgpack"]
-    # what the driver holds: a read-only view over the store's mapping
-    view = np.frombuffer(blob.tobytes(), np.uint8)
-    assert not view.flags.writeable
-    held = Checkpoint.from_dict({**ckpt.to_dict(), "pytree_msgpack": view,
+    # what the driver holds: the framing, and a read-only view over the
+    # store's mapping for each leaf
+    views = tuple(p if isinstance(p, bytes)
+                  else np.frombuffer(p.tobytes(), np.uint8)
+                  for p in _payload(ckpt))
+    assert len(_arrays(views)) == 3
+    assert not any(v.flags.writeable for v in _arrays(views))
+    held = Checkpoint.from_dict({**ckpt.to_dict(), "pytree_msgpack": views,
                                  "u8": np.arange(4, dtype=np.uint8)})
     path = held.to_directory(str(tmp_path / "c"))
     with open(tmp_path / "c" / "pytree_msgpack", "rb") as f:
@@ -213,7 +314,9 @@ def _saving_loop(config):
 
     for i in (1, 2):
         tree = {"w": jnp.arange(1 << 20, dtype=jnp.float32) * i,  # 4 MB
-                "b": jnp.full((3,), i, jnp.bfloat16), "step": i}
+                "b": jnp.full((3,), i, jnp.bfloat16), "step": i,
+                "layers": [jnp.full((1 << 16,), i + k, jnp.float32)
+                           for k in range(12)]}
         session.report({"step": i},
                        checkpoint=Checkpoint.from_pytree(tree))
 
@@ -239,8 +342,9 @@ def test_a_save_lands_whole_and_the_driver_lets_go_of_the_store(
         _saving_loop, scaling_config=ScalingConfig(num_workers=1),
         run_config=RunConfig(storage_path=str(tmp_path))).fit()
     assert result.error is None
-    # the pinned views of both payloads are gone with the rows that
-    # carried them (a release is a message to the raylet: give it time)
+    # the pinned views of both payloads, one a leaf, are gone with the
+    # rows that carried them (a release is a message to the raylet: give
+    # it time)
     deadline = time.time() + 20
     while _store_used() > before and time.time() < deadline:
         time.sleep(0.1)
@@ -248,13 +352,16 @@ def test_a_save_lands_whole_and_the_driver_lets_go_of_the_store(
 
     for i in (1, 2):
         want = {"w": np.arange(1 << 20, dtype=np.float32) * i,
-                "b": np.full((3,), i, jnp.bfloat16), "step": i}
+                "b": np.full((3,), i, jnp.bfloat16), "step": i,
+                "layers": [np.full((1 << 16,), i + k, np.float32)
+                           for k in range(12)]}
         path = tmp_path / f"checkpoint_{i:06d}"
         with open(path / "pytree_msgpack", "rb") as f:
             assert f.read() == serialization.to_bytes(want)
         back = Checkpoint.from_directory(str(path)).to_pytree(want)
         np.testing.assert_array_equal(back["w"], want["w"])
         np.testing.assert_array_equal(back["b"], want["b"])
+        np.testing.assert_array_equal(back["layers"][11], want["layers"][11])
         assert back["step"] == i
     latest = result.checkpoint.to_pytree(want)
     np.testing.assert_array_equal(latest["w"], want["w"])

@@ -245,7 +245,7 @@ def _spans(ended_run, cat, name):
     ("xla", "lower", {"fun_name"}),
     ("xla", "backend_compile", {"fun_name"}),
     ("train", "ckpt.from_pytree", {"ckpt", "bytes", "leaves", "d2h_ms",
-                                   "encode_ms", "copies"}),
+                                   "encode_ms", "copies", "pieces"}),
     ("train", "ckpt.d2h", {"ckpt"}),
     ("train", "ckpt.encode", {"ckpt"}),
     ("train", "report", {"ckpt"}),
@@ -301,7 +301,13 @@ def test_from_pytree_names_its_two_children_and_its_copies(ended_run):
                           ("encode_ms", "ckpt.encode")):
             assert kids[name]["args"]["ckpt"] == top["args"]["ckpt"]
             assert abs(top["args"][arg] - kids[name]["dur"] / 1e3) < 20
+        # array bytes copied on the host over the tree's: a whole leaf of
+        # the CPU backend is the device's own buffer, so it is copied (a
+        # leaf the transfer left on the host is carried: 0.0 on a chip,
+        # tests/test_checkpoint_encode.py)
         assert top["args"]["copies"] == 1.0
+        # the array pieces of the payload, each a buffer of the reply
+        assert top["args"]["pieces"] == top["args"]["leaves"] == 1
         assert top["args"]["bytes"] > 2048 * 2048 * 4
         # the two are all of it
         assert top["args"]["d2h_ms"] + top["args"]["encode_ms"] \
