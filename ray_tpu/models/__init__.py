@@ -2,6 +2,10 @@
 annotations consumed by ``ray_tpu.parallel.sharding``."""
 
 from ray_tpu.models.afmoe import AFMoE, AFMoEConfig  # noqa: F401
+from ray_tpu.models.deepseek_v3 import (  # noqa: F401
+    DeepseekV3,
+    DeepseekV3Config,
+)
 from ray_tpu.models.gpt2 import GPT2, GPT2Config  # noqa: F401
 from ray_tpu.models.llama import Llama, LlamaConfig  # noqa: F401
 from ray_tpu.models.moe import (  # noqa: F401

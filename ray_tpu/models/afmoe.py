@@ -139,13 +139,17 @@ class AFMoEConfig:
 
     def plan_args(self, tokens: int) -> Dict[str, Any]:
         """What was compiled, for the ``moe.plan`` span."""
-        return {"experts": self.num_experts,
-                "held_first": self.experts_held[0],
-                "held": self.experts_held[1], "top_k": self.top_k,
-                "row_bound": tokens * self.top_k,
-                "block_rows": BLOCK_ROWS, "window": self.window,
+        return {**routed_plan_args(self, tokens), "window": self.window,
                 "heads": self.num_heads, "kv_heads": self.kv_heads,
                 "layers": ",".join(k[0] for k in self.layer_kinds())}
+
+
+def routed_plan_args(cfg, tokens: int) -> Dict[str, Any]:
+    """The routed layer's part of a ``moe.plan`` span, for any
+    configuration that carries one (:class:`RoutedExperts`' fields)."""
+    return {"experts": cfg.num_experts, "held_first": cfg.experts_held[0],
+            "held": cfg.experts_held[1], "top_k": cfg.top_k,
+            "row_bound": tokens * cfg.top_k, "block_rows": BLOCK_ROWS}
 
 
 def _rope(x: jax.Array, theta: float) -> jax.Array:
@@ -174,21 +178,21 @@ class _HeadNorm(nn.Module):
         return _rmsnorm_ref(x, w, self.eps)
 
 
-def _dense(cfg: AFMoEConfig, features: int, name: str, axes: tuple):
+def _dense(cfg, features: int, name: str, axes: tuple):
     return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
                     param_dtype=cfg.param_dtype,
                     kernel_init=nn.with_partitioning(
                         nn.initializers.normal(0.02), axes), name=name)
 
 
-def _swiglu(cfg: AFMoEConfig, h, width: int, prefix: str):
+def _swiglu(cfg, h, width: int, prefix: str):
     gate = _dense(cfg, width, prefix + "gate", ("embed", "mlp"))(h)
     up = _dense(cfg, width, prefix + "up", ("embed", "mlp"))(h)
     return _dense(cfg, cfg.embed_dim, prefix + "down",
                   ("mlp", "embed"))(nn.silu(gate) * up)
 
 
-def route(cfg: AFMoEConfig, h: jax.Array, w_router: jax.Array,
+def route(cfg, h: jax.Array, w_router: jax.Array,
           chosen: Optional[jax.Array] = None):
     """Sigmoid scores (float32 by default) over all published experts,
     the ``top_k`` largest, weights normalised over the chosen and scaled.
@@ -209,8 +213,12 @@ def route(cfg: AFMoEConfig, h: jax.Array, w_router: jax.Array,
 
 
 class RoutedExperts(nn.Module):
-    """The routed part of an expert layer, for the share held here."""
-    config: AFMoEConfig
+    """The routed part of an expert layer, for the share held here.
+    ONE layer for every model that routes so (``models/deepseek_v3.py``
+    too): ``config`` is any dataclass with ``num_experts``, ``top_k``,
+    ``experts_held``, ``route_scale``, ``expert_dim``, ``dtype``,
+    ``param_dtype`` and ``router_dtype``."""
+    config: Any
 
     @nn.compact
     def __call__(self, h: jax.Array,
@@ -309,6 +317,20 @@ class MLPPart(nn.Module):
         return x + RMSNorm(cfg.rms_eps, name="mlp_post_norm")(out)
 
 
+def each_sequence(attn, mlp, x: jax.Array,
+                  chosen: Optional[jax.Array] = None) -> jax.Array:
+    """``mlp(attn(x))`` over ONE sequence of the batch at a time (a
+    kernel call a sequence, the activation memory of one sequence);
+    ``chosen [B*T, k]``: the routing each sequence's MLP part replays."""
+    seq = x.shape[1]
+    out = []
+    for i in range(x.shape[0]):
+        h = attn(x[i:i + 1])
+        out.append(mlp(h) if chosen is None else
+                   mlp(h, chosen[i * seq:(i + 1) * seq]))
+    return jnp.concatenate(out)
+
+
 class AFMoEBlock(nn.Module):
     """One layer: its two parts, each over one sequence at a time and
     each recomputed on its own in the backward pass under ``remat``: the
@@ -326,15 +348,8 @@ class AFMoEBlock(nn.Module):
         attn, mlp = AttentionPart, MLPPart
         if cfg.remat == "full":
             attn, mlp = nn.remat(attn), nn.remat(mlp)
-        attn = attn(cfg, self.kind, name="attn")
-        mlp = mlp(cfg, self.routed, name="mlp")
-        seq = x.shape[1]
-        out = []
-        for i in range(x.shape[0]):
-            h = attn(x[i:i + 1])
-            out.append(mlp(h) if chosen is None else
-                       mlp(h, chosen[i * seq:(i + 1) * seq]))
-        return jnp.concatenate(out)
+        return each_sequence(attn(cfg, self.kind, name="attn"),
+                             mlp(cfg, self.routed, name="mlp"), x, chosen)
 
 
 class AFMoE(nn.Module):
@@ -384,7 +399,7 @@ class AFMoE(nn.Module):
         return self.init(rng, tokens)["params"]
 
 
-def loss_fn(model: AFMoE, params, tokens: jax.Array,
+def loss_fn(model: nn.Module, params, tokens: jax.Array,
             head_chunk: int = 2048,
             head_logits_dtype: Any = None,
             choices: Optional[List[jax.Array]] = None,
@@ -395,11 +410,13 @@ def loss_fn(model: AFMoE, params, tokens: jax.Array,
     (``assumed`` in the configuration).  ``choices``: a recorded routing
     to replay (:meth:`AFMoE.hidden`).  ``with_choices``: also what every
     expert layer's router chose ITSELF on the way, ``[B*T, k]`` a layer,
-    as :func:`router_choices` gives it."""
+    as :func:`router_choices` gives it.  ``model``: any module with
+    AFMoE's ``hidden(tokens, choices)`` whose expert layers are
+    ``h<i>/mlp/moe`` (a :class:`RoutedExperts`)."""
     from ray_tpu.ops.fused import chunked_lm_loss
 
     out = model.apply({"params": params}, tokens, choices,
-                      method=AFMoE.hidden,
+                      method=type(model).hidden,
                       mutable=["intermediates"] if with_choices else False)
     (x, head), state = out if with_choices else (out, None)
     compute = jnp.bfloat16 if model.config.dtype == jnp.bfloat16 else None
@@ -409,14 +426,14 @@ def loss_fn(model: AFMoE, params, tokens: jax.Array,
     return (loss, _own_choices(model, state)) if with_choices else loss
 
 
-def _own_choices(model: AFMoE, state) -> List[jax.Array]:
+def _own_choices(model: nn.Module, state) -> List[jax.Array]:
     # sown once a call, and a call sees one sequence
     return [jnp.concatenate(
         state["intermediates"][f"h{i}"]["mlp"]["moe"]["expert_choice"])
         for i in range(model.config.num_layers)]
 
 
-def make_train_step(model: AFMoE, tx):
+def make_train_step(model: nn.Module, tx):
     """The donated ``(params, opt_state, tokens) -> (params, opt_state,
     loss)`` step, as GPT-2's."""
     import optax
@@ -432,7 +449,7 @@ def make_train_step(model: AFMoE, tx):
 
 
 @functools.partial(jax.jit, static_argnums=0)
-def router_stats(model: AFMoE, params, tokens: jax.Array
+def router_stats(model: nn.Module, params, tokens: jax.Array
                  ) -> Dict[str, jax.Array]:
     """What a routed layer must tell its operator, per expert layer (in
     order): ``load [L, held]`` (token, choice) pairs that chose each held
@@ -442,7 +459,8 @@ def router_stats(model: AFMoE, params, tokens: jax.Array
     :func:`report_router_stats` and ``session.report``."""
     cfg = model.config
     _, state = model.apply({"params": params}, tokens,
-                           method=AFMoE.hidden, mutable=["intermediates"])
+                           method=type(model).hidden,
+                           mutable=["intermediates"])
     layers = state["intermediates"]
     # sown once a call, and a call sees one sequence
     load = jnp.stack([
@@ -454,19 +472,21 @@ def router_stats(model: AFMoE, params, tokens: jax.Array
 
 
 @functools.partial(jax.jit, static_argnums=0)
-def router_choices(model: AFMoE, params, tokens: jax.Array
+def router_choices(model: nn.Module, params, tokens: jax.Array
                    ) -> List[jax.Array]:
     """The experts every expert layer's router chose, ``[B*T, k]`` a
     layer: for comparing a near tie with a reference's own choice."""
     _, state = model.apply({"params": params}, tokens,
-                           method=AFMoE.hidden, mutable=["intermediates"])
+                           method=type(model).hidden,
+                           mutable=["intermediates"])
     return _own_choices(model, state)
 
 
 def report_router_stats(stats: Dict[str, Any], model_name: str = "afmoe"
                         ) -> Dict[str, float]:
-    """Host side: the stats as gauges, and as flat scalars for
-    ``session.report``."""
+    """Host side: the stats as gauges (tagged ``model_name``: another
+    model's module binds its own, ``models/deepseek_v3.py``), and as flat
+    scalars for ``session.report``."""
     import numpy as np
 
     out: Dict[str, float] = {}

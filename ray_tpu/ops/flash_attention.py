@@ -10,19 +10,31 @@ chip, ``ray_tpu.parallel.ring_attention`` composes with this kernel
 per shard.
 
 Two kernel families, chosen from the shapes alone (``_nl_eligible``):
-the native-layout kernels read ``[B, T, H, D]`` as it lies, so nothing
-is transposed around the calls, but they need whole 128-lane slabs of
-heads; the head-major kernels take any head count and dimension.  Both
-stay because the benchmark has cells on each side: 20 heads of 64
-(GPT-2 large) and 32 on 4 heads of 128 (Trinity-Mini) are eligible, 25
-heads of 64 (GPT-2 XL) cannot pack two to a slab (``PERF.md`` section
-4).
+
+* native layout: q, k and v of ONE head width, 64 or 128, heads filling
+  whole 128-lane slabs (any count of 128-wide heads, an even count of
+  64-wide ones); K/V may carry fewer heads than q where the width is 128
+  (one head a slab).  Reads ``[B, T, H, D]`` as it lies, so nothing is
+  transposed around the calls.  20 heads of 64 (GPT-2 large), 32 on 4
+  heads of 128 (Trinity-Mini);
+* head-major (``[B, H, T, D]``, transposes around the calls): any head
+  count and width, K/V heads any divisor of the query heads; q, k and v
+  of one width.  25 heads of 64 (GPT-2 XL) cannot pack two to a slab.
+  And its latent-attention variant (``k_rope=``): the key in two parts,
+  each head's own ``[B, T, H, nope]`` beside ONE rotary head ``[B, T, 1,
+  rope]`` that every query head shares, q ``nope + rope`` wide, v and the
+  output of their own width ``dv``; equal head counts, no window.  32
+  heads of 128 + 64 against v of 128 (Kanana-2-30B-A3B): a 192-wide head
+  fills no whole slabs.
+
+Both families stay because the benchmark has cells on each side
+(``PERF.md`` section 4).
 
 Matmul operands stay in the input dtype (bf16 on TPU) with f32
 accumulation via ``preferred_element_type`` — the MXU's native mode.
 
-Two static extras, both off by default (the default traces the kernels
-body for body as before they existed):
+Two static extras of the equal-width kernels, both off by default (the
+default traces the kernels body for body as before they existed):
 
 * ``window``: position ``t`` sees keys ``t - window + 1 .. t``.  Tiles
   wholly outside the window are skipped the way tiles above the
@@ -949,6 +961,343 @@ def _flash_nl_backward(q, k, v, out, lse, g, causal, scale, block_q,
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
 
+# ---------------------------------------------------------------------------
+# Latent attention (MLA) kernels: the key in two parts, v narrower than q.
+#
+# ``q [B, T, H, nope + rope]`` scores against a key that is, per head,
+# ``k [B, T, H, nope]`` (up-projected from the latent) beside ONE rotary
+# key head ``k_rope [B, T, 1, rope]`` that all ``H`` query heads share;
+# ``v [B, T, H, dv]`` and the output are ``dv`` wide.  Nothing is padded
+# or copied in HBM: v and o keep their own width, and the shared rotary
+# head is read through the block index (head 0 for every query head, the
+# grouped-head idiom with a group of ``H`` on that one operand).  Inside
+# a tile the two key parts are joined along lanes, so the score is one
+# ``nope + rope``-deep product and dQ/dK one ``nope + rope``-wide one.
+#
+# Head-major like the kernels at the top (a 192-wide head is no whole
+# number of 128-lane slabs), with the tile classification of the
+# native-layout ones (``_causal_dispatch``: only diagonal tiles pay for
+# the mask).  dK/dV walks ALL query heads of a K tile along its
+# sequential axis: the per-head dK/dV accumulators are flushed at each
+# head's last Q tile, the shared rotary key's gradient accumulates over
+# every head and is written once.
+# ---------------------------------------------------------------------------
+
+
+def _mla_key(k_ref, r_ref):
+    """The tile's whole key ``[block_k, nope + rope]``: the head's own
+    part beside the shared rotary part."""
+    return jnp.concatenate([k_ref[:], r_ref[:]], axis=1)
+
+
+def _mla_scores(q_ref, k_ref, r_ref):
+    """``[block_q, block_k]`` float32, unscaled: ONE product as deep as
+    the whole key (two products, one a key part, summed before the
+    softmax measured 1.8% slower a layer on a v5e: PERF.md, PR 33)."""
+    return jax.lax.dot_general(
+        q_ref[:], _mla_key(k_ref, r_ref), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _fa_mla_kernel(q_ref, k_ref, r_ref, v_ref, o_ref, lse_ref, m_ref,
+                   l_ref, acc_ref, *, scale: float, causal: bool,
+                   block_q: int, block_k: int):
+    """MLA forward: grid (B, H, q_tiles, k_tiles), k sequential."""
+    from jax.experimental import pallas as pl
+
+    iq = pl.program_id(2)
+    ik = pl.program_id(3)
+    n_k = pl.num_programs(3)
+
+    @pl.when(ik == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    q_offset = iq * block_q
+    k_offset = ik * block_k
+
+    def _tile(apply_mask: bool):
+        v = v_ref[:]
+        s = _mla_scores(q_ref, k_ref, r_ref) * scale
+        if apply_mask:
+            s = jnp.where(_keep_mask(q_offset, k_offset, block_q, block_k),
+                          s, NEG_INF)
+        m = m_ref[:]            # [bq, 1]
+        m_new = jnp.maximum(m, s.max(axis=-1)[:, None])
+        safe_m = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
+        p = jnp.exp(s - safe_m)  # masked entries underflow to exactly 0
+        corr = jnp.where(m <= NEG_INF / 2, 0.0, jnp.exp(m - safe_m))
+        l_ref[:] = l_ref[:] * corr + p.sum(axis=-1)[:, None]
+        m_ref[:] = m_new
+        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile)
+
+    @pl.when(ik == n_k - 1)
+    def _finish():
+        m = m_ref[:]
+        l = l_ref[:]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[:] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
+        lse_ref[:] = jnp.where(m <= NEG_INF / 2, NEG_INF,
+                               m + jnp.log(l_safe)).astype(jnp.float32)
+
+
+def _mla_blocks(q, k, block_q: int, block_k: int):
+    seq_q, seq_k = q.shape[1], k.shape[1]
+    block_q = min(block_q, seq_q)
+    block_k = min(block_k, seq_k)
+    assert seq_q % block_q == 0 and seq_k % block_k == 0, (
+        f"sequence lengths ({seq_q}, {seq_k}) must divide into blocks "
+        f"({block_q}, {block_k})")
+    return block_q, block_k
+
+
+def _flash_mla_forward(q, k, k_rope, v, causal: bool, scale: float,
+                       block_q: int, block_k: int, interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, seq_q, heads, dim = q.shape
+    seq_k, nope, rope, dim_v = (k.shape[1], k.shape[3], k_rope.shape[3],
+                                v.shape[3])
+    block_q, block_k = _mla_blocks(q, k, block_q, block_k)
+    # pallas layout: [B, H, T, D]
+    qt, kt, rt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, k_rope, v))
+
+    def k_tile(j, i):
+        return _clamp_k_tile(j, i, block_q, block_k) if causal else j
+
+    rows_q = lambda b, h, i, j: (b, h, i, 0)  # noqa: E731
+    out, lse = pl.pallas_call(
+        functools.partial(_fa_mla_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
+        grid=(batch, heads, seq_q // block_q, seq_k // block_k),
+        in_specs=[
+            pl.BlockSpec((None, None, block_q, dim), rows_q),
+            pl.BlockSpec((None, None, block_k, nope),
+                         lambda b, h, i, j: (b, h, k_tile(j, i), 0)),
+            pl.BlockSpec((None, None, block_k, rope),
+                         lambda b, h, i, j: (b, 0, k_tile(j, i), 0)),
+            pl.BlockSpec((None, None, block_k, dim_v),
+                         lambda b, h, i, j: (b, h, k_tile(j, i), 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, None, block_q, dim_v), rows_q),
+            pl.BlockSpec((None, None, block_q, 1), rows_q),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((batch, heads, seq_q, dim_v), q.dtype),
+            jax.ShapeDtypeStruct((batch, heads, seq_q, 1), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, 1), jnp.float32),      # running max
+            pltpu.VMEM((block_q, 1), jnp.float32),      # running sum
+            pltpu.VMEM((block_q, dim_v), jnp.float32),  # accumulator
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=interpret,
+    )(qt, kt, rt, vt)
+    return out.transpose(0, 2, 1, 3), lse
+
+
+def _mla_probs(q_ref, k_ref, r_ref, lse_ref, scale, keep):
+    """``p [bq, bk]`` of a tile from the forward's row statistics."""
+    s = _mla_scores(q_ref, k_ref, r_ref) * scale
+    if keep is not None:
+        s = jnp.where(keep, s, NEG_INF)
+    lse = lse_ref[:]            # [bq, 1]
+    lse = jnp.where(lse <= NEG_INF / 2, 0.0, lse)  # clamp: keeps
+    return jnp.exp(s - lse)     # fully-masked rows at p == 0
+
+
+def _fa_mla_bwd_dkdv_kernel(q_ref, k_ref, r_ref, v_ref, do_ref, lse_ref,
+                            delta_ref, dk_ref, dr_ref, dv_ref, dk_acc,
+                            dv_acc, *, scale: float, causal: bool,
+                            block_q: int, block_k: int, q_tiles: int):
+    """MLA dK/dV: grid (B, k_tiles, H * q_tiles); the last axis is
+    sequential: every query head in turn, its Q tiles one after the
+    other.  ``dk_acc`` is ``nope + rope`` wide: its first ``nope`` lanes
+    are the head's own dK, zeroed at each head's first Q tile and written
+    at its last; the rest is the shared rotary key's gradient, which
+    accumulates over all heads and is written at the very end."""
+    from jax.experimental import pallas as pl
+
+    ik = pl.program_id(1)
+    j = pl.program_id(2)
+    n_j = pl.num_programs(2)
+    iq = j % q_tiles
+    nope = k_ref.shape[-1]
+
+    @pl.when(j == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+
+    @pl.when(iq == 0)
+    def _init_head():
+        dk_acc[:, :nope] = jnp.zeros((block_k, nope), jnp.float32)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    k_offset = ik * block_k
+    q_offset = iq * block_q
+
+    def _tile(apply_mask: bool):
+        q = q_ref[:]
+        do = do_ref[:]
+        keep = _keep_mask(q_offset, k_offset, block_q, block_k) \
+            if apply_mask else None
+        p = _mla_probs(q_ref, k_ref, r_ref, lse_ref, scale, keep)
+        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(
+            do, v_ref[:], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[:]) * scale
+        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile)
+
+    @pl.when(iq == q_tiles - 1)
+    def _finish_head():
+        dk_ref[:] = dk_acc[:, :nope].astype(dk_ref.dtype)
+        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when(j == n_j - 1)
+    def _finish():
+        dr_ref[:] = dk_acc[:, nope:].astype(dr_ref.dtype)
+
+
+def _fa_mla_bwd_dq_kernel(q_ref, k_ref, r_ref, v_ref, do_ref, lse_ref,
+                          delta_ref, dq_ref, dq_acc, *, scale: float,
+                          causal: bool, block_q: int, block_k: int):
+    """MLA dQ: grid (B, H, q_tiles, k_tiles); k sequential."""
+    from jax.experimental import pallas as pl
+
+    iq = pl.program_id(2)
+    ik = pl.program_id(3)
+    n_k = pl.num_programs(3)
+
+    @pl.when(ik == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    q_offset = iq * block_q
+    k_offset = ik * block_k
+
+    def _tile(apply_mask: bool):
+        keep = _keep_mask(q_offset, k_offset, block_q, block_k) \
+            if apply_mask else None
+        p = _mla_probs(q_ref, k_ref, r_ref, lse_ref, scale, keep)
+        dp = jax.lax.dot_general(
+            do_ref[:], v_ref[:], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[:]) * scale
+        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
+            ds.astype(k_ref.dtype), _mla_key(k_ref, r_ref),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile)
+
+    @pl.when(ik == n_k - 1)
+    def _finish():
+        dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def _flash_mla_backward(q, k, k_rope, v, out, lse, g, causal, scale,
+                        block_q, block_k, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, seq_q, heads, dim = q.shape
+    seq_k, nope, rope, dim_v = (k.shape[1], k.shape[3], k_rope.shape[3],
+                                v.shape[3])
+    block_q, block_k = _mla_blocks(q, k, block_q, block_k)
+    n_q = seq_q // block_q
+    qt, kt, rt, vt, dot = (x.transpose(0, 2, 1, 3)
+                           for x in (q, k, k_rope, v, g))
+    # delta_i = rowsum(dO_i * O_i), [B, H, T, 1] like lse
+    delta = jnp.sum(dot.astype(jnp.float32)
+                    * out.transpose(0, 2, 1, 3).astype(jnp.float32),
+                    axis=-1, keepdims=True)
+
+    def q_tile(j, i):  # dK/dV grid: i = k tile, j = head * n_q + q tile
+        return _clamp_q_tile(j % n_q, i, block_q, block_k) if causal \
+            else j % n_q
+
+    def k_tile(j, i):  # dQ grid: i = q tile, j = k tile
+        return _clamp_k_tile(j, i, block_q, block_k) if causal else j
+
+    # dK/dV: the head rides the sequential axis
+    walk_q = lambda b, i, j: (b, j // n_q, q_tile(j, i), 0)  # noqa: E731
+    head_k = lambda b, i, j: (b, j // n_q, i, 0)             # noqa: E731
+    shared_k = lambda b, i, j: (b, 0, i, 0)                  # noqa: E731
+    dk, dr, dv = pl.pallas_call(
+        functools.partial(_fa_mla_bwd_dkdv_kernel, scale=scale,
+                          causal=causal, block_q=block_q, block_k=block_k,
+                          q_tiles=n_q),
+        grid=(batch, seq_k // block_k, heads * n_q),
+        in_specs=[
+            pl.BlockSpec((None, None, block_q, dim), walk_q),
+            pl.BlockSpec((None, None, block_k, nope), head_k),
+            pl.BlockSpec((None, None, block_k, rope), shared_k),
+            pl.BlockSpec((None, None, block_k, dim_v), head_k),
+            pl.BlockSpec((None, None, block_q, dim_v), walk_q),
+            pl.BlockSpec((None, None, block_q, 1), walk_q),
+            pl.BlockSpec((None, None, block_q, 1), walk_q),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, None, block_k, nope), head_k),
+            pl.BlockSpec((None, None, block_k, rope), shared_k),
+            pl.BlockSpec((None, None, block_k, dim_v), head_k),
+        ],
+        out_shape=[jax.ShapeDtypeStruct(kt.shape, k.dtype),
+                   jax.ShapeDtypeStruct(rt.shape, k_rope.dtype),
+                   jax.ShapeDtypeStruct(vt.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, dim), jnp.float32),
+                        pltpu.VMEM((block_k, dim_v), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(qt, kt, rt, vt, dot, lse, delta)
+
+    rows_q = lambda b, h, i, j: (b, h, i, 0)  # noqa: E731
+    dq = pl.pallas_call(
+        functools.partial(_fa_mla_bwd_dq_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
+        grid=(batch, heads, n_q, seq_k // block_k),
+        in_specs=[
+            pl.BlockSpec((None, None, block_q, dim), rows_q),
+            pl.BlockSpec((None, None, block_k, nope),
+                         lambda b, h, i, j: (b, h, k_tile(j, i), 0)),
+            pl.BlockSpec((None, None, block_k, rope),
+                         lambda b, h, i, j: (b, 0, k_tile(j, i), 0)),
+            pl.BlockSpec((None, None, block_k, dim_v),
+                         lambda b, h, i, j: (b, h, k_tile(j, i), 0)),
+            pl.BlockSpec((None, None, block_q, dim_v), rows_q),
+            pl.BlockSpec((None, None, block_q, 1), rows_q),
+            pl.BlockSpec((None, None, block_q, 1), rows_q),
+        ],
+        out_specs=pl.BlockSpec((None, None, block_q, dim), rows_q),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, dim), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=interpret,
+    )(qt, kt, rt, vt, dot, lse, delta)
+
+    return tuple(x.transpose(0, 2, 1, 3) for x in (dq, dk, dr, dv))
+
+
 def _chunk_blocks(seq_q: int, seq_k: int):
     """Ring-chunk block sizes: the default, shrunk to divisors of the
     (arbitrary) chunk lengths."""
@@ -1095,6 +1444,55 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_mla(q, k, k_rope, v, causal, scale, block_q, block_k,
+               interpret):
+    out, _ = _flash_mla_forward(q, k, k_rope, v, causal, scale, block_q,
+                                block_k, interpret)
+    return out
+
+
+def _flash_mla_fwd(q, k, k_rope, v, causal, scale, block_q, block_k,
+                   interpret):
+    out, lse = _flash_mla_forward(q, k, k_rope, v, causal, scale, block_q,
+                                  block_k, interpret)
+    return out, (q, k, k_rope, v, out, lse)
+
+
+def _flash_mla_bwd(causal, scale, block_q, block_k, interpret, res, g):
+    q, k, k_rope, v, out, lse = res
+    return _flash_mla_backward(q, k, k_rope, v, out, lse, g, causal, scale,
+                               block_q, block_k, interpret)
+
+
+_flash_mla.defvjp(_flash_mla_fwd, _flash_mla_bwd)
+
+
+def _latent_attention(q, k, k_rope, v, causal, scale, block_q, block_k,
+                      interpret, native, mesh, window):
+    """``flash_attention`` with the key in two parts (``k_rope``)."""
+    heads, rope = q.shape[2], k_rope.shape[-1]
+    if (k.shape[2], v.shape[2], k_rope.shape[2]) != (heads, heads, 1) \
+            or q.shape[-1] != k.shape[-1] + rope:
+        raise ValueError(
+            f"k_rope= takes q [.., H, nope + rope], k [.., H, nope], "
+            f"k_rope [.., 1, rope] and v [.., H, dv]; got {q.shape}, "
+            f"{k.shape}, {k_rope.shape}, {v.shape}")
+    if window is not None or native or (mesh is not None and mesh.size > 1):
+        raise ValueError("k_rope= runs the head-major kernels on one "
+                         "device, with no window")
+    if interpret is None:
+        if jax.default_backend() != "tpu":
+            whole = jnp.concatenate(
+                [k, jnp.broadcast_to(k_rope, (*k.shape[:3], rope))], -1)
+            return _attention_reference(q, whole, v, causal, scale)
+        interpret = False
+    return _flash_mla(q, k, k_rope, v, causal, scale,
+                      DEFAULT_BLOCK if block_q is None else block_q,
+                      DEFAULT_BLOCK if block_k is None else block_k,
+                      interpret)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: Optional[float] = None,
                     block_q: Optional[int] = None,
@@ -1102,10 +1500,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     interpret: Optional[bool] = None,
                     native: Optional[bool] = None,
                     mesh: Optional[jax.sharding.Mesh] = None,
-                    window: Optional[int] = None) -> jax.Array:
+                    window: Optional[int] = None,
+                    k_rope: Optional[jax.Array] = None) -> jax.Array:
     """Fused attention. Shapes ``[batch, seq, heads, head_dim]``; ``k``
     and ``v`` may carry fewer heads than ``q`` (a divisor of its count:
     query head ``h`` reads K/V head ``h // group``, inside the kernel).
+
+    ``k_rope`` (latent attention): the key's rotary part as its ONE head,
+    ``[batch, seq, 1, rope]``, shared by every query head.  ``k`` then
+    holds each head's own part alone (``q`` is ``k``'s width plus
+    ``rope``, the rotary part last) and ``v``, and with it the result,
+    may be narrower than ``q``: ``q [.., 32, 192]``, ``k [.., 32, 128]``,
+    ``k_rope [.., 1, 64]``, ``v [.., 32, 128]`` is DeepSeek-V3's layer.
+    Equal head counts, causal or not, no window, one device a call.
 
     ``window`` (causal only): position ``t`` sees keys ``t - window + 1
     .. t``; a window that covers the sequence is no window.
@@ -1128,15 +1535,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     matmul.
 
     ``native``: which kernel family runs follows from the shapes
-    (``_nl_eligible``: head_dim 64 or 128 and whole 128-lane slabs of
-    heads take the native-layout kernels, which read ``[B, T, H, D]``
-    with no transposes around the calls; anything else, 25 heads of 64
-    among them, takes the head-major kernels).  A test passes ``True``
-    or ``False`` to hold one family against the other; both agree to
-    f32-ulp level (test_ops.py).
+    (``_nl_eligible``: q, k and v of one head_dim, 64 or 128, and whole
+    128-lane slabs of heads take the native-layout kernels, which read
+    ``[B, T, H, D]`` with no transposes around the calls; anything else,
+    25 heads of 64 among them, takes the head-major kernels, and so does
+    the two-part key of ``k_rope``, whose 192-wide heads fill no whole
+    slabs).  A test passes ``True`` or ``False`` to hold one family
+    against the other; both agree to f32-ulp level (test_ops.py).
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if k_rope is not None:
+        return _latent_attention(q, k, k_rope, v, causal, scale, block_q,
+                                 block_k, interpret, native, mesh, window)
     if q.shape[2] % k.shape[2] or k.shape[2] != v.shape[2]:
         raise ValueError(
             f"query heads ({q.shape[2]}) must be a multiple of the K/V "

@@ -79,7 +79,8 @@ TRAFFIC_DEPENDENT = {
     "ray_tpu_serve_kv_pages_freed_total",
     "ray_tpu_serve_kv_page_occupancy",
     # routed expert layers report only where a training loop asks
-    # (models/afmoe.py report_router_stats)
+    # (models/afmoe.py report_router_stats; models/deepseek_v3.py's
+    # under its own `model` tag, the same three series)
     "ray_tpu_moe_expert_load",
     "ray_tpu_moe_landed_share",
     "ray_tpu_moe_load_imbalance",

@@ -105,6 +105,35 @@ def test_windowed_grouped_head_flash_compiles_at_afmoe_shapes(one_chip,
     assert compiled.as_text().count("tpu_custom_call") == 3
 
 
+def test_latent_attention_flash_compiles_at_deepseek_v3_shapes(one_chip):
+    """One sequence of 16,384, 32 heads, q/k of 128 + 64 against v of
+    128, the rotary key ONE head, as ``models/deepseek_v3.py`` calls it:
+    forward, dK/dV and dQ at the default 1024 blocks; and what lies in
+    HBM around them: v and the output 128 wide, the rotary key never 32
+    times."""
+    def shape(heads, width):
+        return jax.ShapeDtypeStruct((1, 16384, heads, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def grads(q, k, r, v):
+        return jax.grad(lambda q, k, r, v: fa._flash_mla(
+            q, k, r, v, True, 192 ** -0.5, fa.DEFAULT_BLOCK,
+            fa.DEFAULT_BLOCK, False).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2, 3))(q, k, r, v)
+
+    compiled = jax.jit(grads).lower(
+        shape(32, 192), shape(32, 128), shape(1, 64),
+        shape(32, 128)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert all("bf16[1,1,16384,64]" in line for line in calls)
+    assert not any("bf16[1,32,16384,64]" in line for line in calls)
+    assert any(re.search(r"= \(bf16\[1,32,16384,128\]\S*, "
+                         r"f32\[1,32,16384,1\]", line) for line in calls)
+
+
 def test_grouped_products_compile_at_afmoe_widths(one_chip):
     """The worst-case row buffer of one sequence (8 x 8,192 pairs and a
     tile of padding for each of 16 experts) times 16 experts' matrices
