@@ -33,13 +33,34 @@ Both families stay because the benchmark has cells on each side
 Matmul operands stay in the input dtype (bf16 on TPU) with f32
 accumulation via ``preferred_element_type`` — the MXU's native mode.
 
+A tile the mask cuts is not multiplied as one square and then half
+thrown away.  On square tiles the diagonal runs corner to corner
+through the tiles ``iq == ik``; such a tile is worked in ``n`` static
+blocks (``_cut_parts``): row-block ``b`` of the Q tile against keys
+``[0, (b + 1) * sub)`` of the K/V tile already in VMEM (forward, dQ),
+column-block ``b`` of the K tile against queries ``[b * sub, block)``
+(dK/dV), each block updating ITS rows of the statistics or
+accumulators, and only the ``sub x sub`` corner on the diagonal pays for
+a mask: ``(n + 1) / (2n)`` of the square's products.  The tile on the
+lower edge of a ``window`` of whole tiles keeps its strict upper
+triangle and is worked mirrored.  The grid, the block specs, the DMAs
+and the pairs that contribute are those of the uncut kernels; ``n`` is
+``CUT_BLOCKS`` for dK/dV and dQ and ``CUT_BLOCKS_FORWARD`` for the
+forward, which takes a cut tile's blocks stage by stage so that the
+row maximum and the row sum are reduced across lanes once for all the
+tile's rows (``_row_max``).  Any other straddling tile (unequal blocks, a window that
+is no whole number of tiles or shorter than one) is multiplied whole
+under its mask, and one block traces exactly that body.  What a call
+works for what counts is in its ``ops:flash.plan`` span.
+
 Two static extras of the equal-width kernels, both off by default (the
 default traces the kernels body for body as before they existed):
 
 * ``window``: position ``t`` sees keys ``t - window + 1 .. t``.  Tiles
   wholly outside the window are skipped the way tiles above the
   diagonal are (compute gated off, index maps clamped so the DMA is
-  elided); tiles that straddle its edge are masked.
+  elided); tiles that straddle its edge are masked, or worked in blocks
+  as above.
 * grouped heads: ``k``/``v`` may carry fewer heads than ``q``.  Query
   head ``h`` reads K/V head ``h // group`` through the block index — no
   copies of K and V in HBM — and the dK/dV kernel walks the ``group``
@@ -49,16 +70,40 @@ default traces the kernels body for body as before they existed):
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
+
+from ray_tpu.core import telemetry
 
 NEG_INF = -1e30
 
 #: block size along both sequence axes when the caller names none
 DEFAULT_BLOCK = 1024
+
+#: blocks a tile cut by the mask is worked in (``_cut_parts``), each at
+#: least ``CUT_MIN`` rows and keys: whole 128-lane vregs of scores.
+#: Chosen on a v5e (PERF.md section 6, PR 34): dK/dV and dQ gain up to
+#: four blocks and nothing beyond; the forward, which takes a cut tile's
+#: blocks stage by stage (``_row_max``), gains up to eight.
+CUT_BLOCKS = 4
+CUT_BLOCKS_FORWARD = 8
+CUT_MIN = 128
+
+
+def _traced_once(*static):
+    """``jax.jit`` over a function that builds kernel calls: a model
+    calls it once a layer (and again under ``remat``) with the same
+    shapes, and each call would trace its kernel bodies afresh, some
+    thousand jnp operations of Python a layer.  Under an inner ``jit``
+    the first call's jaxpr serves the others and lowers to ONE function
+    that every layer calls; XLA inlines it, so the step's program is
+    the one it was."""
+    return functools.partial(jax.jit, static_argnames=static)
 
 
 def _clamp_k_tile(j, i, block_q: int, block_k: int,
@@ -115,17 +160,182 @@ def _tile_live(causal: bool, q_offset, k_offset, block_q: int,
     return live
 
 
+class _Part(NamedTuple):
+    """One product of a (Q tile, K tile) pair: ``rows`` of the Q tile
+    against ``cols`` of the K tile (slices of the blocks in VMEM), the
+    product's side lengths, and its mask.  ``keep`` builds the mask when
+    the kernel body asks (``None``: every pair is visible); it covers the
+    whole product, or, for a part of a cut tile, only the ``sub x sub``
+    ``corner`` the mask's edge runs through: (axis of the scores, at its
+    end?), the rest of such a part being visible whole."""
+    rows: slice
+    cols: slice
+    n_rows: int
+    n_cols: int
+    keep: Optional[Callable[[], jax.Array]] = None
+    corner: Optional[Tuple[int, bool]] = None
+
+
+def _part_keep(part: _Part):
+    return None if part.keep is None else part.keep()
+
+
+def _hide(s, keep, part: _Part):
+    """Scores ``[n_rows, n_cols]`` with the part's hidden pairs at
+    ``NEG_INF`` (``keep`` is what ``_part_keep`` gave)."""
+    if keep is None:
+        return s
+    if keep.shape == s.shape:  # the whole tile's mask, or a part all corner
+        return jnp.where(keep, s, NEG_INF)
+    # only the corner pays for the select; the cut falls on whole vregs
+    axis, at_end = part.corner
+    size, sub = s.shape[axis], keep.shape[axis]
+    split = size - sub if at_end else sub
+    a = lax.slice_in_dim(s, 0, split, axis=axis)
+    b = lax.slice_in_dim(s, split, size, axis=axis)
+    if at_end:
+        b = jnp.where(keep, b, NEG_INF)
+    else:
+        a = jnp.where(keep, a, NEG_INF)
+    return jnp.concatenate([a, b], axis=axis)
+
+
+def _lanes(parts, op):
+    """``[rows_i, cols_i]`` each ``-> [sum(rows_i), 128]``: ``op``
+    folded over the whole vregs along each row, nothing moved across
+    lanes (narrower than 128 only where a test cuts smaller blocks)."""
+    width = math.gcd(128, *[x.shape[1] for x in parts])
+    return jnp.concatenate([
+        functools.reduce(op, [x[:, i:i + width]
+                              for i in range(0, x.shape[1], width)])
+        for x in parts], axis=0)
+
+
+def _row_max(scores):
+    """``[block_q, 1]`` row maxima of a tile from the scores of its
+    parts (row-blocks, in order).  A forward kernel takes a cut tile
+    stage by stage: every block's scores, ONE maximum over all the
+    tile's rows, every block's ``exp`` and ``p v``, ONE sum
+    (``_row_sum``).  A block of its own would pay the reduction across
+    lanes in a loop of 128 rows, too short to hide its latency: that
+    way the forward gained nothing from the fewer products (PERF.md
+    section 6, PR 34).  So each block folds its vregs elementwise and
+    the tile's rows are reduced across lanes at once."""
+    if len(scores) == 1:
+        return scores[0].max(axis=-1)[:, None]
+    return _lanes(scores, jnp.maximum).max(axis=-1)[:, None]
+
+
+def _row_sum(probs):
+    """``[block_q, 1]`` row sums, as ``_row_max``."""
+    if len(probs) == 1:
+        return probs[0].sum(axis=-1)[:, None]
+    return _lanes(probs, jnp.add).sum(axis=-1)[:, None]
+
+
+def _rows_of(x, part: _Part, parts):
+    """A tile's row statistic ``[block_q, 1]`` at the rows of one of its
+    parts."""
+    return x if len(parts) == 1 else x[part.rows]
+
+
+def _cut_blocks(block_q: int, block_k: int, forward: bool = False) -> int:
+    """Into how many blocks a tile that the mask cuts is split (1: it is
+    worked whole under its mask): ``CUT_BLOCKS``, or in a forward kernel
+    ``CUT_BLOCKS_FORWARD``, halved until a block is whole ``CUT_MIN``
+    rows; square tiles only."""
+    if block_q != block_k:
+        return 1
+    n = CUT_BLOCKS_FORWARD if forward else CUT_BLOCKS
+    while n > 1 and (block_q % n or (block_q // n) % CUT_MIN):
+        n //= 2
+    return n
+
+
+def _cut_parts(edge: bool, walk: str, block: int, n: int):
+    """The ``n`` products a cut ``block x block`` tile is worked as.
+
+    The diagonal tile keeps its lower triangle (``edge=False``), the
+    tile the window's lower edge cuts its strict upper one.  ``walk``
+    says whose blocks the kernel's accumulators follow: ``"q"`` (forward
+    and dQ: row-block ``b`` of the Q tile against the keys it can see),
+    ``"k"`` (dK/dV: column-block ``b`` of the K tile against the queries
+    that can see it).  What a block sees is a static slice of the other
+    tile, and only the ``sub x sub`` corner on the mask's edge is
+    masked."""
+    sub = block // n
+
+    def corner_keep():
+        i = lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+        j = lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+        return i < j if edge else i >= j
+
+    # the lower triangle's rows see keys from the tile's start, its
+    # columns are seen by queries up to the tile's end; the upper
+    # triangle mirrors both
+    from_start = edge != (walk == "q")
+    parts = []
+    for b in range(n):
+        own = slice(b * sub, (b + 1) * sub)
+        lo, hi = (0, (b + 1) * sub) if from_start else (b * sub, block)
+        if walk == "q":
+            parts.append(_Part(own, slice(lo, hi), sub, hi - lo,
+                               corner_keep, (1, from_start)))
+        else:
+            parts.append(_Part(slice(lo, hi), own, hi - lo, sub,
+                               corner_keep, (0, from_start)))
+    return parts
+
+
+def _cut_kinds(block_q: int, block_k: int, window: Optional[int],
+               forward: bool = False):
+    """(blocks a cut tile is split in, is the diagonal tile cut so, is
+    the window's edge tile): static, from the tile and the window.  On
+    square tiles the diagonal runs corner to corner through the tiles
+    ``iq == ik`` and through no other; that tile is purely causal unless
+    the window is shorter than it.  The window's edge does the same
+    through the tiles ``iq - ik == window / block`` when the window is
+    whole tiles, and through two tiles a row otherwise."""
+    n = _cut_blocks(block_q, block_k, forward)
+    diag = n > 1 and (window is None or window >= block_q)
+    edge = diag and window is not None and window % block_k == 0
+    return n, diag, edge
+
+
 def _causal_dispatch(causal: bool, q_offset, k_offset, block_q: int,
-                     block_k: int, tile, window: Optional[int] = None):
-    """Run ``tile(apply_mask)`` under the causal tile classification:
-    diagonal-straddling tiles get the (iota + compare + select) causal
-    mask, fully-visible tiles skip it, fully-masked tiles run nothing.
-    The two predicates are mutually exclusive and their union equals the
-    old "not fully masked" gate, so no tile is dropped or run twice."""
+                     block_k: int, tile, window: Optional[int] = None,
+                     walk: str = "q", forward: bool = False):
+    """Run ``tile(part)`` (a forward kernel: ``tile(parts)``, a tile's
+    parts together) under the causal tile classification:
+    fully-visible tiles run whole with no mask, fully-masked tiles run
+    nothing, and a tile the mask cuts is worked in blocks over what
+    each block can see (``_cut_parts``) where the cut is the diagonal's
+    or a whole-tile window's on square tiles; any other straddling tile
+    gets the (iota + compare + select) mask over the whole tile.  The
+    predicates are mutually exclusive and their union equals the old
+    "not fully masked" gate, so no tile is dropped or run twice."""
     from jax.experimental import pallas as pl
 
+    def whole(keep=None):
+        part = _Part(slice(None), slice(None), block_q, block_k, keep)
+        tile([part] if forward else part)
+
+    def masked():
+        whole(lambda: _keep_mask(q_offset, k_offset, block_q, block_k,
+                                 window))
+
+    def cut(edge: bool):
+        def run():
+            parts = _cut_parts(edge, walk, block_q, n)
+            if forward:
+                tile(parts)
+            else:
+                for part in parts:
+                    tile(part)
+        return run
+
     if not causal:
-        tile(False)
+        whole()
         return
     straddles = jnp.logical_and(k_offset <= q_offset + block_q - 1,
                                 k_offset + block_k - 1 > q_offset)
@@ -141,8 +351,71 @@ def _causal_dispatch(causal: bool, q_offset, k_offset, block_q: int,
                                  jnp.logical_and(fully_visible, edge)))
         fully_visible = jnp.logical_and(
             live, jnp.logical_and(fully_visible, jnp.logical_not(edge)))
-    pl.when(straddles)(lambda: tile(True))
-    pl.when(fully_visible)(lambda: tile(False))
+    n, cut_diag, cut_edge = _cut_kinds(block_q, block_k, window, forward)
+    if cut_diag:
+        on_diag = q_offset == k_offset
+        pl.when(on_diag)(cut(False))
+        if cut_edge:
+            pl.when(q_offset - k_offset == window)(cut(True))
+        elif window is not None:
+            pl.when(jnp.logical_and(straddles,
+                                    jnp.logical_not(on_diag)))(masked)
+    else:
+        pl.when(straddles)(masked)
+    pl.when(fully_visible)(whole)
+
+
+def _plan_args(family: str, q, seq_k: int, block_q: int, block_k: int,
+               causal: bool, window: Optional[int]) -> Dict[str, Any]:
+    """What a call's kernels were compiled to do, for the ``flash.plan``
+    span: counts from shapes, for one (batch, head), by the rules of
+    ``_causal_dispatch``.  ``tiles_live`` (Q tile, K tile) pairs hold a
+    visible (query, key); ``tiles_cut`` of them are worked in blocks of
+    ``sub`` rows by dK/dV and dQ and of ``sub_forward`` by the forward
+    (the diagonal's and the window's edge's, where they are cut so);
+    ``pairs_worked`` (query, key) pairs are multiplied by a backward
+    kernel, ``pairs_worked_forward`` by the forward, for the
+    ``pairs_visible`` that count: their quotient is what the cutting is
+    for."""
+    seq_q = q.shape[1]
+    block_q, block_k = min(block_q, seq_q), min(block_k, seq_k)
+    # (blocks, diagonal tile cut, edge tile cut) of the backward kernels
+    # and of the forward
+    kinds = [_cut_kinds(block_q, block_k, window, forward) if causal
+             else (1, False, False) for forward in (False, True)]
+    in_cut = [sum(p.n_rows * p.n_cols
+                  for p in _cut_parts(False, "q", block_q, n))
+              for n, _, _ in kinds]
+    live = cut = 0
+    worked = [0, 0]
+    for q0 in range(0, seq_q, block_q):
+        for k0 in range(0, seq_k, block_k):
+            if causal and (k0 > q0 + block_q - 1 or (
+                    window is not None
+                    and k0 + block_k - 1 < q0 - (window - 1))):
+                continue
+            live += 1
+            in_blocks = [(diag and q0 == k0)
+                         or (edge and q0 - k0 == window)
+                         for _, diag, edge in kinds]
+            cut += in_blocks[0]
+            for i, yes in enumerate(in_blocks):
+                worked[i] += in_cut[i] if yes else block_q * block_k
+    if causal:
+        last = np.minimum(np.arange(seq_q), seq_k - 1)  # a query's last key
+        first = np.zeros_like(last) if window is None \
+            else np.maximum(last - (window - 1), 0)
+        visible = int((last - first + 1).sum())
+    else:
+        visible = seq_q * seq_k
+    return {"family": family, "heads": q.shape[2], "width": q.shape[3],
+            "seq": seq_q,
+            "block": block_q if block_q == block_k
+            else f"{block_q}x{block_k}",
+            "window": window or 0, "sub": block_q // kinds[0][0],
+            "sub_forward": block_q // kinds[1][0], "tiles_live": live,
+            "tiles_cut": cut, "pairs_worked": worked[0],
+            "pairs_worked_forward": worked[1], "pairs_visible": visible}
 
 
 def _attention_reference(q, k, v, causal: bool, scale: float,
@@ -192,32 +465,31 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     # compute is gated off here, and the K/V index maps clamp those grid
     # steps to the diagonal tile so their DMAs are skipped too (pallas
     # elides the copy when consecutive steps map to the same block)
-    @pl.when(_tile_live(causal, q_offset, k_offset, block_q, block_k,
-                        window))
-    def _compute():
-        q = q_ref[:]
-        k = k_ref[:]
-        v = v_ref[:]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = jnp.where(_keep_mask(q_offset, k_offset, block_q, block_k,
-                                     window), s, NEG_INF)
-        m = m_ref[:][:, 0]
-        l = l_ref[:][:, 0]
-        m_new = jnp.maximum(m, s.max(axis=-1))
+    def _tile(parts):
+        scores = []
+        for part in parts:
+            s = jax.lax.dot_general(
+                q_ref[part.rows], k_ref[part.cols], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            scores.append(_hide(s, _part_keep(part), part))
+        m = m_ref[:]            # [bq, 1]
+        m_new = jnp.maximum(m, _row_max(scores))
         safe_m = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
         # masked entries: exp(-1e30 - safe_m) underflows to exactly 0.0,
         # so no [bq, bk] guard select is needed
-        p = jnp.exp(s - safe_m[:, None])
+        probs = [jnp.exp(s - _rows_of(safe_m, part, parts))
+                 for part, s in zip(parts, scores)]
         corr = jnp.where(m <= NEG_INF / 2, 0.0, jnp.exp(m - safe_m))
-        l_new = l * corr + p.sum(axis=-1)
-        acc_ref[:] = acc_ref[:] * corr[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = m_new[:, None]
-        l_ref[:] = l_new[:, None]
+        l_ref[:] = l_ref[:] * corr + _row_sum(probs)
+        m_ref[:] = m_new
+        acc_ref[:] = acc_ref[:] * corr + jnp.concatenate(
+            [jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[part.cols],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+             for part, p in zip(parts, probs)], axis=0)
+
+    _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile,
+                     window, forward=True)
 
     @pl.when(ik == n_k - 1)
     def _finish():
@@ -235,6 +507,8 @@ def _kv_head(h, group: int):
     return h if group == 1 else h // group
 
 
+@_traced_once("causal", "scale", "block_q", "block_k", "interpret",
+              "out_dtype", "window")
 def _flash_forward(q, k, v, causal: bool, scale: float,
                    block_q: int, block_k: int, interpret: bool,
                    out_dtype=None, window: Optional[int] = None):
@@ -323,37 +597,34 @@ def _fa_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     k_offset = ik * block_k
     q_offset = (iq % q_tiles if q_tiles else iq) * block_q
-    live = jnp.logical_or(not causal, q_offset + block_q - 1 >= k_offset)
-    if window is not None:
-        live = _tile_live(causal, q_offset, k_offset, block_q, block_k,
-                          window)
 
-    @pl.when(live)
-    def _compute():
-        k = k_ref[:]
-        v = v_ref[:]
-        q = q_ref[:]
-        do = do_ref[:]
-        lse = lse_ref[:][:, 0]
-        delta = delta_ref[:][:, 0]
+    def _tile(part: _Part):
+        rows, cols = part.rows, part.cols
+        k = k_ref[cols]
+        v = v_ref[cols]
+        q = q_ref[rows]
+        do = do_ref[rows]
+        lse = lse_ref[rows][:, 0]
+        delta = delta_ref[rows][:, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = jnp.where(_keep_mask(q_offset, k_offset, block_q, block_k,
-                                     window), s, NEG_INF)
-        lse = jnp.where(lse <= NEG_INF / 2, 0.0, lse)  # [bq] clamp: keeps
+        s = _hide(s, _part_keep(part), part)
+        lse = jnp.where(lse <= NEG_INF / 2, 0.0, lse)  # clamp: keeps
         p = jnp.exp(s - lse[:, None])  # fully-masked rows at p == 0
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
+        dv_acc[cols] = dv_acc[cols] + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         ds = p * (dp - delta[:, None]) * scale
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
+        dk_acc[cols] = dk_acc[cols] + jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile,
+                     window, walk="k")
 
     @pl.when(iq == n_q - 1)
     def _finish():
@@ -379,36 +650,38 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q_offset = iq * block_q
     k_offset = ik * block_k
 
-    @pl.when(_tile_live(causal, q_offset, k_offset, block_q, block_k,
-                        window))
-    def _compute():
-        q = q_ref[:]
-        do = do_ref[:]
-        lse = lse_ref[:][:, 0]
-        delta = delta_ref[:][:, 0]
-        k = k_ref[:]
-        v = v_ref[:]
+    def _tile(part: _Part):
+        rows = part.rows
+        q = q_ref[rows]
+        do = do_ref[rows]
+        lse = lse_ref[rows][:, 0]
+        delta = delta_ref[rows][:, 0]
+        k = k_ref[part.cols]
+        v = v_ref[part.cols]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = jnp.where(_keep_mask(q_offset, k_offset, block_q, block_k,
-                                     window), s, NEG_INF)
-        lse = jnp.where(lse <= NEG_INF / 2, 0.0, lse)  # [bq] clamp: keeps
+        s = _hide(s, _part_keep(part), part)
+        lse = jnp.where(lse <= NEG_INF / 2, 0.0, lse)  # clamp: keeps
         p = jnp.exp(s - lse[:, None])  # fully-masked rows at p == 0
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         ds = p * (dp - delta[:, None]) * scale
-        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
+        dq_acc[rows] = dq_acc[rows] + jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile,
+                     window)
 
     @pl.when(ik == n_k - 1)
     def _finish():
         dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
 
 
+@_traced_once("causal", "scale", "block_q", "block_k", "interpret",
+              "grad_dtype", "window")
 def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                     interpret, grad_dtype=None, delta=None,
                     window: Optional[int] = None):
@@ -582,37 +855,39 @@ def _fa_nl_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     q_offset = iq * block_q
     k_offset = ik * block_k
 
-    def _tile(apply_mask: bool):
-        q = q_ref[:]
-        k = k_ref[:]
-        v = v_ref[:]
-        if apply_mask:
-            causal_keep = _keep_mask(q_offset, k_offset, block_q, block_k,
-                                     window)
+    def _tile(parts):
+        qs = [q_ref[part.rows] for part in parts]
+        ks = [k_ref[part.cols] for part in parts]
+        vs = [v_ref[part.cols] for part in parts]
+        keeps = [_part_keep(part) for part in parts]
         corrs = []
         pvs = []
         for h in range(pack):
-            qh = q * _lane_mask(h, pack, dim, block_q, q.dtype) if pack > 1 else q
-            s = jax.lax.dot_general(
-                qh, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            if apply_mask:
-                s = jnp.where(causal_keep, s, NEG_INF)
+            scores = []
+            for part, q, k, keep in zip(parts, qs, ks, keeps):
+                qh = q * _lane_mask(h, pack, dim, part.n_rows, q.dtype) if pack > 1 else q
+                s = jax.lax.dot_general(
+                    qh, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                scores.append(_hide(s, keep, part))
             m = m_refs[h][:]            # [bq, 1]
             l = l_refs[h][:]
-            m_new = jnp.maximum(m, s.max(axis=-1)[:, None])
+            m_new = jnp.maximum(m, _row_max(scores))
             safe_m = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
             # masked entries: exp(-1e30 - safe_m) underflows to exactly
             # 0.0, so no [bq, bk] guard select is needed
-            p = jnp.exp(s - safe_m)
+            probs = [jnp.exp(s - _rows_of(safe_m, part, parts))
+                     for part, s in zip(parts, scores)]
             corr = jnp.where(m <= NEG_INF / 2, 0.0, jnp.exp(m - safe_m))
-            l_refs[h][:] = l * corr + p.sum(axis=-1)[:, None]
+            l_refs[h][:] = l * corr + _row_sum(probs)
             m_refs[h][:] = m_new
-            pv = jax.lax.dot_general(
+            pv = [jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+                for p, v in zip(probs, vs)]
             corrs.append(corr)
-            pvs.append(pv)
+            pvs.append(pv[0] if len(pv) == 1
+                       else jnp.concatenate(pv, axis=0))
         if pack == 1:
             acc_ref[:] = acc_ref[:] * corrs[0] + pvs[0]
         else:
@@ -621,7 +896,7 @@ def _fa_nl_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                           + jnp.where(sel, pvs[0], pvs[1]))
 
     _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile,
-                     window)
+                     window, forward=True)
 
     @pl.when(ik == n_k - 1)
     def _finish():
@@ -644,6 +919,8 @@ def _fa_nl_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             lse_ref[:] = jnp.concatenate(lses, axis=1).astype(jnp.float32)
 
 
+@_traced_once("causal", "scale", "block_q", "block_k", "interpret",
+              "out_dtype", "window")
 def _flash_nl_forward(q, k, v, causal: bool, scale: float,
                       block_q: int, block_k: int, interpret: bool,
                       out_dtype=None, window: Optional[int] = None):
@@ -735,28 +1012,26 @@ def _fa_nl_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k_offset = ik * block_k
     q_offset = (iq % q_tiles if q_tiles else iq) * block_q
 
-    def _tile(apply_mask: bool):
-        q = q_ref[:]
-        k = k_ref[:]
-        v = v_ref[:]
-        do = do_ref[:]
-        if apply_mask:
-            causal_keep = _keep_mask(q_offset, k_offset, block_q, block_k,
-                                     window)
+    def _tile(part: _Part):
+        rows, cols = part.rows, part.cols
+        q = q_ref[rows]
+        k = k_ref[cols]
+        v = v_ref[cols]
+        do = do_ref[rows]
+        keep = _part_keep(part)
         pdos = []
         dsqs = []
         for h in range(pack):
-            mask_q = (_lane_mask(h, pack, dim, block_q, q.dtype)
+            mask_q = (_lane_mask(h, pack, dim, part.n_rows, q.dtype)
                       if pack > 1 else None)
             qh = q * mask_q if pack > 1 else q
             doh = do * mask_q if pack > 1 else do
             s = jax.lax.dot_general(
                 qh, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
-            if apply_mask:
-                s = jnp.where(causal_keep, s, NEG_INF)
-            lse = lse_ref[:][:, h:h + 1]     # [bq, 1]
-            delta = delta_ref[:][:, h:h + 1]
+            s = _hide(s, keep, part)
+            lse = lse_ref[rows][:, h:h + 1]     # [rows, 1]
+            delta = delta_ref[rows][:, h:h + 1]
             lse = jnp.where(lse <= NEG_INF / 2, 0.0, lse)  # [bq, 1]
             p = jnp.exp(s - lse)  # clamp keeps fully-masked rows at p == 0
             pdo = jax.lax.dot_general(
@@ -772,15 +1047,15 @@ def _fa_nl_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             pdos.append(pdo)
             dsqs.append(dsq)
         if pack == 1:
-            dv_acc[:] = dv_acc[:] + pdos[0]
-            dk_acc[:] = dk_acc[:] + dsqs[0]
+            dv_acc[cols] = dv_acc[cols] + pdos[0]
+            dk_acc[cols] = dk_acc[cols] + dsqs[0]
         else:
-            sel = _head_sel(pack, dim, block_k)
-            dv_acc[:] = dv_acc[:] + jnp.where(sel, pdos[0], pdos[1])
-            dk_acc[:] = dk_acc[:] + jnp.where(sel, dsqs[0], dsqs[1])
+            sel = _head_sel(pack, dim, part.n_cols)
+            dv_acc[cols] = dv_acc[cols] + jnp.where(sel, pdos[0], pdos[1])
+            dk_acc[cols] = dk_acc[cols] + jnp.where(sel, dsqs[0], dsqs[1])
 
     _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile,
-                     window)
+                     window, walk="k")
 
     @pl.when(iq == n_q - 1)
     def _finish():
@@ -806,27 +1081,25 @@ def _fa_nl_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q_offset = iq * block_q
     k_offset = ik * block_k
 
-    def _tile(apply_mask: bool):
-        q = q_ref[:]
-        k = k_ref[:]
-        v = v_ref[:]
-        do = do_ref[:]
-        if apply_mask:
-            causal_keep = _keep_mask(q_offset, k_offset, block_q, block_k,
-                                     window)
+    def _tile(part: _Part):
+        rows = part.rows
+        q = q_ref[rows]
+        k = k_ref[part.cols]
+        v = v_ref[part.cols]
+        do = do_ref[rows]
+        keep = _part_keep(part)
         dsks = []
         for h in range(pack):
-            mask_q = (_lane_mask(h, pack, dim, block_q, q.dtype)
+            mask_q = (_lane_mask(h, pack, dim, part.n_rows, q.dtype)
                       if pack > 1 else None)
             qh = q * mask_q if pack > 1 else q
             doh = do * mask_q if pack > 1 else do
             s = jax.lax.dot_general(
                 qh, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
-            if apply_mask:
-                s = jnp.where(causal_keep, s, NEG_INF)
-            lse = lse_ref[:][:, h:h + 1]     # [bq, 1]
-            delta = delta_ref[:][:, h:h + 1]
+            s = _hide(s, keep, part)
+            lse = lse_ref[rows][:, h:h + 1]     # [rows, 1]
+            delta = delta_ref[rows][:, h:h + 1]
             lse = jnp.where(lse <= NEG_INF / 2, 0.0, lse)  # [bq, 1]
             p = jnp.exp(s - lse)  # clamp keeps fully-masked rows at p == 0
             dp = jax.lax.dot_general(
@@ -838,10 +1111,10 @@ def _fa_nl_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 preferred_element_type=jnp.float32)
             dsks.append(dsk)
         if pack == 1:
-            dq_acc[:] = dq_acc[:] + dsks[0]
+            dq_acc[rows] = dq_acc[rows] + dsks[0]
         else:
-            sel = _head_sel(pack, dim, block_q)
-            dq_acc[:] = dq_acc[:] + jnp.where(sel, dsks[0], dsks[1])
+            sel = _head_sel(pack, dim, part.n_rows)
+            dq_acc[rows] = dq_acc[rows] + jnp.where(sel, dsks[0], dsks[1])
 
     _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile,
                      window)
@@ -851,6 +1124,8 @@ def _fa_nl_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
 
 
+@_traced_once("causal", "scale", "block_q", "block_k", "interpret",
+              "grad_dtype", "window")
 def _flash_nl_backward(q, k, v, out, lse, g, causal, scale, block_q,
                        block_k, interpret, grad_dtype=None, delta=None,
                        window: Optional[int] = None):
@@ -984,19 +1259,19 @@ def _flash_nl_backward(q, k, v, out, lse, g, causal, scale, block_q,
 # ---------------------------------------------------------------------------
 
 
-def _mla_key(k_ref, r_ref):
-    """The tile's whole key ``[block_k, nope + rope]``: the head's own
-    part beside the shared rotary part."""
-    return jnp.concatenate([k_ref[:], r_ref[:]], axis=1)
+def _mla_key(k_ref, r_ref, cols: slice):
+    """The whole key ``[cols, nope + rope]`` of those rows of the K
+    tile: the head's own part beside the shared rotary part."""
+    return jnp.concatenate([k_ref[cols], r_ref[cols]], axis=1)
 
 
-def _mla_scores(q_ref, k_ref, r_ref):
-    """``[block_q, block_k]`` float32, unscaled: ONE product as deep as
-    the whole key (two products, one a key part, summed before the
-    softmax measured 1.8% slower a layer on a v5e: PERF.md, PR 33)."""
+def _mla_scores(q_ref, k_ref, r_ref, part: _Part):
+    """``[part.n_rows, part.n_cols]`` float32, unscaled: ONE product as
+    deep as the whole key (two products, one a key part, summed before
+    the softmax measured 1.8% slower a layer on a v5e: PERF.md, PR 33)."""
     return jax.lax.dot_general(
-        q_ref[:], _mla_key(k_ref, r_ref), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        q_ref[part.rows], _mla_key(k_ref, r_ref, part.cols),
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
 def _fa_mla_kernel(q_ref, k_ref, r_ref, v_ref, o_ref, lse_ref, m_ref,
@@ -1018,24 +1293,30 @@ def _fa_mla_kernel(q_ref, k_ref, r_ref, v_ref, o_ref, lse_ref, m_ref,
     q_offset = iq * block_q
     k_offset = ik * block_k
 
-    def _tile(apply_mask: bool):
-        v = v_ref[:]
-        s = _mla_scores(q_ref, k_ref, r_ref) * scale
-        if apply_mask:
-            s = jnp.where(_keep_mask(q_offset, k_offset, block_q, block_k),
-                          s, NEG_INF)
+    def _tile(parts):
+        vs = [v_ref[part.cols] for part in parts]
+        scores = []
+        for part in parts:
+            s = _mla_scores(q_ref, k_ref, r_ref, part) * scale
+            scores.append(_hide(s, _part_keep(part), part))
         m = m_ref[:]            # [bq, 1]
-        m_new = jnp.maximum(m, s.max(axis=-1)[:, None])
+        m_new = jnp.maximum(m, _row_max(scores))
         safe_m = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-        p = jnp.exp(s - safe_m)  # masked entries underflow to exactly 0
+        # masked entries underflow to exactly 0
+        probs = [jnp.exp(s - _rows_of(safe_m, part, parts))
+                 for part, s in zip(parts, scores)]
         corr = jnp.where(m <= NEG_INF / 2, 0.0, jnp.exp(m - safe_m))
-        l_ref[:] = l_ref[:] * corr + p.sum(axis=-1)[:, None]
+        l_ref[:] = l_ref[:] * corr + _row_sum(probs)
         m_ref[:] = m_new
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+        acc = acc_ref[:] * corr
+        pv = [jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32) for p, v in zip(probs, vs)]
+        acc_ref[:] = acc + (pv[0] if len(pv) == 1
+                            else jnp.concatenate(pv, axis=0))
 
-    _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile)
+    _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile,
+                     forward=True)
 
     @pl.when(ik == n_k - 1)
     def _finish():
@@ -1057,6 +1338,7 @@ def _mla_blocks(q, k, block_q: int, block_k: int):
     return block_q, block_k
 
 
+@_traced_once("causal", "scale", "block_q", "block_k", "interpret")
 def _flash_mla_forward(q, k, k_rope, v, causal: bool, scale: float,
                        block_q: int, block_k: int, interpret: bool):
     from jax.experimental import pallas as pl
@@ -1107,12 +1389,12 @@ def _flash_mla_forward(q, k, k_rope, v, causal: bool, scale: float,
     return out.transpose(0, 2, 1, 3), lse
 
 
-def _mla_probs(q_ref, k_ref, r_ref, lse_ref, scale, keep):
-    """``p [bq, bk]`` of a tile from the forward's row statistics."""
-    s = _mla_scores(q_ref, k_ref, r_ref) * scale
-    if keep is not None:
-        s = jnp.where(keep, s, NEG_INF)
-    lse = lse_ref[:]            # [bq, 1]
+def _mla_probs(q_ref, k_ref, r_ref, lse_ref, scale, keep, part: _Part):
+    """``p [part.n_rows, part.n_cols]`` from the forward's row
+    statistics."""
+    s = _mla_scores(q_ref, k_ref, r_ref, part) * scale
+    s = _hide(s, keep, part)
+    lse = lse_ref[part.rows]    # [rows, 1]
     lse = jnp.where(lse <= NEG_INF / 2, 0.0, lse)  # clamp: keeps
     return jnp.exp(s - lse)     # fully-masked rows at p == 0
 
@@ -1147,24 +1429,25 @@ def _fa_mla_bwd_dkdv_kernel(q_ref, k_ref, r_ref, v_ref, do_ref, lse_ref,
     k_offset = ik * block_k
     q_offset = iq * block_q
 
-    def _tile(apply_mask: bool):
-        q = q_ref[:]
-        do = do_ref[:]
-        keep = _keep_mask(q_offset, k_offset, block_q, block_k) \
-            if apply_mask else None
-        p = _mla_probs(q_ref, k_ref, r_ref, lse_ref, scale, keep)
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
+    def _tile(part: _Part):
+        rows, cols = part.rows, part.cols
+        q = q_ref[rows]
+        do = do_ref[rows]
+        keep = _part_keep(part)
+        p = _mla_probs(q_ref, k_ref, r_ref, lse_ref, scale, keep, part)
+        dv_acc[cols] = dv_acc[cols] + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(
-            do, v_ref[:], (((1,), (1,)), ((), ())),
+            do, v_ref[cols], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[:]) * scale
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
+        ds = p * (dp - delta_ref[rows]) * scale
+        dk_acc[cols] = dk_acc[cols] + jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile)
+    _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile,
+                     walk="k")
 
     @pl.when(iq == q_tiles - 1)
     def _finish_head():
@@ -1193,16 +1476,16 @@ def _fa_mla_bwd_dq_kernel(q_ref, k_ref, r_ref, v_ref, do_ref, lse_ref,
     q_offset = iq * block_q
     k_offset = ik * block_k
 
-    def _tile(apply_mask: bool):
-        keep = _keep_mask(q_offset, k_offset, block_q, block_k) \
-            if apply_mask else None
-        p = _mla_probs(q_ref, k_ref, r_ref, lse_ref, scale, keep)
+    def _tile(part: _Part):
+        rows, cols = part.rows, part.cols
+        keep = _part_keep(part)
+        p = _mla_probs(q_ref, k_ref, r_ref, lse_ref, scale, keep, part)
         dp = jax.lax.dot_general(
-            do_ref[:], v_ref[:], (((1,), (1,)), ((), ())),
+            do_ref[rows], v_ref[cols], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[:]) * scale
-        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
-            ds.astype(k_ref.dtype), _mla_key(k_ref, r_ref),
+        ds = p * (dp - delta_ref[rows]) * scale
+        dq_acc[rows] = dq_acc[rows] + jax.lax.dot_general(
+            ds.astype(k_ref.dtype), _mla_key(k_ref, r_ref, cols),
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
     _causal_dispatch(causal, q_offset, k_offset, block_q, block_k, _tile)
@@ -1212,6 +1495,7 @@ def _fa_mla_bwd_dq_kernel(q_ref, k_ref, r_ref, v_ref, do_ref, lse_ref,
         dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
 
 
+@_traced_once("causal", "scale", "block_q", "block_k", "interpret")
 def _flash_mla_backward(q, k, k_rope, v, out, lse, g, causal, scale,
                         block_q, block_k, interpret):
     from jax.experimental import pallas as pl
@@ -1487,10 +1771,12 @@ def _latent_attention(q, k, k_rope, v, causal, scale, block_q, block_k,
                 [k, jnp.broadcast_to(k_rope, (*k.shape[:3], rope))], -1)
             return _attention_reference(q, whole, v, causal, scale)
         interpret = False
-    return _flash_mla(q, k, k_rope, v, causal, scale,
-                      DEFAULT_BLOCK if block_q is None else block_q,
-                      DEFAULT_BLOCK if block_k is None else block_k,
-                      interpret)
+    block_q = DEFAULT_BLOCK if block_q is None else block_q
+    block_k = DEFAULT_BLOCK if block_k is None else block_k
+    with telemetry.span("ops", "flash.plan", **_plan_args(
+            "latent", q, k.shape[1], block_q, block_k, causal, None)):
+        return _flash_mla(q, k, k_rope, v, causal, scale, block_q, block_k,
+                          interpret)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -1583,8 +1869,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     block_k = DEFAULT_BLOCK if block_k is None else block_k
     if native is None:
         native = _nl_eligible(q, k, v)
-    if native:
-        return _flash_nl(q, k, v, causal, scale, block_q, block_k,
-                         interpret, window)
-    return _flash(q, k, v, causal, scale, block_q, block_k, interpret,
-                  window)
+    with telemetry.span("ops", "flash.plan", **_plan_args(
+            "native" if native else "head_major", q, k.shape[1], block_q,
+            block_k, causal, window)):
+        kernels = _flash_nl if native else _flash
+        return kernels(q, k, v, causal, scale, block_q, block_k, interpret,
+                       window)

@@ -86,11 +86,33 @@ def test_flash_kernels_compile_for_v5e(one_chip, family, shape):
     assert compiled.as_text().count("tpu_custom_call") == 3
 
 
+@pytest.mark.parametrize("family,heads", [("native", 20),
+                                          ("head_major", 25)],
+                         ids=["gpt2-large", "gpt2-xl"])
+def test_cut_tile_flash_compiles_at_gpt2_cells_shapes(one_chip, family,
+                                                      heads):
+    """8 sequences of 1,024 a device, 20 heads of 64 (two a slab) and 25
+    (head-major): ONE tile a head, and the diagonal cuts it, so dK/dV
+    and dQ work it in four blocks of 256 (``_cut_parts``: slices of the
+    tiles in VMEM at multiples of 256 rows, scores of 256 to 1,024
+    lanes) and the forward in eight of 128, stage by stage."""
+    assert fa._cut_kinds(1024, 1024, None) == (4, True, False)
+    assert fa._cut_kinds(1024, 1024, None, forward=True) == (8, True, False)
+    x = jax.ShapeDtypeStruct((8, 1024, heads, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    compiled = jax.jit(_attn_grads(family)).lower(x, x, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+
 @pytest.mark.parametrize("window", [2048, None], ids=["sliding", "full"])
 def test_windowed_grouped_head_flash_compiles_at_afmoe_shapes(one_chip,
                                                               window):
     """One sequence of 8,192, 32 query heads on 4 K/V heads of 128, as
-    ``models/afmoe.py`` calls it: forward, dK/dV and dQ."""
+    ``models/afmoe.py`` calls it: forward, dK/dV and dQ.  The diagonal
+    tile, and the tile on the edge of a window of two tiles, are worked
+    in blocks: four in the backward kernels, eight in the forward."""
+    assert fa._cut_kinds(1024, 1024, window) == (4, True, window is not None)
+    assert fa._cut_kinds(1024, 1024, window, forward=True)[0] == 8
     q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16,
                              sharding=one_chip)
     kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16,
@@ -108,9 +130,11 @@ def test_windowed_grouped_head_flash_compiles_at_afmoe_shapes(one_chip,
 def test_latent_attention_flash_compiles_at_deepseek_v3_shapes(one_chip):
     """One sequence of 16,384, 32 heads, q/k of 128 + 64 against v of
     128, the rotary key ONE head, as ``models/deepseek_v3.py`` calls it:
-    forward, dK/dV and dQ at the default 1024 blocks; and what lies in
-    HBM around them: v and the output 128 wide, the rotary key never 32
-    times."""
+    forward, dK/dV and dQ at the default 1024 blocks, the 16 diagonal
+    tiles a head worked in blocks; and what lies in HBM around them: v
+    and the output 128 wide, the rotary key never 32 times."""
+    assert fa._cut_blocks(1024, 1024) == 4
+    assert fa._cut_blocks(1024, 1024, forward=True) == 8
     def shape(heads, width):
         return jax.ShapeDtypeStruct((1, 16384, heads, width), jnp.bfloat16,
                                     sharding=one_chip)

@@ -118,17 +118,20 @@ def test_the_kernels_read_one_rotary_head_and_write_v_s_width():
 
 
 #: sha256 of the jaxpr text of d(sum(flash_attention(q, k, v)))/d(q,k,v)
-#: at the calls the benchmark's other configurations make, traced from
-#: the file as it was before ``k_rope`` existed (commit 4a98c94)
+#: at the calls the benchmark's other configurations make: as PR 34 left
+#: them, when a tile the mask cuts came to be worked in blocks and the
+#: functions that build the kernel calls came under an inner ``jit``
+#: (the kernels from before ``k_rope`` existed, commit 4a98c94, are what
+#: ``tests/test_flash_cut_tiles.py`` holds one block to)
 BEFORE = {
     "trinity-mini.sliding": ((1, 8192, 32, 128), (1, 8192, 4, 128), 2048,
-        "fc7abe0b053fbf8f7ace5d84d7958ff1c1e20aacff0d1ff3708bd24235b1caf3"),
+        "be5eedee4f89553da3a870d134a4e0c98344c8e0e0a9751a3a1d382529ca6179"),
     "trinity-mini.full": ((1, 8192, 32, 128), (1, 8192, 4, 128), None,
-        "d3767cae3ee0e8fc7b75ec0063707fe1621d11502dbd75c2efda71adb8d5800d"),
+        "b882149e1eec844d96c46fa47bd58a3a17922b8bc124108c1ad4ae23878c2118"),
     "gpt2-large": ((8, 1024, 20, 64), (8, 1024, 20, 64), None,
-        "567f081a8e9888f4411d1c1d9921e671d4dffc0058583282991e5249ccf7e8b5"),
+        "3072be4dd5757e8bd01d5b9e0a56e224f1cf2a34d208bd3cad5238cc0d1bd5c6"),
     "gpt2-xl": ((8, 1024, 25, 64), (8, 1024, 25, 64), None,
-        "b683aa0481590bcbd83d3d956c349d986a67aab2b4a209ba356e795064f47d9e"),
+        "c084d8f29f36d053fe3cf7f1390b6e0e0597bc2dce6604bac355b8acb4b5b6fb"),
 }
 
 

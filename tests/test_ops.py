@@ -131,8 +131,10 @@ def test_flash_family_follows_the_shapes(q_heads, k_heads, dim, native, env,
         )(q, kv, kv))
 
     text = traced()
+    family = "_flash_nl" if native else "_flash"
+    # the custom_vjp, and inside it the jitted builder of its kernel call
     assert re.findall(r"name=(_flash\w*)", text) == [
-        "_flash_nl" if native else "_flash"]
+        family, family + "_forward"]
     for name, value in env.items():
         monkeypatch.setenv(name, value)
     assert traced() == text

@@ -447,11 +447,23 @@ def test_chunked_lm_loss_under_the_mesh_is_the_unsharded_call(chunk):
 
 #: sha256 of the jaxpr text of the GPT-2 train step on ``GPT2Config.tiny``
 #: with no mesh, the flash kernels traced (not the CPU's reference), as
-#: the file traced it BEFORE it said anything about activations
+#: the file traced it BEFORE it said anything about activations; re-pinned
+#: in PR 34 for what ``ops/flash_attention.py`` changed under it on purpose
+#: (the functions that build the kernel calls under an inner ``jit``, the
+#: head-major kernels' tile classification; 09b991c8... and 4882aa21...
+#: before): what the step says about placement is as it was, nothing
 STEP_BEFORE = {
-    "": "09b991c8836ef891cbed683d1ac75064057677209de52cd59976c40a3ecc6336",
-    "full": "4882aa219589166e88dc5205f5d4e39a542547341b9f8583c2bc4b606098acc5",
+    "": "19bff496e0e7c944a324556660bcc7abd42fd9a9a5315b1ca6c8cea13029a006",
+    "full": "cb0e2585ab16211d33165d64d3819403386dbe88b4cd73583c18b567603cfa2e",
 }
+
+
+def _kernel_calls(jaxpr) -> int:
+    """Kernel calls the program makes: a jaxpr shared by several call
+    sites (the inner ``jit`` around a kernel call) counts at each."""
+    return sum(1 if e.primitive.name == "pallas_call" else sum(
+        _kernel_calls(sub) for sub in jax.core.jaxprs_in_params(e.params))
+        for e in jaxpr.eqns)
 
 
 def _step_jaxpr(remat, mesh=None):
@@ -468,16 +480,16 @@ def _step_jaxpr(remat, mesh=None):
             jax.ShapeDtypeStruct((4, 128), jnp.int32))
     with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
             use_mesh(mesh):
-        return str(jax.make_jaxpr(make_train_step(model, tx))(*args)), \
-            params
+        return jax.make_jaxpr(make_train_step(model, tx))(*args), params
 
 
 @pytest.mark.parametrize("remat", sorted(STEP_BEFORE))
 def test_with_no_mesh_the_step_traces_as_it_did(remat):
     telemetry.drain_spans("test")
-    text, _ = _step_jaxpr(remat)
+    jaxpr, _ = _step_jaxpr(remat)
+    text = str(jaxpr)
     assert "sharding_constraint" not in text and "shard_map" not in text
-    assert text.count("pallas_call") == (8 if remat else 6)
+    assert _kernel_calls(jaxpr.jaxpr) == (8 if remat else 6)
     assert hashlib.sha256(text.encode()).hexdigest() == STEP_BEFORE[remat]
     assert not [r for r in telemetry.drain_spans("test")
                 if r["name"] == "fsdp.plan"]
@@ -486,7 +498,8 @@ def test_with_no_mesh_the_step_traces_as_it_did(remat):
 def test_under_a_mesh_the_step_says_its_plan_once_a_trace():
     mesh = build_mesh(MeshConfig(fsdp=4), devices=jax.devices()[:4])
     telemetry.drain_spans("test")
-    text, params = _step_jaxpr("full", mesh)
+    jaxpr, params = _step_jaxpr("full", mesh)
+    text = str(jaxpr)
     rows = [r for r in telemetry.drain_spans("test")
             if r["name"] == "fsdp.plan"]
     assert len(rows) == 1 and rows[0]["cat"] == "parallel"
