@@ -8,6 +8,10 @@ from ray_tpu.models.deepseek_v3 import (  # noqa: F401
 )
 from ray_tpu.models.gpt2 import GPT2, GPT2Config  # noqa: F401
 from ray_tpu.models.llama import Llama, LlamaConfig  # noqa: F401
+from ray_tpu.models.nemotron_h import (  # noqa: F401
+    NemotronH,
+    NemotronHConfig,
+)
 from ray_tpu.models.moe import (  # noqa: F401
     MoEConfig,
     MoETransformer,

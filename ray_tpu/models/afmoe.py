@@ -217,7 +217,10 @@ class RoutedExperts(nn.Module):
     ONE layer for every model that routes so (``models/deepseek_v3.py``
     too): ``config`` is any dataclass with ``num_experts``, ``top_k``,
     ``experts_held``, ``route_scale``, ``expert_dim``, ``dtype``,
-    ``param_dtype`` and ``router_dtype``."""
+    ``param_dtype`` and ``router_dtype``.  An expert is the gated three
+    matrices, ``down(silu(gate h) * up h)``, unless the configuration's
+    ``expert_form`` says ``"relu2"``: two, ``down(relu(up h)^2)``
+    (``models/nemotron_h.py``)."""
     config: Any
 
     @nn.compact
@@ -238,8 +241,10 @@ class RoutedExperts(nn.Module):
                                            axes), shape,
                 cfg.param_dtype).astype(cfg.dtype)
 
-        w_gate = experts("experts_gate", (held, embed, cfg.expert_dim),
-                         ("expert", "embed", "mlp"))
+        gated = getattr(cfg, "expert_form", "gated") == "gated"
+        if gated:
+            w_gate = experts("experts_gate", (held, embed, cfg.expert_dim),
+                             ("expert", "embed", "mlp"))
         w_up = experts("experts_up", (held, embed, cfg.expert_dim),
                        ("expert", "embed", "mlp"))
         w_down = experts("experts_down", (held, cfg.expert_dim, embed),
@@ -254,9 +259,14 @@ class RoutedExperts(nn.Module):
         with jax.named_scope("moe.dispatch"):
             rows = gm.dispatch(flat, plan)
         with jax.named_scope("moe.experts"):
-            gate = gm.grouped_matmul(rows, w_gate, plan)
-            up = gm.grouped_matmul(rows, w_up, plan)
-            out = gm.grouped_matmul(nn.silu(gate) * up, w_down, plan)
+            if gated:
+                gate = gm.grouped_matmul(rows, w_gate, plan)
+                up = gm.grouped_matmul(rows, w_up, plan)
+                mid = nn.silu(gate) * up
+            else:
+                mid = jnp.square(nn.relu(
+                    gm.grouped_matmul(rows, w_up, plan)))
+            out = gm.grouped_matmul(mid, w_down, plan)
         with jax.named_scope("moe.combine"):
             routed = gm.combine(out, weights, plan)
         return routed.astype(cfg.dtype).reshape(batch, seq, embed)
@@ -317,17 +327,20 @@ class MLPPart(nn.Module):
         return x + RMSNorm(cfg.rms_eps, name="mlp_post_norm")(out)
 
 
-def each_sequence(attn, mlp, x: jax.Array,
+def each_sequence(parts, x: jax.Array,
                   chosen: Optional[jax.Array] = None) -> jax.Array:
-    """``mlp(attn(x))`` over ONE sequence of the batch at a time (a
-    kernel call a sequence, the activation memory of one sequence);
-    ``chosen [B*T, k]``: the routing each sequence's MLP part replays."""
+    """A layer's ``parts``, one after the other, over ONE sequence of the
+    batch at a time (a kernel call a sequence, the activation memory of
+    one sequence); ``chosen [B*T, k]``: the routing each sequence's LAST
+    part, the routed one, replays."""
     seq = x.shape[1]
     out = []
     for i in range(x.shape[0]):
-        h = attn(x[i:i + 1])
-        out.append(mlp(h) if chosen is None else
-                   mlp(h, chosen[i * seq:(i + 1) * seq]))
+        h = x[i:i + 1]
+        for part in parts[:-1]:
+            h = part(h)
+        out.append(parts[-1](h) if chosen is None else
+                   parts[-1](h, chosen[i * seq:(i + 1) * seq]))
     return jnp.concatenate(out)
 
 
@@ -348,8 +361,8 @@ class AFMoEBlock(nn.Module):
         attn, mlp = AttentionPart, MLPPart
         if cfg.remat == "full":
             attn, mlp = nn.remat(attn), nn.remat(mlp)
-        return each_sequence(attn(cfg, self.kind, name="attn"),
-                             mlp(cfg, self.routed, name="mlp"), x, chosen)
+        return each_sequence((attn(cfg, self.kind, name="attn"),
+                              mlp(cfg, self.routed, name="mlp")), x, chosen)
 
 
 class AFMoE(nn.Module):

@@ -216,8 +216,8 @@ class DeepseekV3Block(nn.Module):
         attn, mlp = AttentionPart, MLPPart
         if cfg.remat == "full":
             attn, mlp = nn.remat(attn), nn.remat(mlp)
-        return each_sequence(attn(cfg, name="attn"),
-                             mlp(cfg, self.routed, name="mlp"), x, chosen)
+        return each_sequence((attn(cfg, name="attn"),
+                              mlp(cfg, self.routed, name="mlp")), x, chosen)
 
 
 class DeepseekV3(nn.Module):

@@ -39,6 +39,10 @@ def _rmsnorm(x, weight, eps, interpret):
     # scoped limit at Llama widths (512 x 4096 bf16 overflowed it)
     block = min(512, rows,
                 max(8, (2 << 20) // (x.shape[-1] * x.dtype.itemsize)))
+    if block < rows:
+        # a width that is no power of two (2688) gives a bound that is
+        # none either (390), and halving that ends at one row a tile
+        block = 1 << (block.bit_length() - 1)
     while rows % block:
         block //= 2
     block = max(block, 1)

@@ -28,6 +28,13 @@ are gathers in both directions — the plan carries the row of every pair
 and the pair of every row — because a TPU scatter-add of 10^5 rows
 serializes.
 
+A width no tile divides (1856 = 2^6 x 29: its largest divisor under 512
+is 464, which is neither whole 128-lane registers nor the whole width,
+and Mosaic refuses such a block) is taken AS IT LIES in HBM, under a
+masked last tile (:func:`lane_block`): the grid rounds up, the columns a
+block reads past the edge reach only columns of the result that are past
+the edge too, and those are never written.  No weight is padded.
+
 On other backends (tests) the products fall back to plain ``jnp`` unless
 ``interpret=True`` forces the kernels through the Pallas interpreter.
 """
@@ -41,6 +48,19 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.flash_attention import fit_block
+
+
+def lane_block(width: int, block: int) -> int:
+    """The tile of a dimension of ``width`` that lies along the lanes (or
+    is contracted over whole): the largest divisor up to ``block`` where
+    that is whole 128-lane registers or the whole width; else the
+    multiple of 128 up to ``block`` that overhangs the edge least, the
+    last tile masked."""
+    fit = fit_block(width, block)
+    tiles = range(128, block + 1, 128)   # none under 128 (tests' tiles)
+    if fit % 128 == 0 or fit == width or not tiles:
+        return fit
+    return min(tiles, key=lambda b: (-(-width // b) * b, -b))
 
 
 class RowPlan(NamedTuple):
@@ -209,7 +229,7 @@ def _gmm_pallas(lhs, rhs, tile_expert, n_live, block_m, block_n,
 
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    block_n = fit_block(n, block_n)
+    block_n = lane_block(n, block_n)
     if transpose_rhs:   # rhs [E, N, K]: rows of the block are outputs
         rhs_spec = pl.BlockSpec(
             (None, block_n, k),
@@ -224,7 +244,7 @@ def _gmm_pallas(lhs, rhs, tile_expert, n_live, block_m, block_n,
         functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(n // block_n, m // block_m),
+            grid=(-(-n // block_n), m // block_m),
             in_specs=[
                 pl.BlockSpec((block_m, k),
                              lambda j, i, te, nl: (_live_tile(i, nl), 0)),
@@ -275,12 +295,12 @@ def _tgmm_pallas(lhs, dout, tile_expert, n_live, experts, block_m, block_k,
 
     m, k = lhs.shape
     n = dout.shape[1]
-    block_k, block_n = fit_block(k, block_k), fit_block(n, block_n)
+    block_k, block_n = lane_block(k, block_k), lane_block(n, block_n)
     return pl.pallas_call(
         _tgmm_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(k // block_k, n // block_n, m // block_m),
+            grid=(-(-k // block_k), -(-n // block_n), m // block_m),
             in_specs=[
                 pl.BlockSpec((block_m, block_k),
                              lambda a, b, i, te, nl: (_live_tile(i, nl), a)),

@@ -79,10 +79,13 @@ TRAFFIC_DEPENDENT = {
     "ray_tpu_serve_kv_pages_freed_total",
     "ray_tpu_serve_kv_page_occupancy",
     # routed expert layers report only where a training loop asks
-    # (models/afmoe.py report_router_stats; models/deepseek_v3.py's
-    # under its own `model` tag, the same three series).  What a step
-    # was COMPILED as is in spans, not series, so nothing of it is listed
-    # here: `model:moe.plan`, `model:mla.plan`, and `ops:flash.plan`
+    # (models/afmoe.py report_router_stats; models/deepseek_v3.py's and
+    # models/nemotron_h.py's under their own `model` tags, the same
+    # three series).  What a step was COMPILED as is in spans, not
+    # series, so nothing of it is listed here: `model:moe.plan`,
+    # `model:mla.plan`, `model:hybrid.plan`, `ops:ssd.plan` (the chunked
+    # scan: heads, groups, state, chunk, heads_a_step, carry) and
+    # `ops:flash.plan`
     # (family, heads, width, seq, block, window, sub, sub_forward,
     # tiles_live, tiles_cut, pairs_worked, pairs_worked_forward,
     # pairs_visible: docs/observability.md)

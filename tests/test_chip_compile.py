@@ -284,3 +284,124 @@ def test_gpt2_fsdp_step_partitions_over_four_v5e(topo):
     assert jax.tree.all(jax.tree.map(
         lambda a, b, x: a.is_equivalent_to(b, x.ndim),
         params_in, compiled.output_shardings[0], args[0]))
+
+
+# ---------------------------------------------------------------------------
+# Nemotron-H (PR 35): the chunked scan, the step, the gradient check
+# ---------------------------------------------------------------------------
+
+def test_ssd_kernels_compile_at_the_nemotron_cells_shapes(one_chip):
+    """One sequence of 8,192, 64 heads of 64, 8 groups of state 128,
+    chunks of 128, a whole group a grid step: the forward kernel and the
+    backward one (``_ssd``: the public wrapper asks the backend).  What
+    lies in HBM around them: B and C by group, never 64 heads of them,
+    and no ``Q x Q`` array a head."""
+    ssd = importlib.import_module("ray_tpu.ops.ssd")
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    xs = shape((1, 8192, 64, 64))
+    bc = shape((1, 8192, 8, 128))
+    head = shape((64,), jnp.float32)
+    plan = ssd._plan(xs, bc, 128)
+    assert plan.heads_a_step == 8
+
+    def grads(*a):
+        def loss(*a):
+            y = ssd._ssd(*a, plan, False).astype(jnp.float32)
+            return (y * y).sum()
+        return jax.grad(loss, argnums=tuple(range(6)))(*a)
+
+    compiled = jax.jit(grads).lower(
+        xs, shape((1, 8192, 64), jnp.float32), head, bc, bc, head).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "[1,8192,64,128]" not in text            # B or C a head
+    assert not re.search(r"\[(1,)?64,64,128,128\]", text)   # Q x Q a head
+
+
+def _nemotron_share(**kw):
+    nh = importlib.import_module("ray_tpu.models.nemotron_h")
+    cfg = nh.NemotronHConfig.nemotron_3_nano_30b_a3b_share(remat="full",
+                                                           **kw)
+    return nh, cfg, nh.NemotronH(cfg)
+
+
+def _abstract_params(model, one_chip, batch):
+    from flax.core import meta
+
+    shapes = meta.unbox(jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0), batch=batch)))
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), shapes)
+
+
+#: bytes of the cell's training state: 666,962,944 parameters, f32
+#: weights and AdamW's two moments (the gradients are temporaries)
+NEMOTRON_STATE_BYTES = 666_962_944 * 12
+
+
+def test_nemotron_share_train_step_fits_one_v5e(one_chip):
+    """``nemotron-3-nano-30b-a3b.steady``'s step: EMEMEMEM* at the
+    published widths, batch 2 x 8,192, donated state."""
+    import optax
+
+    nh, cfg, model = _nemotron_share()
+    params = _abstract_params(model, one_chip, 2)
+    assert sum(a.size for a in jax.tree.leaves(params)) == 666_962_944
+    tx = optax.adamw(1e-5, weight_decay=0.01)
+    opt_state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(tx.init, params))
+    tokens = jax.ShapeDtypeStruct((2, cfg.max_seq_len), jnp.int32,
+                                  sharding=one_chip)
+    compiled = _lower_as_on_tpu(nh.make_train_step(model, tx),
+                                (params, opt_state, tokens)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    named = lambda name: sum(name in line for line in calls)  # noqa: E731
+    # 4 mixers x 2 sequences: forward twice (remat), backward once
+    assert named("ssd_chunk_scan_bwd") == 8
+    assert named("ssd_chunk_scan") - named("ssd_chunk_scan_bwd") == 16
+    # 4 expert layers x 2 sequences x 2 products x (2 forward, d lhs, d rhs)
+    assert named("grouped_matmul") == 64
+    mem = compiled.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 0.9 * V5E_HBM_BYTES, f"{total / 2**30:.2f} GiB"
+    assert mem.alias_size_in_bytes >= mem.argument_size_in_bytes - 2 ** 20
+
+
+def test_nemotron_gradient_check_fits_beside_the_training_state(one_chip):
+    """The harness's check (``benchmarks/kinds/train.py``
+    ``gradient_check``): depth 2, ``EMEM*``, two sequences, the program's
+    paired loss and the step-by-step reference, BOTH gradients in one
+    program, while the training state is still on the chip."""
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    reference = importlib.import_module("benchmarks.reference.nemotron_h")
+    paired = importlib.import_module(
+        "benchmarks.reference.nemotron_h_paired")
+    _, cfg, model = _nemotron_share(num_layers=2)
+    params = _abstract_params(model, one_chip, 2)
+    tokens = jax.ShapeDtypeStruct((2, cfg.max_seq_len), jnp.int32,
+                                  sharding=one_chip)
+    sizes = {"n_layer": 2, "n_head": cfg.num_heads, "ln_eps": cfg.rms_eps}
+
+    def error(p, t):
+        return reference.grad_error(
+            jax.grad(lambda q: paired.program_loss(model, q, t))(p),
+            jax.grad(lambda q: reference.loss(q, t, **sizes))(p))
+
+    compiled = _lower_as_on_tpu(jax.jit(error), (params, tokens)).compile()
+    assert "ssd_chunk_scan_bwd" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    check = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert check + NEMOTRON_STATE_BYTES < 0.97 * V5E_HBM_BYTES, \
+        f"{check / 2**30:.2f} GiB beside " \
+        f"{NEMOTRON_STATE_BYTES / 2**30:.2f} GiB of state"
