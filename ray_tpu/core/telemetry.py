@@ -624,13 +624,14 @@ def _moekey(model: str, layer: int, expert: Optional[int] = None) -> Tuple:
 
 
 def moe_router_load(model: str, layer: int, load, landed_share: float,
-                    imbalance: float) -> None:
+                    imbalance: float, live_share: float) -> None:
     """What one routed expert layer saw in its last observed batch:
     ``load[e]`` (token, choice) pairs that chose held expert ``e``, the
     share of all pairs that landed on this layer's share of the experts,
-    and the largest load over the mean (1.0 is an even router; the
+    the largest load over the mean (1.0 is an even router; the
     grouped products' time follows the sum, a straggling expert
-    parallel rank follows the largest)."""
+    parallel rank follows the largest), and the share of the worst-case
+    row buffers' tiles that held rows: what the layer worked on."""
     if not enabled():
         return
     per = _gauge("ray_tpu_moe_expert_load",
@@ -646,6 +647,10 @@ def moe_router_load(model: str, layer: int, load, landed_share: float,
     _gauge("ray_tpu_moe_load_imbalance",
            "largest held expert's load over the mean held load",
            ("model", "layer")).set_key(key, float(imbalance))
+    _gauge("ray_tpu_moe_live_share",
+           "row tiles that held rows, which the layer worked on, over "
+           "the tiles of its worst-case row buffers",
+           ("model", "layer")).set_key(key, float(live_share))
 
 
 # ---------------------------------------------------------------------------

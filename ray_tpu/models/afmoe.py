@@ -146,10 +146,17 @@ class AFMoEConfig:
 
 def routed_plan_args(cfg, tokens: int) -> Dict[str, Any]:
     """The routed layer's part of a ``moe.plan`` span, for any
-    configuration that carries one (:class:`RoutedExperts`' fields)."""
+    configuration that carries one (:class:`RoutedExperts`' fields).
+    ``buffer_passes``: passes of XLA a layer-call makes over ALL rows of
+    the worst-case buffer whatever the load (the rest is work of the
+    live tiles); ``row_gather``: how ``rows <- tokens`` keeps to the
+    live rows: ``reach``, one gather of the least of ``gather_reaches``
+    of the buffer that holds them."""
     return {"experts": cfg.num_experts, "held_first": cfg.experts_held[0],
             "held": cfg.experts_held[1], "top_k": cfg.top_k,
-            "row_bound": tokens * cfg.top_k, "block_rows": BLOCK_ROWS}
+            "row_bound": tokens * cfg.top_k, "block_rows": BLOCK_ROWS,
+            "buffer_passes": 0, "row_gather": "reach",
+            "gather_reaches": ",".join(f"1/{r}" for r in gm.REACHES)}
 
 
 def _rope(x: jax.Array, theta: float) -> jax.Array:
@@ -241,12 +248,12 @@ class RoutedExperts(nn.Module):
                                            axes), shape,
                 cfg.param_dtype).astype(cfg.dtype)
 
-        gated = getattr(cfg, "expert_form", "gated") == "gated"
-        if gated:
-            w_gate = experts("experts_gate", (held, embed, cfg.expert_dim),
-                             ("expert", "embed", "mlp"))
-        w_up = experts("experts_up", (held, embed, cfg.expert_dim),
-                       ("expert", "embed", "mlp"))
+        # an expert's matrices in the order of its products
+        into = [experts(name, (held, embed, cfg.expert_dim),
+                        ("expert", "embed", "mlp"))
+                for name in (("experts_gate", "experts_up")
+                             if getattr(cfg, "expert_form", "gated")
+                             == "gated" else ("experts_up",))]
         w_down = experts("experts_down", (held, cfg.expert_dim, embed),
                          ("expert", "mlp", "embed"))
 
@@ -256,17 +263,13 @@ class RoutedExperts(nn.Module):
             plan = gm.plan_rows(idx, first, held, block_m=BLOCK_ROWS)
         self.sow("intermediates", "expert_load", plan.sizes)
         self.sow("intermediates", "expert_choice", own)
+        # of that buffer's tiles the live ones alone are worked on
+        self.sow("intermediates", "live_tiles", plan.n_live[0])
+        self.sow("intermediates", "buffer_tiles", plan.tile_expert.shape[0])
         with jax.named_scope("moe.dispatch"):
             rows = gm.dispatch(flat, plan)
         with jax.named_scope("moe.experts"):
-            if gated:
-                gate = gm.grouped_matmul(rows, w_gate, plan)
-                up = gm.grouped_matmul(rows, w_up, plan)
-                mid = nn.silu(gate) * up
-            else:
-                mid = jnp.square(nn.relu(
-                    gm.grouped_matmul(rows, w_up, plan)))
-            out = gm.grouped_matmul(mid, w_down, plan)
+            out = gm.expert_products(rows, (*into, w_down), plan)
         with jax.named_scope("moe.combine"):
             routed = gm.combine(out, weights, plan)
         return routed.astype(cfg.dtype).reshape(batch, seq, embed)
@@ -468,7 +471,9 @@ def router_stats(model: nn.Module, params, tokens: jax.Array
     order): ``load [L, held]`` (token, choice) pairs that chose each held
     expert; ``landed_share [L]`` of all pairs that land here (an even
     router gives ``held / experts``); ``imbalance [L]`` largest load over
-    mean load.  For a training loop to pass to
+    mean load; ``live_tiles [L]`` and ``buffer_tiles [L]``: row tiles
+    that held rows, which the layer worked on, of those its worst-case
+    buffers span.  For a training loop to pass to
     :func:`report_router_stats` and ``session.report``."""
     cfg = model.config
     _, state = model.apply({"params": params}, tokens,
@@ -479,9 +484,13 @@ def router_stats(model: nn.Module, params, tokens: jax.Array
     load = jnp.stack([
         sum(layers[f"h{i}"]["mlp"]["moe"]["expert_load"])
         for i in range(cfg.num_layers)]).astype(jnp.float32)
+    tiles = {k: jnp.stack([sum(layers[f"h{i}"]["mlp"]["moe"][k])
+                           for i in range(cfg.num_layers)])
+             for k in ("live_tiles", "buffer_tiles")}
     pairs = tokens.shape[0] * tokens.shape[1] * cfg.top_k
     return {"load": load, "landed_share": load.sum(-1) / pairs,
-            "imbalance": load.max(-1) / jnp.maximum(load.mean(-1), 1e-9)}
+            "imbalance": load.max(-1) / jnp.maximum(load.mean(-1), 1e-9),
+            **tiles}
 
 
 @functools.partial(jax.jit, static_argnums=0)
@@ -503,11 +512,14 @@ def report_router_stats(stats: Dict[str, Any], model_name: str = "afmoe"
     import numpy as np
 
     out: Dict[str, float] = {}
-    for layer, (load, share, imb) in enumerate(zip(
-            np.asarray(stats["load"]), np.asarray(stats["landed_share"]),
-            np.asarray(stats["imbalance"]))):
+    for layer, (load, share, imb, live, buffer) in enumerate(zip(*(
+            np.asarray(stats[k]) for k in (
+                "load", "landed_share", "imbalance", "live_tiles",
+                "buffer_tiles")))):
+        live_share = float(live) / float(buffer)
         telemetry.moe_router_load(model_name, layer, load.tolist(),
-                                  float(share), float(imb))
+                                  float(share), float(imb), live_share)
         out[f"moe/h{layer}/landed_share"] = float(share)
         out[f"moe/h{layer}/imbalance"] = float(imb)
+        out[f"moe/h{layer}/live_share"] = live_share
     return out

@@ -16,24 +16,47 @@ is chosen per row tile through a scalar-prefetched table:
   :func:`_tgmm` (``d rhs[e] = lhs[rows of e]^T @ d out[rows of e]``,
   accumulated over the expert's row tiles in VMEM scratch).
 
-Only the ``n_live`` leading row tiles hold rows; the grid still spans
-the buffer, but a dead tile's step computes nothing and its index
-maps repeat the last live tile's blocks, so Pallas elides its DMAs: the
-cost follows the live rows.  The output rows of dead tiles are NOT
-written (whatever the buffer held); :func:`dispatch` / :func:`combine`
-never read them.
+**The buffer is the worst case's in ADDRESSES only: nothing in the
+program reads or writes a dead row.**  Only the ``n_live`` leading row
+tiles hold rows, and all work in row space is done a live tile at a
+time, in one of two forms:
 
-``dispatch`` (rows <- tokens) and ``combine`` (tokens <- rows, weighted)
-are gathers in both directions — the plan carries the row of every pair
-and the pair of every row — because a TPU scatter-add of 10^5 rows
-serializes.
+* inside the kernels.  The grid still spans the buffer, but a dead
+  tile's step computes nothing and its index maps repeat the last live
+  tile's blocks, so Pallas elides its DMAs.  What stood between the
+  products as passes of XLA over all ``M`` rows is work on a tile in
+  VMEM (:func:`expert_products`, one ``custom_vjp`` over an expert's
+  two or three products): the down product forms ``silu(gate) * up``
+  (or ``relu(up)^2``) on its lhs tile, float32 from the bfloat16 tiles,
+  and so does its ``d rhs``; its ``d lhs`` ends in the activation's
+  derivative against the ``gate`` and ``up`` tiles and writes ``d gate``
+  and ``d up``; the second ``d lhs`` onto the rows (up's, after gate's)
+  adds the first's tile before it writes.  The activation's result is
+  never an array.
+* under a reach the plan bounds (:func:`_rows_of_tokens`, the one
+  gather ``rows <- tokens`` of :func:`dispatch` and of :func:`combine`'s
+  backward): one gather of the buffer's leading eighth, quarter, half or
+  whole (:data:`REACHES`), the least that holds the ``n_live`` live
+  tiles, written into a buffer that was allocated and never initialised
+  (``lax.empty``); at most twice the live rows (or the buffer's eighth)
+  are written, the dead ones among them read token 0.  (A ``fori_loop``
+  of ``n_live``-bounded trips, chunk after chunk, touched fewer rows and
+  was slower a row, and a step with 24 such loops was scheduled into a
+  gibibyte more memory: PERF.md, PR 36.)
+
+The rows of dead tiles hold whatever the memory held; nothing reads
+them: the pair passes of :func:`combine` and :func:`dispatch`'s backward
+(gathers over ``tokens x choices`` pairs, a TPU scatter-add of 10^5 rows
+serializes) read a landed pair's row, or row 0 under a mask.
 
 A width no tile divides (1856 = 2^6 x 29: its largest divisor under 512
 is 464, which is neither whole 128-lane registers nor the whole width,
 and Mosaic refuses such a block) is taken AS IT LIES in HBM, under a
 masked last tile (:func:`lane_block`): the grid rounds up, the columns a
 block reads past the edge reach only columns of the result that are past
-the edge too, and those are never written.  No weight is padded.
+the edge too, and those are never written.  That holds for the
+activation formed on a tile: it is elementwise, and ``d rhs`` contracts
+over ROWS, never over the width.  No weight is padded.
 
 On other backends (tests) the products fall back to plain ``jnp`` unless
 ``interpret=True`` forces the kernels through the Pallas interpreter.
@@ -130,15 +153,54 @@ def plan_rows(expert_idx: jax.Array, first: int, held: int, *,
 
 
 # ---------------------------------------------------------------------------
-# rows <- tokens, tokens <- rows: gathers both ways
+# rows <- tokens (as far as the live rows reach), tokens <- rows (the pairs)
 # ---------------------------------------------------------------------------
+
+#: how far a gather ``rows <- tokens`` may reach, as divisors of the
+#: buffer's tiles: the least reach that holds the live tiles is taken
+REACHES = (8, 4, 2, 1)
+
+
+def _rows_of_tokens(x: jax.Array, plan: RowPlan, weights=None) -> jax.Array:
+    """``x [T, D]`` -> rows ``[M, D]``: each row of a live tile its
+    pair's token, times the pair's weight where ``weights [T, k]`` are
+    given (a row that holds no pair: token 0, weight 0).  ONE gather of
+    the buffer's leading ``S`` rows, written into a buffer nobody
+    initialised, ``S`` the least of the buffer's eighth, quarter, half
+    and whole (in whole tiles) that holds the ``n_live`` live tiles,
+    chosen under a ``lax.switch``; the rows past ``S`` are not touched,
+    the dead ones before it read token 0."""
+    k = plan.pair_row.shape[1]
+    m, tiles = plan.row_pair.shape[0], plan.tile_expert.shape[0]
+    block_m = m // tiles
+    flat_w = None if weights is None else weights.reshape(-1)
+    reaches = [-(-tiles // share) * block_m for share in REACHES]
+
+    def reach(rows):
+        def gather():
+            pair = plan.row_pair[:rows]
+            part = x[pair // k]
+            if flat_w is not None:
+                part = part * jnp.where(plan.row_valid[:rows], flat_w[pair],
+                                        0.0)[:, None]
+            part = part.astype(x.dtype)
+            if rows == m:
+                return part
+            return jax.lax.dynamic_update_slice(
+                jax.lax.empty((m, x.shape[1]), x.dtype), part, (0, 0))
+        return gather
+
+    live = plan.n_live[0] * block_m
+    shorter = sum((live > rows).astype(jnp.int32) for rows in reaches[:-1])
+    return jax.lax.switch(shorter, [reach(rows) for rows in reaches])
+
 
 @jax.custom_vjp
 def dispatch(x: jax.Array, plan: RowPlan) -> jax.Array:
     """``x [T, D]`` -> rows ``[M, D]``: each row its pair's token (rows
-    that hold no pair read token 0 and are never combined)."""
-    k = plan.pair_row.shape[1]
-    return x[plan.row_pair // k]
+    of live tiles that hold no pair read token 0 and are never combined;
+    rows of dead tiles are not written)."""
+    return _rows_of_tokens(x, plan)
 
 
 def _dispatch_fwd(x, plan):
@@ -182,21 +244,50 @@ def _combine_fwd(rows, weights, plan):
 
 def _combine_bwd(res, g):
     rows, weights, plan = res
-    tokens, k = plan.pair_row.shape
-    w_row = weights.reshape(tokens * k)[plan.row_pair]
-    # gathered in the rows' dtype: the float32 cotangent of every row
-    # of the buffer is twice the buffer
-    w_row = jnp.where(plan.row_valid, w_row, 0.0)
-    d_rows = g.astype(rows.dtype)[plan.row_pair // k] * w_row[:, None]
+    k = plan.pair_row.shape[1]
+    # gathered in the rows' dtype (the float32 cotangent of a row is
+    # twice the row), each row weighted as it is gathered
+    d_rows = _rows_of_tokens(g.astype(rows.dtype), plan, weights)
     d_w = jnp.stack([
         jnp.where(plan.pair_valid[:, c],
                   jnp.sum(rows[plan.pair_row[:, c]].astype(jnp.float32)
                           * g, axis=-1), 0.0)
         for c in range(k)], axis=1)
-    return d_rows.astype(rows.dtype), d_w.astype(weights.dtype), None
+    return d_rows, d_w.astype(weights.dtype), None
 
 
 combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+# ---------------------------------------------------------------------------
+# an expert's activation, on a tile in VMEM or (off the TPU) on an array
+# ---------------------------------------------------------------------------
+
+def _act(hidden) -> jax.Array:
+    """``(gate, up)`` -> ``silu(gate) * up``; ``(up,)`` -> ``relu(up)^2``;
+    in float32."""
+    h = [a.astype(jnp.float32) for a in hidden]
+    if len(h) == 2:
+        return h[0] * jax.nn.sigmoid(h[0]) * h[1]
+    return jnp.square(jnp.maximum(h[0], 0.0))
+
+
+def _act_bwd(hidden, d_mid: jax.Array):
+    """The cotangents of ``hidden`` (float32) under ``d_mid`` of
+    :func:`_act`'s result."""
+    h = [a.astype(jnp.float32) for a in hidden]
+    if len(h) == 2:
+        gate, up = h
+        s = jax.nn.sigmoid(gate)
+        return (d_mid * up * s * (1.0 + gate * (1.0 - s)),
+                d_mid * gate * s)
+    return (d_mid * 2.0 * jnp.maximum(h[0], 0.0),)
+
+
+def _lhs_of(lhs, act: bool) -> jax.Array:
+    """The product's left operand from its arrays (or tiles): the one
+    there is, or the activation of the one or two."""
+    return _act(lhs).astype(lhs[0].dtype) if act else lhs[0]
 
 
 # ---------------------------------------------------------------------------
@@ -209,25 +300,41 @@ def _live_tile(m, n_live_ref):
     return jnp.minimum(m, jnp.maximum(n_live_ref[0] - 1, 0))
 
 
-def _gmm_kernel(te_ref, n_live_ref, lhs_ref, rhs_ref, out_ref, *,
-                transpose_rhs: bool):
+def _gmm_kernel(te_ref, n_live_ref, *refs, n_lhs: int, act: bool,
+                add: bool, n_hidden: int, transpose_rhs: bool):
+    """``refs``: the lhs tiles (``act``: the activation's inputs), the
+    weight block, the tile to add (``add``), the ``n_hidden`` tiles the
+    activation's derivative is taken against, then the results: one, or
+    ``n_hidden``."""
     from jax.experimental import pallas as pl
+
+    lhs_refs, rhs_ref = refs[:n_lhs], refs[n_lhs]
+    rest = refs[n_lhs + 1:]
+    add_ref = rest[0] if add else None
+    rest = rest[int(add):]
+    hidden_refs, out_refs = rest[:n_hidden], rest[n_hidden:]
 
     @pl.when(pl.program_id(1) < n_live_ref[0])
     def _compute():
         contract = (((1,), (1,)), ((), ())) if transpose_rhs else \
             (((1,), (0,)), ((), ()))
-        out_ref[:] = jax.lax.dot_general(
-            lhs_ref[:], rhs_ref[:], contract,
-            preferred_element_type=jnp.float32).astype(out_ref.dtype)
+        acc = jax.lax.dot_general(
+            _lhs_of([r[:] for r in lhs_refs], act), rhs_ref[:], contract,
+            preferred_element_type=jnp.float32)
+        if add:
+            acc = acc + add_ref[:].astype(jnp.float32)
+        outs = _act_bwd([r[:] for r in hidden_refs], acc) if n_hidden \
+            else (acc,)
+        for out_ref, out in zip(out_refs, outs):
+            out_ref[:] = out.astype(out_ref.dtype)
 
 
 def _gmm_pallas(lhs, rhs, tile_expert, n_live, block_m, block_n,
-                transpose_rhs, interpret):
+                transpose_rhs, interpret, act=False, add=None, hidden=()):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    m, k = lhs.shape
+    m, k = lhs[0].shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     block_n = lane_block(n, block_n)
     if transpose_rhs:   # rhs [E, N, K]: rows of the block are outputs
@@ -238,33 +345,43 @@ def _gmm_pallas(lhs, rhs, tile_expert, n_live, block_m, block_n,
         rhs_spec = pl.BlockSpec(
             (None, k, block_n),
             lambda j, i, te, nl: (te[_live_tile(i, nl)], 0, j))
+    lhs_spec = pl.BlockSpec((block_m, k),
+                            lambda j, i, te, nl: (_live_tile(i, nl), 0))
+    out_spec = pl.BlockSpec((block_m, block_n),
+                            lambda j, i, te, nl: (_live_tile(i, nl), j))
+    adds = () if add is None else (add,)
+    n_out = max(len(hidden), 1)
+    out_shape = [jax.ShapeDtypeStruct((m, n), lhs[0].dtype)] * n_out
+    name = "grouped_matmul" + ("_t" if transpose_rhs else "") \
+        + ("_act" if act or hidden else "") + ("_add" if adds else "")
     # n outermost: consecutive row tiles of one expert keep its weight
     # block resident
-    return pl.pallas_call(
-        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
+    out = pl.pallas_call(
+        functools.partial(_gmm_kernel, n_lhs=len(lhs), act=act,
+                          add=bool(adds), n_hidden=len(hidden),
+                          transpose_rhs=transpose_rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(-(-n // block_n), m // block_m),
-            in_specs=[
-                pl.BlockSpec((block_m, k),
-                             lambda j, i, te, nl: (_live_tile(i, nl), 0)),
-                rhs_spec,
-            ],
-            out_specs=pl.BlockSpec(
-                (block_m, block_n),
-                lambda j, i, te, nl: (_live_tile(i, nl), j)),
+            in_specs=[lhs_spec] * len(lhs) + [rhs_spec]
+            + [out_spec] * (len(adds) + len(hidden)),
+            out_specs=[out_spec] * n_out,
         ),
-        out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        out_shape=out_shape,
+        # the tile to add is the result's own: the sum takes its place
+        input_output_aliases={2 + len(lhs) + 1: 0} if adds else {},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        name="grouped_matmul_t" if transpose_rhs else "grouped_matmul",
-    )(tile_expert, n_live, lhs, rhs)
+        name=name,
+    )(tile_expert, n_live, *lhs, rhs, *adds, *hidden)
+    return tuple(out) if hidden else out[0]
 
 
-def _tgmm_kernel(te_ref, n_live_ref, lhs_ref, dout_ref, out_ref, acc_ref):
+def _tgmm_kernel(te_ref, n_live_ref, *refs, act: bool):
     from jax.experimental import pallas as pl
 
+    *lhs_refs, dout_ref, out_ref, acc_ref = refs
     i = pl.program_id(2)
     last = pl.num_programs(2) - 1
     n_live = n_live_ref[0]
@@ -280,8 +397,8 @@ def _tgmm_kernel(te_ref, n_live_ref, lhs_ref, dout_ref, out_ref, acc_ref):
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
         acc_ref[:] = acc_ref[:] + jax.lax.dot_general(
-            lhs_ref[:], dout_ref[:], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            _lhs_of([r[:] for r in lhs_refs], act), dout_ref[:],
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
         @pl.when(closes)
         def _store():
@@ -289,21 +406,21 @@ def _tgmm_kernel(te_ref, n_live_ref, lhs_ref, dout_ref, out_ref, acc_ref):
 
 
 def _tgmm_pallas(lhs, dout, tile_expert, n_live, experts, block_m, block_k,
-                 block_n, interpret):
+                 block_n, interpret, act=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    m, k = lhs.shape
+    m, k = lhs[0].shape
     n = dout.shape[1]
     block_k, block_n = lane_block(k, block_k), lane_block(n, block_n)
+    lhs_spec = pl.BlockSpec((block_m, block_k),
+                            lambda a, b, i, te, nl: (_live_tile(i, nl), a))
     return pl.pallas_call(
-        _tgmm_kernel,
+        functools.partial(_tgmm_kernel, act=act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(-(-k // block_k), -(-n // block_n), m // block_m),
-            in_specs=[
-                pl.BlockSpec((block_m, block_k),
-                             lambda a, b, i, te, nl: (_live_tile(i, nl), a)),
+            in_specs=[lhs_spec] * len(lhs) + [
                 pl.BlockSpec((block_m, block_n),
                              lambda a, b, i, te, nl: (_live_tile(i, nl), b)),
             ],
@@ -312,12 +429,12 @@ def _tgmm_pallas(lhs, dout, tile_expert, n_live, experts, block_m, block_k,
                 lambda a, b, i, te, nl: (te[_live_tile(i, nl)], a, b)),
             scratch_shapes=[pltpu.VMEM((block_k, block_n), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((experts, k, n), lhs.dtype),
+        out_shape=jax.ShapeDtypeStruct((experts, k, n), lhs[0].dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="grouped_matmul_drhs",
-    )(tile_expert, n_live, lhs, dout)
+        name="grouped_matmul_drhs" + ("_act" if act else ""),
+    )(tile_expert, n_live, *lhs, dout)
 
 
 # ---------------------------------------------------------------------------
@@ -329,13 +446,14 @@ def _tiles_live(tile_expert, n_live):
 
 
 def _gmm_ref(lhs, rhs, tile_expert, n_live, block_m, transpose_rhs):
+    """Float32; a dead tile's rows zero."""
     tiles = lhs.reshape(tile_expert.shape[0], block_m, lhs.shape[1])
     out = jnp.einsum("tmn,tkn->tmk" if transpose_rhs else "tmk,tkn->tmn",
                      tiles, rhs[tile_expert],
                      preferred_element_type=jnp.float32)
     out = jnp.where(_tiles_live(tile_expert, n_live)[:, None, None],
                     out, 0.0)
-    return out.reshape(lhs.shape[0], -1).astype(lhs.dtype)
+    return out.reshape(lhs.shape[0], -1)
 
 
 def _tgmm_ref(lhs, dout, tile_expert, n_live, experts, block_m):
@@ -348,26 +466,53 @@ def _tgmm_ref(lhs, dout, tile_expert, n_live, experts, block_m):
     per_tile = jnp.where(live[:, None, None], per_tile, 0.0)
     onehot = (tile_expert[:, None] == jnp.arange(experts)[None]) & \
         live[:, None]
-    return jnp.einsum("te,tkn->ekn", onehot.astype(jnp.float32),
-                      per_tile).astype(lhs.dtype)
+    return jnp.einsum("te,tkn->ekn", onehot.astype(jnp.float32), per_tile)
 
 
 # ---------------------------------------------------------------------------
-# the differentiable product
+# the differentiable products
 # ---------------------------------------------------------------------------
 
 def _product(lhs, rhs, tile_expert, n_live, block_m, block_n,
-             transpose_rhs, interpret):
+             transpose_rhs, interpret, act=False, add=None, hidden=()):
+    """``lhs``: a tuple, the left operand or (``act``) what its
+    activation is formed from; ``add [M, N]``: added to the product;
+    ``hidden``: the product is the cotangent of their activation, the
+    results theirs."""
+    if interpret is not None:
+        return _gmm_pallas(lhs, rhs, tile_expert, n_live, block_m, block_n,
+                           transpose_rhs, interpret, act, add, hidden)
+    dtype = lhs[0].dtype
+    out = _gmm_ref(_lhs_of(lhs, act), rhs, tile_expert, n_live, block_m,
+                   transpose_rhs)
+    if add is not None:
+        out = out + add.astype(jnp.float32)
+    if hidden:
+        return tuple(d.astype(dtype) for d in _act_bwd(hidden, out))
+    return out.astype(dtype)
+
+
+def _d_rhs(lhs, dout, rhs, tile_expert, n_live, block_m, block_n, interpret,
+           act=False):
+    """``d rhs[e] = lhs[rows of e]^T @ dout[rows of e]``, in ``rhs``'s
+    dtype; ``act`` as :func:`_product`'s."""
+    experts = rhs.shape[0]
     if interpret is None:
-        return _gmm_ref(lhs, rhs, tile_expert, n_live, block_m,
-                        transpose_rhs)
-    return _gmm_pallas(lhs, rhs, tile_expert, n_live, block_m, block_n,
-                       transpose_rhs, interpret)
+        return _tgmm_ref(_lhs_of(lhs, act), dout, tile_expert, n_live,
+                         experts, block_m).astype(rhs.dtype)
+    d_rhs = _tgmm_pallas(lhs, dout, tile_expert, n_live, experts, block_m,
+                         1024, block_n, interpret, act)
+    # an expert with no rows was never visited: its block is whatever
+    # the buffer held
+    seen = jnp.any(
+        (tile_expert[:, None] == jnp.arange(experts)[None])
+        & _tiles_live(tile_expert, n_live)[:, None], axis=0)
+    return jnp.where(seen[:, None, None], d_rhs, 0).astype(rhs.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _gmm(lhs, rhs, tile_expert, n_live, block_m, block_n, interpret):
-    return _product(lhs, rhs, tile_expert, n_live, block_m, block_n,
+    return _product((lhs,), rhs, tile_expert, n_live, block_m, block_n,
                     False, interpret)
 
 
@@ -378,24 +523,20 @@ def _gmm_fwd(lhs, rhs, tile_expert, n_live, block_m, block_n, interpret):
 
 def _gmm_bwd(block_m, block_n, interpret, res, g):
     lhs, rhs, tile_expert, n_live = res
-    experts = rhs.shape[0]
-    d_lhs = _product(g, rhs, tile_expert, n_live, block_m, block_n, True,
-                     interpret)
-    if interpret is None:
-        d_rhs = _tgmm_ref(lhs, g, tile_expert, n_live, experts, block_m)
-    else:
-        d_rhs = _tgmm_pallas(lhs, g, tile_expert, n_live, experts, block_m,
-                             1024, block_n, interpret)
-        # an expert with no rows was never visited: its block is
-        # whatever the buffer held
-        seen = jnp.any(
-            (tile_expert[:, None] == jnp.arange(experts)[None])
-            & _tiles_live(tile_expert, n_live)[:, None], axis=0)
-        d_rhs = jnp.where(seen[:, None, None], d_rhs, 0)
-    return d_lhs, d_rhs.astype(rhs.dtype), None, None
+    plan = (tile_expert, n_live, block_m, block_n)
+    d_lhs = _product((g,), rhs, *plan, True, interpret)
+    return d_lhs, _d_rhs((lhs,), g, rhs, *plan, interpret), None, None
 
 
 _gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def _kernels(interpret: Optional[bool]) -> Optional[bool]:
+    """``interpret`` as given; left open, the kernels on a TPU and plain
+    ``jnp`` elsewhere."""
+    if interpret is None and jax.default_backend() == "tpu":
+        return False
+    return interpret
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, plan: RowPlan, *,
@@ -406,7 +547,53 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, plan: RowPlan, *,
     accumulation, output in ``lhs``'s dtype.  Rows of dead tiles are not
     written."""
     block_m = lhs.shape[0] // plan.tile_expert.shape[0]
-    if interpret is None and jax.default_backend() == "tpu":
-        interpret = False
     return _gmm(lhs, rhs, plan.tile_expert, plan.n_live, block_m, block_n,
-                interpret)
+                _kernels(interpret))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _experts(rows, weights, tile_expert, n_live, block_m, block_n,
+             interpret):
+    return _experts_fwd(rows, weights, tile_expert, n_live, block_m,
+                        block_n, interpret)[0]
+
+
+def _experts_fwd(rows, weights, tile_expert, n_live, block_m, block_n,
+                 interpret):
+    plan = (tile_expert, n_live, block_m, block_n)
+    hidden = tuple(_product((rows,), w, *plan, False, interpret)
+                   for w in weights[:-1])
+    out = _product(hidden, weights[-1], *plan, False, interpret, act=True)
+    return out, (rows, hidden, weights, tile_expert, n_live)
+
+
+def _experts_bwd(block_m, block_n, interpret, res, g):
+    rows, hidden, weights, tile_expert, n_live = res
+    plan = (tile_expert, n_live, block_m, block_n)
+    d_down = _d_rhs(hidden, g, weights[-1], *plan, interpret, act=True)
+    d_hidden = _product((g,), weights[-1], *plan, True, interpret,
+                        hidden=hidden)
+    d_rows, d_weights = None, []
+    for d, w in zip(d_hidden, weights[:-1]):
+        d_weights.append(_d_rhs((rows,), d, w, *plan, interpret))
+        d_rows = _product((d,), w, *plan, True, interpret, add=d_rows)
+    return d_rows, (*d_weights, d_down), None, None
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
+
+
+def expert_products(rows: jax.Array, weights, plan: RowPlan, *,
+                    block_n: int = 512,
+                    interpret: Optional[bool] = None) -> jax.Array:
+    """Every live row through its expert: ``rows [M, D]`` laid out by
+    ``plan``; ``weights`` the experts' matrices, ``(gate, up, down)``
+    (``down(silu(gate r) * up r)``) or ``(up, down)`` (``down(relu(up
+    r)^2)``), ``[E, D, F]`` and ``down [E, F, D]`` -> ``[M, D]``.  One
+    kernel call a product forward (``gate r`` and ``up r`` kept for the
+    backward in ``rows``' dtype, the activation formed on the down
+    product's tile) and two backward; rows of dead tiles are neither
+    read nor written."""
+    block_m = rows.shape[0] // plan.tile_expert.shape[0]
+    return _experts(rows, tuple(weights), plan.tile_expert, plan.n_live,
+                    block_m, block_n, _kernels(interpret))

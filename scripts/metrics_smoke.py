@@ -81,7 +81,7 @@ TRAFFIC_DEPENDENT = {
     # routed expert layers report only where a training loop asks
     # (models/afmoe.py report_router_stats; models/deepseek_v3.py's and
     # models/nemotron_h.py's under their own `model` tags, the same
-    # three series).  What a step was COMPILED as is in spans, not
+    # four series).  What a step was COMPILED as is in spans, not
     # series, so nothing of it is listed here: `model:moe.plan`,
     # `model:mla.plan`, `model:hybrid.plan`, `ops:ssd.plan` (the chunked
     # scan: heads, groups, state, chunk, heads_a_step, carry) and
@@ -91,6 +91,7 @@ TRAFFIC_DEPENDENT = {
     # pairs_visible: docs/observability.md)
     "ray_tpu_moe_expert_load",
     "ray_tpu_moe_landed_share",
+    "ray_tpu_moe_live_share",
     "ray_tpu_moe_load_imbalance",
     "ray_tpu_serve_gang_bringup_seconds",
     "ray_tpu_serve_gang_shards",

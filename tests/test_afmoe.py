@@ -5,6 +5,7 @@ adding up to the uncut layer, nothing dropped at any imbalance, the
 grouped products through the Pallas interpreter, what the router tells
 its operator, and what the ``moe.plan`` span says was compiled."""
 
+import functools
 import os
 import sys
 
@@ -224,13 +225,21 @@ def test_the_shares_add_up_to_the_uncut_layer():
     assert float(jnp.abs(parts[0] - uncut).max()) > 1e-2
 
 
-@pytest.mark.parametrize("held", [(0, 8), (0, 2), (6, 2)])
-def test_no_row_is_dropped_when_every_token_picks_the_same_experts(held):
+@pytest.mark.parametrize("held,picks,kernels", [
+    ((0, 8), (0, 1), None), ((0, 2), (0, 1), None), ((6, 2), (0, 1), None),
+    # a replayed routing may name ONE expert twice: every pair of every
+    # token on expert 0, the gathers' loop at its longest, and through
+    # the kernels (the interpreter's dead rows are NaN)
+    ((0, 1), (0, 0), True), ((0, 2), (0, 0), True), ((0, 2), (0, 1), True),
+])
+def test_no_row_is_dropped_when_every_token_picks_the_same_experts(
+        held, picks, kernels, monkeypatch):
     """Every token alike, so all 48 pick the same 2 of 8 experts: the
     worst imbalance there is.  The layer's result is the reference's for
     every token, whether the picked experts are held here (all 96 pairs
-    then land on 2 experts, four times what an even router lands) or
-    not: the buffers are the worst case's."""
+    then land on 2 experts, or on one, four and eight times what an even
+    router lands) or not: the buffers are the worst case's."""
+    monkeypatch.setattr(gm, "_kernels", lambda interpret: kernels)
     cfg = afmoe.AFMoEConfig.tiny(dtype=jnp.float32, experts_held=held)
     row = jax.random.normal(jax.random.PRNGKey(9), (cfg.embed_dim,))
     full = _all_pick(_layer_params(cfg, jax.random.PRNGKey(8)), row, (0, 1))
@@ -238,18 +247,26 @@ def test_no_row_is_dropped_when_every_token_picks_the_same_experts(held):
     flat = h.reshape(-1, cfg.embed_dim)
     picked, _ = ref.route(flat, full["router"], cfg.top_k, cfg.route_scale)
     assert (jnp.sort(picked, -1) == jnp.array([0, 1])).all()
-    got = _layer(cfg, h, _share(full, *held)).reshape(flat.shape)
+    chosen = None
+    if picks != (0, 1):
+        chosen = picked = jnp.broadcast_to(jnp.array(picks), picked.shape)
+    got = afmoe.RoutedExperts(cfg).apply(
+        {"params": _share(full, *held)}, h, chosen).reshape(flat.shape)
     with jax.default_matmul_precision("highest"):
         want, _ = ref._routed(flat, _share(full, *held),
                               dict(_arch(cfg), first_held=held[0]),
-                              None, 16)
+                              chosen, 16)
     here = sum(int(held[0] <= int(e) < sum(held)) for e in picked[0])
     plan = gm.plan_rows(picked, held[0], held[1],
                         block_m=afmoe.BLOCK_ROWS)
     assert bool(plan.fits)   # sized for the worst case: always
     assert int(plan.pair_valid.sum()) == 48 * here == int(plan.sizes.sum())
     assert int(plan.row_valid.sum()) == 48 * here
-    assert here == (0 if held == (6, 2) else 2)
+    assert here == {(6, 2): 0, (0, 1): 2 if picks == (0, 0) else 1}.get(
+        held, 2)
+    # the rows fill their tiles: 6 tiles of 8 a choice, of 12 + held
+    assert int(plan.n_live[0]) == 6 * here
+    assert plan.tile_expert.shape[0] == 12 + held[1]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
 
@@ -286,6 +303,108 @@ def test_a_plan_past_its_buffers_says_so_and_dropping_it_is_wrong():
     np.testing.assert_allclose(np.asarray(exact).reshape(want.shape),
                                np.asarray(want), rtol=1e-4, atol=1e-5)
     assert float(jnp.abs(dropped - want).max()) > 1e-3
+
+
+def _plain_layer(cfg, p, h, chosen=None):
+    """The routed layer unfused, in plain ``jnp`` under plain autodiff:
+    every held expert applied to every token, under the routing's mask."""
+    flat = h.reshape(-1, h.shape[-1])
+    idx, w, _ = afmoe.route(cfg, flat, p["router"], chosen)
+    ids = cfg.experts_held[0] + jnp.arange(cfg.experts_held[1])
+    w_held = jnp.einsum("tk,tke->te", w, (
+        idx[:, :, None] == ids[None, None]).astype(jnp.float32))
+    up = jnp.einsum("td,edf->etf", flat, p["experts_up"])
+    if "experts_gate" in p:
+        mid = jax.nn.silu(jnp.einsum("td,edf->etf", flat,
+                                     p["experts_gate"])) * up
+    else:
+        mid = jnp.square(jax.nn.relu(up))
+    out = jnp.einsum("etf,efd->etd", mid, p["experts_down"])
+    return jnp.einsum("te,etd->td", w_held, out).reshape(h.shape)
+
+
+class _Relu2(afmoe.AFMoEConfig):
+    expert_form = "relu2"
+
+
+def _nan_rows(monkeypatch, block_n):
+    """The kernels through the interpreter, whose unwritten results and
+    whose blocks past an array's edge are NaN, tiles of ``block_n``; and
+    the gathers' buffer NaN before the gather writes as far as it
+    reaches: every dead row of every buffer is poison."""
+    seen = []
+
+    def empty(shape, dtype):
+        seen.append(shape)
+        return jnp.full(shape, jnp.nan, dtype)
+
+    monkeypatch.setattr(jax.lax, "empty", empty)
+    monkeypatch.setattr(gm, "expert_products", functools.partial(
+        gm.expert_products, block_n=block_n, interpret=True))
+    return seen
+
+
+# 464 = 29 x 16: under tiles of 128 (and of 256, d rhs's) the last tile
+# is masked, as the published 1856 under 384 and 640
+@pytest.mark.parametrize("form", ["gated", "relu2"])
+@pytest.mark.parametrize("width,block_n", [(32, 512), (464, 128)])
+def test_the_fused_layer_is_the_unfused_one_with_its_dead_rows_poisoned(
+        form, width, block_n, monkeypatch):
+    """Output and every gradient (``h``, router, gate, up, down) of the
+    layer as it runs (the activation on the down product's tile, its
+    derivative at the end of ``d lhs``, ``d rows`` summed in the second
+    kernel, the gathers no further than they must reach) against plain
+    ``jnp``, with
+    every dead row NaN; expert 3 (held, the second of four) gets no
+    row."""
+    kinds = {"gated": afmoe.AFMoEConfig, "relu2": _Relu2}
+    cfg = kinds[form].tiny(dtype=jnp.float32, experts_held=(2, 4),
+                           expert_dim=width)
+    p = _share(_layer_params(cfg, jax.random.PRNGKey(5)), 2, 4)
+    if form == "relu2":
+        del p["experts_gate"]
+    h = jax.random.normal(jax.random.PRNGKey(7), (1, 40, cfg.embed_dim))
+    chosen = afmoe.route(cfg, h[0], p["router"])[0]
+    chosen = jnp.where(chosen == 3, 7, chosen)     # nobody picks expert 3
+    cot = jax.random.normal(jax.random.PRNGKey(11), h.shape)
+
+    def loss(layer):
+        return lambda h, p: (layer(h, p) * cot).sum()
+
+    want, want_grads = jax.value_and_grad(loss(
+        lambda h, p: _plain_layer(cfg, p, h, chosen)), argnums=(0, 1))(h, p)
+    buffers = _nan_rows(monkeypatch, block_n)
+    got, grads = jax.value_and_grad(loss(
+        lambda h, p: afmoe.RoutedExperts(cfg).apply({"params": p}, h,
+                                                    chosen)),
+        argnums=(0, 1))(h, p)
+    # dispatch's rows and combine's d rows: both through the one
+    # gather, which is traced for each of its three shorter reaches
+    plan = gm.plan_rows(chosen, 2, 4, block_m=afmoe.BLOCK_ROWS)
+    assert buffers == [(plan.row_valid.shape[0], cfg.embed_dim)] * 6
+    assert 0 < int(plan.n_live[0]) < plan.tile_expert.shape[0] - 2
+    assert int(plan.sizes[1]) == 0 and int(plan.sizes.min(initial=99,
+                                           where=plan.sizes > 0)) > 0
+    rows = gm.dispatch(h[0], plan)
+    # 14 tiles of 8 rows: an eighth is 2 tiles, a quarter 4, a half 7
+    reach = next(r for r in (16, 32, 56, 112)
+                 if r >= int(plan.n_live[0]) * afmoe.BLOCK_ROWS)
+    assert reach == 56 and plan.tile_expert.shape[0] == 14
+    assert np.isnan(np.asarray(rows)[reach:]).all()
+    assert np.isfinite(np.asarray(rows)[:reach]).all()
+    up = np.asarray(gm.grouped_matmul(rows, p["experts_up"], plan,
+                                      interpret=True))
+    live = int(plan.n_live[0]) * afmoe.BLOCK_ROWS
+    assert np.isnan(up[live:]).all() and np.isfinite(up[:live]).all()
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    flat, want_flat = (jax.tree.leaves_with_path(g) for g in
+                       (grads, want_grads))
+    assert len(flat) == (6 if form == "gated" else 5) - 1
+    for (path, g), (_, w) in zip(flat, want_flat):
+        assert np.isfinite(np.asarray(g)).all(), path
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
+                                   atol=2e-5, err_msg=str(path))
+    assert float(jnp.abs(grads[1]["experts_down"][1]).max()) == 0.0
 
 
 def _by_loop(lhs, rhs, groups, block_m):
@@ -388,12 +507,20 @@ def test_router_stats_against_counts_made_by_hand():
             sum(load) / picked.size)
         assert float(stats["imbalance"][layer]) == pytest.approx(
             max(load) / (sum(load) / 4))
+        # a call sees one sequence of 64: its groups padded to tiles of
+        # 8, in buffers of 64 x 2 / 8 tiles and one more an expert
+        live = sum(-(-int((picked[s * 64:(s + 1) * 64] == e).sum()) // 8)
+                   for s in range(2) for e in range(2, 6))
+        assert int(stats["live_tiles"][layer]) == live
+        assert int(stats["buffer_tiles"][layer]) == 2 * (16 + 4)
     flat = afmoe.report_router_stats(stats)
-    assert set(flat) == {"moe/h0/landed_share", "moe/h0/imbalance",
-                         "moe/h1/landed_share", "moe/h1/imbalance"}
-    text = telemetry.metrics_text() if hasattr(telemetry, "metrics_text") \
-        else ""
-    assert not text or "ray_tpu_moe_landed_share" in text
+    assert set(flat) == {f"moe/h{i}/{k}" for i in range(2) for k in (
+        "landed_share", "imbalance", "live_share")}
+    assert flat["moe/h1/live_share"] == pytest.approx(live / 40)
+    gauge = telemetry._gauge("ray_tpu_moe_live_share", "")
+    assert gauge.tag_keys == ("model", "layer")
+    assert gauge._values[telemetry._moekey("afmoe", 1)] \
+        == pytest.approx(live / 40)
 
 
 def test_moe_plan_span_says_what_was_compiled():
@@ -407,7 +534,11 @@ def test_moe_plan_span_says_what_was_compiled():
     assert rows[0]["args"] == {
         "experts": 8, "held_first": 2, "held": 4, "top_k": 2,
         "row_bound": 64 * 2,   # every pair of a sequence
-        "block_rows": 8, "window": 24,
+        "block_rows": 8,
+        # what of that buffer a layer-call still passes over whole, and
+        # how the gathers keep to the live rows
+        "buffer_passes": 0, "row_gather": "reach",
+        "gather_reaches": "1/8,1/4,1/2,1/1", "window": 24,
         "heads": 4, "kv_heads": 2, "layers": "s,s,f"}
 
 

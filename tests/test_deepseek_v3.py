@@ -240,8 +240,8 @@ def test_router_stats_against_counts_made_by_hand():
         assert float(stats["imbalance"][layer]) == pytest.approx(
             max(load) / (sum(load) / 4))
     flat = ds.report_router_stats(stats)
-    assert set(flat) == {"moe/h0/landed_share", "moe/h0/imbalance",
-                         "moe/h1/landed_share", "moe/h1/imbalance"}
+    assert set(flat) == {f"moe/h{i}/{k}" for i in range(2) for k in (
+        "landed_share", "imbalance", "live_share")}
     # the gauges carry THIS model's name, Trinity's stay its own
     assert ds.report_router_stats.keywords == {"model_name": "deepseek_v3"}
     assert ("deepseek_v3", 0, None) in telemetry._moe_keys
@@ -262,7 +262,8 @@ def test_the_plan_spans_say_what_was_compiled():
         "seq": 64, "family": "head_major", "score": "concat"}
     assert rows["moe.plan"]["args"] == {
         "experts": 8, "held_first": 2, "held": 4, "top_k": 2,
-        "row_bound": 64 * 2, "block_rows": 8}
+        "row_bound": 64 * 2, "block_rows": 8, "buffer_passes": 0,
+        "row_gather": "reach", "gather_reaches": "1/8,1/4,1/2,1/1"}
 
 
 def test_the_scopes_name_the_kernel_call_and_the_up_projection():
