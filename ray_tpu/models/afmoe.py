@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.core import telemetry
+from ray_tpu.models import step
 from ray_tpu.models.llama import RMSNorm
 from ray_tpu.ops import grouped_matmul as gm
 from ray_tpu.ops.flash_attention import flash_attention
@@ -248,17 +249,21 @@ class RoutedExperts(nn.Module):
                                            axes), shape,
                 cfg.param_dtype).astype(cfg.dtype)
 
-        # an expert's matrices in the order of its products
-        into = [experts(name, (held, embed, cfg.expert_dim),
-                        ("expert", "embed", "mlp"))
-                for name in (("experts_gate", "experts_up")
-                             if getattr(cfg, "expert_form", "gated")
-                             == "gated" else ("experts_up",))]
-        w_down = experts("experts_down", (held, cfg.expert_dim, embed),
-                         ("expert", "mlp", "embed"))
+        # every op of the layer is in one of its five parts (``step.
+        # PARTS``); the rounding of an expert's matrices is the products'
+        with step.scope("moe.experts"):
+            # an expert's matrices in the order of its products
+            into = [experts(name, (held, embed, cfg.expert_dim),
+                            ("expert", "embed", "mlp"))
+                    for name in (("experts_gate", "experts_up")
+                                 if getattr(cfg, "expert_form", "gated")
+                                 == "gated" else ("experts_up",))]
+            w_down = experts("experts_down", (held, cfg.expert_dim, embed),
+                             ("expert", "mlp", "embed"))
 
-        with jax.named_scope("moe.route"):
+        with step.scope("moe.route"):
             idx, weights, own = route(cfg, flat, w_router, chosen)
+        with step.scope("moe.plan"):
             # buffers for the worst case: every pair may land here
             plan = gm.plan_rows(idx, first, held, block_m=BLOCK_ROWS)
         self.sow("intermediates", "expert_load", plan.sizes)
@@ -266,17 +271,20 @@ class RoutedExperts(nn.Module):
         # of that buffer's tiles the live ones alone are worked on
         self.sow("intermediates", "live_tiles", plan.n_live[0])
         self.sow("intermediates", "buffer_tiles", plan.tile_expert.shape[0])
-        with jax.named_scope("moe.dispatch"):
+        with step.scope("moe.dispatch"):
             rows = gm.dispatch(flat, plan)
-        with jax.named_scope("moe.experts"):
+        with step.scope("moe.experts"):
             out = gm.expert_products(rows, (*into, w_down), plan)
-        with jax.named_scope("moe.combine"):
+        with step.scope("moe.combine"):
             routed = gm.combine(out, weights, plan)
-        return routed.astype(cfg.dtype).reshape(batch, seq, embed)
+            return routed.astype(cfg.dtype).reshape(batch, seq, embed)
 
 
 class AttentionPart(nn.Module):
-    """``x + post_norm(attention(pre_norm(x)))``."""
+    """``x + post_norm(attention(pre_norm(x)))``.  A block names it
+    ``attn``, and flax puts a module's name around its ops: that IS the
+    step's part ``attn`` (``models/step.py``), norms, projections,
+    rotation, gate and residual add included."""
     config: AFMoEConfig
     kind: str      # "sliding" | "full"
 
@@ -302,7 +310,7 @@ class AttentionPart(nn.Module):
         sliding = self.kind == "sliding"
         if sliding:
             q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
-        with jax.named_scope("attn." + self.kind):
+        with step.scope("attn." + self.kind):
             attn = flash_attention(
                 q, k, v, causal=True, mesh=get_global_mesh(),
                 window=cfg.window if sliding else None)
@@ -313,38 +321,54 @@ class AttentionPart(nn.Module):
 
 class MLPPart(nn.Module):
     """``x + post_norm(mlp(pre_norm(x)))``: the dense SwiGLU of a leading
-    layer, or the shared expert plus the routed experts held here."""
+    layer, or the shared expert plus the routed experts held here.  The
+    norms, the dense or shared SwiGLU and the residual add are the step's
+    part ``mlp``; the routed experts are their own five parts BESIDE it,
+    so the module names its parts itself (``step.names_its_parts``)."""
     config: AFMoEConfig
     routed: bool
+    names_its_parts = True
 
     @nn.compact
     def __call__(self, x: jax.Array,
                  chosen: Optional[jax.Array] = None) -> jax.Array:
         cfg = self.config
-        h = RMSNorm(cfg.rms_eps, name="mlp_norm")(x)
-        if self.routed:
-            out = _swiglu(cfg, h, cfg.expert_dim, "shared_") + \
-                RoutedExperts(cfg, name="moe")(h, chosen)
-        else:
-            out = _swiglu(cfg, h, cfg.dense_dim, "w_")
-        return x + RMSNorm(cfg.rms_eps, name="mlp_post_norm")(out)
+        with step.named_children():
+            with step.scope("mlp"):
+                h = RMSNorm(cfg.rms_eps, name="mlp_norm")(x)
+                out = _swiglu(cfg, h, cfg.expert_dim, "shared_") \
+                    if self.routed else _swiglu(cfg, h, cfg.dense_dim, "w_")
+            if self.routed:
+                routed = RoutedExperts(cfg, name="moe")(h, chosen)
+            with step.scope("mlp"):
+                if self.routed:
+                    out = out + routed
+                return x + RMSNorm(cfg.rms_eps, name="mlp_post_norm")(out)
 
 
 def each_sequence(parts, x: jax.Array,
-                  chosen: Optional[jax.Array] = None) -> jax.Array:
+                  chosen: Optional[jax.Array] = None,
+                  around: Tuple[str, str] = ("attn", "mlp")) -> jax.Array:
     """A layer's ``parts``, one after the other, over ONE sequence of the
     batch at a time (a kernel call a sequence, the activation memory of
     one sequence); ``chosen [B*T, k]``: the routing each sequence's LAST
-    part, the routed one, replays."""
+    part, the routed one, replays.  ``around``: the step's parts
+    (``models/step.py``) that taking a sequence out of the batch and
+    joining the sequences again are put down to: the layer's first and
+    its last."""
     seq = x.shape[1]
     out = []
     for i in range(x.shape[0]):
-        h = x[i:i + 1]
+        with step.scope(around[0]):
+            h = x[i:i + 1]
         for part in parts[:-1]:
-            h = part(h)
-        out.append(parts[-1](h) if chosen is None else
-                   parts[-1](h, chosen[i * seq:(i + 1) * seq]))
-    return jnp.concatenate(out)
+            with step.names_its_parts(part):
+                h = part(h)
+        with step.names_its_parts(parts[-1]):
+            out.append(parts[-1](h) if chosen is None else
+                       parts[-1](h, chosen[i * seq:(i + 1) * seq]))
+    with step.scope(around[1]):
+        return jnp.concatenate(out)
 
 
 class AFMoEBlock(nn.Module):
@@ -388,9 +412,10 @@ class AFMoE(nn.Module):
             "head", nn.with_partitioning(nn.initializers.normal(0.02),
                                          ("vocab", "embed")),
             (cfg.vocab_size, cfg.embed_dim), cfg.param_dtype)
-        x = embed.astype(cfg.dtype)[tokens]
-        if cfg.mup:
-            x = x * jnp.asarray(math.sqrt(cfg.embed_dim), cfg.dtype)
+        with step.scope("embed"):
+            x = embed.astype(cfg.dtype)[tokens]
+            if cfg.mup:
+                x = x * jnp.asarray(math.sqrt(cfg.embed_dim), cfg.dtype)
         # the timeline says what was compiled: one span around the trace
         # of the layers (a call of a layer sees one sequence)
         with telemetry.span("model", "moe.plan",
@@ -401,8 +426,10 @@ class AFMoE(nn.Module):
                                    name=f"h{i}" if i >= 0 else f"dense{n}")
                 x = block(x) if choices is None or i < 0 \
                     else block(x, choices[i])
-        x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
-        return x.astype(jnp.float32), head
+        # the final norm is the head's: ``loss_fn`` opens the part again
+        with step.scope("head"):
+            x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
+            return x.astype(jnp.float32), head
 
     def __call__(self, tokens: jax.Array) -> jax.Array:
         x, head = self.hidden(tokens)
@@ -436,9 +463,10 @@ def loss_fn(model: nn.Module, params, tokens: jax.Array,
                       mutable=["intermediates"] if with_choices else False)
     (x, head), state = out if with_choices else (out, None)
     compute = jnp.bfloat16 if model.config.dtype == jnp.bfloat16 else None
-    loss = chunked_lm_loss(x[:, :-1], head, tokens[:, 1:],
-                           chunk=head_chunk, compute_dtype=compute,
-                           logits_dtype=head_logits_dtype)
+    with step.scope("head"):  # the scan's body inherits it
+        loss = chunked_lm_loss(x[:, :-1], head, tokens[:, 1:],
+                               chunk=head_chunk, compute_dtype=compute,
+                               logits_dtype=head_logits_dtype)
     return (loss, _own_choices(model, state)) if with_choices else loss
 
 
@@ -451,17 +479,9 @@ def _own_choices(model: nn.Module, state) -> List[jax.Array]:
 
 def make_train_step(model: nn.Module, tx):
     """The donated ``(params, opt_state, tokens) -> (params, opt_state,
-    loss)`` step, as GPT-2's."""
-    import optax
-
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def train_step(params, opt_state, tokens):
-        loss, grads = jax.value_and_grad(
-            lambda p: loss_fn(model, p, tokens))(params)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss
-
-    return train_step
+    loss)`` step, GPT-2's (``models/step.py``)."""
+    return step.make_train_step(functools.partial(loss_fn, model), tx,
+                                remat=model.config.remat)
 
 
 @functools.partial(jax.jit, static_argnums=0)

@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.core import telemetry
-from ray_tpu.models import afmoe
+from ray_tpu.models import afmoe, step
 from ray_tpu.models.afmoe import (  # noqa: F401 — this model's step too
     RoutedExperts,
     _dense,
@@ -152,7 +152,10 @@ def rope_interleaved(x: jax.Array, theta: float) -> jax.Array:
 
 
 class AttentionPart(nn.Module):
-    """``x + attention(norm(x))``, latent attention."""
+    """``x + attention(norm(x))``, latent attention.  A block names it
+    ``attn``, and flax puts a module's name around its ops: that IS the
+    step's part ``attn`` (``models/step.py``); ``mla.kv_up`` and
+    ``attn.mla`` are pieces of it."""
     config: DeepseekV3Config
 
     @nn.compact
@@ -176,7 +179,7 @@ class AttentionPart(nn.Module):
             [q[..., :nope], rope_interleaved(q[..., nope:], cfg.rope_theta)],
             axis=-1)
         k_rope = rope_interleaved(k_rope, cfg.rope_theta)
-        with jax.named_scope("attn.mla"):
+        with step.scope("attn.mla"):
             attn = flash_attention(q, kv[..., :nope], kv[..., nope:],
                                    k_rope=k_rope, causal=True)
         attn = attn.reshape(batch, seq, heads * dim_v)
@@ -186,20 +189,27 @@ class AttentionPart(nn.Module):
 class MLPPart(nn.Module):
     """``x + mlp(norm(x))``: the dense SwiGLU of a leading layer, or the
     shared experts (one SwiGLU of their joint width) plus the routed
-    experts held here."""
+    experts held here.  Norm, SwiGLU and residual adds are the step's
+    part ``mlp``, the routed experts their own five BESIDE it, as
+    ``afmoe.MLPPart``."""
     config: DeepseekV3Config
     routed: bool
+    names_its_parts = True
 
     @nn.compact
     def __call__(self, x: jax.Array,
                  chosen: Optional[jax.Array] = None) -> jax.Array:
         cfg = self.config
-        h = RMSNorm(cfg.rms_eps, name="mlp_norm")(x)
-        if self.routed:
-            shared = cfg.expert_dim * cfg.num_shared_experts
-            return x + _swiglu(cfg, h, shared, "shared_") + \
-                RoutedExperts(cfg, name="moe")(h, chosen)
-        return x + _swiglu(cfg, h, cfg.dense_dim, "w_")
+        with step.named_children():
+            with step.scope("mlp"):
+                h = RMSNorm(cfg.rms_eps, name="mlp_norm")(x)
+                if not self.routed:
+                    return x + _swiglu(cfg, h, cfg.dense_dim, "w_")
+                shared = cfg.expert_dim * cfg.num_shared_experts
+                x = x + _swiglu(cfg, h, shared, "shared_")
+            routed = RoutedExperts(cfg, name="moe")(h, chosen)
+            with step.scope("mlp"):
+                return x + routed
 
 
 class DeepseekV3Block(nn.Module):
@@ -238,7 +248,8 @@ class DeepseekV3(nn.Module):
                 (cfg.vocab_size, cfg.embed_dim), cfg.param_dtype)
 
         embed, head = table("embed"), table("head")
-        x = embed.astype(cfg.dtype)[tokens]
+        with step.scope("embed"):
+            x = embed.astype(cfg.dtype)[tokens]
         # the timeline says what was compiled: spans around the trace of
         # the layers (a call of a layer sees one sequence)
         seq = tokens.shape[1]
@@ -251,8 +262,10 @@ class DeepseekV3(nn.Module):
                     cfg, i >= 0, name=f"h{i}" if i >= 0 else f"dense{n}")
                 x = block(x) if choices is None or i < 0 \
                     else block(x, choices[i])
-        x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
-        return x.astype(jnp.float32), head
+        # the final norm is the head's: ``loss_fn`` opens the part again
+        with step.scope("head"):
+            x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
+            return x.astype(jnp.float32), head
 
     def __call__(self, tokens: jax.Array) -> jax.Array:
         x, head = self.hidden(tokens)

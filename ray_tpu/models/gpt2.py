@@ -21,6 +21,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import step
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.parallel.mesh import get_global_mesh
 from ray_tpu.parallel.sharding import constrain_activation, fsdp_plan
@@ -107,69 +108,75 @@ class Block(nn.Module):
     def __call__(self, x: jax.Array, deterministic: bool = True) -> jax.Array:
         cfg = self.config
         head_dim = cfg.embed_dim // cfg.num_heads
-        # under a mesh an activation lies on its batch shard, whole along
-        # embed: a weight sharded there is gathered for its use and its
-        # gradient scattered back.  Said inside the block, so that the
-        # recomputed forward of a remat is laid out the same
-        x = constrain_activation(x, "batch", "seq", "embed")
+        # the block's two halves are the parts ``attn`` and ``mlp``, each
+        # with its norm, its projections and its residual add
+        with step.scope("attn"):
+            # under a mesh an activation lies on its batch shard, whole
+            # along embed: a weight sharded there is gathered for its use
+            # and its gradient scattered back.  Said inside the block, so
+            # that the recomputed forward of a remat is laid out the same
+            x = constrain_activation(x, "batch", "seq", "embed")
 
-        # block LNs emit cfg.dtype (statistics still accumulate f32
-        # inside flax): an f32 round-trip costs 3x the HBM traffic
-        h = nn.LayerNorm(dtype=cfg.dtype, name="ln_1",
-                         scale_init=nn.with_partitioning(
-                             nn.initializers.ones, ("embed",)),
-                         bias_init=nn.with_partitioning(
-                             nn.initializers.zeros, ("embed",)))(x)
-        qkv = _dense(3 * cfg.embed_dim, cfg, "attn_qkv",
-                     ("embed", "heads"))(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        batch, seq = x.shape[:2]
+            # block LNs emit cfg.dtype (statistics still accumulate f32
+            # inside flax): an f32 round-trip costs 3x the HBM traffic
+            h = nn.LayerNorm(dtype=cfg.dtype, name="ln_1",
+                             scale_init=nn.with_partitioning(
+                                 nn.initializers.ones, ("embed",)),
+                             bias_init=nn.with_partitioning(
+                                 nn.initializers.zeros, ("embed",)))(x)
+            qkv = _dense(3 * cfg.embed_dim, cfg, "attn_qkv",
+                         ("embed", "heads"))(h)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            batch, seq = x.shape[:2]
 
-        def heads(t):
-            return t.reshape(batch, seq, cfg.num_heads, head_dim)
+            def heads(t):
+                return t.reshape(batch, seq, cfg.num_heads, head_dim)
 
-        q, k, v = heads(q), heads(k), heads(v)
-        if cfg.attn_impl == "ring":
-            from ray_tpu.parallel.ring_attention import ring_attention
+            q, k, v = heads(q), heads(k), heads(v)
+            if cfg.attn_impl == "ring":
+                from ray_tpu.parallel.ring_attention import ring_attention
 
-            # under plain jit/GSPMD the sp axis is bound via the global
-            # mesh (shard_map applied inside ring_attention); inside a
-            # user shard_map the axis is already bound and mesh is None
-            attn = ring_attention(q, k, v, axis_name=cfg.sp_axis,
-                                  causal=True, mesh=get_global_mesh())
-        elif cfg.attn_impl == "ulysses":
-            from ray_tpu.parallel.ulysses import ulysses_attention
+                # under plain jit/GSPMD the sp axis is bound via the
+                # global mesh (shard_map applied inside ring_attention);
+                # inside a user shard_map the axis is already bound and
+                # mesh is None
+                attn = ring_attention(q, k, v, axis_name=cfg.sp_axis,
+                                      causal=True, mesh=get_global_mesh())
+            elif cfg.attn_impl == "ulysses":
+                from ray_tpu.parallel.ulysses import ulysses_attention
 
-            # same binding rules as "ring": mesh when under plain
-            # jit/GSPMD, already-bound axis inside a user shard_map
-            attn = ulysses_attention(q, k, v, axis_name=cfg.sp_axis,
-                                     causal=True, mesh=get_global_mesh())
-        elif cfg.attn_impl == "reference":
-            from ray_tpu.ops.flash_attention import _attention_reference
+                # same binding rules as "ring": mesh when under plain
+                # jit/GSPMD, already-bound axis inside a user shard_map
+                attn = ulysses_attention(
+                    q, k, v, axis_name=cfg.sp_axis, causal=True,
+                    mesh=get_global_mesh())
+            elif cfg.attn_impl == "reference":
+                from ray_tpu.ops.flash_attention import _attention_reference
 
-            attn = _attention_reference(q, k, v, True, head_dim ** -0.5)
-        else:
-            # same binding rule as "ring": under a multi-device mesh the
-            # kernel runs per (batch, head) shard
-            attn = flash_attention(q, k, v, causal=True,
-                                   mesh=get_global_mesh())
-        attn = attn.reshape(batch, seq, cfg.embed_dim)
-        attn = _dense(cfg.embed_dim, cfg, "attn_proj",
-                      ("heads", "embed"))(attn)
-        x = x + attn
+                attn = _attention_reference(q, k, v, True, head_dim ** -0.5)
+            else:
+                # same binding rule as "ring": under a multi-device mesh
+                # the kernel runs per (batch, head) shard
+                attn = flash_attention(q, k, v, causal=True,
+                                       mesh=get_global_mesh())
+            attn = attn.reshape(batch, seq, cfg.embed_dim)
+            attn = _dense(cfg.embed_dim, cfg, "attn_proj",
+                          ("heads", "embed"))(attn)
+            x = x + attn
 
-        h = nn.LayerNorm(dtype=cfg.dtype, name="ln_2",
-                         scale_init=nn.with_partitioning(
-                             nn.initializers.ones, ("embed",)),
-                         bias_init=nn.with_partitioning(
-                             nn.initializers.zeros, ("embed",)))(x)
-        h = _dense(cfg.mlp_ratio * cfg.embed_dim, cfg, "mlp_up",
-                   ("embed", "mlp"))(h)
-        h = nn.gelu(h)
-        h = _dense(cfg.embed_dim, cfg, "mlp_down", ("mlp", "embed"))(h)
-        if cfg.dropout > 0:
-            h = nn.Dropout(cfg.dropout)(h, deterministic=deterministic)
-        return constrain_activation(x + h, "batch", "seq", "embed")
+        with step.scope("mlp"):
+            h = nn.LayerNorm(dtype=cfg.dtype, name="ln_2",
+                             scale_init=nn.with_partitioning(
+                                 nn.initializers.ones, ("embed",)),
+                             bias_init=nn.with_partitioning(
+                                 nn.initializers.zeros, ("embed",)))(x)
+            h = _dense(cfg.mlp_ratio * cfg.embed_dim, cfg, "mlp_up",
+                       ("embed", "mlp"))(h)
+            h = nn.gelu(h)
+            h = _dense(cfg.embed_dim, cfg, "mlp_down", ("mlp", "embed"))(h)
+            if cfg.dropout > 0:
+                h = nn.Dropout(cfg.dropout)(h, deterministic=deterministic)
+            return constrain_activation(x + h, "batch", "seq", "embed")
 
 
 class GPT2(nn.Module):
@@ -192,11 +199,14 @@ class GPT2(nn.Module):
                                  (None, "embed")),
             (cfg.max_seq_len, cfg.embed_dim), cfg.param_dtype)
         seq = tokens.shape[1]
-        # the lookup is a use of wte like any other: the rounded table is
-        # gathered along embed for it, not the looked-up rows exchanged
-        table = constrain_activation(wte.astype(cfg.dtype), "vocab", "embed")
-        x = table[tokens] + wpe.astype(cfg.dtype)[None, :seq]
-        x = constrain_activation(x, "batch", "seq", "embed")
+        with step.scope("embed"):
+            # the lookup is a use of wte like any other: the rounded table
+            # is gathered along embed for it, not the looked-up rows
+            # exchanged
+            table = constrain_activation(wte.astype(cfg.dtype), "vocab",
+                                         "embed")
+            x = table[tokens] + wpe.astype(cfg.dtype)[None, :seq]
+            x = constrain_activation(x, "batch", "seq", "embed")
         block_cls = Block
         if cfg.remat == "full":
             block_cls = nn.remat(Block, static_argnums=(2,))
@@ -206,12 +216,14 @@ class GPT2(nn.Module):
                 policy=jax.checkpoint_policies.dots_saveable)
         for i in range(cfg.num_layers):
             x = block_cls(cfg, name=f"h{i}")(x, deterministic)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_f",
-                         scale_init=nn.with_partitioning(
-                             nn.initializers.ones, ("embed",)),
-                         bias_init=nn.with_partitioning(
-                             nn.initializers.zeros, ("embed",)))(x)
-        return constrain_activation(x, "batch", "seq", "embed"), wte
+        # the final norm is the head's: ``loss_fn`` opens the part again
+        with step.scope("head"):
+            x = nn.LayerNorm(dtype=jnp.float32, name="ln_f",
+                             scale_init=nn.with_partitioning(
+                                 nn.initializers.ones, ("embed",)),
+                             bias_init=nn.with_partitioning(
+                                 nn.initializers.zeros, ("embed",)))(x)
+            return constrain_activation(x, "batch", "seq", "embed"), wte
 
     def __call__(self, tokens: jax.Array,
                  deterministic: bool = True) -> jax.Array:
@@ -255,27 +267,20 @@ def loss_fn(model: GPT2, params, tokens: jax.Array,
     # bf16-activation models run the head matmuls on the MXU in bf16;
     # logits accumulate and are stored in f32
     compute = jnp.bfloat16 if model.config.dtype == jnp.bfloat16 else None
-    return chunked_lm_loss(x[:, :-1], wte, tokens[:, 1:],
-                           chunk=head_chunk, compute_dtype=compute,
-                           mesh=get_global_mesh())
+    with step.scope("head"):  # the scan's body inherits it
+        return chunked_lm_loss(x[:, :-1], wte, tokens[:, 1:],
+                               chunk=head_chunk, compute_dtype=compute,
+                               mesh=get_global_mesh())
 
 
 def make_train_step(model: GPT2, tx):
     """The jitted train step the GPT-2 entry points share: chunked-head
-    loss, gradients, one ``tx`` (optax) update.  Params and optimizer
-    state are donated so XLA updates them in place (saves an HBM copy
-    of the full state per step)."""
-    import optax
-
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def train_step(params, opt_state, tokens):
+    loss, gradients, one ``tx`` (optax) update (``models/step.py``, the
+    step of every model here)."""
+    remat = model.config.remat
+    return step.make_train_step(
+        functools.partial(loss_fn, model), tx, remat=remat,
         # under a mesh the timeline says what the step asks of it
-        with fsdp_plan(params,
-                       functools.partial(param_axes, model.config),
-                       passes=3 if model.config.remat == "full" else 2):
-            loss, grads = jax.value_and_grad(
-                lambda p: loss_fn(model, p, tokens))(params)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss
-
-    return train_step
+        plan=lambda params: fsdp_plan(
+            params, functools.partial(param_axes, model.config),
+            passes=3 if remat == "full" else 2))

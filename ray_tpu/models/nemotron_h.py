@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.core import telemetry
-from ray_tpu.models import afmoe
+from ray_tpu.models import afmoe, step
 from ray_tpu.models.afmoe import (  # noqa: F401 — this model's step too
     RoutedExperts,
     _dense,
@@ -219,7 +219,10 @@ def gated_group_norm(y, z, scale, groups: int, eps: float) -> jax.Array:
 
 
 class MixerPart(nn.Module):
-    """``x + mixer(norm(x))``, a Mamba-2 mixer."""
+    """``x + mixer(norm(x))``, a Mamba-2 mixer: five of the step's parts
+    (``models/step.py``), and every op in one of them: the norm and the
+    split are ``ssm.in_proj``'s, the step sizes and decays the scan's,
+    the residual add ``ssm.out_proj``'s."""
     config: NemotronHConfig
 
     @nn.compact
@@ -233,33 +236,34 @@ class MixerPart(nn.Module):
             return self.param(name, nn.with_partitioning(init, axes), shape,
                               cfg.param_dtype)
 
-        h = RMSNorm(cfg.rms_eps, name="norm")(x)
-        with jax.named_scope("ssm.in_proj"):
+        with step.scope("ssm.in_proj"):
+            h = RMSNorm(cfg.rms_eps, name="norm")(x)
             zxd = _dense(cfg, inner + cfg.conv_dim + heads, "in_proj",
                          ("embed", "mlp"))(h)
-        z, u, dt_raw = jnp.split(zxd, [inner, inner + cfg.conv_dim], axis=-1)
-        with jax.named_scope("ssm.conv"):
+            z, u, dt_raw = jnp.split(zxd, [inner, inner + cfg.conv_dim],
+                                     axis=-1)
+        with step.scope("ssm.conv"):
             c = causal_conv(
                 u, vector("conv_kernel", nn.initializers.normal(0.02),
                           (cfg.conv, cfg.conv_dim), (None, "mlp")),
                 vector("conv_bias", nn.initializers.zeros, (cfg.conv_dim,),
                        ("mlp",))).astype(cfg.dtype)
-        xs, b, c = jnp.split(c, [inner, inner + groups * state], axis=-1)
-        dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + vector(
-            "dt_bias", _dt_bias_init(cfg), (heads,)).astype(jnp.float32))
-        a = -jnp.exp(vector("A_log", _a_log_init, (heads,)).astype(
-            jnp.float32))
-        skip = vector("D", nn.initializers.ones, (heads,))
-        with jax.named_scope("ssm.scan"):
+            xs, b, c = jnp.split(c, [inner, inner + groups * state], axis=-1)
+        with step.scope("ssm.scan"):
+            dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + vector(
+                "dt_bias", _dt_bias_init(cfg), (heads,)).astype(jnp.float32))
+            a = -jnp.exp(vector("A_log", _a_log_init, (heads,)).astype(
+                jnp.float32))
+            skip = vector("D", nn.initializers.ones, (heads,))
             y = ssd(xs.reshape(batch, seq, heads, dim), dt, a,
                     b.reshape(batch, seq, groups, state),
                     c.reshape(batch, seq, groups, state),
                     skip.astype(jnp.float32), chunk=cfg.chunk)
-        with jax.named_scope("ssm.gate_norm"):
+        with step.scope("ssm.gate_norm"):
             scale = _GateScale(name="gate_norm")(inner)
             g = gated_group_norm(y.reshape(batch, seq, inner), z, scale,
                                  groups, cfg.rms_eps).astype(cfg.dtype)
-        with jax.named_scope("ssm.out_proj"):
+        with step.scope("ssm.out_proj"):
             return x + _dense(cfg, cfg.embed_dim, "out_proj",
                               ("mlp", "embed"))(g)
 
@@ -274,7 +278,9 @@ class _GateScale(nn.Module):
 
 
 class AttentionPart(nn.Module):
-    """``x + attention(norm(x))``: grouped heads, causal, no rotation."""
+    """``x + attention(norm(x))``: grouped heads, causal, no rotation.
+    A block names it ``attn``, and flax puts a module's name around its
+    ops: that IS the step's part ``attn`` (``models/step.py``)."""
     config: NemotronHConfig
 
     @nn.compact
@@ -288,7 +294,7 @@ class AttentionPart(nn.Module):
         q = _dense(cfg, heads * dim, "wq", ("embed", "heads"))(h)
         k = _dense(cfg, kv * dim, "wk", ("embed", "kv"))(h)
         v = _dense(cfg, kv * dim, "wv", ("embed", "kv"))(h)
-        with jax.named_scope("attn.full"):
+        with step.scope("attn.full"):
             attn = flash_attention(
                 q.reshape(batch, seq, heads, dim),
                 k.reshape(batch, seq, kv, dim),
@@ -300,22 +306,31 @@ class AttentionPart(nn.Module):
 
 class ExpertPart(nn.Module):
     """``x + shared(norm(x)) + routed(norm(x))``: the shared expert plus
-    the routed experts held here, all ``down(relu(up h)^2)``."""
+    the routed experts held here, all ``down(relu(up h)^2)``.  Norm,
+    shared expert and residual adds are the step's part ``mlp``, the
+    routed experts their own five BESIDE it, as ``afmoe.MLPPart``."""
     config: NemotronHConfig
+    names_its_parts = True
 
     @nn.compact
     def __call__(self, x: jax.Array,
                  chosen: Optional[jax.Array] = None) -> jax.Array:
         cfg = self.config
-        h = RMSNorm(cfg.rms_eps, name="mlp_norm")(x)
-        return x + _relu2(cfg, h, cfg.shared_dim, "shared_") + \
-            RoutedExperts(cfg, name="moe")(h, chosen)
+        with step.named_children():
+            with step.scope("mlp"):
+                h = RMSNorm(cfg.rms_eps, name="mlp_norm")(x)
+                x = x + _relu2(cfg, h, cfg.shared_dim, "shared_")
+            routed = RoutedExperts(cfg, name="moe")(h, chosen)
+            with step.scope("mlp"):
+                return x + routed
 
 
 #: a layer's letter -> (its part, the part's name in the tree, the
-#: layer's: ``h<i>`` expert layers, ``m<i>`` mixers, ``a<i>`` attention)
-PARTS = {"M": (MixerPart, "mixer", "m"), "E": (ExpertPart, "mlp", "h"),
-         "*": (AttentionPart, "attn", "a")}
+#: layer's: ``h<i>`` expert layers, ``m<i>`` mixers, ``a<i>`` attention,
+#: the step's parts its first and its last op are of: ``models/step.py``)
+PARTS = {"M": (MixerPart, "mixer", "m", ("ssm.in_proj", "ssm.out_proj")),
+         "E": (ExpertPart, "mlp", "h", ("mlp", "mlp")),
+         "*": (AttentionPart, "attn", "a", ("attn", "attn"))}
 
 
 class HybridBlock(nn.Module):
@@ -327,10 +342,11 @@ class HybridBlock(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array,
                  chosen: Optional[jax.Array] = None) -> jax.Array:
-        part, name, _ = PARTS[self.kind]
+        part, name, _, around = PARTS[self.kind]
         if self.config.remat == "full":
             part = nn.remat(part)
-        return each_sequence((part(self.config, name=name),), x, chosen)
+        return each_sequence((part(self.config, name=name),), x, chosen,
+                             around)
 
 
 class NemotronH(nn.Module):
@@ -352,7 +368,8 @@ class NemotronH(nn.Module):
                 (cfg.vocab_size, cfg.embed_dim), cfg.param_dtype)
 
         embed, head = table("embed"), table("head")
-        x = embed.astype(cfg.dtype)[tokens]
+        with step.scope("embed"):
+            x = embed.astype(cfg.dtype)[tokens]
         seq = tokens.shape[1]
         count = dict.fromkeys(PARTS, 0)
         # the timeline says what was compiled: spans around the trace of
@@ -366,8 +383,10 @@ class NemotronH(nn.Module):
                 block = HybridBlock(cfg, kind, name=f"{PARTS[kind][2]}{i}")
                 x = block(x, choices[i]) if kind == "E" and \
                     choices is not None else block(x)
-        x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
-        return x.astype(jnp.float32), head
+        # the final norm is the head's: ``loss_fn`` opens the part again
+        with step.scope("head"):
+            x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
+            return x.astype(jnp.float32), head
 
     def __call__(self, tokens: jax.Array) -> jax.Array:
         x, head = self.hidden(tokens)

@@ -1,0 +1,100 @@
+"""What a train step is made of, said once for every model.
+
+A compiled step has no host spans inside it: its spans are the names
+the program puts on its ops (``jax.named_scope``, flax's module names),
+which XLA keeps as each instruction's ``op_name`` and the profiler
+writes beside each device op.  :data:`PARTS` is the ONE list of the
+top-level pieces a step is split by; the models open them through
+:func:`scope`, and :func:`make_train_step`, the step the models' entry
+points share, says the list in the timeline (``model:step.scopes``),
+which is where a reader of a trace takes it from.
+
+An op belongs to the OUTERMOST component of its name that is a part,
+whole or before a dot (``attn.sliding``, ``attn.full`` and ``attn.mla``
+are ``attn``; ``mla.kv_up`` is a child of whatever part it stands in),
+so it is in at most one.  A scope is a Python context at trace time and
+a string in the instruction's metadata: the jaxpr, the compiled code
+and the step's memory are what they are without it.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import functools
+from typing import Any, Callable, Optional
+
+import flax.linen as nn
+import jax
+
+from ray_tpu.core import telemetry
+
+#: the parts of a step, in the order a step meets them
+PARTS = ("embed", "attn", "mlp", "moe.route", "moe.plan", "moe.dispatch",
+         "moe.experts", "moe.combine", "ssm.in_proj", "ssm.conv",
+         "ssm.scan", "ssm.gate_norm", "ssm.out_proj", "head", "optimizer")
+
+
+def part_of(component: str) -> Optional[str]:
+    """The part a name component is: itself, or what stands before a dot
+    of it (``attn.sliding`` -> ``attn``); ``None`` for any other name."""
+    for part in PARTS:
+        if component == part or component.startswith(part + "."):
+            return part
+    return None
+
+
+def scope(name: str):
+    """``with scope("attn"):`` around the trace of a part's work, or of
+    a named piece of it (``attn.sliding``): ``jax.named_scope``, for the
+    names of :data:`PARTS` alone."""
+    if part_of(name) is None:
+        raise ValueError(f"{name!r} is no part of a step: {PARTS}")
+    return jax.named_scope(name)
+
+
+def names_its_parts(module: nn.Module):
+    """``with names_its_parts(m): m(x)`` around the call of a flax module
+    whose work is MORE than one part (a routed MLP: the shared expert is
+    ``mlp``, the routed experts are the five ``moe.*``): flax would put
+    the module's own name around all of it, and that name (``mlp``) is a
+    part.  A module whose class sets ``names_its_parts`` is called with
+    flax's naming off and turns it on again for its children
+    (:func:`named_children`); any other module is called as ever."""
+    if getattr(module, "names_its_parts", False):
+        return nn.override_named_call(False)
+    return contextlib.nullcontext()
+
+
+def named_children():
+    """The other half of :func:`names_its_parts`, around the body of
+    such a module's ``__call__``."""
+    return nn.override_named_call(True)
+
+
+def make_train_step(loss: Callable[[Any, jax.Array], jax.Array], tx, *,
+                    remat: str = "",
+                    plan: Optional[Callable[[Any], Any]] = None):
+    """The donated, jitted ``(params, opt_state, tokens) -> (params,
+    opt_state, loss)`` every model's ``make_train_step`` returns:
+    ``loss(params, tokens)`` and its gradients, then one ``tx`` (optax)
+    update under the part ``optimizer``.  Params and optimizer state are
+    donated so XLA updates them in place (saves an HBM copy of the full
+    state per step).  ``plan(params)``: a context around the trace of
+    loss and gradients (GPT-2's ``fsdp_plan``).  A trace leaves the span
+    ``model:step.scopes`` (``parts``: :data:`PARTS` comma-joined,
+    ``remat``); nothing runs with the step."""
+    import optax
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def train_step(params, opt_state, tokens):
+        with telemetry.span("model", "step.scopes", parts=",".join(PARTS),
+                            remat=remat):
+            with plan(params) if plan else contextlib.nullcontext():
+                value, grads = jax.value_and_grad(
+                    lambda p: loss(p, tokens))(params)
+            with scope("optimizer"):
+                updates, opt_state = tx.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
+        return params, opt_state, value
+
+    return train_step

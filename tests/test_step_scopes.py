@@ -1,0 +1,232 @@
+"""Every op of a train step under the program's name.
+
+A compiled step has no host spans inside it: the names the program puts
+on its ops (``models/step.py``: ONE list of parts for every model) are
+what a device trace is split by.  Here each of the four model files'
+``make_train_step`` is compiled at its tiny size on the CPU and every
+instruction's ``op_name`` read from the compiled text, with the reader
+the benchmark uses on a chip's trace (``benchmarks/reduce/scopes.py``);
+the list of parts comes from the ``model:step.scopes`` span, as there.
+Names only: nothing here is a speed.
+"""
+
+import functools
+import hashlib
+import re
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from flax.core import meta
+
+from benchmarks.reduce import scopes
+from ray_tpu.core import telemetry
+from ray_tpu.models import afmoe, deepseek_v3, gpt2, nemotron_h, step
+
+MODELS = {
+    "gpt2": (gpt2, gpt2.GPT2Config, gpt2.GPT2),
+    "afmoe": (afmoe, afmoe.AFMoEConfig, afmoe.AFMoE),
+    "deepseek_v3": (deepseek_v3, deepseek_v3.DeepseekV3Config,
+                    deepseek_v3.DeepseekV3),
+    "nemotron_h": (nemotron_h, nemotron_h.NemotronHConfig,
+                   nemotron_h.NemotronH),
+}
+ROUTED = ("moe.route", "moe.plan", "moe.dispatch", "moe.experts",
+          "moe.combine")
+SEEN = {
+    "gpt2": {"embed", "attn", "mlp", "head", "optimizer"},
+    "afmoe": {"embed", "attn", "mlp", "head", "optimizer", *ROUTED},
+    "deepseek_v3": {"embed", "attn", "mlp", "head", "optimizer", *ROUTED},
+    "nemotron_h": {"embed", "attn", "mlp", "head", "optimizer", *ROUTED,
+                   "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm",
+                   "ssm.out_proj"},
+}
+#: sha256 of ``str(make_jaxpr(train_step))`` (kernels traced as for a
+#: TPU) and of the parameter tree's paths, shapes and dtypes, taken at
+#: the commit BEFORE the scopes (bef306c): a scope is a string in an
+#: instruction's metadata, the program is what it was
+BEFORE = {
+    ("gpt2", ""): (
+        "b44adab6fb34775dc0c94decab7a4c607acf82d9c43576a682e59b42bb05ed81",
+        "8673451a825d006b3c71617386c7388d4450a1a51087cba9af55ae5eebf1757a"),
+    ("gpt2", "full"): (
+        "fa3f37a661e8da6623e537f659738ddb6890262cdc0db8c8b090190b3ae69d99",
+        "8673451a825d006b3c71617386c7388d4450a1a51087cba9af55ae5eebf1757a"),
+    ("afmoe", ""): (
+        "f7806bd36c8bcb4230cb373c974270a07fdac349f5d70b6272179d99d1f89e5f",
+        "73a54f273ca8d1cab30e4c5907da0f975cba6a8dcc30a4dda90aad118cc3b47d"),
+    ("afmoe", "full"): (
+        "49614d4b0c051e67411235986fae522c287b284e64ed1c2650b59a96f2528168",
+        "73a54f273ca8d1cab30e4c5907da0f975cba6a8dcc30a4dda90aad118cc3b47d"),
+    ("deepseek_v3", ""): (
+        "cc33485479b0b0d7b6ad2074cdb88145a5af1b0a2a3c91555b39c12e5cf87ade",
+        "80259fb632cdea7eb743ab44b67d71fd30d782bcfb29589957d50eecca4b4839"),
+    ("deepseek_v3", "full"): (
+        "d70c2e9116785efd8ad32c7126f8e10a25e956425ea09f10c1b1027e21515287",
+        "80259fb632cdea7eb743ab44b67d71fd30d782bcfb29589957d50eecca4b4839"),
+    ("nemotron_h", ""): (
+        "2c989c44d1ef1f9c6837d717647eb5b334ea90848c5f525a35d8401992f23874",
+        "dc97618ee45dda61ed25a95141a3b81a3c70d427ead3341d15b02ac03fd1409e"),
+    ("nemotron_h", "full"): (
+        "18a128d24adef13adc3f1835f53ad18cb2f472827d35f2825a941724d5f3cd58",
+        "dc97618ee45dda61ed25a95141a3b81a3c70d427ead3341d15b02ac03fd1409e"),
+}
+
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = .*? ([a-z][\w\-]*)\(.*"
+    r"metadata=\{[^}]*op_name=\"([^\"]*)\"")
+#: what a backend puts in for itself under the name of whatever stood
+#: there (the CPU rounds bfloat16 through float32 around every op):
+#: no work of the program's
+_PLUMBING = {"convert", "constant", "bitcast", "copy", "tuple",
+             "get-tuple-element", "parameter"}
+
+
+@functools.lru_cache(maxsize=None)
+def compiled(name):
+    """``(instructions [(opcode, op_name)], the trace's step.scopes
+    rows)`` of the model's tiny step under ``remat="full"``."""
+    module, config, model_cls = MODELS[name]
+    cfg = config.tiny(remat="full")
+    model = model_cls(cfg)
+    params = meta.unbox(model.init_params(jax.random.PRNGKey(0), batch=2))
+    tx = optax.adamw(1e-3)
+    train_step = module.make_train_step(model, tx)
+    tokens = jnp.zeros((2, cfg.max_seq_len), jnp.int32)
+    telemetry.drain_spans("test")
+    text = train_step.lower(params, tx.init(params), tokens) \
+        .compile().as_text()
+    rows = [r for r in telemetry.drain_spans("test")
+            if (r["cat"], r["name"]) == ("model", "step.scopes")]
+    found = [m.groups() for m in map(_INSTRUCTION.match, text.splitlines())
+             if m]
+    # instructions of the step's own: a reduction's or a sort's little
+    # computations carry bare names
+    return [(op, n) for op, n in found if n.startswith("jit(")], rows
+
+
+def parts_of(name):
+    (row,) = compiled(name)[1]
+    return row["args"]["parts"].split(",")
+
+
+def all_parts(op_name, parts):
+    """EVERY part among an op's components (the reader takes the
+    outermost: an op has to sit under one)."""
+    return {p for piece in scopes.components(op_name) for p in parts
+            if piece == p or piece.startswith(p + ".")}
+
+
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_the_span_says_the_list_once_a_trace(name):
+    instructions, rows = compiled(name)
+    assert len(rows) == 1
+    assert rows[0]["args"] == {"parts": ",".join(step.PARTS),
+                               "remat": "full"}
+    parts = parts_of(name)
+    seen = {scopes.part(n, parts) for _, n in instructions} - {None}
+    assert seen == SEEN[name]
+    # the program's rule and the reader's are one rule
+    for _, n in instructions:
+        mine = [step.part_of(c) for c in scopes.components(n)]
+        assert next((p for p in mine if p), None) == scopes.part(n, parts)
+
+
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_every_product_sits_under_exactly_one_part(name):
+    instructions, _ = compiled(name)
+    parts = parts_of(name)
+    products = [(op, n) for op, n in instructions
+                if op in ("dot", "convolution", "custom-call")]
+    assert len(products) >= 10
+    astray = [(op, n) for op, n in products
+              if len(all_parts(n, parts)) != 1]
+    assert not astray, astray[:5]
+
+
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_under_two_percent_of_the_ops_sit_under_no_part(name):
+    instructions, _ = compiled(name)
+    parts = parts_of(name)
+    work = [n for op, n in instructions if op not in _PLUMBING]
+    bare = [n for n in work if scopes.part(n, parts) is None]
+    assert len(work) > 500
+    assert len(bare) < 0.02 * len(work), (
+        len(bare), len(work), sorted(set(bare))[:10])
+    # and no op is under two: what the routed layer adds stands BESIDE
+    # ``mlp``, ``moe.plan`` beside ``moe.route``
+    assert not [n for n in work if len(all_parts(n, parts)) > 1]
+
+
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_phases_are_told_by_jaxs_own_markers(name):
+    instructions, _ = compiled(name)
+    parts = parts_of(name)
+    by_phase = {}
+    for _, n in instructions:
+        by_phase.setdefault(scopes.phase(n), []).append(n)
+    assert set(by_phase) >= {"forward", "recompute", "backward",
+                             "optimizer"}
+    # the update is outside the gradient
+    assert not [n for n in by_phase["optimizer"]
+                if "jvp(" in n or "transpose(" in n]
+    # the marker the reader keys on, under remat="full": the forward run
+    # again is inside the backward pass, and the parts are told in it
+    recomputed = by_phase["recompute"]
+    assert all("transpose(" in n and "/rematted_computation/" in n
+               for n in recomputed)
+    # (a part's last op is not run again where no gradient needs it)
+    assert {scopes.part(n, parts) for n in recomputed} >= \
+        SEEN[name] - {"embed", "optimizer", "moe.combine", "ssm.out_proj"}
+    # a backward op never passes for a forward one
+    assert not [n for n in by_phase["forward"] if "transpose(" in n]
+
+
+@pytest.mark.parametrize("name", sorted(set(MODELS) - {"gpt2"}))
+def test_the_plan_is_not_under_the_router(name):
+    instructions, _ = compiled(name)
+    planned = [n for _, n in instructions if "moe.plan" in n]
+    assert planned
+    assert not [n for n in planned if "moe.route" in n]
+    routed = [n for _, n in instructions if "moe.route" in n]
+    # the router's product and its top-k stay the router's
+    assert any(n.endswith("/dot_general") for n in routed)
+    assert any("top_k" in n for n in routed)
+    # a custom_vjp's backward lands under its forward's part
+    parts = parts_of(name)
+    assert [n for _, n in instructions if "transpose(" in n
+            and "rematted_computation" not in n
+            and scopes.part(n, parts) == "moe.dispatch"]
+
+
+@pytest.mark.parametrize("name,remat", sorted(BEFORE))
+def test_the_program_is_what_it_was(name, remat):
+    """Parameter paths and the jaxpr as before the scopes (GPT-2 at
+    another size: ``tests/test_parallel.py`` ``STEP_BEFORE``)."""
+    module, config, model_cls = MODELS[name]
+    cfg = config.tiny(remat=remat)
+    model = model_cls(cfg)
+    tx = optax.adamw(1e-3)
+    params = meta.unbox(jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0), batch=2)))
+    args = (params, jax.eval_shape(tx.init, params),
+            jax.ShapeDtypeStruct((2, cfg.max_seq_len), jnp.int32))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = str(jax.make_jaxpr(module.make_train_step(model, tx))(*args))
+    paths = "\n".join(sorted(
+        jax.tree_util.keystr(k) + str(v.shape) + str(v.dtype)
+        for k, v in jax.tree_util.tree_leaves_with_path(params)))
+    assert (hashlib.sha256(text.encode()).hexdigest(),
+            hashlib.sha256(paths.encode()).hexdigest()) == \
+        BEFORE[name, remat]
+
+
+def test_a_scope_that_is_no_part_is_refused():
+    with pytest.raises(ValueError):
+        step.scope("attention")
+    assert step.part_of("attn.mla") == "attn"
+    assert step.part_of("mla.kv_up") is None and step.part_of("moe") is None
+    with step.scope("moe.plan"):
+        pass
