@@ -237,12 +237,44 @@ def shutdown() -> None:
                 leave_timeline(core)
             core.shutdown()
         if _head_proc is not None and _owns_head:
+            from ray_tpu.core import node as node_mod
+            below = node_mod.processes_below(_head_proc.pid)
             _head_proc.terminate()
             try:
                 _head_proc.wait(timeout=10)
             except subprocess.TimeoutExpired:
                 _head_proc.kill()
             _head_proc = None
+            _await_session_processes(below)
+
+
+def _await_session_processes(below, patience: float = 30.0) -> None:
+    """The head's workers die of its death (``PR_SET_PDEATHSIG``), not at
+    once: a gang worker closes its chips first, up to 19 s for four
+    after a cold compile (PERF.md, PR 45), and a process started right
+    after ``shutdown()`` found ``/dev/vfio/<n>`` busy.  So ``shutdown()``
+    returns when they are gone: ``below`` is ``{pid: start time}`` of the
+    head's descendants, taken while it lived."""
+    import signal
+    import time
+
+    from ray_tpu.core import node as node_mod
+
+    started = time.monotonic()
+    left = node_mod.wait_until_gone(below, patience)
+    waited = time.monotonic() - started
+    if waited > 1.0:
+        logger.info("shutdown: waited %.1f s for the head's %d processes "
+                    "to end", waited, len(below))
+    for pid in left:
+        logger.warning(
+            "shutdown: process %d of this session still alive %.0f s "
+            "after its head; SIGKILL", pid, patience)
+        try:
+            os.kill(pid, signal.SIGKILL)
+        except OSError:
+            pass
+    node_mod.wait_until_gone(left, 5.0)
 
 
 def remote(*args, **options):

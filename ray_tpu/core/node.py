@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import errno
 import glob
 import json
 import logging
@@ -25,7 +26,7 @@ import sys
 import tempfile
 import time
 import uuid
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 from ray_tpu.core.config import Config
 
@@ -54,13 +55,136 @@ def detect_tpu_resources() -> Dict[str, float]:
     n = os.environ.get("RAY_TPU_CHIPS")
     if n is not None:
         return {"TPU": float(n)} if float(n) > 0 else {}
-    chips = len(glob.glob("/dev/accel*"))
-    if not chips:
-        try:
-            chips = sum(e.isdigit() for e in os.listdir("/dev/vfio"))
-        except OSError:
-            chips = 0
+    chips = len(chip_device_files())
     return {"TPU": float(chips)} if chips else {}
+
+
+def chip_device_files() -> List[str]:
+    """The device nodes the chips expose, in the chips' order."""
+    found = sorted(glob.glob("/dev/accel*"))
+    if not found:
+        try:
+            found = [os.path.join("/dev/vfio", e) for e in sorted(
+                (e for e in os.listdir("/dev/vfio") if e.isdigit()), key=int)]
+        except OSError:
+            found = []
+    return found
+
+
+def leased_chip_files() -> List[str]:
+    """Device nodes of the chips this process was spawned for
+    (``TPU_VISIBLE_CHIPS`` of :func:`tpu_worker_env`; all of the host's
+    where the lease is the whole host)."""
+    files = chip_device_files()
+    visible = os.environ.get("TPU_VISIBLE_CHIPS")
+    try:
+        mine = [int(i) for i in visible.split(",")] if visible else None
+    except ValueError:
+        mine = None
+    if mine is None:
+        return files
+    return [files[i] for i in mine if 0 <= i < len(files)]
+
+
+def wait_for_chips(paths, timeout: float = 60.0, poll: float = 0.25) -> float:
+    """Seconds waited until none of the device nodes ``paths`` is held by
+    another process, ``timeout`` at most.  A chip's node opens for one
+    process at a time (``EBUSY`` otherwise), and its last owner may
+    still be exiting: a gang worker whose head was just terminated takes
+    up to 19 s to close four chips (PERF.md, PR 45), and a backend that
+    failed to initialise is not safely initialised again in one process.
+    So the wait is HERE, before jax opens anything.  A free chip costs
+    an ``open`` and a ``close``; any other error is the backend's to
+    report."""
+    started, busy = time.monotonic(), False
+    for path in paths:
+        while _held(path) and time.monotonic() - started < timeout:
+            busy = True
+            time.sleep(poll)
+    return time.monotonic() - started if busy else 0.0
+
+
+def _held(path: str) -> bool:
+    """Whether another process holds the device node ``path``."""
+    try:
+        with open(path, "r+b", buffering=0):
+            return False
+    except OSError as e:
+        return e.errno == errno.EBUSY
+
+
+def _proc_stat(pid: int) -> Optional[Tuple[str, int, int]]:
+    """``(state, parent, start time)`` of a process from ``/proc``, or
+    ``None`` where it is gone (or there is no ``/proc``)."""
+    try:
+        with open(f"/proc/{pid}/stat") as f:
+            fields = f.read().rsplit(")", 1)[1].split()
+        return fields[0], int(fields[1]), int(fields[19])
+    except (OSError, IndexError, ValueError):
+        return None
+
+
+def processes_below(pid: int) -> Dict[int, int]:
+    """``{pid: start time}`` of every process that descends from ``pid``:
+    a head's raylet workers, its zygote and what that forked."""
+    born: Dict[int, int] = {}
+    children: Dict[int, List[int]] = {}
+    try:
+        entries = os.listdir("/proc")
+    except OSError:
+        return {}
+    for entry in entries:
+        stat = _proc_stat(int(entry)) if entry.isdigit() else None
+        if stat is not None:
+            born[int(entry)] = stat[2]
+            children.setdefault(stat[1], []).append(int(entry))
+    below: Dict[int, int] = {}
+    todo = [pid]
+    while todo:
+        for child in children.get(todo.pop(), ()):
+            if child not in below:
+                below[child] = born[child]
+                todo.append(child)
+    return below
+
+
+def _threads_run_on(pid: int) -> bool:
+    """Whether a process whose main thread has exited (it reads ``Z``)
+    still has other threads: a gang worker in ``exit_group`` closes its
+    chips in whichever thread drops the last reference to them, seconds
+    after its leader is a zombie."""
+    try:
+        return len(os.listdir(f"/proc/{pid}/task")) > 1
+    except OSError:
+        return False
+
+
+def wait_until_gone(procs: Dict[int, int], timeout: float,
+                    poll: float = 0.05) -> Dict[int, int]:
+    """Those of ``procs`` (``{pid: start time}``) still alive after
+    ``timeout`` seconds.  A zombie holds nothing and is gone, with two
+    exceptions, both met on the chip (PERF.md, PR 45): one whose other
+    threads run on, and any zombie while a chip of this host is still
+    busy: in a sandbox whose init does not reap (gVisor), the workers
+    of a session read ``Z`` with no threads to list for the seconds it
+    takes to close their chips, and vanish when the chips are free."""
+    deadline = time.monotonic() + timeout
+    left = dict(procs)
+    while left:
+        chips_busy = None
+        for pid, born in list(left.items()):
+            stat = _proc_stat(pid)
+            if stat is None or stat[2] != born:
+                del left[pid]
+            elif stat[0] == "Z" and not _threads_run_on(pid):
+                if chips_busy is None:
+                    chips_busy = any(map(_held, chip_device_files()))
+                if not chips_busy:
+                    del left[pid]
+        if not left or time.monotonic() >= deadline:
+            break
+        time.sleep(poll)
+    return left
 
 
 #: chips-per-process bounds libtpu accepts for a sub-host lease

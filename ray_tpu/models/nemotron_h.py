@@ -56,6 +56,7 @@ from ray_tpu.models.afmoe import (  # noqa: F401 — this model's step too
 from ray_tpu.models.llama import RMSNorm
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.ops.fused import _rmsnorm_ref
+from ray_tpu.ops.short_conv import short_conv
 from ray_tpu.ops.ssd import ssd
 
 #: the ``ray_tpu_moe_*`` gauges under this model's name
@@ -200,13 +201,10 @@ def _a_log_init(key, shape, dtype):
 
 def causal_conv(u: jax.Array, w: jax.Array, bias: jax.Array) -> jax.Array:
     """``silu(bias + sum_j w[j] * u[t - (taps - 1) + j])`` over ``u [B, T,
-    C]``, ``u`` zero before the sequence; ``w [taps, C]``.  float32."""
-    taps, seq = w.shape[0], u.shape[1]
-    padded = jnp.pad(u.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    out = bias.astype(jnp.float32)
-    for j in range(taps):
-        out = out + w[j].astype(jnp.float32) * padded[:, j:j + seq]
-    return nn.silu(out)
+    C]``, ``u`` zero before the sequence; ``w [taps, C]``.  The sum and
+    ``silu`` in float32, the result in ``u``'s dtype: one pass over the
+    channels where the shapes are the kernels' (``ops/short_conv.py``)."""
+    return short_conv(u, w, bias)
 
 
 def gated_group_norm(y, z, scale, groups: int, eps: float) -> jax.Array:

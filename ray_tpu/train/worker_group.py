@@ -83,12 +83,12 @@ class TrainWorker:
         """
         _dt.record_xla_phases()
         # every gang leaves this span, a CPU one too (then ~0 s): what
-        # opening the chips costs, apart from the rest of gang bring-up
+        # opening the chips costs; ``waited_s``: for their last owner
         with _tm.span("train", "chip_open", backend=None, devices=0) as sp:
             if not use_tpu:
                 return True
             import jax
-
+            sp.args.update(waited_s=_chips_free())
             if coordinator is not None and self.world_size > 1:
                 jax.distributed.initialize(
                     coordinator_address=coordinator,
@@ -310,3 +310,15 @@ class WorkerGroup:
             except Exception:
                 pass
             self.pg = None
+
+
+def _chips_free() -> float:
+    """Seconds waited for this worker's leased chips to be let go by a
+    process that is exiting (``core/node.py`` ``wait_for_chips``); before
+    jax opens them, because a backend that failed to initialise is not
+    safely initialised again in this process.  (Down here so that no
+    line above the train loop's frame moves: a kernel's cache key holds
+    the line of every frame above its trace.)"""
+    from ray_tpu.core import node
+
+    return round(node.wait_for_chips(node.leased_chip_files()), 3)

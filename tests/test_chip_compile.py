@@ -371,6 +371,44 @@ def test_ssd_kernels_compile_at_the_nemotron_cells_shapes(one_chip):
     assert not re.search(r"\[(1,)?64,64,128,128\]", text)   # Q x Q a head
 
 
+@pytest.mark.parametrize("dtype,rows", [(jnp.bfloat16, 1024),
+                                        (jnp.float32, 512)])
+def test_short_conv_kernels_compile_at_the_nemotron_cells_shapes(
+        one_chip, dtype, rows):
+    """One sequence of 8,192 over the mixer's 6,144 channels, four taps:
+    the forward kernel and the backward one (``_short_conv``: the public
+    wrapper asks the backend), in the cell's bfloat16 and in the float32
+    a check may run the mixer in (half the rows a tile, so that both fit
+    the kernels' fast memory).  No float32 copy of ``u`` in HBM, padded
+    or not, and every call's FIRST result 2-d."""
+    conv = importlib.import_module("ray_tpu.ops.short_conv")
+
+    def shape(dims, kind):
+        return jax.ShapeDtypeStruct(dims, kind, sharding=one_chip)
+
+    u = shape((1, 8192, 6144), dtype)
+    tile = conv.tiles(u, 4)
+    assert tile == (rows, 512)
+
+    def grads(*a):
+        def loss(*a):
+            y = conv._short_conv(*a, tile, False).astype(jnp.float32)
+            return (y * y).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(*a)
+
+    text = jax.jit(grads).lower(u, shape((4, 6144), jnp.float32),
+                                shape((6144,), jnp.float32)
+                                ).compile().as_text()
+    calls = _kernel_calls(text)
+    assert len(calls) == 2
+    kind = "bf16" if dtype == jnp.bfloat16 else "f32"
+    for line in calls:
+        assert re.search(rf"= \(?{kind}\[8192,6144\]", line), line[:200]
+    assert "[1,8195,6144]" not in text and "[8195,6144]" not in text
+    if dtype == jnp.bfloat16:
+        assert "f32[1,8192,6144]" not in text
+
+
 def _nemotron_share(**kw):
     nh = importlib.import_module("ray_tpu.models.nemotron_h")
     cfg = nh.NemotronHConfig.nemotron_3_nano_30b_a3b_share(remat="full",
@@ -485,12 +523,23 @@ def test_nemotron_share_train_step_fits_one_v5e(one_chip):
     # 4 mixers x 2 sequences: forward twice (remat), backward once
     assert named("ssd_chunk_scan_bwd") == 8
     assert named("ssd_chunk_scan") - named("ssd_chunk_scan_bwd") == 16
+    # the mixers' convolution likewise (PR 45), and every call's FIRST
+    # result 2-d [8192, channels]: the benchmark's readers tell kernel
+    # calls apart by result shapes, and file such a one with the norms
+    assert named("short_conv_bwd") == 8
+    assert named("short_conv") - named("short_conv_bwd") == 16
+    for line in calls:
+        if "short_conv" in line:
+            assert re.search(r"= \(?bf16\[8192,6144\]", line), line[:200]
+    # no padded float32 copy of a mixer's ``u``
+    assert not re.search(r"f32\[(1,)?8195,6144\]", text)
     # 4 expert layers x 2 sequences x 2 products x (2 forward, d lhs, d rhs)
     assert named("grouped_matmul") == 64
     assert _passes_over_the_row_buffer(text, 6 * 8192 + 8 * 256) == []
     # what it took before the routed layer kept to its live rows (PR 35:
     # 11.83 GiB), and a hundredth of a GiB
     assert total < 11.84 * 2 ** 30, f"{total / 2**30:.3f} GiB"
+    print(f"nemotron step: {total / 2**30:.3f} GiB")
 
 
 def test_nemotron_gradient_check_fits_beside_the_training_state(one_chip):
@@ -518,9 +567,11 @@ def test_nemotron_gradient_check_fits_beside_the_training_state(one_chip):
 
     compiled = _lower_as_on_tpu(jax.jit(error), (params, tokens)).compile()
     assert "ssd_chunk_scan_bwd" in compiled.as_text()
+    assert "short_conv_bwd" in compiled.as_text()
     mem = compiled.memory_analysis()
     check = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
              + mem.output_size_in_bytes)
     assert check + NEMOTRON_STATE_BYTES < 0.97 * V5E_HBM_BYTES, \
         f"{check / 2**30:.2f} GiB beside " \
         f"{NEMOTRON_STATE_BYTES / 2**30:.2f} GiB of state"
+    print(f"nemotron gradient check: {check / 2**30:.3f} GiB")

@@ -11,6 +11,7 @@ import os
 import subprocess
 import sys
 import threading
+import time
 
 import pytest
 
@@ -357,3 +358,214 @@ def test_raylet_that_reconnects_after_a_head_stall_stays_alive():
     finally:
         ray_tpu.shutdown()
         c.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# a chip whose last owner is leaving (PR 45): the gang waits for it, and
+# shutdown() waits for the processes of its session
+# ---------------------------------------------------------------------------
+
+class _Busy:
+    """Stands in for device nodes another process holds: ``node``'s
+    ``open`` of a path in ``held`` raises ``EBUSY``."""
+
+    def __init__(self, monkeypatch, held, other_errno=None):
+        import errno
+
+        self.held, self.opens, self.closes = set(held), [], []
+        self.errno = other_errno or errno.EBUSY
+        busy = self
+
+        class Node:
+            def __init__(self, path):
+                self.path = path
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                busy.closes.append(self.path)
+
+        def fake_open(path, mode="r", buffering=-1):
+            if not path.startswith("/dev/"):
+                return open(path, mode, buffering)
+            assert (mode, buffering) == ("r+b", 0)
+            self.opens.append(path)
+            if path in self.held:
+                raise OSError(self.errno, os.strerror(self.errno), path)
+            if not path.startswith("/dev/standin"):
+                raise FileNotFoundError(path)
+            return Node(path)
+
+        monkeypatch.setattr(node, "open", fake_open, raising=False)
+
+
+CHIPS = [f"/dev/standin{i}" for i in range(4)]
+
+
+def test_free_chips_cost_an_open_and_a_close_each(monkeypatch):
+    busy = _Busy(monkeypatch, held=())
+    assert node.wait_for_chips(CHIPS, timeout=5.0) == 0.0
+    assert busy.opens == CHIPS and len(busy.closes) == 4
+
+
+def test_the_probe_waits_for_a_chip_its_last_owner_is_closing(monkeypatch):
+    busy = _Busy(monkeypatch, held={CHIPS[2]})
+    threading.Timer(0.6, busy.held.clear).start()
+    waited = node.wait_for_chips(CHIPS, timeout=30.0, poll=0.05)
+    assert 0.5 <= waited < 5.0
+    assert busy.opens.count(CHIPS[2]) > 2 and busy.opens[-1] == CHIPS[3]
+    assert len(busy.closes) == 4     # every chip was seen free, once
+
+
+def test_the_probe_gives_up_at_its_bound_and_leaves_the_error_to_jax(
+        monkeypatch):
+    busy = _Busy(monkeypatch, held={CHIPS[0], CHIPS[1]})
+    waited = node.wait_for_chips(CHIPS, timeout=0.4, poll=0.05)
+    assert 0.4 <= waited < 2.0       # ONE bound for all the chips
+    assert busy.opens[-2:] == CHIPS[2:] and len(busy.closes) == 2
+
+
+def test_an_error_that_is_not_busy_is_not_waited_for(monkeypatch):
+    import errno
+
+    _Busy(monkeypatch, held=set(CHIPS), other_errno=errno.EACCES)
+    assert node.wait_for_chips(CHIPS, timeout=30.0) == 0.0
+    assert node.wait_for_chips(["/dev/none"], timeout=30.0) == 0.0
+
+
+@pytest.mark.parametrize("visible,mine", [
+    (None, [0, 1, 2, 3]), ("2", [2]), ("1,3", [1, 3]), ("0,9", [0]),
+    ("all", [0, 1, 2, 3])])
+def test_the_probe_looks_at_the_leased_chips_alone(monkeypatch, visible,
+                                                   mine):
+    monkeypatch.setattr(node.glob, "glob", lambda pat: [])
+    monkeypatch.setattr(node.os, "listdir",
+                        lambda d: ["vfio", "10", "2", "0", "3"])
+    assert node.chip_device_files() == [
+        "/dev/vfio/0", "/dev/vfio/2", "/dev/vfio/3", "/dev/vfio/10"]
+    if visible is None:
+        monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    else:
+        monkeypatch.setenv("TPU_VISIBLE_CHIPS", visible)
+    assert node.leased_chip_files() == [
+        node.chip_device_files()[i] for i in mine]
+
+
+def test_the_chip_open_span_says_how_long_it_waited(monkeypatch):
+    """``waited_s`` on ``train:chip_open``, before jax opens anything: a
+    worker that leased chips on this CPU finds no device node, waits for
+    nothing, and then fails as it always did."""
+    from ray_tpu.core import telemetry
+    from ray_tpu.train import worker_group
+
+    busy = _Busy(monkeypatch, held={CHIPS[1]})
+    monkeypatch.setattr(node, "chip_device_files", lambda: CHIPS[:2])
+    threading.Timer(0.4, busy.held.clear).start()
+    telemetry.drain_spans("test")
+    with pytest.raises(RuntimeError, match="leased TPU chips but jax"):
+        worker_group.TrainWorker(0, 1).setup_jax(None, True)
+    (span,) = [r for r in telemetry.drain_spans("test")
+               if (r["cat"], r["name"]) == ("train", "chip_open")]
+    assert 0.25 <= span["args"]["waited_s"] < 5.0
+    assert span["args"]["backend"] == "cpu"
+    monkeypatch.setattr(node, "chip_device_files", lambda: [])
+    assert worker_group._chips_free() == 0.0
+
+
+_IGNORES_SIGTERM = ("import signal, time; "
+                    "signal.signal(signal.SIGTERM, signal.SIG_IGN); "
+                    "print('up', flush=True); time.sleep(120)")
+
+
+def test_no_process_of_a_session_is_alive_when_shutdown_returns():
+    ray_tpu.init(num_cpus=2)
+    try:
+        pids = set(ray_tpu.get([ray_tpu.remote(os.getpid).remote()
+                                for _ in range(4)], timeout=60))
+        below = node.processes_below(ray_tpu._head_proc.pid)
+        assert pids <= set(below) and os.getpid() not in below
+    finally:
+        ray_tpu.shutdown()
+    assert node.wait_until_gone(below, 0.0) == {}
+
+
+def test_a_process_that_outlives_its_head_is_killed_at_the_bound(caplog):
+    proc = subprocess.Popen([sys.executable, "-c", _IGNORES_SIGTERM],
+                            stdout=subprocess.PIPE, text=True)
+    try:
+        assert proc.stdout.readline().strip() == "up"
+        mine = node.processes_below(os.getpid())
+        below = {proc.pid: mine[proc.pid]}
+        proc.terminate()                       # what PDEATHSIG would send
+        assert node.wait_until_gone(below, 0.3) == below
+        with caplog.at_level("WARNING", logger="ray_tpu"):
+            ray_tpu._await_session_processes(below, patience=0.3)
+        assert "SIGKILL" in caplog.text and str(proc.pid) in caplog.text
+        assert node.wait_until_gone(below, 0.0) == {}
+        assert proc.wait(timeout=10) == -9
+    finally:
+        proc.kill()
+
+
+def test_a_pid_given_to_another_process_counts_as_gone():
+    mine = node.processes_below(os.getppid())
+    assert os.getpid() in mine
+    assert node.wait_until_gone({os.getpid(): mine[os.getpid()]}, 0.0)
+    assert node.wait_until_gone({os.getpid(): mine[os.getpid()] + 1},
+                                0.0) == {}
+
+
+_LEADER_LEAVES_FIRST = """
+import ctypes, threading, time
+threading.Thread(target=time.sleep, args=(1.5,)).start()
+print('up', flush=True)
+ctypes.CDLL(None).pthread_exit(None)   # the main thread alone
+"""
+
+
+def _until_a_zombie(pid: int, bound: float) -> None:
+    deadline = time.monotonic() + bound
+    while node._proc_stat(pid)[0] != "Z" and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert node._proc_stat(pid)[0] == "Z"
+
+
+def test_a_zombie_whose_threads_run_on_is_not_gone():
+    """What four busy chips hid behind (PR 45): the worker's main thread
+    had exited, ``/proc/<pid>/stat`` read ``Z``, and another thread was
+    still closing the device files."""
+    proc = subprocess.Popen([sys.executable, "-c", _LEADER_LEAVES_FIRST],
+                            stdout=subprocess.PIPE, text=True)
+    try:
+        assert proc.stdout.readline().strip() == "up"
+        born = node.processes_below(os.getpid())[proc.pid]
+        _until_a_zombie(proc.pid, 1.0)
+        assert node.wait_until_gone({proc.pid: born}, 0.0) == \
+            {proc.pid: born}
+        # ... and once the last thread has ended, a zombie like any other
+        assert node.wait_until_gone({proc.pid: born}, 10.0) == {}
+    finally:
+        proc.kill()
+        proc.wait()
+
+
+def test_a_zombie_counts_while_a_chip_of_the_host_is_busy(monkeypatch):
+    """A sandbox whose init does not reap: a session's workers read
+    ``Z``, list no threads, and the chips they held are busy for seconds
+    more; they are gone when the chips are free."""
+    proc = subprocess.Popen([sys.executable, "-c", "pass"])
+    try:
+        born = node.processes_below(os.getpid())[proc.pid]
+        _until_a_zombie(proc.pid, 10.0)
+        mine = {proc.pid: born}
+        assert node.wait_until_gone(mine, 0.0) == {}   # no chip here
+        busy = _Busy(monkeypatch, held={CHIPS[3]})
+        monkeypatch.setattr(node, "chip_device_files", lambda: CHIPS)
+        assert node.wait_until_gone(mine, 0.2) == mine
+        threading.Timer(0.3, busy.held.clear).start()
+        started = time.monotonic()
+        assert node.wait_until_gone(mine, 10.0) == {}
+        assert 0.2 <= time.monotonic() - started < 5.0
+    finally:
+        proc.wait()

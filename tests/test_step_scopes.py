@@ -66,11 +66,14 @@ BEFORE = {
     ("deepseek_v3", "full"): (
         "d70c2e9116785efd8ad32c7126f8e10a25e956425ea09f10c1b1027e21515287",
         "80259fb632cdea7eb743ab44b67d71fd30d782bcfb29589957d50eecca4b4839"),
+    # re-pinned at PR 45: the mixer's convolution is two kernel calls
+    # (``ops/short_conv.py``) where it was XLA's passes; the parameter
+    # tree is what it was, and the three models above do not run it
     ("nemotron_h", ""): (
-        "2c989c44d1ef1f9c6837d717647eb5b334ea90848c5f525a35d8401992f23874",
+        "34d2bbfb40cd0fd2421cac7adfb8a350e65ecae44a9574ad5bed62b8bd8a7061",
         "dc97618ee45dda61ed25a95141a3b81a3c70d427ead3341d15b02ac03fd1409e"),
     ("nemotron_h", "full"): (
-        "18a128d24adef13adc3f1835f53ad18cb2f472827d35f2825a941724d5f3cd58",
+        "5800c87db525945e397adea40c924c2f43877f2a3332b0bde5bc08b8f384c918",
         "dc97618ee45dda61ed25a95141a3b81a3c70d427ead3341d15b02ac03fd1409e"),
 }
 
