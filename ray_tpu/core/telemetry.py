@@ -654,6 +654,33 @@ def moe_router_load(model: str, layer: int, load, landed_share: float,
 
 
 # ---------------------------------------------------------------------------
+# looped stacks (models/ouro.py)
+# ---------------------------------------------------------------------------
+
+def loop_exits(model: str, exit_share, expected_passes: float,
+               exit_entropy: float) -> None:
+    """What the exit gate of a looped stack made of its last observed
+    batch: ``exit_share[t]`` the mean probability that a token leaves at
+    exit ``t + 1``, the mean number of passes a token would be given, and
+    the mean entropy of a token's exit distribution (0: the gate has
+    decided; ``log(passes)``: it has not)."""
+    if not enabled():
+        return
+    per = _gauge("ray_tpu_loop_exit_share",
+                 "mean probability that a token leaves the loop at this "
+                 "exit, last observed batch", ("model", "exit"))
+    for t, share in enumerate(exit_share):
+        per.set_key((("model", model), ("exit", str(t + 1))), float(share))
+    key = (("model", model),)
+    _gauge("ray_tpu_loop_expected_passes",
+           "mean passes through the stack a token's exit gate gives it",
+           ("model",)).set_key(key, float(expected_passes))
+    _gauge("ray_tpu_loop_exit_entropy",
+           "mean entropy of a token's exit distribution",
+           ("model",)).set_key(key, float(exit_entropy))
+
+
+# ---------------------------------------------------------------------------
 # serving plane (serve/_internal.py, serve/batching.py, serve/http_proxy.py)
 # ---------------------------------------------------------------------------
 

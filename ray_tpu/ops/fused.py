@@ -103,8 +103,10 @@ def chunked_lm_loss(hidden: jax.Array, emb: jax.Array, labels: jax.Array,
                     mesh: Optional[jax.sharding.Mesh] = None) -> jax.Array:
     """Mean next-token cross entropy with a chunked LM head.
 
-    ``hidden`` [B,T,E] (f32), ``emb`` [V,E] (tied embedding), ``labels``
-    [B,T].  Tokens are processed ``chunk`` at a time under
+    ``hidden`` [B,T,E] (f32), ``emb`` [V,E] (the output head's matrix:
+    the embedding where a model ties the two, GPT-2's cells, an untied
+    head elsewhere), ``labels`` [B,T].  Tokens are processed ``chunk`` at
+    a time under
     ``jax.checkpoint``: the [chunk,V] logits block lives only inside one
     scan step (forward) and is recomputed in backward — HBM never holds
     [B,T,V], which at GPT-2-small scale is both the largest tensor and
@@ -163,27 +165,68 @@ def _lm_loss_sum(hidden, emb, labels, *, chunk, compute_dtype,
     @jax.checkpoint
     def body(carry, xs):
         h, y, m = xs
-        if compute_dtype is not None:
-            # MXU path: bf16 operands, f32 accumulation by default.
-            # ``logits_dtype=bf16`` opts into storing the [chunk, V]
-            # block (the step's largest HBM consumer, read several
-            # times per chunk in fwd+bwd) in half width: logits then
-            # quantize at FULL magnitude before the max-subtract, so
-            # the error grows with logit scale (~0.06 per logit at
-            # |x|~16).  No training path asks for it; the benchmark's
-            # ``lower_precision`` control does (``models/afmoe.py``).
-            logits = jax.lax.dot_general(
-                h.astype(compute_dtype), emb_f32.astype(compute_dtype),
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=logits_dtype or jnp.float32)
-        else:
-            logits = h @ emb_f32.T  # [chunk, V]
-        mx = jax.lax.stop_gradient(logits.max(axis=-1, keepdims=True))
-        shifted = (logits - mx).astype(jnp.float32)
-        lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
-        label_logit = jnp.take_along_axis(
-            shifted, y[:, None], axis=-1)[:, 0]
-        return carry + jnp.sum((lse - label_logit) * m), None
+        nll = _chunk_nll(h, y, emb_f32, compute_dtype, logits_dtype)
+        return carry + jnp.sum(nll * m), None
 
     total, _ = jax.lax.scan(body, jnp.float32(0.0), (h_c, y_c, m_c))
     return total
+
+
+def _chunk_nll(h, y, emb_f32, compute_dtype, logits_dtype):
+    """One scan step of the chunked head: the cross entropy ``[chunk]``
+    of ``h [chunk, E]`` against ``y [chunk]``; the ``[chunk, V]`` logits
+    live and die here."""
+    if compute_dtype is not None:
+        # MXU path: bf16 operands, f32 accumulation by default.
+        # ``logits_dtype=bf16`` opts into storing the [chunk, V] block
+        # (the step's largest HBM consumer, read several times per chunk
+        # in fwd+bwd) in half width: logits then quantize at FULL
+        # magnitude before the max-subtract, so the error grows with
+        # logit scale (~0.06 per logit at |x|~16).  No training path asks
+        # for it; the benchmark's ``lower_precision`` controls do
+        # (``models/afmoe.py``, ``models/ouro.py``).
+        logits = jax.lax.dot_general(
+            h.astype(compute_dtype), emb_f32.astype(compute_dtype),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=logits_dtype or jnp.float32)
+    else:
+        logits = h @ emb_f32.T  # [chunk, V]
+    mx = jax.lax.stop_gradient(logits.max(axis=-1, keepdims=True))
+    shifted = (logits - mx).astype(jnp.float32)
+    lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
+    label_logit = jnp.take_along_axis(shifted, y[:, None], axis=-1)[:, 0]
+    return lse - label_logit
+
+
+def chunked_token_loss(hidden: jax.Array, emb: jax.Array, labels: jax.Array,
+                       *, chunk: int = 8192, compute_dtype: Any = None,
+                       logits_dtype: Any = None) -> jax.Array:
+    """The chunked LM head giving a loss a TOKEN: ``[B, T]`` float32
+    next-token cross entropies of ``hidden [B, T, E]`` against ``labels
+    [B, T]`` under the head ``emb [V, E]``, for a loss that weighs its
+    tokens itself (``models/ouro.py``: every token's loss at four exits
+    under its own exit distribution).  The scan step is
+    ``chunked_lm_loss``'s: ``chunk`` tokens at a time under
+    ``jax.checkpoint``, so the ``[chunk, V]`` logits live in one scan
+    step and are recomputed in the backward pass, whose cotangent is a
+    vector a token; HBM never holds ``[B, T, V]``.  One device (or
+    replicated operands): no ``mesh``."""
+    B, T, E = hidden.shape
+    flat_h = hidden.reshape(B * T, E)   # cast a chunk at a time, below
+    flat_y = labels.reshape(B * T)
+    n = B * T
+    pad = (-n) % chunk
+    if pad:
+        flat_h = jnp.pad(flat_h, ((0, pad), (0, 0)))
+        flat_y = jnp.pad(flat_y, (0, pad))
+    emb_f32 = emb.astype(jnp.float32)
+
+    @jax.checkpoint
+    def body(_, xs):
+        h, y = xs
+        return None, _chunk_nll(h.astype(jnp.float32), y, emb_f32,
+                                compute_dtype, logits_dtype)
+
+    _, nll = jax.lax.scan(body, None, (flat_h.reshape(-1, chunk, E),
+                                       flat_y.reshape(-1, chunk)))
+    return nll.reshape(-1)[:n].reshape(B, T)

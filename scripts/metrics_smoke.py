@@ -89,6 +89,12 @@ TRAFFIC_DEPENDENT = {
     # (family, heads, width, seq, block, window, sub, sub_forward,
     # tiles_live, tiles_cut, pairs_worked, pairs_worked_forward,
     # pairs_visible: docs/observability.md)
+    # a looped stack's exit gate likewise (models/ouro.py
+    # report_exit_stats); what loop was compiled is the `model:loop.plan`
+    # span
+    "ray_tpu_loop_exit_share",
+    "ray_tpu_loop_expected_passes",
+    "ray_tpu_loop_exit_entropy",
     "ray_tpu_moe_expert_load",
     "ray_tpu_moe_landed_share",
     "ray_tpu_moe_live_share",

@@ -6,6 +6,7 @@ on the host must be carried, not copied, any other copied once; and the
 leaves must ride the object plane out of band."""
 
 import collections
+import gc
 
 import jax
 import jax.numpy as jnp
@@ -218,6 +219,11 @@ def test_what_is_saved_is_the_tree_at_the_call(kind):
     elif kind == "jax_deleted":
         leaf.delete()
     else:
+        # on the CPU backend ``np.asarray(leaf)`` is a view of the device's
+        # buffer, ``_to_host`` leaves its views in garbage that waits for
+        # a collection, and a buffer with a view outstanding is silently
+        # not donated: collect, so that the donation below is one
+        gc.collect()
         step = jax.jit(lambda x: x + 100.0, donate_argnums=0)
         for _ in range(3):
             leaf, old = step(leaf), leaf

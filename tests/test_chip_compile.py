@@ -575,3 +575,98 @@ def test_nemotron_gradient_check_fits_beside_the_training_state(one_chip):
         f"{check / 2**30:.2f} GiB beside " \
         f"{NEMOTRON_STATE_BYTES / 2**30:.2f} GiB of state"
     print(f"nemotron gradient check: {check / 2**30:.3f} GiB")
+
+
+def _ouro_stage(**kw):
+    ouro = importlib.import_module("ray_tpu.models.ouro")
+    cfg = ouro.OuroConfig.ouro_2_6b_stage(remat="full", **kw)
+    return ouro, cfg, ouro.Ouro(cfg)
+
+
+#: bytes of ``ouro-2.6b.steady``'s training state: 509,661,185
+#: parameters, f32 weights and AdamW's two moments
+OURO_STATE_BYTES = 509_661_185 * 12
+
+
+def test_flash_compiles_at_the_ouro_cells_shapes(one_chip):
+    """One sequence of 4,096, 16 query on 16 K/V heads of 128, as
+    ``models/ouro.py`` calls it: the native-layout family (one head a
+    128-lane slab), full causal: forward, dK/dV and dQ."""
+    x = jax.ShapeDtypeStruct((1, 4096, 16, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    assert fa._nl_eligible(x, x, x)
+    compiled = jax.jit(_attn_grads("native")).lower(x, x, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+@pytest.mark.slow
+def test_ouro_stage_train_step_fits_one_v5e(one_chip):
+    """``ouro-2.6b.steady``'s step: 6 layers x 4 passes at the published
+    widths, batch 2 x 4,096, donated state.  Marked slow: it takes two
+    minutes of a file that is the suite's longest, and the benchmark
+    reads the same number on the chip (``step_hbm_gib``, 10.75)."""
+    ouro, _, model = _ouro_stage()
+    text, params, total = _compiled_step(ouro, model, 2, one_chip)
+    assert sum(a.size for a in jax.tree.leaves(params)) == 509_661_185
+    # an instruction is named after its kernel (a backward call also
+    # READS a ``_flash_nl_forward`` result: count names, not mentions)
+    calls = [line.split(" = ")[0] for line in _kernel_calls(text)]
+    named = lambda name: sum(name in call for call in calls)  # noqa: E731
+    # 6 layers x 4 passes x 2 sequences: forward twice (remat), dK/dV
+    # and dQ once
+    assert named("_flash_nl_forward") == 96
+    assert named("_flash_nl_backward") == 96
+    assert not re.search(r"f32\[(2,4095|8190|8192|32760|32768),49152\]",
+                         text)
+    assert total < 0.9 * V5E_HBM_BYTES, f"{total / 2**30:.2f} GiB"
+    print(f"ouro step: {total / 2**30:.3f} GiB")
+
+
+@pytest.mark.slow
+def test_ouro_gradient_check_fits_beside_the_training_state(one_chip):
+    """The harness's check (``benchmarks/kinds/train.py``
+    ``gradient_check``): depth 2 (2 layers x 4 passes), two sequences,
+    the program's loss and the reference's, BOTH gradients in one
+    program, while the training state is still on the chip: what decided
+    the cell's depth (a stage of 8 layers' state leaves it no room).
+    Marked slow as the cell's own step above: 155 s of compiling in the
+    file that alone decides how long the tier-1 run lasts (the driver's
+    took 1,334 s of its 1,470 with it), and every traced run of the cell
+    makes this check on the chip."""
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    reference = importlib.import_module("benchmarks.reference.ouro")
+    ouro, cfg, model = _ouro_stage(num_layers=2)
+    params = _abstract_params(model, one_chip, 2)
+    tokens = jax.ShapeDtypeStruct((2, cfg.max_seq_len), jnp.int32,
+                                  sharding=one_chip)
+    sizes = {"n_layer": 2, "n_head": cfg.num_heads, "ln_eps": cfg.rms_eps}
+
+    def error(p, t):
+        return reference.grad_error(
+            jax.grad(lambda q: ouro.loss_fn(model, q, t))(p),
+            jax.grad(lambda q: reference.loss(q, t, **sizes))(p))
+
+    compiled = _lower_as_on_tpu(jax.jit(error), (params, tokens)).compile()
+    text = compiled.as_text()
+    # an instruction is named after its kernel (a backward call also
+    # READS a ``_flash_nl_forward`` result: count names, not mentions)
+    calls = [line.split(" = ")[0] for line in _kernel_calls(text)]
+    named = lambda name: sum(name in call for call in calls)  # noqa: E731
+    # the program's half: 2 layers x 4 passes x 2 sequences, forward
+    # twice (remat), dK/dV and dQ once
+    assert named("_flash_nl_forward") == 32
+    assert named("_flash_nl_backward") == 32
+    # no [tokens, vocabulary] logits on either side: a chunk at a time
+    assert not re.search(r"f32\[(2,4095|8190|8192|32760|32768),49152\]",
+                         text)
+    assert "f32[1024,49152]" in text
+    mem = compiled.memory_analysis()
+    check = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert check + OURO_STATE_BYTES < 0.97 * V5E_HBM_BYTES, \
+        f"{check / 2**30:.2f} GiB beside " \
+        f"{OURO_STATE_BYTES / 2**30:.2f} GiB of state"
+    print(f"ouro gradient check: {check / 2**30:.3f} GiB")
