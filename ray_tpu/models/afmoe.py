@@ -152,12 +152,19 @@ def routed_plan_args(cfg, tokens: int) -> Dict[str, Any]:
     the worst-case buffer whatever the load (the rest is work of the
     live tiles); ``row_gather``: how ``rows <- tokens`` keeps to the
     live rows: ``reach``, one gather of the least of ``gather_reaches``
-    of the buffer that holds them."""
+    of the buffer that holds them.  The sums ``tokens <- rows`` on a
+    TPU: ``walk_tile`` tokens a step of the kernel that walks the
+    ``pairs`` of a call and reads a row for those that landed alone (0:
+    no tile fits these shapes, a row is gathered for every pair);
+    ``walked``: what it walks, the ``table`` ``[k, tile]`` of the pairs'
+    rows as the plan has it (no list of the landed pairs is made)."""
     return {"experts": cfg.num_experts, "held_first": cfg.experts_held[0],
             "held": cfg.experts_held[1], "top_k": cfg.top_k,
             "row_bound": tokens * cfg.top_k, "block_rows": BLOCK_ROWS,
             "buffer_passes": 0, "row_gather": "reach",
-            "gather_reaches": ",".join(f"1/{r}" for r in gm.REACHES)}
+            "gather_reaches": ",".join(f"1/{r}" for r in gm.REACHES),
+            "walk_tile": gm.walk_tile(tokens, cfg.embed_dim) or 0,
+            "pairs": tokens * cfg.top_k, "walked": "table"}
 
 
 def _rope(x: jax.Array, theta: float) -> jax.Array:
@@ -276,8 +283,8 @@ class RoutedExperts(nn.Module):
         with step.scope("moe.experts"):
             out = gm.expert_products(rows, (*into, w_down), plan)
         with step.scope("moe.combine"):
-            routed = gm.combine(out, weights, plan)
-            return routed.astype(cfg.dtype).reshape(batch, seq, embed)
+            routed = gm.combine(out, weights, plan, dtype=cfg.dtype)
+            return routed.reshape(batch, seq, embed)
 
 
 class AttentionPart(nn.Module):
@@ -490,7 +497,11 @@ def router_stats(model: nn.Module, params, tokens: jax.Array
     """What a routed layer must tell its operator, per expert layer (in
     order): ``load [L, held]`` (token, choice) pairs that chose each held
     expert; ``landed_share [L]`` of all pairs that land here (an even
-    router gives ``held / experts``); ``imbalance [L]`` largest load over
+    router gives ``held / experts``), which on a TPU is also what the
+    layer's sums over tokens COST: ``combine``, its ``d_w`` and
+    ``dispatch``'s backward read ``landed_share x pairs`` rows (a group
+    of 8 each) where they read ``pairs`` (``ops/grouped_matmul.py``
+    ``_walk_pallas``); ``imbalance [L]`` largest load over
     mean load; ``live_tiles [L]`` and ``buffer_tiles [L]``: row tiles
     that held rows, which the layer worked on, of those its worst-case
     buffers span.  For a training loop to pass to
@@ -528,7 +539,10 @@ def report_router_stats(stats: Dict[str, Any], model_name: str = "afmoe"
                         ) -> Dict[str, float]:
     """Host side: the stats as gauges (tagged ``model_name``: another
     model's module binds its own, ``models/deepseek_v3.py``), and as flat
-    scalars for ``session.report``."""
+    scalars for ``session.report``.  ``moe/h<layer>/landed_share`` is
+    the share of a call's pairs whose row the sums over tokens fetch:
+    their cost goes with it, so a chip that draws a hot expert pays
+    more there (:func:`router_stats`)."""
     import numpy as np
 
     out: Dict[str, float] = {}

@@ -44,10 +44,28 @@ time, in one of two forms:
   was slower a row, and a step with 24 such loops was scheduled into a
   gibibyte more memory: PERF.md, PR 36.)
 
-The rows of dead tiles hold whatever the memory held; nothing reads
-them: the pair passes of :func:`combine` and :func:`dispatch`'s backward
-(gathers over ``tokens x choices`` pairs, a TPU scatter-add of 10^5 rows
-serializes) read a landed pair's row, or row 0 under a mask.
+The rows of dead tiles hold whatever the memory held; nothing adds
+them.  The three sums ``tokens <- rows`` (:func:`combine`, its ``d_w``
+and :func:`dispatch`'s backward; a TPU scatter-add of 10^5 rows
+serializes, so they pull) WALK THE PAIRS THAT LANDED: a kernel over
+tiles of tokens (:func:`_walk_pallas`) that reads a tile's ``[k, tile]``
+table of the pairs' rows from SMEM, lists the landed ones in pair order
+without a branch, fetches each one's row by DMA into a ring in VMEM and
+adds it in float32, a token's pairs in the order of its choices.  It
+reads ``landed_share x pairs`` rows where a gather a choice read
+``pairs`` (7 of 8 of which, 15 of 16 at a sixteenth held, were masked
+after the read).  What a DMA can address is a whole tile of the array in
+HBM, 8 rows (Mosaic refuses a slice of a tiled dimension that is not
+whole tiles): the walk fetches the row's GROUP and adds the one row out
+of it, two-byte rows as the 32-bit words they share with their
+neighbour.  No list, no sort and no field of the plan is made for it.
+Off the TPU, and for shapes no tile of the walk fits
+(:func:`walk_tile`), the same sums are :func:`_gather_sum` and
+:func:`_gather_dots`: a gather of ``T`` rows a choice, the pairs that
+did not land masked after the read; the walk is held against them
+(``tests/test_routed_walk.py``).  The walk's results are the TOKENS'
+rows (``[T, D]``, ``[T, k]``) and never the row buffer's: the
+benchmark's readers tell kernel families apart by result shape.
 
 A width no tile divides (1856 = 2^6 x 29: its largest divisor under 512
 is 464, which is neither whole 128-lane registers nor the whole width,
@@ -70,7 +88,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.flash_attention import fit_block
+from ray_tpu.ops.flash_attention import _traced_once, fit_block
 
 
 def lane_block(width: int, block: int) -> int:
@@ -153,7 +171,7 @@ def plan_rows(expert_idx: jax.Array, first: int, held: int, *,
 
 
 # ---------------------------------------------------------------------------
-# rows <- tokens (as far as the live rows reach), tokens <- rows (the pairs)
+# rows <- tokens (as far as the live rows reach)
 # ---------------------------------------------------------------------------
 
 #: how far a gather ``rows <- tokens`` may reach, as divisors of the
@@ -195,30 +213,75 @@ def _rows_of_tokens(x: jax.Array, plan: RowPlan, weights=None) -> jax.Array:
     return jax.lax.switch(shorter, [reach(rows) for rows in reaches])
 
 
-@jax.custom_vjp
-def dispatch(x: jax.Array, plan: RowPlan) -> jax.Array:
+def dispatch(x: jax.Array, plan: RowPlan, *,
+             interpret: Optional[bool] = None) -> jax.Array:
     """``x [T, D]`` -> rows ``[M, D]``: each row its pair's token (rows
     of live tiles that hold no pair read token 0 and are never combined;
-    rows of dead tiles are not written)."""
+    rows of dead tiles are not written).  Backward: every token the sum
+    of its landed pairs' rows (:func:`_token_sums`)."""
+    return _dispatch(x, plan, _kernels(interpret))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _dispatch(x, plan, interpret):
     return _rows_of_tokens(x, plan)
 
 
-def _dispatch_fwd(x, plan):
-    return dispatch(x, plan), plan
+def _dispatch_fwd(x, plan, interpret):
+    return _dispatch(x, plan, interpret), plan
 
 
-def _dispatch_bwd(plan, g):
-    dx = _gather_sum(g, plan, None)
-    return dx.astype(g.dtype), None
+def _dispatch_bwd(interpret, plan, g):
+    return _token_sums(g, plan, None, g.dtype, interpret), None
 
 
-dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
+
+def combine(rows: jax.Array, weights: jax.Array, plan: RowPlan, *,
+            dtype=jnp.float32, interpret: Optional[bool] = None
+            ) -> jax.Array:
+    """Rows ``[M, D]`` and the pairs' weights ``[T, k]`` (f32) ->
+    ``[T, D]``: every token the weighted sum of its landed pairs' rows,
+    float32, in the order of its choices; rounded ONCE to ``dtype``
+    where that is another (the float32 sums of a call are twice the
+    rows')."""
+    return _combine(rows, weights, plan, jnp.dtype(dtype),
+                    _kernels(interpret))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _combine(rows, weights, plan, dtype, interpret):
+    return _token_sums(rows, plan, weights, dtype, interpret)
+
+
+def _combine_fwd(rows, weights, plan, dtype, interpret):
+    return (_combine(rows, weights, plan, dtype, interpret),
+            (rows, weights, plan))
+
+
+def _combine_bwd(dtype, interpret, res, g):
+    rows, weights, plan = res
+    # gathered in the rows' dtype (the float32 cotangent of a row is
+    # twice the row), each row weighted as it is gathered
+    d_rows = _rows_of_tokens(g.astype(rows.dtype), plan, weights)
+    d_w = _pair_dots(rows, plan, g, interpret)
+    return d_rows, d_w.astype(weights.dtype), None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+# ---------------------------------------------------------------------------
+# tokens <- rows: the sums over a token's landed pairs
+# ---------------------------------------------------------------------------
 
 def _gather_sum(rows, plan: RowPlan, weights):
     """``out[t] = sum_c [valid] w[t, c] * rows[pair_row[t, c]]`` in f32,
     one choice at a time (``[T, k, D]`` at once is the worst-case
-    buffer again)."""
+    buffer again): a gather of ``T`` rows a CHOICE, the pairs that did
+    not land masked after the read.  The path off the TPU, and what the
+    walk is held against."""
     tokens, k = plan.pair_row.shape
     out = jnp.zeros((tokens, rows.shape[1]), jnp.float32)
     for c in range(k):
@@ -229,34 +292,220 @@ def _gather_sum(rows, plan: RowPlan, weights):
     return out
 
 
-@jax.custom_vjp
-def combine(rows: jax.Array, weights: jax.Array, plan: RowPlan
-            ) -> jax.Array:
-    """Rows ``[M, D]`` and the pairs' weights ``[T, k]`` (f32) ->
-    ``[T, D]`` f32: every token the weighted sum of its landed pairs'
-    rows."""
-    return _gather_sum(rows, plan, weights)
-
-
-def _combine_fwd(rows, weights, plan):
-    return combine(rows, weights, plan), (rows, weights, plan)
-
-
-def _combine_bwd(res, g):
-    rows, weights, plan = res
-    k = plan.pair_row.shape[1]
-    # gathered in the rows' dtype (the float32 cotangent of a row is
-    # twice the row), each row weighted as it is gathered
-    d_rows = _rows_of_tokens(g.astype(rows.dtype), plan, weights)
-    d_w = jnp.stack([
+def _gather_dots(rows, plan: RowPlan, g):
+    """``out[t, c] = [valid] sum_d rows[pair_row[t, c], d] * g[t, d]``
+    in f32, :func:`_gather_sum`'s way."""
+    return jnp.stack([
         jnp.where(plan.pair_valid[:, c],
                   jnp.sum(rows[plan.pair_row[:, c]].astype(jnp.float32)
                           * g, axis=-1), 0.0)
-        for c in range(k)], axis=1)
-    return d_rows, d_w.astype(weights.dtype), None
+        for c in range(plan.pair_row.shape[1])], axis=1)
 
 
-combine.defvjp(_combine_fwd, _combine_bwd)
+#: rows of one tile of an array in HBM, of two- and of four-byte
+#: elements alike: the least a DMA can address (Mosaic refuses a slice
+#: of a tiled dimension that is not whole tiles)
+ROW_GROUP = 8
+#: row groups in flight a token tile
+WALK_RING = 16
+#: a landed pair in the kernel's list: ``token * _CHOICES + choice``
+_CHOICES = 16
+
+
+def walk_tile(tokens: int, width: int) -> Optional[int]:
+    """Tokens a step of the walk's grid: whole 128-lane registers of the
+    tables in SMEM (or all the tokens there are), as many as keep the
+    result's block (and the cotangent's beside it) within VMEM; None:
+    no such tile, the gathers stay."""
+    fits = [t for t in (512, 256, 128)
+            if tokens % t == 0 and t * width * 4 <= 2 ** 21]
+    if fits:
+        return fits[0]
+    return tokens if tokens % ROW_GROUP == 0 and tokens <= 1024 else None
+
+
+def _walks(rows, plan: RowPlan, interpret) -> bool:
+    """Whether the sums over tokens walk the landed pairs (a kernel) or
+    gather a row a pair: the kernels' switch, and shapes a DMA can
+    address."""
+    tokens = plan.pair_row.shape[0]
+    return interpret is not None \
+        and rows.dtype in (jnp.bfloat16, jnp.float32) \
+        and rows.shape[0] % ROW_GROUP == 0 \
+        and plan.pair_row.shape[1] <= _CHOICES \
+        and walk_tile(tokens, rows.shape[1]) is not None
+
+
+def _token_sums(rows, plan: RowPlan, weights, dtype, interpret):
+    """``[T, D]``: every token the sum of its landed pairs' rows (times
+    ``weights [T, k]`` where given), float32 in the order of its
+    choices, rounded once to ``dtype``."""
+    if not _walks(rows, plan, interpret):
+        return _gather_sum(rows, plan, weights).astype(dtype)
+    return _walk_pallas(rows, plan, weights, None, dtype, interpret)
+
+
+def _pair_dots(rows, plan: RowPlan, g, interpret):
+    """``[T, k]`` f32: every landed pair's row against its token's
+    ``g [T, D]``, in float32; a pair that did not land 0."""
+    if not _walks(rows, plan, interpret) or g.dtype not in (
+            jnp.bfloat16, jnp.float32):
+        return _gather_dots(rows, plan, g.astype(jnp.float32))
+    return _walk_pallas(rows, plan, None, g, jnp.float32, interpret)
+
+
+def _walk_kernel(some, tab, *refs, k: int, tile: int, weighted: bool,
+                 dots: bool):
+    """One tile of tokens.  ``some [1, tile]`` (SMEM): a token's landed
+    choices as bits; ``tab [k, tile]`` (SMEM): the row of a pair; then
+    ``w [k, tile]`` (SMEM, where ``weighted``), ``g [tile, D]`` (where
+    ``dots``), the rows (HBM), the result's block, and scratch: the
+    tile's landed pairs in pair order (SMEM), the ring of row groups,
+    its semaphores and (a result that is not float32) the sums.  Every
+    loop is rolled over a count read from SMEM; a landed pair costs one
+    DMA of its row's group and one register row of work."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    refs = list(refs)
+    w_ref = refs.pop(0) if weighted else None
+    g_ref = refs.pop(0) if dots else None
+    rows_ref, out_ref, landed, ring, sem, *acc = refs
+    acc_ref = acc[0] if acc else out_ref
+    # two-byte elements as the 32-bit words they lie in (a word: the
+    # same column of an even row and the next): half the rows, and no
+    # packed row is ever indexed
+    words = rows_ref.dtype.itemsize == 2
+    src = rows_ref.bitcast(jnp.uint32) if words else rows_ref
+    per = ring.shape[1]
+
+    def one_row(ref, row):
+        """Row ``row`` of ``ref`` as ``[1, D]`` float32: of a word, the
+        row's half shifted to the top IS the float32."""
+        if ref.dtype == jnp.float32:
+            return ref[pl.ds(row, 1), :]
+        if ref.dtype != jnp.uint32:
+            ref = ref.bitcast(jnp.uint32)
+        word = ref[pl.ds(row // 2, 1), :]
+        return jax.lax.bitcast_convert_type(
+            (word >> (row % 2 * 16).astype(jnp.uint32)) << 16, jnp.float32)
+
+    def find(t, n):
+        chose = some[0, t]      # bit c: the token's choice c landed
+
+        def its_pairs(n):
+            for c in range(k):  # no branch: written always, kept if landed
+                landed[n] = t * _CHOICES + c
+                n = n + ((chose >> c) & 1)
+            return n
+        return jax.lax.cond(chose != 0, its_pairs, lambda n: n, n)
+    n = jax.lax.fori_loop(0, tile, find, 0)
+
+    def group(j, row=0):
+        slot = j % WALK_RING
+        return pltpu.make_async_copy(
+            src.at[pl.ds(pl.multiple_of(row // ROW_GROUP * per, per), per)],
+            ring.at[slot], sem.at[slot])
+
+    def ask(j):
+        at = landed[j]
+        group(j, tab[at % _CHOICES, at // _CHOICES]).start()
+
+    def each(do):   # a loop's body that carries nothing
+        return lambda j, carry: (do(j), carry)[1]
+
+    jax.lax.fori_loop(0, jnp.minimum(n, WALK_RING), each(ask), 0)
+    some_rows = 16 if tile % 16 == 0 else tile
+
+    def some_of(i):
+        return pl.ds(pl.multiple_of(i * some_rows, some_rows), some_rows)
+
+    def zero(i):
+        acc_ref[some_of(i), :] = jnp.zeros((some_rows, acc_ref.shape[1]),
+                                           jnp.float32)
+    jax.lax.fori_loop(0, tile // some_rows, each(zero), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+
+    def add(j):
+        group(j).wait()         # any group's bytes: the slot's own
+        at = landed[j]
+        t, c = at // _CHOICES, at % _CHOICES
+        got = one_row(ring.at[j % WALK_RING], tab[c, t] % ROW_GROUP)
+        of = pl.ds(t, 1)
+        if dots:
+            dot = jnp.sum(got * one_row(g_ref, t), axis=1, keepdims=True)
+            acc_ref[of, :] = jnp.where(lane == c, dot, acc_ref[of, :])
+        else:
+            if weighted:
+                got = got * w_ref[c, t]
+            acc_ref[of, :] = acc_ref[of, :] + got
+
+    # two straight bodies, no branch in either: while groups remain to
+    # be asked for, a slot is filled again as soon as its row is added
+    def add_and_ask(j):
+        add(j)
+        ask(j + WALK_RING)
+
+    ahead = jnp.maximum(n - WALK_RING, 0)
+    jax.lax.fori_loop(0, ahead, each(add_and_ask), 0)
+    jax.lax.fori_loop(ahead, n, each(add), 0)
+
+    if acc:     # the sums, rounded once
+        def rounded(i):
+            out_ref[some_of(i), :] = acc_ref[some_of(i), :].astype(
+                out_ref.dtype)
+        jax.lax.fori_loop(0, tile // some_rows, each(rounded), 0)
+
+
+@_traced_once("dtype", "interpret")
+def _walk_pallas(rows, plan: RowPlan, weights, g, dtype, interpret):
+    """The walk: ``weights`` (or neither) the sums ``[T, D]`` in
+    ``dtype``, ``g`` the dots ``[T, k]``; summed in float32.  Traced and
+    lowered once for all the layer-calls of a step that run it on the
+    same shapes (a kernel's lowering is a quarter of a second, and a
+    step holds 15 to 32 calls)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    tokens, k = plan.pair_row.shape
+    width = rows.shape[1]
+    tile = walk_tile(tokens, width)
+    words = rows.dtype.itemsize == 2
+    weighted, dots = weights is not None, g is not None
+    some = jnp.sum(jnp.where(plan.pair_valid, 1 << jnp.arange(k), 0), axis=1,
+                   dtype=jnp.int32)
+    in_smem = pl.BlockSpec((k, tile), lambda i: (0, i),
+                           memory_space=pltpu.SMEM)
+    specs = [pl.BlockSpec((1, tile), lambda i: (0, i),
+                          memory_space=pltpu.SMEM), in_smem]
+    args = [some[None], plan.pair_row.astype(jnp.int32).T]
+    if weighted:
+        specs.append(in_smem)
+        args.append(weights.astype(jnp.float32).T)
+    if dots:
+        specs.append(pl.BlockSpec((tile, width), lambda i: (i, 0)))
+        args.append(g)
+    out_width = k if dots else width
+    scratch = [
+        pltpu.SMEM((tile * k,), jnp.int32),
+        pltpu.VMEM((WALK_RING, ROW_GROUP // 2 if words else ROW_GROUP,
+                    width), jnp.uint32 if words else rows.dtype),
+        pltpu.SemaphoreType.DMA((WALK_RING,))]
+    if jnp.dtype(dtype) != jnp.float32:
+        scratch.append(pltpu.VMEM((tile, out_width), jnp.float32))
+    return pl.pallas_call(
+        functools.partial(_walk_kernel, k=k, tile=tile, weighted=weighted,
+                          dots=dots),
+        grid=(tokens // tile,),
+        in_specs=specs + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((tile, out_width), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((tokens, out_width), dtype),
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="landed_rows_dot" if dots else "landed_rows_sum",
+    )(*args, rows)
 
 
 # ---------------------------------------------------------------------------

@@ -538,7 +538,9 @@ def test_moe_plan_span_says_what_was_compiled():
         # what of that buffer a layer-call still passes over whole, and
         # how the gathers keep to the live rows
         "buffer_passes": 0, "row_gather": "reach",
-        "gather_reaches": "1/8,1/4,1/2,1/1", "window": 24,
+        "gather_reaches": "1/8,1/4,1/2,1/1",
+        # the sums over tokens: a sequence of 64 is one tile of the walk
+        "walk_tile": 64, "pairs": 64 * 2, "walked": "table", "window": 24,
         "heads": 4, "kv_heads": 2, "layers": "s,s,f"}
 
 

@@ -229,6 +229,45 @@ def test_fused_expert_products_compile_at_the_cells_widths(one_chip, cell):
     assert _passes_over_the_row_buffer(text, tiles * 256) == []
 
 
+@pytest.mark.parametrize("cell", sorted(ROUTED_CELLS))
+def test_the_walks_compile_at_the_cells_shapes(one_chip, cell):
+    """The routed layer's three sums over tokens as ``RoutedExperts``
+    runs them on a TPU: ``combine`` (the weighted walk), its backward
+    (``d_w``: the walk's dots; ``d_rows`` stays a gather under a reach)
+    and ``dispatch``'s backward (the plain walk), over the worst-case
+    row buffer of one call.  Three kernel calls, each with a result of
+    the TOKENS' rows: the benchmark's readers file such a call with the
+    norms, not with the grouped products."""
+    gm = importlib.import_module("ray_tpu.ops.grouped_matmul")
+    held, hidden, _, top_k, tokens, _ = ROUTED_CELLS[cell]
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    plan = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda i: gm.plan_rows(i, 0, held, block_m=256),
+                       shape((tokens, top_k), jnp.int32)))
+    rows = plan.row_pair.shape[0]
+    assert rows == top_k * tokens + held * 256
+    assert gm.walk_tile(tokens, hidden) == (256 if hidden == 2048 else 128)
+
+    def sums(r, w, p, x):   # the sums leave in the model's dtype
+        out, vjp = jax.vjp(lambda r, w: gm._combine(
+            r, w, p, jnp.dtype(jnp.bfloat16), False), r, w)
+        return out, vjp(out), jax.vjp(
+            lambda x: gm._dispatch(x, p, False), x)[1](r)
+
+    text = jax.jit(sums).lower(
+        shape((rows, hidden)), shape((tokens, top_k), jnp.float32), plan,
+        shape((tokens, hidden))).compile().as_text()
+    calls = _kernel_calls(text)
+    assert len(calls) == 3
+    assert sum("landed_rows_sum" in _called(line) for line in calls) == 2
+    assert sum("landed_rows_dot" in _called(line) for line in calls) == 1
+    assert _kernel_results_of_rows(calls, rows) == []
+
+
 def test_fused_rmsnorm_compiles_at_llama_width(one_chip):
     x = jax.ShapeDtypeStruct((8, 2048, 4096), jnp.bfloat16,
                              sharding=one_chip)
@@ -456,6 +495,41 @@ def _kernel_calls(text):
             if "tpu_custom_call" in line and " custom-call(" in line]
 
 
+def _called(line):
+    """The instruction's own name, which holds the kernel's ``name=``
+    (``%grouped_matmul_act.7``, ``%jvp_landed_rows_sum_.1``).  The rest
+    of the line will not do: it names the operands too, and a walk reads
+    what a grouped product wrote."""
+    return line.split(" = ")[0].strip()
+
+
+def _kernel_results_of_rows(calls, rows):
+    """Kernel calls other than the grouped products with a 2-d result of
+    ``rows`` rows, the routed layer's row buffer: the benchmark's readers
+    (``benchmarks/reduce/kernels.py``) tell kernel families apart by
+    result shape and would count such a call as a grouped product, find
+    "another number of calls" and let ``gmm_roofline*`` fall silent."""
+    return [_called(line) for line in calls
+            if "grouped_matmul" not in _called(line)
+            and re.search(rf"(?:bf16|f32)\[{rows},\d+\]",
+                          line.split(" = ", 1)[1].split(" custom-call(")[0])]
+
+
+def _walks(calls, layer_calls, sums=2):
+    """The walks of a step: three a layer-call (``combine`` forward, its
+    ``d_w``, ``dispatch``'s backward: two sums and the dots; the
+    recomputed forward ends before ``combine`` where no gradient needs
+    its result), every one under the part it belongs to."""
+    walks = [line for line in calls if "landed_rows" in _called(line)]
+    dots = [line for line in walks if "landed_rows_dot" in _called(line)]
+    assert (len(dots), len(walks)) == (layer_calls,
+                                       (1 + sums) * layer_calls)
+    for line in walks:
+        part = "moe.combine" if line in dots else r"moe\.(combine|dispatch)"
+        assert re.search(rf'op_name="[^"]*/{part}/', line), line[:300]
+    assert sum("/moe.dispatch/" in line for line in walks) == layer_calls
+
+
 def _passes_over_the_row_buffer(text, rows):
     """Instructions of a compiled step that WRITE a float ``[rows,
     width]`` array, the routed layer's worst-case row buffer, other than
@@ -503,12 +577,19 @@ def test_gated_share_steps_count_their_kernels_and_pass_no_row_buffer(
             module.DeepseekV3Config.kanana_2_30b_a3b_share(remat="full"))
         batch, calls = 1, 60
     text, _, total = _compiled_step(module, model, batch, one_chip)
-    assert sum("grouped_matmul" in line
-               for line in _kernel_calls(text)) == calls
+    kernels = _kernel_calls(text)
+    assert sum("grouped_matmul" in _called(line)
+               for line in kernels) == calls
     held, _, _, top_k, tokens, _ = ROUTED_CELLS[cell]
     rows = top_k * tokens + held * 256
-    assert any(f"[{rows}," in line for line in _kernel_calls(text))
+    assert any(f"[{rows}," in line for line in kernels)
     assert _passes_over_the_row_buffer(text, rows) == []
+    # twelve grouped kernels a layer-call (Trinity's norm after the
+    # layer wants the routed sums again: its combine is recomputed); and
+    # the row buffer is no other kernel's result (the readers' trap,
+    # PERF.md section 7)
+    _walks(kernels, calls // 12, sums=3 if cell == "trinity-mini" else 2)
+    assert _kernel_results_of_rows(kernels, rows) == []
     assert total < 0.9 * V5E_HBM_BYTES, f"{total / 2**30:.2f} GiB"
 
 
@@ -519,7 +600,8 @@ def test_nemotron_share_train_step_fits_one_v5e(one_chip):
     text, params, total = _compiled_step(nh, model, 2, one_chip)
     assert sum(a.size for a in jax.tree.leaves(params)) == 666_962_944
     calls = _kernel_calls(text)
-    named = lambda name: sum(name in line for line in calls)  # noqa: E731
+    named = lambda name: sum(  # noqa: E731
+        name in _called(line) for line in calls)
     # 4 mixers x 2 sequences: forward twice (remat), backward once
     assert named("ssd_chunk_scan_bwd") == 8
     assert named("ssd_chunk_scan") - named("ssd_chunk_scan_bwd") == 16
@@ -536,6 +618,8 @@ def test_nemotron_share_train_step_fits_one_v5e(one_chip):
     # 4 expert layers x 2 sequences x 2 products x (2 forward, d lhs, d rhs)
     assert named("grouped_matmul") == 64
     assert _passes_over_the_row_buffer(text, 6 * 8192 + 8 * 256) == []
+    _walks(calls, 8)
+    assert _kernel_results_of_rows(calls, 6 * 8192 + 8 * 256) == []
     # what it took before the routed layer kept to its live rows (PR 35:
     # 11.83 GiB), and a hundredth of a GiB
     assert total < 11.84 * 2 ** 30, f"{total / 2**30:.3f} GiB"

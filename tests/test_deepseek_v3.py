@@ -263,7 +263,8 @@ def test_the_plan_spans_say_what_was_compiled():
     assert rows["moe.plan"]["args"] == {
         "experts": 8, "held_first": 2, "held": 4, "top_k": 2,
         "row_bound": 64 * 2, "block_rows": 8, "buffer_passes": 0,
-        "row_gather": "reach", "gather_reaches": "1/8,1/4,1/2,1/1"}
+        "row_gather": "reach", "gather_reaches": "1/8,1/4,1/2,1/1",
+        "walk_tile": 64, "pairs": 64 * 2, "walked": "table"}
 
 
 def test_the_scopes_name_the_kernel_call_and_the_up_projection():

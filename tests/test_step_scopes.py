@@ -24,6 +24,7 @@ from flax.core import meta
 from benchmarks.reduce import scopes
 from ray_tpu.core import telemetry
 from ray_tpu.models import afmoe, deepseek_v3, gpt2, nemotron_h, step
+from ray_tpu.ops import grouped_matmul as gm
 
 MODELS = {
     "gpt2": (gpt2, gpt2.GPT2Config, gpt2.GPT2),
@@ -54,26 +55,30 @@ BEFORE = {
     ("gpt2", "full"): (
         "fa3f37a661e8da6623e537f659738ddb6890262cdc0db8c8b090190b3ae69d99",
         "8673451a825d006b3c71617386c7388d4450a1a51087cba9af55ae5eebf1757a"),
+    # the three routed models re-pinned at PR 48: on a TPU the routed
+    # layer's sums over tokens are kernel calls (``ops/grouped_matmul.py``
+    # ``_walk_pallas``) where they were a gather a choice, and
+    # ``combine`` rounds its sums itself; the parameter trees are what
+    # they were, and GPT-2 runs none of it
     ("afmoe", ""): (
-        "f7806bd36c8bcb4230cb373c974270a07fdac349f5d70b6272179d99d1f89e5f",
+        "38a0c3242ae259e8589691ca70c0d7498618c7110a5a467ba460f6a2b22cd443",
         "73a54f273ca8d1cab30e4c5907da0f975cba6a8dcc30a4dda90aad118cc3b47d"),
     ("afmoe", "full"): (
-        "49614d4b0c051e67411235986fae522c287b284e64ed1c2650b59a96f2528168",
+        "18f70711abfa4da4fe8523d6cc67ea4da421318d8961b2a46d61d8668bd29b2e",
         "73a54f273ca8d1cab30e4c5907da0f975cba6a8dcc30a4dda90aad118cc3b47d"),
     ("deepseek_v3", ""): (
-        "cc33485479b0b0d7b6ad2074cdb88145a5af1b0a2a3c91555b39c12e5cf87ade",
+        "8f40e4af0b454b125be74d905803a4efd3492218bc9fa42bafa494f87017acba",
         "80259fb632cdea7eb743ab44b67d71fd30d782bcfb29589957d50eecca4b4839"),
     ("deepseek_v3", "full"): (
-        "d70c2e9116785efd8ad32c7126f8e10a25e956425ea09f10c1b1027e21515287",
+        "24ffcd5a6b950efccd4fecb64282cee7c749c69cbd8f7c80b4da0ce79250b85f",
         "80259fb632cdea7eb743ab44b67d71fd30d782bcfb29589957d50eecca4b4839"),
-    # re-pinned at PR 45: the mixer's convolution is two kernel calls
-    # (``ops/short_conv.py``) where it was XLA's passes; the parameter
-    # tree is what it was, and the three models above do not run it
+    # re-pinned at PR 45 too: the mixer's convolution is two kernel
+    # calls (``ops/short_conv.py``) where it was XLA's passes
     ("nemotron_h", ""): (
-        "34d2bbfb40cd0fd2421cac7adfb8a350e65ecae44a9574ad5bed62b8bd8a7061",
+        "4d2abd86e95bc04f5eba42cc3f9e5428a45aef81f47a97a7facc952d8168ad31",
         "dc97618ee45dda61ed25a95141a3b81a3c70d427ead3341d15b02ac03fd1409e"),
     ("nemotron_h", "full"): (
-        "5800c87db525945e397adea40c924c2f43877f2a3332b0bde5bc08b8f384c918",
+        "a7a6719bd8abbfdc51bb3b3b60c637e1c1a1d955df6b398056b6beb69af29e8f",
         "dc97618ee45dda61ed25a95141a3b81a3c70d427ead3341d15b02ac03fd1409e"),
 }
 
@@ -88,9 +93,18 @@ _PLUMBING = {"convert", "constant", "bitcast", "copy", "tuple",
 
 
 @functools.lru_cache(maxsize=None)
-def compiled(name):
+def compiled(name, walking=False):
     """``(instructions [(opcode, op_name)], the trace's step.scopes
-    rows)`` of the model's tiny step under ``remat="full"``."""
+    rows)`` of the model's tiny step under ``remat="full"``.
+    ``walking``: the routed layer's sums over tokens as on a TPU, the
+    walk of the landed pairs, its kernel through the interpreter (whose
+    ops are then the program's own, under the names the trace gave)."""
+    if walking:
+        walk = gm._walk_pallas
+        with mock.patch.object(gm, "_walks", lambda *_: True), \
+                mock.patch.object(gm, "_walk_pallas", lambda *a:
+                                  walk(*a[:-1], True)):
+            return compiled.__wrapped__(name)
     module, config, model_cls = MODELS[name]
     cfg = config.tiny(remat="full")
     model = model_cls(cfg)
@@ -202,6 +216,45 @@ def test_the_plan_is_not_under_the_router(name):
     assert [n for _, n in instructions if "transpose(" in n
             and "rematted_computation" not in n
             and scopes.part(n, parts) == "moe.dispatch"]
+
+
+@pytest.mark.parametrize("name", sorted(set(MODELS) - {"gpt2"}))
+def test_every_op_of_the_walk_sits_under_combine_or_dispatch(name):
+    """The step whose sums over tokens walk the landed pairs (a TPU's)
+    against the step that gathers: what the walk brings, the kernel's
+    body and the table it is given, is all under ``moe.combine``
+    (forward, and ``d_w`` in the backward pass) and ``moe.dispatch``
+    (backward); under every other part, and under none, there is no op
+    more."""
+    parts = parts_of(name)
+
+    def by_part(walking):
+        counts = {}
+        # (the gathers' program under the key the other tests hold it by)
+        for op, n in (compiled(name, True) if walking else compiled(name))[0]:
+            if op not in _PLUMBING:
+                key = (scopes.part(n, parts), scopes.phase(n))
+                counts[key] = counts.get(key, 0) + 1
+        return counts
+
+    gathers, walks = by_part(False), by_part(True)
+    for key in (("moe.combine", "forward"), ("moe.combine", "backward"),
+                ("moe.dispatch", "backward")):
+        assert walks[key] > gathers[key] + 100, key
+    # no part but those two gains an op (the CPU's own plumbing around
+    # them may go: fewer, never more); under NO part a handful at most:
+    # a fusion the CPU makes at a ``checkpoint``'s edge is named after
+    # the edge (``h0/remat2``), two a layer where the edge's neighbour
+    # became a kernel's result
+    assert set(walks) == set(gathers)
+    grew = {key for key in walks if walks[key] > gathers[key]}
+    assert {part for part, _ in grew} - {None} == {"moe.combine",
+                                                   "moe.dispatch"}
+    bare = [sum(v for (part, _), v in c.items() if part is None)
+            for c in (gathers, walks)]
+    assert bare[1] <= bare[0] + 8 and bare[1] < 0.02 * sum(walks.values())
+    work = [n for op, n in compiled(name, True)[0] if op not in _PLUMBING]
+    assert not [n for n in work if len(all_parts(n, parts)) > 1]
 
 
 @pytest.mark.parametrize("name,remat", sorted(BEFORE))
