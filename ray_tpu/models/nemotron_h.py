@@ -6,7 +6,8 @@ The stack is a PATTERN of layer kinds (``hybrid_override_pattern``), one
 letter a layer, and every layer is ONE part behind its own RMS norm and
 residual, ``x = x + part(norm(x))``:
 
-* ``M``, a Mamba-2 mixer: ``[z | u | dt_raw] = W_in h``; a causal
+* ``M``, a Mamba-2 mixer: ``[z | u | dt_raw] = W_in h`` (one weight, a
+  product a part: :class:`_SplitDense`); a causal
   depthwise convolution of ``conv`` taps with bias over ``u``, then
   ``silu``; ``u`` split into ``xs [T, H, P]``, ``B`` and ``C [T, G, N]``;
   ``dt = softplus(dt_raw + dt_bias)``, ``A = -exp(A_log)``; the selective
@@ -35,6 +36,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import math
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -55,7 +57,7 @@ from ray_tpu.models.afmoe import (  # noqa: F401 — this model's step too
 )
 from ray_tpu.models.llama import RMSNorm
 from ray_tpu.ops.flash_attention import flash_attention
-from ray_tpu.ops.fused import _rmsnorm_ref
+from ray_tpu.ops import gate_norm
 from ray_tpu.ops.short_conv import short_conv
 from ray_tpu.ops.ssd import ssd
 
@@ -167,12 +169,16 @@ class NemotronHConfig:
         return math.gcd(self.num_heads, self.num_kv_heads)
 
     def plan_args(self) -> Dict[str, Any]:
-        """What stack was compiled, for the ``hybrid.plan`` span."""
+        """What stack was compiled, for the ``hybrid.plan`` span
+        (``gate_norm``: the form a sequence's shapes chose)."""
         kinds = self.layer_kinds()
+        tile = gate_norm.tiles(self.max_seq_len, self.ssm_inner,
+                               self.ssm_groups, self.dtype)
         return {"pattern": kinds, "mixers": kinds.count("M"),
                 "experts": kinds.count("E"), "attention": kinds.count("*"),
                 "conv": self.conv, "norm_group":
                 self.ssm_inner // self.ssm_groups,
+                "gate_norm": "kernel" if tile else "jnp",
                 "expert_form": self.expert_form,
                 "experts_held": self.experts_held[1]}
 
@@ -209,18 +215,17 @@ def causal_conv(u: jax.Array, w: jax.Array, bias: jax.Array) -> jax.Array:
 
 def gated_group_norm(y, z, scale, groups: int, eps: float) -> jax.Array:
     """``RMSNorm(y * silu(z))`` in ``groups`` groups of the last axis,
-    one learned scale over all of it; float32."""
-    g = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
-    parts = g.reshape(*g.shape[:-1], groups, -1)
-    return _rmsnorm_ref(parts, scale.reshape(groups, -1), eps).reshape(
-        g.shape)
+    one learned scale over all of it.  Gate, statistics and scale in
+    float32, the result in ``y``'s dtype: one pass over the rows where
+    the shapes are the kernels' (``ops/gate_norm.py``)."""
+    return gate_norm.gate_norm(y, z, scale, groups, eps)
 
 
 class MixerPart(nn.Module):
     """``x + mixer(norm(x))``, a Mamba-2 mixer: five of the step's parts
     (``models/step.py``), and every op in one of them: the norm and the
-    split are ``ssm.in_proj``'s, the step sizes and decays the scan's,
-    the residual add ``ssm.out_proj``'s."""
+    three products are ``ssm.in_proj``'s, the step sizes and decays the
+    scan's, the residual add ``ssm.out_proj``'s."""
     config: NemotronHConfig
 
     @nn.compact
@@ -236,10 +241,8 @@ class MixerPart(nn.Module):
 
         with step.scope("ssm.in_proj"):
             h = RMSNorm(cfg.rms_eps, name="norm")(x)
-            zxd = _dense(cfg, inner + cfg.conv_dim + heads, "in_proj",
-                         ("embed", "mlp"))(h)
-            z, u, dt_raw = jnp.split(zxd, [inner, inner + cfg.conv_dim],
-                                     axis=-1)
+            z, u, dt_raw = _SplitDense(cfg, (inner, cfg.conv_dim, heads),
+                                       name="in_proj")(h)
         with step.scope("ssm.conv"):
             c = causal_conv(
                 u, vector("conv_kernel", nn.initializers.normal(0.02),
@@ -264,6 +267,30 @@ class MixerPart(nn.Module):
         with step.scope("ssm.out_proj"):
             return x + _dense(cfg, cfg.embed_dim, "out_proj",
                               ("mlp", "embed"))(g)
+
+
+class _SplitDense(nn.Module):
+    """``in_proj``: one weight ``kernel [embed, sum(widths)]`` as
+    ``nn.Dense`` holds it, one product a part of its columns.  The gate,
+    the convolution's input and the step sizes then leave as arrays of
+    their own: a kernel's operand has to, and cut out of ONE result each
+    is a copy of its bytes held beside the whole (PERF.md, PR 49)."""
+    config: NemotronHConfig
+    widths: Tuple[int, ...]
+
+    @nn.compact
+    def __call__(self, h: jax.Array) -> List[jax.Array]:
+        cfg = self.config
+        kernel = self.param("kernel", nn.with_partitioning(
+            nn.initializers.normal(0.02), ("embed", "mlp")),
+            (h.shape[-1], sum(self.widths)), cfg.param_dtype)
+        kernel = kernel.astype(cfg.dtype)
+        # slices, not ``jnp.split``: under that one's transpose the
+        # compiler keeps a copy of every mixer's weight and both its
+        # moments for the whole step (+0.86 GiB in the Nemotron cell)
+        starts = itertools.accumulate(self.widths[:-1], initial=0)
+        return [h @ kernel[:, start:start + width]
+                for start, width in zip(starts, self.widths)]
 
 
 class _GateScale(nn.Module):

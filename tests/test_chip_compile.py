@@ -448,6 +448,40 @@ def test_short_conv_kernels_compile_at_the_nemotron_cells_shapes(
         assert "f32[1,8192,6144]" not in text
 
 
+@pytest.mark.parametrize("dtype,rows", [(jnp.bfloat16, 512),
+                                        (jnp.float32, 256)])
+def test_gate_norm_kernels_compile_at_the_nemotron_cells_shapes(
+        one_chip, dtype, rows):
+    """One sequence of 8,192 over the mixer's 4,096 inner channels in 8
+    groups of 512: the forward kernel and the backward one
+    (``_gate_norm``: the public wrapper asks the backend), in the cell's
+    bfloat16 and in float32 (half the rows a tile).  No array of the
+    tokens by group in HBM, and the FIRST result of both calls 2-d."""
+    norm = importlib.import_module("ray_tpu.ops.gate_norm")
+
+    def shape(dims, kind):
+        return jax.ShapeDtypeStruct(dims, kind, sharding=one_chip)
+
+    y = shape((1, 8192, 4096), dtype)
+    tile = norm.tiles(8192, 4096, 8, dtype)
+    assert tile == (rows, 512)
+
+    def grads(*a):
+        def loss(*a):
+            g = norm._gate_norm(*a, 8, 1e-5, tile, False).astype(jnp.float32)
+            return (g * g).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(*a)
+
+    text = jax.jit(grads).lower(y, y, shape((4096,), jnp.float32)
+                                ).compile().as_text()
+    calls = _kernel_calls(text)
+    assert [_called(line).count("gate_norm") for line in calls] == [1, 1]
+    kind = "bf16" if dtype == jnp.bfloat16 else "f32"
+    for line in calls:
+        assert re.search(rf"= \(?{kind}\[8192,4096\]", line), line[:200]
+    assert "[8192,8,512]" not in text
+
+
 def _nemotron_share(**kw):
     nh = importlib.import_module("ray_tpu.models.nemotron_h")
     cfg = nh.NemotronHConfig.nemotron_3_nano_30b_a3b_share(remat="full",
@@ -615,6 +649,17 @@ def test_nemotron_share_train_step_fits_one_v5e(one_chip):
             assert re.search(r"= \(?bf16\[8192,6144\]", line), line[:200]
     # no padded float32 copy of a mixer's ``u``
     assert not re.search(r"f32\[(1,)?8195,6144\]", text)
+    # the mixers' gated norm likewise (PR 49), 2-d [8192, inner], and no
+    # float32 array by group for its statistics
+    assert named("gate_norm_bwd") == 8
+    assert named("gate_norm") - named("gate_norm_bwd") == 16
+    for line in calls:
+        if "gate_norm" in _called(line):
+            assert re.search(r"= \(?bf16\[8192,4096\]", line), line[:200]
+    assert "f32[8192,8,512]" not in text
+    # ``z``, ``u`` and the step sizes leave ``in_proj`` as arrays of their
+    # own: no ``[8192, 4096 + 6144 + 64]`` one to copy them out of
+    assert "8192,10304]" not in text
     # 4 expert layers x 2 sequences x 2 products x (2 forward, d lhs, d rhs)
     assert named("grouped_matmul") == 64
     assert _passes_over_the_row_buffer(text, 6 * 8192 + 8 * 256) == []
@@ -652,6 +697,7 @@ def test_nemotron_gradient_check_fits_beside_the_training_state(one_chip):
     compiled = _lower_as_on_tpu(jax.jit(error), (params, tokens)).compile()
     assert "ssd_chunk_scan_bwd" in compiled.as_text()
     assert "short_conv_bwd" in compiled.as_text()
+    assert "gate_norm_bwd" in compiled.as_text()
     mem = compiled.memory_analysis()
     check = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
              + mem.output_size_in_bytes)

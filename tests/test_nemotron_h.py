@@ -409,14 +409,56 @@ def test_the_plan_spans_say_what_was_compiled():
     assert set(rows) == {"hybrid.plan", "moe.plan"}
     assert rows["hybrid.plan"]["args"] == {
         "pattern": "EMEM*", "mixers": 2, "experts": 2, "attention": 1,
-        "conv": 4, "norm_group": 32, "expert_form": "relu2",
-        "experts_held": 4}
+        "conv": 4, "norm_group": 32, "gate_norm": "jnp",
+        "expert_form": "relu2", "experts_held": 4}
     assert rows["moe.plan"]["args"] == {
         "experts": 8, "held_first": 2, "held": 4, "top_k": 2,
         "row_bound": 64 * 2, "block_rows": 8, "buffer_passes": 0,
         "row_gather": "reach", "gather_reaches": "1/8,1/4,1/2,1/1",
         "walk_tile": 64, "pairs": 64 * 2, "walked": "table",
         "form": "relu2"}
+
+
+@pytest.mark.parametrize("config,kw,form", [
+    ("tiny", {}, "jnp"),                        # a group of 32 lanes
+    ("nemotron_3_nano_30b_a3b_share", {}, "kernel"),   # 8 groups of 512
+    ("nemotron_3_nano_30b_a3b_share", {"dtype": jnp.float32}, "kernel"),
+    ("nemotron_3_nano_30b_a3b_share", {"max_seq_len": 8200}, "jnp"),
+])
+def test_the_plan_names_the_gate_norm_s_form_by_the_shapes(config, kw, form):
+    cfg = getattr(nh.NemotronHConfig, config)(**kw)
+    assert cfg.plan_args()["gate_norm"] == form
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-6),
+                                       (jnp.bfloat16, 1e-2)])
+def test_in_proj_is_one_weight_and_a_product_a_part(dtype, tol):
+    """``_SplitDense``: ``nn.Dense``'s one ``kernel [embed, z + u + dt]``
+    in the tree, each part its own product of the kernel's columns, equal
+    to the parts cut out of the one product, and no array of all the
+    columns traced for the activations."""
+    cfg = nh.NemotronHConfig.tiny(dtype=dtype)
+    widths = (cfg.ssm_inner, cfg.conv_dim, cfg.ssm_heads)
+    part = nh._SplitDense(cfg, widths)
+    h = jax.random.normal(jax.random.PRNGKey(0), (1, 16, cfg.embed_dim)
+                          ).astype(dtype)
+    params = part.init(jax.random.PRNGKey(1), h)["params"]
+    kernel = meta.unbox(params)["kernel"]
+    assert list(params) == ["kernel"]
+    assert kernel.shape == (cfg.embed_dim, sum(widths))
+    assert kernel.dtype == cfg.param_dtype
+    assert params["kernel"].names == ("embed", "mlp")
+    got = part.apply({"params": params}, h)
+    assert [g.shape[-1] for g in got] == list(widths)
+    assert all(g.dtype == dtype for g in got)
+    whole = (h @ kernel.astype(dtype)).astype(jnp.float32)
+    want = jnp.split(whole, [widths[0], widths[0] + widths[1]], axis=-1)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(w),
+                                   rtol=tol, atol=tol)
+    traced = str(jax.make_jaxpr(lambda p: part.apply({"params": p}, h))(
+        params))
+    assert f"16,{sum(widths)}]" not in traced
 
 
 def test_the_scopes_name_the_mixer_s_parts():

@@ -73,12 +73,15 @@ BEFORE = {
         "24ffcd5a6b950efccd4fecb64282cee7c749c69cbd8f7c80b4da0ce79250b85f",
         "80259fb632cdea7eb743ab44b67d71fd30d782bcfb29589957d50eecca4b4839"),
     # re-pinned at PR 45 too: the mixer's convolution is two kernel
-    # calls (``ops/short_conv.py``) where it was XLA's passes
+    # calls (``ops/short_conv.py``) where it was XLA's passes; and at
+    # PR 49: ``in_proj`` is a product a part of its one weight's columns
+    # (``_SplitDense``), and the gated norm rounds its own result
+    # (``ops/gate_norm.py``; ``tiny``'s groups of 32 take its ``jnp`` form)
     ("nemotron_h", ""): (
-        "4d2abd86e95bc04f5eba42cc3f9e5428a45aef81f47a97a7facc952d8168ad31",
+        "9e35f1b30dfe192a0637f1e05dab4c628ef092d15af655d44b0e69a25e74b99e",
         "dc97618ee45dda61ed25a95141a3b81a3c70d427ead3341d15b02ac03fd1409e"),
     ("nemotron_h", "full"): (
-        "a7a6719bd8abbfdc51bb3b3b60c637e1c1a1d955df6b398056b6beb69af29e8f",
+        "60227b7f7aaac727061f8a899a65f96d8078a05ab18e7cad3d8deada2734e28d",
         "dc97618ee45dda61ed25a95141a3b81a3c70d427ead3341d15b02ac03fd1409e"),
 }
 
