@@ -6,10 +6,8 @@
 #   make tsan   — ThreadSanitizer build of the concurrency stress
 #                 harness (src/store_stress.cc) + run
 #   make asan   — AddressSanitizer+UBSan build + run
-.PHONY: all native check check-fast test chaos bench-transfer bench-serve \
-	bench-serve-sharded bench-controlplane bench-store \
-	bench-ha bench-data metrics-smoke metrics-history-smoke \
-	postmortem-smoke tsan asan sanitize clean
+.PHONY: all native check check-fast test chaos metrics-smoke \
+	metrics-history-smoke postmortem-smoke tsan asan sanitize clean
 
 CXX ?= g++
 CXXFLAGS = -std=c++17 -O1 -g -fno-omit-frame-pointer -Wall -Wextra
@@ -73,52 +71,6 @@ chaos: native
 	  tests/test_fair_queue.py tests/test_autoscaler_chaos.py \
 	  -q -m "slow or not slow" \
 	  -p no:cacheprovider -p no:randomly
-
-# Quick transfer-plane microbench (broadcast + multi-client put) with a
-# one-line JSON delta vs the newest BENCH_r*.json baseline artifact.
-bench-transfer: native
-	JAX_PLATFORMS=cpu python scripts/bench_transfer.py
-
-# Sustained-load serving bench: continuous-batching QPS/p50/p99 vs
-# max_batch_size=1, plus 2x-overload goodput with 429 shedding on vs
-# off; one-line JSON delta vs the newest BENCH_r*.json serve rows.
-bench-serve: native
-	JAX_PLATFORMS=cpu python scripts/bench_serve.py
-
-# Sharded-serving bench: gang-replica QPS/chip vs single-chip at equal
-# per-chip batch, decode-step latency vs shard count 1/2/4, KV page
-# occupancy, and prefill/decode disaggregation (short-request p99
-# under a long-prompt barrage, unified vs disaggregated); one-line
-# JSON delta vs the newest BENCH_r*.json rows (docs/serving.md).
-bench-serve-sharded: native
-	JAX_PLATFORMS=cpu python scripts/bench_serve_sharded.py
-
-# Control-plane bench: actor-storm creation rate (many_actors row),
-# create+destroy churn, PG churn, and lease-grant p99 flatness 1 node
-# vs 4; one-line JSON delta vs the newest BENCH_r*.json rows.
-bench-controlplane: native
-	JAX_PLATFORMS=cpu python scripts/bench_controlplane.py
-
-# Object-store microbench: 1/2/4/8-writer put-bandwidth sweep on the
-# sharded arena plus a larger-than-arena put/get round through the
-# spill tier; one-line JSON delta vs the newest BENCH_r*.json rows.
-bench-store: native
-	JAX_PLATFORMS=cpu python scripts/bench_store.py
-
-# Streaming data-plane bench: ingest-overlapped GPT-2-style train loop
-# (iter_batches(streaming=True), dataset ~1.5x the arena) vs the
-# materialize-then-train baseline; reports tokens/s both ways, their
-# ratio, the streaming ingest gap %, and peak arena fraction; one-line
-# JSON delta vs the newest BENCH_r*.json rows (docs/data.md).
-bench-data: native
-	JAX_PLATFORMS=cpu python scripts/bench_data.py
-
-# HA control-plane bench: SIGKILL the GCS mid-fleet-creation-storm
-# under serve load, measure kill -> all-actors-ALIVE reconvergence and
-# serve p99 through the outage (zero failed requests required);
-# one-line JSON delta vs the newest BENCH_r*.json rows (docs/ha.md).
-bench-ha: native
-	JAX_PLATFORMS=cpu python scripts/bench_ha.py
 
 # Boot a mini-cluster, scrape dashboard /metrics, and diff the exported
 # ray_tpu_* series list against scripts/metrics_golden.txt (catches

@@ -63,9 +63,9 @@ class NodeKiller:
 class HeadKiller:
     """Kill-mid-storm tooling (docs/ha.md): SIGKILLs the HEAD node the
     moment a driver-observable condition holds — e.g. "the GCS has
-    acked at least K registrations of my fleet" — so chaos tests and
-    ``bench_ha.py`` land the kill deterministically *inside* a
-    registration storm instead of sleeping and hoping.
+    acked at least K registrations of my fleet" — so chaos tests land
+    the kill deterministically *inside* a registration storm instead of
+    sleeping and hoping.
 
     The trigger runs on a watcher thread polling ``predicate()`` (any
     callable; typically a closure over ``gcs_call("debug_state")`` or

@@ -79,6 +79,7 @@ import numpy as np
 from jax import lax
 
 from ray_tpu.core import telemetry
+from ray_tpu.ops._kernel import fit_block, kernel_mode, traced_once
 
 NEG_INF = -1e30
 
@@ -93,17 +94,6 @@ DEFAULT_BLOCK = 1024
 CUT_BLOCKS = 4
 CUT_BLOCKS_FORWARD = 8
 CUT_MIN = 128
-
-
-def _traced_once(*static):
-    """``jax.jit`` over a function that builds kernel calls: a model
-    calls it once a layer (and again under ``remat``) with the same
-    shapes, and each call would trace its kernel bodies afresh, some
-    thousand jnp operations of Python a layer.  Under an inner ``jit``
-    the first call's jaxpr serves the others and lowers to ONE function
-    that every layer calls; XLA inlines it, so the step's program is
-    the one it was."""
-    return functools.partial(jax.jit, static_argnames=static)
 
 
 def _clamp_k_tile(j, i, block_q: int, block_k: int,
@@ -507,7 +497,7 @@ def _kv_head(h, group: int):
     return h if group == 1 else h // group
 
 
-@_traced_once("causal", "scale", "block_q", "block_k", "interpret",
+@traced_once("causal", "scale", "block_q", "block_k", "interpret",
               "out_dtype", "window")
 def _flash_forward(q, k, v, causal: bool, scale: float,
                    block_q: int, block_k: int, interpret: bool,
@@ -680,7 +670,7 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
 
 
-@_traced_once("causal", "scale", "block_q", "block_k", "interpret",
+@traced_once("causal", "scale", "block_q", "block_k", "interpret",
               "grad_dtype", "window")
 def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                     interpret, grad_dtype=None, delta=None,
@@ -919,7 +909,7 @@ def _fa_nl_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             lse_ref[:] = jnp.concatenate(lses, axis=1).astype(jnp.float32)
 
 
-@_traced_once("causal", "scale", "block_q", "block_k", "interpret",
+@traced_once("causal", "scale", "block_q", "block_k", "interpret",
               "out_dtype", "window")
 def _flash_nl_forward(q, k, v, causal: bool, scale: float,
                       block_q: int, block_k: int, interpret: bool,
@@ -1124,7 +1114,7 @@ def _fa_nl_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
 
 
-@_traced_once("causal", "scale", "block_q", "block_k", "interpret",
+@traced_once("causal", "scale", "block_q", "block_k", "interpret",
               "grad_dtype", "window")
 def _flash_nl_backward(q, k, v, out, lse, g, causal, scale, block_q,
                        block_k, interpret, grad_dtype=None, delta=None,
@@ -1338,7 +1328,7 @@ def _mla_blocks(q, k, block_q: int, block_k: int):
     return block_q, block_k
 
 
-@_traced_once("causal", "scale", "block_q", "block_k", "interpret")
+@traced_once("causal", "scale", "block_q", "block_k", "interpret")
 def _flash_mla_forward(q, k, k_rope, v, causal: bool, scale: float,
                        block_q: int, block_k: int, interpret: bool):
     from jax.experimental import pallas as pl
@@ -1495,7 +1485,7 @@ def _fa_mla_bwd_dq_kernel(q_ref, k_ref, r_ref, v_ref, do_ref, lse_ref,
         dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
 
 
-@_traced_once("causal", "scale", "block_q", "block_k", "interpret")
+@traced_once("causal", "scale", "block_q", "block_k", "interpret")
 def _flash_mla_backward(q, k, k_rope, v, out, lse, g, causal, scale,
                         block_q, block_k, interpret):
     from jax.experimental import pallas as pl
@@ -1647,15 +1637,6 @@ def _flash_chunk_bwd(q, k, v, out, lse, g, causal: bool, scale: float,
                            else delta.transpose(0, 2, 1)[..., None])
 
 
-def fit_block(seq: int, block: int) -> int:
-    """Largest divisor of ``seq`` that is <= ``block`` (the pallas grids
-    need the sequence to divide into whole tiles)."""
-    for d in range(min(block, seq), 0, -1):
-        if seq % d == 0:
-            return d
-    return 1
-
-
 def kernel_block_for(seq: int, block: int = DEFAULT_BLOCK):
     """Fitted block size when ``seq`` divides into sublane-aligned tiles
     big enough for the flash kernels to pay off, else ``None`` — the
@@ -1765,12 +1746,11 @@ def _latent_attention(q, k, k_rope, v, causal, scale, block_q, block_k,
     if window is not None or native or (mesh is not None and mesh.size > 1):
         raise ValueError("k_rope= runs the head-major kernels on one "
                          "device, with no window")
+    interpret = kernel_mode(interpret)
     if interpret is None:
-        if jax.default_backend() != "tpu":
-            whole = jnp.concatenate(
-                [k, jnp.broadcast_to(k_rope, (*k.shape[:3], rope))], -1)
-            return _attention_reference(q, whole, v, causal, scale)
-        interpret = False
+        whole = jnp.concatenate(
+            [k, jnp.broadcast_to(k_rope, (*k.shape[:3], rope))], -1)
+        return _attention_reference(q, whole, v, causal, scale)
     block_q = DEFAULT_BLOCK if block_q is None else block_q
     block_k = DEFAULT_BLOCK if block_k is None else block_k
     with telemetry.span("ops", "flash.plan", **_plan_args(
@@ -1849,11 +1829,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         raise ValueError(
             f"native-layout flash attention needs head_dim in (64, 128) "
             f"and heads divisible by 128//head_dim; got {q.shape}")
-    backend = jax.default_backend()
+    interpret = kernel_mode(interpret)
     if interpret is None:
-        if backend != "tpu":
-            return _attention_reference(q, k, v, causal, scale, window)
-        interpret = False
+        return _attention_reference(q, k, v, causal, scale, window)
     if mesh is not None and mesh.size > 1:
         from jax.sharding import PartitionSpec as P
 

@@ -14,6 +14,8 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops._kernel import kernel_mode
+
 
 def _rmsnorm_ref(x, weight, eps):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
@@ -75,11 +77,9 @@ _rmsnorm.defvjp(_rmsnorm_fwd, _rmsnorm_bwd)
 
 def fused_rmsnorm(x: jax.Array, weight: jax.Array, *, eps: float = 1e-6,
                   interpret: Optional[bool] = None) -> jax.Array:
-    backend = jax.default_backend()
+    interpret = kernel_mode(interpret)
     if interpret is None:
-        if backend != "tpu":
-            return _rmsnorm_ref(x, weight, eps)
-        interpret = False
+        return _rmsnorm_ref(x, weight, eps)
     return _rmsnorm(x, weight, eps, interpret)
 
 

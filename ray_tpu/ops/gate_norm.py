@@ -53,7 +53,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.flash_attention import _traced_once
+from ray_tpu.ops._kernel import fold8, kernel_mode, traced_once
 from ray_tpu.ops.fused import _rmsnorm_ref
 
 #: rows worked at a time inside a tile
@@ -131,15 +131,6 @@ def _forward_kernel(y_ref, z_ref, scale_ref, out_ref, *, width: int,
             out_ref[here] = (g * inv * scale).astype(out_ref.dtype)
 
 
-def _fold(x):
-    """``[n, lanes]`` summed to ``[8, lanes]``: whole registers added,
-    nothing across sublanes."""
-    out = x[0:8]
-    for r in range(8, x.shape[0], 8):
-        out = out + x[r:r + 8]
-    return out
-
-
 def _backward_kernel(y_ref, z_ref, d_ref, scale_ref, dy_ref, dz_ref,
                      dscale_ref, sums_ref, *, width: int, eps: float):
     """``sums_ref [8, lanes]`` float32: ``d scale`` of this block of
@@ -170,7 +161,7 @@ def _backward_kernel(y_ref, z_ref, d_ref, scale_ref, dy_ref, dz_ref,
             dy_ref[here] = (dg * gate).astype(dy_ref.dtype)
             dz_ref[here] = (dg * y * (sig * (1.0 + z * (1.0 - sig)))
                             ).astype(dz_ref.dtype)
-            sums = sums + _fold(d * n)
+            sums = sums + fold8(d * n)
         sums_ref[:, cols] = sums
 
     @pl.when(t == pl.num_programs(1) - 1)
@@ -197,7 +188,7 @@ def _params():
         "parallel", "arbitrary"))
 
 
-@_traced_once("groups", "eps", "tile", "interpret")
+@traced_once("groups", "eps", "tile", "interpret")
 def _forward(y, z, scale, groups: int, eps: float, tile: Tiles,
              interpret: bool):
     from jax.experimental import pallas as pl
@@ -217,7 +208,7 @@ def _forward(y, z, scale, groups: int, eps: float, tile: Tiles,
     return out.reshape(y.shape)
 
 
-@_traced_once("groups", "eps", "tile", "interpret")
+@traced_once("groups", "eps", "tile", "interpret")
 def _backward(y, z, d, scale, groups: int, eps: float, tile: Tiles,
               interpret: bool):
     from jax.experimental import pallas as pl
@@ -277,8 +268,7 @@ def gate_norm(y: jax.Array, z: jax.Array, scale: jax.Array, groups: int,
     inner = y.shape[-1]
     tile = tiles(y.size // inner, inner, groups, y.dtype) \
         if y.shape == z.shape and y.dtype == z.dtype else None
-    kernels = interpret is not None or jax.default_backend() == "tpu"
-    if tile is None or not kernels:
+    interpret = kernel_mode(interpret)
+    if tile is None or interpret is None:
         return gated_group_norm_jnp(y, z, scale, groups, eps)
-    return _gate_norm(y, z, scale, groups, float(eps), tile,
-                      bool(interpret))
+    return _gate_norm(y, z, scale, groups, float(eps), tile, interpret)

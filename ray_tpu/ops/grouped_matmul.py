@@ -88,7 +88,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.flash_attention import _traced_once, fit_block
+from ray_tpu.ops._kernel import fit_block, kernel_mode, traced_once
 
 
 def lane_block(width: int, block: int) -> int:
@@ -219,7 +219,7 @@ def dispatch(x: jax.Array, plan: RowPlan, *,
     of live tiles that hold no pair read token 0 and are never combined;
     rows of dead tiles are not written).  Backward: every token the sum
     of its landed pairs' rows (:func:`_token_sums`)."""
-    return _dispatch(x, plan, _kernels(interpret))
+    return _dispatch(x, plan, kernel_mode(interpret))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -247,7 +247,7 @@ def combine(rows: jax.Array, weights: jax.Array, plan: RowPlan, *,
     where that is another (the float32 sums of a call are twice the
     rows')."""
     return _combine(rows, weights, plan, jnp.dtype(dtype),
-                    _kernels(interpret))
+                    kernel_mode(interpret))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -457,7 +457,7 @@ def _walk_kernel(some, tab, *refs, k: int, tile: int, weighted: bool,
         jax.lax.fori_loop(0, tile // some_rows, each(rounded), 0)
 
 
-@_traced_once("dtype", "interpret")
+@traced_once("dtype", "interpret")
 def _walk_pallas(rows, plan: RowPlan, weights, g, dtype, interpret):
     """The walk: ``weights`` (or neither) the sums ``[T, D]`` in
     ``dtype``, ``g`` the dots ``[T, k]``; summed in float32.  Traced and
@@ -780,14 +780,6 @@ def _gmm_bwd(block_m, block_n, interpret, res, g):
 _gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
-def _kernels(interpret: Optional[bool]) -> Optional[bool]:
-    """``interpret`` as given; left open, the kernels on a TPU and plain
-    ``jnp`` elsewhere."""
-    if interpret is None and jax.default_backend() == "tpu":
-        return False
-    return interpret
-
-
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, plan: RowPlan, *,
                    block_n: int = 512,
                    interpret: Optional[bool] = None) -> jax.Array:
@@ -797,7 +789,7 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, plan: RowPlan, *,
     written."""
     block_m = lhs.shape[0] // plan.tile_expert.shape[0]
     return _gmm(lhs, rhs, plan.tile_expert, plan.n_live, block_m, block_n,
-                _kernels(interpret))
+                kernel_mode(interpret))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
@@ -845,4 +837,4 @@ def expert_products(rows: jax.Array, weights, plan: RowPlan, *,
     read nor written."""
     block_m = rows.shape[0] // plan.tile_expert.shape[0]
     return _experts(rows, tuple(weights), plan.tile_expert, plan.n_live,
-                    block_m, block_n, _kernels(interpret))
+                    block_m, block_n, kernel_mode(interpret))

@@ -56,7 +56,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.flash_attention import _traced_once
+from ray_tpu.ops._kernel import fold8, kernel_mode, traced_once
 
 #: rows of the small blocks beside a tile: one packed bfloat16 register
 HALO = 16
@@ -151,15 +151,6 @@ def _forward_kernel(u_ref, before_ref, w_ref, bias_ref, out_ref, *,
         jax.lax.fori_loop(0, rows // SUB, unit, 0)
 
 
-def _fold(x):
-    """``[n, lanes]`` summed to ``[8, lanes]``: whole registers added,
-    nothing across sublanes."""
-    out = x[0:8]
-    for r in range(8, x.shape[0], 8):
-        out = out + x[r:r + 8]
-    return out
-
-
 def _backward_kernel(u_ref, g_ref, before_ref, after_ref, g_after_ref,
                      w_ref, bias_ref, du_ref, dw_ref, dbias_ref, sums_ref, *,
                      taps: int):
@@ -216,8 +207,8 @@ def _backward_kernel(u_ref, g_ref, before_ref, after_ref, g_after_ref,
             du_ref[pl.ds(base, SUB), cols] = du.astype(du_ref.dtype)
             own = dpre[:SUB]
             return tuple(
-                [sums[j] + _fold(own * shifted[taps - 1 - j][:SUB])
-                 for j in range(taps)] + [sums[taps] + _fold(own)])
+                [sums[j] + fold8(own * shifted[taps - 1 - j][:SUB])
+                 for j in range(taps)] + [sums[taps] + fold8(own)])
 
         zeros = tuple(jnp.zeros((8, UNIT), f32) for _ in range(taps + 1))
         for j, s in enumerate(jax.lax.fori_loop(0, units, unit, zeros)):
@@ -265,7 +256,7 @@ def _params():
         "parallel", "arbitrary", "arbitrary"))
 
 
-@_traced_once("tile", "interpret")
+@traced_once("tile", "interpret")
 def _forward(u, w, bias, tile: Tiles, interpret: bool):
     from jax.experimental import pallas as pl
 
@@ -283,7 +274,7 @@ def _forward(u, w, bias, tile: Tiles, interpret: bool):
     return out.reshape(u.shape)
 
 
-@_traced_once("tile", "interpret")
+@traced_once("tile", "interpret")
 def _backward(u, g, w, bias, tile: Tiles, interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -339,7 +330,7 @@ def short_conv(u: jax.Array, w: jax.Array, bias: jax.Array, *,
     C]`` and ``bias [C]``; taps, bias, ``silu`` and the gradients of
     ``w`` and ``bias`` are float32 whatever ``u`` is."""
     tile = tiles(u, w.shape[0])
-    kernels = interpret is not None or jax.default_backend() == "tpu"
-    if tile is None or not kernels:
+    interpret = kernel_mode(interpret)
+    if tile is None or interpret is None:
         return short_conv_jnp(u, w, bias)
-    return _short_conv(u, w, bias, tile, bool(interpret))
+    return _short_conv(u, w, bias, tile, interpret)

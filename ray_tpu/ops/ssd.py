@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.core import telemetry
+from ray_tpu.ops._kernel import kernel_mode
 
 #: lanes of a vector register: heads narrower than this share a slab
 LANES = 128
@@ -585,8 +586,9 @@ def ssd(xs: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     inputs; the products take their operands in ``xs``'s dtype and
     accumulate in float32."""
     plan = _plan(xs, B, chunk)
-    kernels = interpret is not None or jax.default_backend() == "tpu"
-    with telemetry.span("ops", "ssd.plan", **plan.span_args(kernels)):
-        if not kernels:
+    interpret = kernel_mode(interpret)
+    with telemetry.span("ops", "ssd.plan",
+                        **plan.span_args(interpret is not None)):
+        if interpret is None:
             return ssd_einsum(xs, dt, A, B, C, D, chunk=chunk)
-        return _ssd(xs, dt, A, B, C, D, plan, bool(interpret))
+        return _ssd(xs, dt, A, B, C, D, plan, interpret)

@@ -8,7 +8,7 @@ continuous-batching admission discipline of ``serve/batching.py``
 applied to policy forwards), then scatters the per-request slices back.
 Policy inference over the whole fleet is a stream of a few large
 identical-shape compiled programs instead of thousands of tiny per-step
-dispatches — the fix for BENCH_r05's PPO anti-scaling.
+dispatches.
 
 Weight sync: the learner publishes weights ONCE per update as a single
 object-plane broadcast; only inference actors (O(1) of them, not O(env

@@ -188,8 +188,7 @@ class ToyDecoder:
     # -- convenience -------------------------------------------------------
     def generate_unbatched(self, payload: Any) -> Dict[str, Any]:
         """Request-at-a-time decode through the SAME jitted step (batch
-        dim 1 pool) — the baseline `scripts/bench_serve.py` compares
-        against."""
+        dim 1 pool): what the batched paths' tests compare against."""
         import numpy as np
 
         state = self.begin_request(payload)

@@ -239,7 +239,7 @@ def test_no_row_is_dropped_when_every_token_picks_the_same_experts(
     every token, whether the picked experts are held here (all 96 pairs
     then land on 2 experts, or on one, four and eight times what an even
     router lands) or not: the buffers are the worst case's."""
-    monkeypatch.setattr(gm, "_kernels", lambda interpret: kernels)
+    monkeypatch.setattr(gm, "kernel_mode", lambda interpret: kernels)
     cfg = afmoe.AFMoEConfig.tiny(dtype=jnp.float32, experts_held=held)
     row = jax.random.normal(jax.random.PRNGKey(9), (cfg.embed_dim,))
     full = _all_pick(_layer_params(cfg, jax.random.PRNGKey(8)), row, (0, 1))

@@ -48,3 +48,42 @@ def test_readme_publishes_no_number_of_its_own(benchmarks_section):
         r"\d[\d,.]*\s*(?:%|ms\b|us\b|s\b|tokens/s|/s\b|GiB|GB|TFLOP)",
         benchmarks_section)
     assert not measured, measured
+
+
+DOCUMENTS = ["README.md"] + sorted(
+    "docs/" + name for name in os.listdir(os.path.join(ROOT, "docs"))
+    if name.endswith(".md"))
+_PATH = re.compile(
+    r"(?:scripts|tests|ray_tpu|benchmarks|docs|examples)/[\w./-]*")
+MAKE_TARGETS = set(re.findall(r"^([\w-]+):", _read("Makefile"), re.M))
+
+
+@pytest.mark.parametrize("document", DOCUMENTS)
+def test_a_document_names_what_exists(document):
+    """Every path of the tree, every test and every ``make`` target a
+    document names in backticks is there, and it quotes no record of
+    the CPU instrument that is gone (``BENCH_r*.json``)."""
+    text = re.sub(r"^```.*?^```", "", _read(document), flags=re.M | re.S)
+    assert not re.findall(r"BENCH_r\S*", text)
+    missing, test_file = [], None
+    for quoted in re.findall(r"`([^`]+)`", text):
+        quoted = re.sub(r"\s+", " ", quoted.strip())
+        target = re.fullmatch(r"make ([\w-]+)", quoted)
+        if target and target.group(1) not in MAKE_TARGETS:
+            missing.append(quoted)
+        path = _PATH.match(quoted)
+        if path:
+            rest = quoted[path.end():]
+            # a pattern (`ray_tpu/ops/*.py`, `tests/test_<x>.py`): the
+            # directory it is in
+            name = path.group() if rest[:1] not in ("*", "<", "{") \
+                else os.path.dirname(path.group())
+            if not os.path.exists(os.path.join(ROOT, name)):
+                missing.append(quoted)
+            elif name.startswith("tests/") and name.endswith(".py"):
+                test_file = name
+        case = re.search(r"::\s?(test_\w+)", quoted)
+        if case and (path or quoted.startswith("::")) and test_file \
+                and f"def {case.group(1)}(" not in _read(test_file):
+            missing.append(quoted)
+    assert not missing, f"{document} names what is not there: {missing}"

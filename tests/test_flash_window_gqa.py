@@ -108,7 +108,7 @@ def test_index_maps_visit_the_live_tiles_and_no_other():
 #: sha256 of the jaxpr text of d(sum(flash_attention(q, k, v)))/d(q,k,v)
 #: at GPT-2's call (equal heads of 64, causal, no window) on tiles of
 #: 128, too small to be cut in blocks.  Re-pinned in PR 34: the
-#: functions that build the kernel calls are jitted (``_traced_once``),
+#: functions that build the kernel calls are jitted (``traced_once``),
 #: so the text gained their ``pjit`` frames (``native`` was 1b0b7461...
 #: since before ``window`` and grouped heads existed, and its KERNELS
 #: are still those: ``tests/test_flash_cut_tiles.py`` holds one block to

@@ -1,5 +1,6 @@
 """Kernel correctness tests (pallas interpret mode on CPU)."""
 
+import functools
 import re
 
 import jax
@@ -8,10 +9,16 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops import fused_rmsnorm, fused_softmax_cross_entropy
+from ray_tpu.ops import gate_norm as gn
+from ray_tpu.ops import grouped_matmul as gm
+from ray_tpu.ops import short_conv as sc
+from ray_tpu.ops import ssd
+from ray_tpu.ops._kernel import kernel_mode
 from ray_tpu.ops.flash_attention import (
     _attention_reference,
     flash_attention,
 )
+from ray_tpu.ops.fused import _rmsnorm_ref
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -158,3 +165,87 @@ def test_cross_entropy():
     ref = -jax.nn.log_softmax(logits)[jnp.arange(8), labels]
     np.testing.assert_allclose(np.asarray(loss), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("interpret,backend,mode", [
+    (None, None, None),     # left open off the TPU: the reference form
+    (None, "tpu", False),   # left open on the chip: the compiled kernels
+    (False, None, False),
+    (True, None, True),     # the kernels through the interpreter
+])
+def test_kernel_mode(interpret, backend, mode, monkeypatch):
+    if backend:
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert kernel_mode(interpret) is mode
+
+
+def _normal(seed, shape, dtype=jnp.float32):
+    return jax.random.normal(jax.random.PRNGKey(seed), shape).astype(dtype)
+
+
+def _flash_case():
+    args = tuple(_normal(i, (1, 128, 2, 64)) for i in range(3))
+    return (flash_attention, lambda q, k, v: _attention_reference(
+        q, k, v, True, 64 ** -0.5, None), args)
+
+
+def _rmsnorm_case():
+    args = _normal(0, (64, 256), jnp.bfloat16), _normal(1, (256,))
+    return fused_rmsnorm, lambda x, w: _rmsnorm_ref(x, w, 1e-6), args
+
+
+def _grouped_matmul_case():
+    def plan_of(picked):
+        return gm.plan_rows(picked, 0, 4, block_m=8)
+
+    def plain(picked, lhs, rhs):
+        plan = plan_of(picked)
+        return gm._gmm_ref(lhs, rhs, plan.tile_expert, plan.n_live, 8, False)
+
+    picked = jax.random.randint(jax.random.PRNGKey(0), (32, 2), 0, 4)
+    rows = jax.eval_shape(plan_of, picked).row_pair.shape[0]
+    return (lambda picked, lhs, rhs: gm.grouped_matmul(
+        lhs, rhs, plan_of(picked)), plain,
+        (picked, _normal(1, (rows, 16)), _normal(2, (4, 16, 24))))
+
+
+def _short_conv_case():
+    u = _normal(0, (1, 128, 128), jnp.bfloat16)
+    assert sc.tiles(u, 4) is not None   # a shape the kernels take
+    return (sc.short_conv, sc.short_conv_jnp,
+            (u, _normal(1, (4, 128)), _normal(2, (128,))))
+
+
+def _gate_norm_case():
+    y, z = (_normal(i, (1, 64, 256), jnp.bfloat16) for i in range(2))
+    assert gn.tiles(64, 256, 2, y.dtype) is not None
+    return (lambda y, z, scale: gn.gate_norm(y, z, scale, 2, 1e-5),
+            lambda y, z, scale: gn.gated_group_norm_jnp(y, z, scale, 2, 1e-5),
+            (y, z, _normal(2, (256,))))
+
+
+def _ssd_case():
+    xs = _normal(0, (1, 32, 2, 8))
+    dt = jax.nn.softplus(_normal(1, (1, 32, 2)))
+    a, d = -jnp.exp(_normal(2, (2,))), _normal(3, (2,))
+    b, c = (_normal(i, (1, 32, 1, 16)) for i in (4, 5))
+    return (functools.partial(ssd.ssd, chunk=16),
+            functools.partial(ssd.ssd_einsum, chunk=16),
+            (xs, dt, a, b, c, d))
+
+
+#: ``flash_attention(k_rope=)`` has this case already:
+#: test_flash_mla.py::test_without_a_chip_the_entry_takes_the_reference_on_the_whole_key
+@pytest.mark.parametrize("case", [
+    _rmsnorm_case, _flash_case, _grouped_matmul_case, _short_conv_case,
+    _gate_norm_case, _ssd_case], ids=lambda f: f.__name__[1:-5])
+def test_left_open_off_the_tpu_an_entry_is_its_reference_form(case):
+    """``interpret=None`` with no TPU: bit for bit the plain form, on a
+    shape the kernels would take (both under ``jit``: one compile a
+    side)."""
+    assert jax.default_backend() != "tpu"
+    entry, plain, args = case()
+    got, want = jax.jit(entry)(*args), jax.jit(plain)(*args)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))

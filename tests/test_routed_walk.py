@@ -240,7 +240,7 @@ def test_the_layer_under_checkpoint_has_the_gathers_gradients(
     params = meta.unbox(params)
 
     def grads(kernels):
-        monkeypatch.setattr(gm, "_kernels", lambda interpret: kernels)
+        monkeypatch.setattr(gm, "kernel_mode", lambda interpret: kernels)
         return jax.grad(lambda p, h: (jax.checkpoint(
             lambda p, h: layer.apply({"params": p}, h))(p, h) * cot).sum(),
             argnums=(0, 1))(params, h)
