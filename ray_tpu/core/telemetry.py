@@ -653,6 +653,19 @@ def moe_router_load(model: str, layer: int, load, landed_share: float,
            ("model", "layer")).set_key(key, float(live_share))
 
 
+def moe_exchange_bytes(model: str, nbytes: float) -> None:
+    """Bytes one chip of an expert-parallel group receives and sends for
+    the routed layers' exchange (``parallel/expert.py``: the gathers of
+    the group's rows, the scatters of the parts) in one forward pass over
+    the last observed batch."""
+    if not enabled():
+        return
+    _gauge("ray_tpu_moe_exchange_bytes",
+           "bytes a chip receives and sends for the routed layers' "
+           "exchange in a forward pass over the last observed batch",
+           ("model",)).set_key((("model", model),), float(nbytes))
+
+
 # ---------------------------------------------------------------------------
 # looped stacks (models/ouro.py)
 # ---------------------------------------------------------------------------

@@ -52,6 +52,7 @@ from ray_tpu.models.llama import RMSNorm
 from ray_tpu.ops import grouped_matmul as gm
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.ops.fused import _rmsnorm_ref
+from ray_tpu.parallel import expert
 
 #: rows of one tile of the grouped products
 BLOCK_ROWS = 256
@@ -207,10 +208,17 @@ def _swiglu(cfg, h, width: int, prefix: str):
                   ("mlp", "embed"))(nn.silu(gate) * up)
 
 
+#: a router's scores of its logits, by the configuration's ``score_func``
+_SCORES = {"sigmoid": jax.nn.sigmoid,
+           "softmax": functools.partial(jax.nn.softmax, axis=-1)}
+
+
 def route(cfg, h: jax.Array, w_router: jax.Array,
           chosen: Optional[jax.Array] = None):
-    """Sigmoid scores (float32 by default) over all published experts,
-    the ``top_k`` largest, weights normalised over the chosen and scaled.
+    """Scores (float32 by default) over all published experts: sigmoid,
+    or where the configuration's ``score_func`` says so a softmax over
+    all of them; the ``top_k`` largest, weights normalised over the
+    chosen and scaled.
     ``h [T, E]`` -> ``(expert ids [T, k], weights [T, k] f32, the
     router's own choice [T, k])``.  ``chosen [T, k]``: a recorded routing
     to replay, in place of the router's own largest (the weights are
@@ -218,13 +226,28 @@ def route(cfg, h: jax.Array, w_router: jax.Array,
     logits = jnp.dot(h.astype(cfg.router_dtype),
                      w_router.astype(cfg.router_dtype),
                      precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    scores = _SCORES[getattr(cfg, "score_func", "sigmoid")](logits)
     top, own = jax.lax.top_k(scores, cfg.top_k)
     idx = own
     if chosen is not None:
         idx, top = chosen, jnp.take_along_axis(scores, chosen, axis=1)
     top = top.astype(jnp.float32)
     return idx, cfg.route_scale * top / top.sum(-1, keepdims=True), own
+
+
+def _landed_sums(cfg, rows: jax.Array, weights: jax.Array, matrices,
+                 plan) -> jax.Array:
+    """``rows [T, E]`` with their ``weights [T, k]`` -> ``[T, E]``, every
+    token's weighted sum over the experts of ``plan`` (``gm.plan_rows``:
+    the held ones its pairs landed on): dispatch, the grouped products,
+    combine, each the step's part of its name.  On one chip that is the
+    layer's result; on a chip of a group, its part of it."""
+    with step.scope("moe.dispatch"):
+        rows = gm.dispatch(rows, plan)
+    with step.scope("moe.experts"):
+        out = gm.expert_products(rows, matrices, plan)
+    with step.scope("moe.combine"):
+        return gm.combine(out, weights, plan, dtype=cfg.dtype)
 
 
 class RoutedExperts(nn.Module):
@@ -235,7 +258,14 @@ class RoutedExperts(nn.Module):
     ``param_dtype`` and ``router_dtype``.  An expert is the gated three
     matrices, ``down(silu(gate h) * up h)``, unless the configuration's
     ``expert_form`` says ``"relu2"``: two, ``down(relu(up h)^2)``
-    (``models/nemotron_h.py``)."""
+    (``models/nemotron_h.py``).
+
+    A configuration with ``expert_axis`` (a mesh axis), under a global
+    mesh with several chips along it, makes this ONE CHIP OF THE GROUP
+    that shares the layer: ``experts_held`` is then the group's share,
+    the parameters lie over the axis by expert, ``h``'s batch is split
+    over it, and the layer runs with its exchange
+    (:meth:`_exchanged`)."""
     config: Any
 
     @nn.compact
@@ -268,6 +298,10 @@ class RoutedExperts(nn.Module):
             w_down = experts("experts_down", (held, cfg.expert_dim, embed),
                              ("expert", "mlp", "embed"))
 
+        mesh = expert.group_mesh(getattr(cfg, "expert_axis", None))
+        if mesh is not None:
+            return self._exchanged(mesh, flat, w_router, (*into, w_down),
+                                   chosen).reshape(batch, seq, embed)
         with step.scope("moe.route"):
             idx, weights, own = route(cfg, flat, w_router, chosen)
         with step.scope("moe.plan"):
@@ -278,13 +312,63 @@ class RoutedExperts(nn.Module):
         # of that buffer's tiles the live ones alone are worked on
         self.sow("intermediates", "live_tiles", plan.n_live[0])
         self.sow("intermediates", "buffer_tiles", plan.tile_expert.shape[0])
-        with step.scope("moe.dispatch"):
-            rows = gm.dispatch(flat, plan)
-        with step.scope("moe.experts"):
-            out = gm.expert_products(rows, (*into, w_down), plan)
+        routed = _landed_sums(cfg, flat, weights, (*into, w_down), plan)
         with step.scope("moe.combine"):
-            routed = gm.combine(out, weights, plan, dtype=cfg.dtype)
             return routed.reshape(batch, seq, embed)
+
+    def _exchanged(self, mesh, flat, w_router, matrices, chosen):
+        """The layer as one chip of the group along ``expert_axis``
+        (``parallel/expert.py``), under ``shard_map`` over that axis:
+        each chip routes its OWN tokens over all published experts, the
+        rows are all-gathered with their choices and weights, dispatch,
+        the grouped products and combine run as on one chip over the
+        GROUP's tokens for the experts this chip owns (``first = its
+        index along the axis x held``), and the parts are
+        reduce-scattered so that each chip is left with its own tokens'
+        sums, in the compute dtype.  ``flat [B*T, E]``: the group's
+        tokens, a chip's own together; the router's gradient flows
+        through the gathered weights."""
+        from jax.sharding import PartitionSpec as P
+
+        cfg = self.config
+        axis = cfg.expert_axis
+        chips = mesh.shape[axis]
+        first, held = cfg.experts_held
+        own_held = held // chips
+
+        def local(flat, w_router, matrices, chosen):
+            with step.scope("moe.route"):
+                idx, weights, own = route(cfg, flat, w_router, chosen)
+            with step.scope("moe.exchange"):
+                rows, idx, weights = (expert.gather_tokens(a, axis)
+                                      for a in (flat, idx, weights))
+            with step.scope("moe.plan"):
+                # buffers for the worst case: every pair of the group
+                # may land here
+                plan = gm.plan_rows(
+                    idx, first + own_held * jax.lax.axis_index(axis),
+                    own_held, block_m=BLOCK_ROWS)
+            part = _landed_sums(cfg, rows, weights, matrices, plan)
+            with step.scope("moe.exchange"):
+                mine = expert.scatter_sums(part, axis)
+            return mine, plan.sizes, own, plan.n_live
+
+        rows = P(axis, None)
+        mine, sizes, own, n_live = jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(rows, P(), P(axis, None, None),
+                      None if chosen is None else rows),
+            out_specs=(rows, P(axis), rows, P(axis)), check_vma=False,
+        )(flat, w_router, matrices, chosen)
+        # as on one chip, in the group's view: all its experts' loads,
+        # the chips' live tiles of the chips' buffers
+        pairs = flat.shape[0] * cfg.top_k
+        self.sow("intermediates", "expert_load", sizes)
+        self.sow("intermediates", "expert_choice", own)
+        self.sow("intermediates", "live_tiles", n_live.sum())
+        self.sow("intermediates", "buffer_tiles",
+                 chips * (-(-pairs // BLOCK_ROWS) + own_held))
+        return mine
 
 
 class AttentionPart(nn.Module):

@@ -29,9 +29,10 @@ import jax
 from ray_tpu.core import telemetry
 
 #: the parts of a step, in the order a step meets them
-PARTS = ("embed", "attn", "mlp", "moe.route", "moe.plan", "moe.dispatch",
-         "moe.experts", "moe.combine", "ssm.in_proj", "ssm.conv", "ssm.scan",
-         "ssm.gate_norm", "ssm.out_proj", "head", "exit", "optimizer")
+PARTS = ("embed", "attn", "mlp", "moe.route", "moe.exchange", "moe.plan",
+         "moe.dispatch", "moe.experts", "moe.combine", "ssm.in_proj",
+         "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out_proj", "head",
+         "exit", "optimizer")
 
 
 def part_of(component: str) -> Optional[str]:

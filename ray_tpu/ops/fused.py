@@ -76,10 +76,24 @@ _rmsnorm.defvjp(_rmsnorm_fwd, _rmsnorm_bwd)
 
 
 def fused_rmsnorm(x: jax.Array, weight: jax.Array, *, eps: float = 1e-6,
-                  interpret: Optional[bool] = None) -> jax.Array:
+                  interpret: Optional[bool] = None,
+                  mesh: Optional[jax.sharding.Mesh] = None) -> jax.Array:
+    """``mesh`` (models under a mesh pass ``get_global_mesh()``): GSPMD
+    cannot partition a Mosaic kernel, so over several devices the
+    kernel runs on each device's own rows of ``x [B, T, E]``, split as
+    the activations are, with the whole ``weight``."""
     interpret = kernel_mode(interpret)
     if interpret is None:
         return _rmsnorm_ref(x, weight, eps)
+    if mesh is not None and mesh.size > 1:
+        from ray_tpu.parallel.sharding import MESH_RULES, P
+
+        spec = MESH_RULES.activation_spec("batch", "seq", "embed",
+                                          mesh=mesh, shape=x.shape)
+        return jax.shard_map(
+            lambda a, w: _rmsnorm(a, w, eps, interpret), mesh=mesh,
+            in_specs=(spec, P()), out_specs=spec,
+            check_vma=False)(x, weight)
     return _rmsnorm(x, weight, eps, interpret)
 
 

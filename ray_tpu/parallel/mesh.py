@@ -13,7 +13,17 @@ Axis vocabulary (outermost first, SURVEY.md §7.6):
 - ``pp``   — pipeline stages
 - ``sp``   — sequence/context parallelism (ring attention / Ulysses)
 - ``tp``   — tensor parallelism (megatron-style sharded matmuls)
-- ``ep``   — expert parallelism (MoE all-to-all), usually aliasing dp/fsdp
+- ``ep``   — expert parallelism over an axis of its OWN
+            (``sharding.EP_RULES``; GSPMD chooses the collectives; no
+            step of the training path builds a mesh with ``ep > 1``).
+            The training path's expert parallelism ALIASES ``fsdp``
+            instead: ``sharding.FSDP_EP_RULES`` lays the ``expert``
+            logical axis over the ``fsdp`` MESH axis, the one that also
+            splits the batch and shards every other parameter, so the
+            chips along ``fsdp`` are the group that shares each layer,
+            and ``parallel/expert.py`` runs their exchange (an
+            all-gather of the rows, a reduce-scatter of the parts) under
+            ``shard_map`` over that axis
 
 Multi-host placement: axes listed in ``dcn_axes`` are laid out across
 slice boundaries (DCN); everything else stays inside a slice where

@@ -5,7 +5,9 @@ arrays with *logical* axis names ("batch", "seq", "embed", "mlp",
 "heads", "kv", "vocab", "layers", "expert"); a :class:`ShardingRules`
 table maps logical names to mesh axes per parallelism style.  XLA then
 inserts the collectives.  This replaces the reference's per-backend
-process-group wiring with declarative sharding.
+process-group wiring with declarative sharding.  (One exchange is
+written out and not left to XLA: a routed layer's, over the mesh axis
+its experts lie on under :data:`FSDP_EP_RULES`, ``parallel/expert.py``.)
 
 A table says where PARAMETERS lie (:meth:`ShardingRules.spec`).  Where
 an ACTIVATION lies follows from it (:meth:`ShardingRules.
@@ -51,9 +53,20 @@ class ShardingRules:
     rules: Dict[str, MeshAxis] = field(default_factory=dict)
 
     def spec(self, *logical_axes: Optional[str]) -> P:
-        """Where a PARAMETER with these logical axes lies."""
-        return P(*[self.rules.get(a) if a is not None else None
-                   for a in logical_axes])
+        """Where a PARAMETER with these logical axes lies.  A mesh axis
+        is named once in a spec: the FIRST logical axis that maps to it
+        is split over it and a later one stays whole (under
+        :data:`FSDP_EP_RULES` an expert's ``embed`` beside its
+        ``expert``)."""
+        taken: set = set()
+        out = []
+        for logical in logical_axes:
+            axis = self.rules.get(logical) if logical is not None else None
+            if taken.intersection(_axes(axis)):
+                axis = None
+            taken.update(_axes(axis))
+            out.append(axis)
+        return P(*out)
 
     def activation_spec(self, *logical_axes: Optional[str],
                         mesh: Optional[Mesh] = None,
@@ -114,8 +127,24 @@ TP_RULES = ShardingRules({
 #: Sequence/context parallelism: activations split on seq over sp.
 SP_RULES = TP_RULES.merged(seq="sp")
 
-#: Expert parallelism: experts over ep (usually aliased with fsdp).
+#: Expert parallelism over a mesh axis of its OWN: experts over ``ep``
+#: beside TP's placement of everything else.  What GSPMD makes of a
+#: layer whose experts lie so is its own choice of collectives
+#: (``models/moe.py``: capacity-bounded einsums); no step of the
+#: training path builds a mesh with ``ep > 1``.
 EP_RULES = TP_RULES.merged(expert="ep")
+
+#: Expert parallelism ALIASING fsdp, the layout of the training path:
+#: :data:`FSDP_RULES` with ``expert`` over the SAME mesh axis that
+#: shards everything else.  A parameter with an ``expert`` axis is split
+#: over the chips BY EXPERT and by nothing else (an expert's matrices,
+#: their gradients and AdamW's moments whole on the chip that owns it:
+#: :meth:`ShardingRules.spec` names a mesh axis once); every other
+#: parameter lies as under FSDP, ``embed`` over ``fsdp``, gathered for
+#: use.  The batch is split over the same axis, so the chips of the axis
+#: are one group whose tokens meet each other's experts through the
+#: exchange of ``parallel/expert.py``.
+FSDP_EP_RULES = FSDP_RULES.merged(expert="fsdp")
 
 #: Every preset at once.  Each preset is this table with the axes it
 #: does not use left whole, and a mesh built for a preset has one
@@ -131,6 +160,7 @@ PRESETS: Dict[str, ShardingRules] = {
     "tp": TP_RULES,
     "sp": SP_RULES,
     "ep": EP_RULES,
+    "fsdp_ep": FSDP_EP_RULES,
 }
 
 

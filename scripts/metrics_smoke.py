@@ -95,6 +95,7 @@ TRAFFIC_DEPENDENT = {
     "ray_tpu_loop_exit_share",
     "ray_tpu_loop_expected_passes",
     "ray_tpu_loop_exit_entropy",
+    "ray_tpu_moe_exchange_bytes",
     "ray_tpu_moe_expert_load",
     "ray_tpu_moe_landed_share",
     "ray_tpu_moe_live_share",

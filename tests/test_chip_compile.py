@@ -800,3 +800,157 @@ def test_ouro_gradient_check_fits_beside_the_training_state(one_chip):
         f"{check / 2**30:.2f} GiB beside " \
         f"{OURO_STATE_BYTES / 2**30:.2f} GiB of state"
     print(f"ouro gradient check: {check / 2**30:.3f} GiB")
+
+
+# ---------------------------------------------------------------------------
+# Mellum (PR 51): the four chips that share each layer, and the exchange
+# ---------------------------------------------------------------------------
+
+#: bytes of ``mellum2-12b-a2.5b.ep4.steady``'s training state ON ONE CHIP:
+#: a quarter of 2,123,976,960 parameters, f32 weights and AdamW's two
+#: moments (each chip its own 16 experts a layer whole, and a quarter of
+#: everything else)
+MELLUM_STATE_BYTES_A_CHIP = 2_123_976_960 * 12 // 4
+
+
+def _mellum_on_four(topo, depth):
+    """The stage's model at ``depth`` layers, the 2 x 2 mesh, and
+    ``place(shape, spec)``."""
+    from ray_tpu.parallel import MeshConfig, build_mesh
+
+    ml = importlib.import_module("ray_tpu.models.mellum")
+    mesh = build_mesh(MeshConfig(fsdp=4), devices=topo.devices)
+    cfg = ml.MellumConfig.mellum2_12b_a2_5b_stage(remat="full",
+                                                  num_layers=depth)
+
+    def place(a, spec):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    return ml, cfg, ml.Mellum(cfg), mesh, place
+
+
+def _mellum_params(model, place):
+    from ray_tpu.parallel.sharding import FSDP_EP_RULES, flax_sharding
+
+    plain, specs = flax_sharding(jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0), batch=4)),
+        FSDP_EP_RULES)
+    return jax.tree.map(place, plain, specs), specs
+
+
+def _mellum_step(topo, depth):
+    """``mellum2-12b-a2.5b.ep4.steady``'s donated step at ``depth``
+    layers, global batch 8 x 8,192, compiled for the four described
+    chips."""
+    import optax
+
+    from ray_tpu.parallel.mesh import use_mesh
+
+    ml, cfg, model, mesh, place = _mellum_on_four(topo, depth)
+    with use_mesh(mesh):
+        params, specs = _mellum_params(model, place)
+        tx = optax.adamw(1e-5, weight_decay=0.01)
+        opt_state = jax.eval_shape(tx.init, params)
+        tokens = place(jax.ShapeDtypeStruct((8, cfg.max_seq_len), jnp.int32),
+                       P(("dp", "fsdp"), None))
+        compiled = _lower_as_on_tpu(ml.make_train_step(model, tx), (
+            params, opt_state, tokens)).compile()
+    return compiled, params, specs
+
+
+def _exchange_and_placement(compiled, params, specs, depth):
+    """What holds of the step at any depth: the exchange is all-gathers
+    of the group's rows and reduce-scatters of the parts, never an
+    all-to-all; the experts lie by expert and the state is donated."""
+    text = compiled.as_text()
+    assert text.count(" all-to-all(") == 0
+    # a call of a routed layer gathers the four chips' 4,096 rows of
+    # 2304 (forward and recomputed forward), and the backward of the
+    # scatter gathers as many: 2 sequences x 2 pieces a layer
+    rows = re.findall(r"= \(?bf16\[(?:1,)?16384,2304\][^=]* all-gather", text)
+    assert len(rows) >= depth * 2 * 2 * 3 >= 8, len(rows)
+    # the TPU compiler writes a reduce-scatter as a fused all-reduce
+    # and slice named ``all-reduce-scatter``: [16384, 2304] -> a chip's
+    # 4,096 rows (padded to 4,224); computations alike are written once
+    assert len(re.findall(
+        r"all-reduce-scatter[.\d]* \(input[.\d]*: bf16\[16384,2304\]\)",
+        text)) >= 4
+    moe = specs["h0"]["mlp"]["moe"]
+    assert moe["experts_gate"] == moe["experts_down"] == P("fsdp", None, None)
+    (params_in, _, _), _ = compiled.input_shardings
+    assert jax.tree.all(jax.tree.map(
+        lambda a, b, x: a.is_equivalent_to(b, x.ndim),
+        params_in, compiled.output_shardings[0], params))
+    shard = params_in["h0"]["mlp"]["moe"]["experts_up"].shard_shape(
+        params["h0"]["mlp"]["moe"]["experts_up"].shape)
+    assert shard == (16, 2304, 896)   # each chip's AdamW updates these
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= mem.argument_size_in_bytes - 2 ** 20
+    return text, mem
+
+
+def test_mellum_step_exchanges_over_four_v5e(topo):
+    """One sliding layer of the stage (the exchange and the placement
+    do not depend on depth; the whole period is the slow test below)."""
+    compiled, params, specs = _mellum_step(topo, 1)
+    text, mem = _exchange_and_placement(compiled, params, specs, 1)
+    # 2 sequences x (forward twice, dK/dV, dQ); 2 x 2 pieces x 12 products
+    calls = _kernel_calls(text)
+    assert sum("grouped_matmul" in _called(line) for line in calls) == 48
+    assert mem.peak_memory_in_bytes < 0.9 * V5E_HBM_BYTES
+
+
+@pytest.mark.slow
+def test_mellum_stage_step_fits_four_v5e(topo):
+    """The whole period at the cell's batch: 7.91 GiB of state a chip
+    with the gradients, and the step's temporaries beside it."""
+    compiled, params, specs = _mellum_step(topo, 4)
+    assert sum(a.size for a in jax.tree.leaves(params)) == 2_123_976_960
+    _, mem = _exchange_and_placement(compiled, params, specs, 4)
+    assert mem.argument_size_in_bytes == pytest.approx(
+        MELLUM_STATE_BYTES_A_CHIP, rel=1e-3)
+    # what the compiler says the program holds at its fullest (my
+    # compile, PR 51: 14.25 GiB of the chip's 15.75)
+    assert mem.peak_memory_in_bytes < 14.5 * 2 ** 30, \
+        f"{mem.peak_memory_in_bytes / 2**30:.2f} GiB"
+    print(f"mellum step: peak {mem.peak_memory_in_bytes / 2**30:.3f} GiB")
+
+
+@pytest.mark.slow
+def test_mellum_gradient_check_fits_beside_the_training_state(topo):
+    """The harness's check (``benchmarks/kinds/train.py``
+    ``gradient_check``): depth 2, four sequences, the program's paired
+    loss and the reference, BOTH float32 gradients in one program, over
+    the four chips beside the training state (1.29B parameters x 12
+    bytes = 15.5 GB: it fits only where it lies over the chips)."""
+    import sys
+
+    from ray_tpu.parallel.mesh import use_mesh
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    reference = importlib.import_module("benchmarks.reference.mellum")
+    paired = importlib.import_module("benchmarks.reference.mellum_paired")
+    _, cfg, model, mesh, place = _mellum_on_four(topo, 2)
+    sizes = {"n_layer": 2, "n_head": cfg.num_heads, "ln_eps": cfg.rms_eps}
+
+    def error(p, t):
+        return reference.grad_error(
+            jax.grad(lambda q: paired.program_loss(model, q, t))(p),
+            jax.grad(lambda q: reference.loss(q, t, **sizes))(p))
+
+    with use_mesh(mesh):
+        params, _ = _mellum_params(model, place)
+        tokens = place(jax.ShapeDtypeStruct((4, cfg.max_seq_len), jnp.int32),
+                       P())
+        compiled = _lower_as_on_tpu(jax.jit(error),
+                                    (params, tokens)).compile()
+    assert compiled.as_text().count(" all-to-all(") == 0
+    mem = compiled.memory_analysis()
+    assert mem.peak_memory_in_bytes + MELLUM_STATE_BYTES_A_CHIP \
+        < 0.97 * V5E_HBM_BYTES, \
+        f"{mem.peak_memory_in_bytes / 2**30:.2f} GiB beside " \
+        f"{MELLUM_STATE_BYTES_A_CHIP / 2**30:.2f} GiB of state"
+    print(f"mellum gradient check: peak "
+          f"{mem.peak_memory_in_bytes / 2**30:.3f} GiB")
