@@ -158,14 +158,20 @@ def routed_plan_args(cfg, tokens: int) -> Dict[str, Any]:
     ``pairs`` of a call and reads a row for those that landed alone (0:
     no tile fits these shapes, a row is gathered for every pair);
     ``walked``: what it walks, the ``table`` ``[k, tile]`` of the pairs'
-    rows as the plan has it (no list of the landed pairs is made)."""
+    rows as the plan has it (no list of the landed pairs is made).
+    ``product_tiles``: the grouped products' weight blocks and their
+    sweeps over the rows, ``product_vmem_bytes`` the most VMEM a kernel
+    of theirs is given, both from the function the kernels take their
+    tiles from (``gm.product_tiles``)."""
     return {"experts": cfg.num_experts, "held_first": cfg.experts_held[0],
             "held": cfg.experts_held[1], "top_k": cfg.top_k,
             "row_bound": tokens * cfg.top_k, "block_rows": BLOCK_ROWS,
             "buffer_passes": 0, "row_gather": "reach",
             "gather_reaches": ",".join(f"1/{r}" for r in gm.REACHES),
             "walk_tile": gm.walk_tile(tokens, cfg.embed_dim) or 0,
-            "pairs": tokens * cfg.top_k, "walked": "table"}
+            "pairs": tokens * cfg.top_k, "walked": "table",
+            **gm.product_tiles(BLOCK_ROWS, cfg.embed_dim, cfg.expert_dim,
+                               jnp.dtype(cfg.dtype).itemsize)}
 
 
 def _rope(x: jax.Array, theta: float) -> jax.Array:

@@ -67,14 +67,39 @@ did not land masked after the read; the walk is held against them
 rows (``[T, D]``, ``[T, k]``) and never the row buffer's: the
 benchmark's readers tell kernel families apart by result shape.
 
+**How a tile is chosen** (:func:`gmm_tiles`, :func:`tgmm_tiles`: from
+the shapes alone, one rule for every model).  A product takes the
+contraction whole and tiles the result ``block_m x block_n``, the
+width's tiles outermost, so every tile of the width is one more sweep
+over the rows: each sweep fetches every live row tile again (and forms
+the activation on it again), and walks the buffer's dead steps again.
+So the result's width is ONE tile wherever the blocks fit
+:data:`VMEM_BUDGET`: the grid is ``(1, row tiles)``, a row tile crosses
+HBM once, an expert's whole matrix stays resident over its consecutive
+row tiles, the result is written once.  Where the whole width does not
+fit, the widest divisor that is whole 128-lane registers; last
+:func:`lane_block`'s tile.  ``d rhs`` takes an expert's whole ``[k,
+n]`` block likewise, and where that does not fit (the float32
+accumulator and the product added to it are the block's size each) the
+blocks under which the rows cross HBM the fewest times.  The kernels
+ask Mosaic for the VMEM their blocks come to (it scopes a kernel to 16
+MiB otherwise).  A caller's ``block_n`` is a CAP on the tile (tests
+hold the kernels to narrow tiles with it); models pass none.  On a v5e
+(PERF.md, PR 52) Mellum's nine products of 2304 x 896 over 136 live
+row tiles of 528 took 8.5 ms where tiles of 128 and 384 lanes (seven
+and six sweeps) took 20.2, bit for bit the same results: every element
+is one float32 ``dot_general`` over the whole contraction whatever the
+tile's width.
+
 A width no tile divides (1856 = 2^6 x 29: its largest divisor under 512
 is 464, which is neither whole 128-lane registers nor the whole width,
-and Mosaic refuses such a block) is taken AS IT LIES in HBM, under a
-masked last tile (:func:`lane_block`): the grid rounds up, the columns a
-block reads past the edge reach only columns of the result that are past
-the edge too, and those are never written.  That holds for the
-activation formed on a tile: it is elementwise, and ``d rhs`` contracts
-over ROWS, never over the width.  No weight is padded.
+and Mosaic refuses such a block) is taken AS IT LIES in HBM: whole, or
+(under a cap, or where the whole does not fit) under a masked last tile
+(:func:`lane_block`): the grid rounds up, the columns a block reads
+past the edge reach only columns of the result that are past the edge
+too, and those are never written.  That holds for the activation formed
+on a tile: it is elementwise, and ``d rhs`` contracts over ROWS, never
+over the width.  No weight is padded.
 
 On other backends (tests) the products fall back to plain ``jnp`` unless
 ``interpret=True`` forces the kernels through the Pallas interpreter.
@@ -83,7 +108,7 @@ On other backends (tests) the products fall back to plain ``jnp`` unless
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -102,6 +127,105 @@ def lane_block(width: int, block: int) -> int:
     if fit % 128 == 0 or fit == width or not tiles:
         return fit
     return min(tiles, key=lambda b: (-(-width // b) * b, -b))
+
+
+#: bytes of VMEM the blocks of one grouped product may take, and what
+#: the kernels ask Mosaic for (it scopes a kernel to 16 MiB unless told):
+#: half of a v5e core's 128 MiB.  The widest product of ``models/`` is
+#: Nemotron's, 2688 x 1856 in bfloat16 under 256 rows: an expert's whole
+#: matrix is 10.0 MB, and :func:`_gmm_vmem` counts 2 x (10.0 + 2 row
+#: tiles of 1.4 + 4 result tiles of 1.0) + 14 of float32 values = 47 MB,
+#: which fits; its ``d rhs`` whole is a 20 MB float32 accumulator, the
+#: product's 20 MB value and 2 x 10 MB of result, 76 MB, which does not,
+#: and is split three ways along the rows' width.
+VMEM_BUDGET = 64 * 2 ** 20
+#: what Mosaic scopes a kernel to by default: a kernel asks for no less
+MOSAIC_VMEM = 16 * 2 ** 20
+
+
+def lane_tiles(width: int, cap: Optional[int] = None) -> List[int]:
+    """The tiles a dimension of ``width`` along the lanes may take,
+    widest first: the whole width, then its divisors that are whole
+    128-lane registers, last :func:`lane_block`'s (masked where nothing
+    divides); none above ``cap`` where one is given."""
+    top = width if cap is None else min(cap, width)
+    tiles = [b for b in range(top, 0, -1)
+             if width % b == 0 and (b == width or b % 128 == 0)]
+    last = lane_block(width, 512 if cap is None else cap)
+    return tiles if last in tiles else tiles + [last]
+
+
+def _gmm_vmem(block_m: int, k: int, block_n: int, itemsize: int) -> int:
+    """An upper bound of the bytes :func:`_gmm_pallas` holds in VMEM at
+    a result tile ``[block_m, block_n]``, whichever of its forms runs:
+    two buffers each of two lhs tiles, the weight block and four tiles of
+    the result's shape (the activation's inputs and its two cotangents),
+    and float32 values: three of the result's shape, three of the lhs
+    tile's.  What the kernel asks Mosaic for."""
+    blocks = 2 * block_m * k + k * block_n + 4 * block_m * block_n
+    return max(2 * itemsize * blocks + 4 * 3 * block_m * (block_n + k),
+               MOSAIC_VMEM)
+
+
+def _tgmm_vmem(block_m: int, block_k: int, block_n: int, itemsize: int
+               ) -> int:
+    """The same of :func:`_tgmm_pallas` at a result block ``[block_k,
+    block_n]``: two buffers each of two lhs tiles, the cotangent's tile
+    and the result's block; in float32 the accumulator, the product
+    added to it, and the activation's values on the lhs tile."""
+    blocks = 2 * block_m * block_k + block_m * block_n + block_k * block_n
+    return max(2 * itemsize * blocks + 4 * (2 * block_k * block_n
+                                            + 3 * block_m * block_k),
+               MOSAIC_VMEM)
+
+
+def gmm_tiles(block_m: int, k: int, n: int, itemsize: int,
+              cap: Optional[int] = None) -> Tuple[int, int]:
+    """``(block_n, VMEM bytes)`` of a product ``[M, k] x [k, n]``: the
+    widest of :func:`lane_tiles` whose blocks fit :data:`VMEM_BUDGET`
+    (the narrowest where none does).  At the whole width every operand
+    crosses HBM once: a sweep over the rows for every tile of ``n``
+    fetches every live row tile again."""
+    sized = [(b, _gmm_vmem(block_m, k, b, itemsize))
+             for b in lane_tiles(n, cap)]
+    return next((t for t in sized if t[1] <= VMEM_BUDGET), sized[-1])
+
+
+def tgmm_tiles(block_m: int, k: int, n: int, itemsize: int,
+               cap: Optional[int] = None) -> Tuple[int, int, int]:
+    """``(block_k, block_n, VMEM bytes)`` of ``d rhs [k, n]``: of the
+    blocks that fit :data:`VMEM_BUDGET`, the one under which the rows
+    cross HBM the fewest times (an lhs tile once a tile of ``n``, a
+    cotangent's tile once a tile of ``k``); of equals the one with whole
+    lanes, ``k`` split.  ``cap`` bounds ``block_n`` alone."""
+    sized = [(bk, bn, _tgmm_vmem(block_m, bk, bn, itemsize))
+             for bn in lane_tiles(n, cap) for bk in lane_tiles(k)]
+    fits = [t for t in sized if t[2] <= VMEM_BUDGET] or sized[-1:]
+    return min(fits, key=lambda t: -(-n // t[1]) * k + -(-k // t[0]) * n)
+
+
+def product_tiles(block_m: int, embed: int, width: int, itemsize: int,
+                  cap: Optional[int] = None) -> Dict[str, Any]:
+    """What :func:`expert_products` compiles at these shapes, for a
+    ``moe.plan`` span: the weight block (``k x tile``) and, after the
+    colon, the sweeps over the rows, of the products onto the experts'
+    width (``up``: gate's, up's and the down product's ``d lhs``), of
+    those back onto the rows' (``down``, and gate's and up's ``d lhs``)
+    and of ``d rhs`` (gate's and up's; ``drhs_down``: the down
+    product's); and the most VMEM any of the kernels asks for."""
+    up = gmm_tiles(block_m, embed, width, itemsize, cap)
+    down = gmm_tiles(block_m, width, embed, itemsize, cap)
+    d_up = tgmm_tiles(block_m, embed, width, itemsize, cap)
+    d_down = tgmm_tiles(block_m, width, embed, itemsize, cap)
+    return {
+        "product_tiles": ", ".join([
+            f"up {embed}x{up[0]}:{-(-width // up[0])}",
+            f"down {width}x{down[0]}:{-(-embed // down[0])}",
+            f"drhs {d_up[0]}x{d_up[1]}:"
+            f"{-(-embed // d_up[0])}x{-(-width // d_up[1])}",
+            f"drhs_down {d_down[0]}x{d_down[1]}:"
+            f"{-(-width // d_down[0])}x{-(-embed // d_down[1])}"]),
+        "product_vmem_bytes": max(up[1], down[1], d_up[2], d_down[2])}
 
 
 class RowPlan(NamedTuple):
@@ -585,7 +709,7 @@ def _gmm_pallas(lhs, rhs, tile_expert, n_live, block_m, block_n,
 
     m, k = lhs[0].shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    block_n = lane_block(n, block_n)
+    block_n, vmem = gmm_tiles(block_m, k, n, lhs[0].dtype.itemsize, block_n)
     if transpose_rhs:   # rhs [E, N, K]: rows of the block are outputs
         rhs_spec = pl.BlockSpec(
             (None, block_n, k),
@@ -603,8 +727,8 @@ def _gmm_pallas(lhs, rhs, tile_expert, n_live, block_m, block_n,
     out_shape = [jax.ShapeDtypeStruct((m, n), lhs[0].dtype)] * n_out
     name = "grouped_matmul" + ("_t" if transpose_rhs else "") \
         + ("_act" if act or hidden else "") + ("_add" if adds else "")
-    # n outermost: consecutive row tiles of one expert keep its weight
-    # block resident
+    # n outermost (one tile of it wherever the budget allows):
+    # consecutive row tiles of one expert keep its weight block resident
     out = pl.pallas_call(
         functools.partial(_gmm_kernel, n_lhs=len(lhs), act=act,
                           add=bool(adds), n_hidden=len(hidden),
@@ -620,7 +744,8 @@ def _gmm_pallas(lhs, rhs, tile_expert, n_live, block_m, block_n,
         # the tile to add is the result's own: the sum takes its place
         input_output_aliases={2 + len(lhs) + 1: 0} if adds else {},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem),
         interpret=interpret,
         name=name,
     )(tile_expert, n_live, *lhs, rhs, *adds, *hidden)
@@ -654,14 +779,15 @@ def _tgmm_kernel(te_ref, n_live_ref, *refs, act: bool):
             out_ref[:] = acc_ref[:].astype(out_ref.dtype)
 
 
-def _tgmm_pallas(lhs, dout, tile_expert, n_live, experts, block_m, block_k,
-                 block_n, interpret, act=False):
+def _tgmm_pallas(lhs, dout, tile_expert, n_live, experts, block_m, block_n,
+                 interpret, act=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     m, k = lhs[0].shape
     n = dout.shape[1]
-    block_k, block_n = lane_block(k, block_k), lane_block(n, block_n)
+    block_k, block_n, vmem = tgmm_tiles(block_m, k, n, lhs[0].dtype.itemsize,
+                                        block_n)
     lhs_spec = pl.BlockSpec((block_m, block_k),
                             lambda a, b, i, te, nl: (_live_tile(i, nl), a))
     return pl.pallas_call(
@@ -680,7 +806,8 @@ def _tgmm_pallas(lhs, dout, tile_expert, n_live, experts, block_m, block_k,
         ),
         out_shape=jax.ShapeDtypeStruct((experts, k, n), lhs[0].dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem),
         interpret=interpret,
         name="grouped_matmul_drhs" + ("_act" if act else ""),
     )(tile_expert, n_live, *lhs, dout)
@@ -750,7 +877,7 @@ def _d_rhs(lhs, dout, rhs, tile_expert, n_live, block_m, block_n, interpret,
         return _tgmm_ref(_lhs_of(lhs, act), dout, tile_expert, n_live,
                          experts, block_m).astype(rhs.dtype)
     d_rhs = _tgmm_pallas(lhs, dout, tile_expert, n_live, experts, block_m,
-                         1024, block_n, interpret, act)
+                         block_n, interpret, act)
     # an expert with no rows was never visited: its block is whatever
     # the buffer held
     seen = jnp.any(
@@ -781,12 +908,14 @@ _gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, plan: RowPlan, *,
-                   block_n: int = 512,
+                   block_n: Optional[int] = None,
                    interpret: Optional[bool] = None) -> jax.Array:
     """``lhs [M, K]`` rows laid out by ``plan``, ``rhs [E, K, N]`` ->
     ``[M, N]``: every live row tile times its expert's matrix, f32
     accumulation, output in ``lhs``'s dtype.  Rows of dead tiles are not
-    written."""
+    written.  ``block_n``: a cap on the result's tile along ``N``; left
+    open, the tile is :func:`gmm_tiles`' (the whole width where VMEM
+    holds it)."""
     block_m = lhs.shape[0] // plan.tile_expert.shape[0]
     return _gmm(lhs, rhs, plan.tile_expert, plan.n_live, block_m, block_n,
                 kernel_mode(interpret))
@@ -825,7 +954,7 @@ _experts.defvjp(_experts_fwd, _experts_bwd)
 
 
 def expert_products(rows: jax.Array, weights, plan: RowPlan, *,
-                    block_n: int = 512,
+                    block_n: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Every live row through its expert: ``rows [M, D]`` laid out by
     ``plan``; ``weights`` the experts' matrices, ``(gate, up, down)``
@@ -834,7 +963,7 @@ def expert_products(rows: jax.Array, weights, plan: RowPlan, *,
     kernel call a product forward (``gate r`` and ``up r`` kept for the
     backward in ``rows``' dtype, the activation formed on the down
     product's tile) and two backward; rows of dead tiles are neither
-    read nor written."""
+    read nor written.  ``block_n`` as :func:`grouped_matmul`'s."""
     block_m = rows.shape[0] // plan.tile_expert.shape[0]
     return _experts(rows, tuple(weights), plan.tile_expert, plan.n_live,
                     block_m, block_n, kernel_mode(interpret))

@@ -407,6 +407,57 @@ def test_the_fused_layer_is_the_unfused_one_with_its_dead_rows_poisoned(
     assert float(jnp.abs(grads[1]["experts_down"][1]).max()) == 0.0
 
 
+# 896 = 7 x 128 and 7 is prime (Mellum's width): under the narrowest cap
+# seven tiles, under the rule one; 464 = 29 x 16: no tile divides it,
+# masked under the cap and whole under the rule
+@pytest.mark.parametrize("matrices", [3, 2], ids=["gated", "relu2"])
+@pytest.mark.parametrize("width", [896, 464])
+def test_the_rule_s_tiles_give_the_narrowest_cap_s_results(matrices, width):
+    """``expert_products`` forward, ``d rows`` and every ``d weights``
+    through the Pallas interpreter at the narrowest tile (``block_n=128``:
+    seven sweeps over the rows at 896, a masked fourth tile at 464) and
+    at the tiles the rule picks (the whole width, one sweep): every
+    element is one float32 ``dot_general`` over the whole contraction
+    either way and ``d rhs`` adds the row tiles in order, so the results
+    are the same to a float32 rounding, and the same BITS where the
+    backend sums a contraction in one order whatever the result's width
+    (the test prints which it found: this sandbox's CPU does not, its
+    ``dot`` blocks a [8, 64] x [64, 128] product otherwise than a [8,
+    64] x [64, 896] one; the chip does, PERF.md, PR 52)."""
+    embed, held, block_m = 64, 4, 8
+    assert gm.gmm_tiles(block_m, embed, width, 4)[0] == width
+    assert gm.gmm_tiles(block_m, embed, width, 4, 128)[0] == 128
+    assert gm.tgmm_tiles(block_m, embed, width, 4)[:2] == (embed, width)
+    idx = jax.random.randint(jax.random.PRNGKey(0), (40, 2), 0, 8)
+    plan = gm.plan_rows(idx, 2, held, block_m=block_m)
+    live = int(plan.n_live[0]) * block_m
+    assert 0 < live < plan.row_valid.shape[0]
+    keys = jax.random.split(jax.random.PRNGKey(1), 5)
+    rows = gm.dispatch(jax.random.normal(keys[0], (40, embed)), plan)
+    rows = jnp.where(jnp.arange(rows.shape[0])[:, None] < live, rows, 0.0)
+    weights = tuple(0.1 * jax.random.normal(k, (held, embed, width))
+                    for k in keys[1:matrices]) + (
+        0.1 * jax.random.normal(keys[3], (held, width, embed)),)
+    cot = jax.random.normal(keys[4], rows.shape)
+
+    def through(block_n):
+        out, vjp = jax.vjp(lambda r, w: gm.expert_products(
+            r, w, plan, block_n=block_n, interpret=True), rows, weights)
+        d_rows, d_weights = vjp(cot)
+        return [np.asarray(a) for a in
+                (out[:live], d_rows[:live], *d_weights)]
+
+    narrow, ruled = through(128), through(None)
+    assert len(narrow) == 2 + matrices
+    assert all(np.isfinite(a).all() for a in narrow + ruled)
+    same = all(np.array_equal(a, b) for a, b in zip(narrow, ruled))
+    print("the rule's tiles against the narrowest cap:",
+          "bit for bit" if same else "to a float32 rounding")
+    for a, b in zip(narrow, ruled):   # a rounding at the sums' scale
+        np.testing.assert_allclose(a, b, rtol=1e-6,
+                                   atol=1e-6 * np.abs(b).max())
+
+
 def _by_loop(lhs, rhs, groups, block_m):
     """Each expert's rows times its matrix, one expert at a time."""
     out = np.zeros((lhs.shape[0], rhs.shape[2]), np.float32)
@@ -540,8 +591,117 @@ def test_moe_plan_span_says_what_was_compiled():
         "buffer_passes": 0, "row_gather": "reach",
         "gather_reaches": "1/8,1/4,1/2,1/1",
         # the sums over tokens: a sequence of 64 is one tile of the walk
-        "walk_tile": 64, "pairs": 64 * 2, "walked": "table", "window": 24,
-        "heads": 4, "kv_heads": 2, "layers": "s,s,f"}
+        "walk_tile": 64, "pairs": 64 * 2, "walked": "table",
+        # the grouped products: an expert's whole matrix a block (k x
+        # tile), one sweep over the rows each
+        "product_tiles": "up 32x32:1, down 32x32:1, drhs 32x32:1x1, "
+                         "drhs_down 32x32:1x1",
+        "product_vmem_bytes": gm.product_tiles(8, 32, 32, 4)[
+            "product_vmem_bytes"],
+        "window": 24, "heads": 4, "kv_heads": 2, "layers": "s,s,f"}
+
+
+def _kernel_calls(jaxpr):
+    """Every ``pallas_call`` of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            inner = getattr(inner, "jaxpr", inner)
+            if hasattr(inner, "eqns") and eqn.primitive.name != "pallas_call":
+                yield from _kernel_calls(inner)
+
+
+#: the four routed configurations as their cells run them: the span's
+#: ``product_tiles`` at the published widths
+PRODUCT_TILES = {
+    "trinity": "up 2048x1024:1, down 1024x2048:1, drhs 2048x1024:1x1, "
+               "drhs_down 1024x2048:1x1",
+    "kanana": "up 2048x768:1, down 768x2048:1, drhs 2048x768:1x1, "
+              "drhs_down 768x2048:1x1",
+    # an expert's d rhs whole is 76 MB of blocks: three blocks of it
+    "nemotron": "up 2688x1856:1, down 1856x2688:1, drhs 896x1856:3x1, "
+                "drhs_down 1856x896:1x3",
+    # where the parent made seven sweeps (896 under 128) and six
+    "mellum": "up 2304x896:1, down 896x2304:1, drhs 2304x896:1x1, "
+              "drhs_down 896x2304:1x1",
+}
+
+
+def _published(name):
+    from ray_tpu.models import deepseek_v3, mellum, nemotron_h
+
+    return {
+        "trinity": afmoe.AFMoEConfig.trinity_mini_share,
+        "kanana": deepseek_v3.DeepseekV3Config.kanana_2_30b_a3b_share,
+        "nemotron":
+            nemotron_h.NemotronHConfig.nemotron_3_nano_30b_a3b_share,
+        "mellum": mellum.MellumConfig.mellum2_12b_a2_5b_stage,
+    }[name]()
+
+
+@pytest.mark.parametrize("name", sorted(PRODUCT_TILES))
+def test_moe_plan_span_names_the_tiles_the_kernels_are_built_with(
+        name, monkeypatch):
+    """``product_tiles`` and ``product_vmem_bytes`` of the ``moe.plan``
+    span at a cell's published widths, and the kernels traced at those
+    widths (compiled form, not run): every grouped product's grid makes
+    the sweeps over the rows the span says, its weight block is the
+    span's, and no kernel asks for more VMEM than the span's bytes.  One
+    function (``gm.gmm_tiles`` / ``gm.tgmm_tiles``), two callers."""
+    import re
+
+    monkeypatch.setattr(afmoe, "BLOCK_ROWS", 256)   # the cells'
+    cfg = _published(name)
+    args = afmoe.routed_plan_args(cfg, 8192)
+    assert args["product_tiles"] == PRODUCT_TILES[name]
+    assert args["block_rows"] == 256
+    assert 16 * 2 ** 20 < args["product_vmem_bytes"] <= gm.VMEM_BUDGET
+    said = {kind: tuple(int(x) for x in rest if x)
+            for kind, *rest in re.findall(
+                r"(\w+) (\d+)x(\d+):(\d+)(?:x(\d+))?",
+                args["product_tiles"])}
+    embed, width = cfg.embed_dim, cfg.expert_dim
+    gated = getattr(cfg, "expert_form", "gated") == "gated"
+    tiles, held = 3, 2
+
+    def shape(*dims, dtype=cfg.dtype):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    def grads(rows, weights, tile_expert, n_live):
+        out, vjp = jax.vjp(lambda r, w: gm._experts(
+            r, w, tile_expert, n_live, 256, None, False), rows, weights)
+        return out, vjp(out)
+
+    weights = (shape(held, embed, width),) * (2 if gated else 1) \
+        + (shape(held, width, embed),)
+    calls = list(_kernel_calls(jax.make_jaxpr(grads)(
+        shape(tiles * 256, embed), weights, shape(tiles, dtype=jnp.int32),
+        shape(1, dtype=jnp.int32)).jaxpr))
+    assert len(calls) == (9 if gated else 6)
+    for eqn in calls:
+        grid = eqn.params["grid_mapping"].grid
+        blocks = [tuple(getattr(b, "block_size", None) for b in m.block_shape)
+                  for m in eqn.params["grid_mapping"].block_mappings]
+        limit = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+        assert 16 * 2 ** 20 <= limit <= args["product_vmem_bytes"]
+        kernel = eqn.params["name"]
+        result = eqn.params["out_avals"][0].shape
+        if "drhs" in kernel:
+            k, n = result[1:]
+            block_k, block_n, over_k, over_n = said[
+                "drhs" if k == embed else "drhs_down"]
+            assert grid == (over_k, over_n, tiles), kernel
+            assert (None, block_k, block_n) in blocks, kernel
+            continue
+        # onto the experts' width (gate, up, the down product's d lhs)
+        # or back onto the rows' (down, gate's and up's d lhs)
+        k, tile, sweeps = said["up" if result[1] == width else "down"]
+        assert grid == (sweeps, tiles), kernel
+        assert k + result[1] == embed + width
+        assert ((None, tile, k) if "_t" in kernel else (None, k, tile)) \
+            in blocks, kernel
 
 
 def test_the_cut_configuration_is_the_file_s():

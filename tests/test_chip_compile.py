@@ -161,7 +161,9 @@ def test_latent_attention_flash_compiles_at_deepseek_v3_shapes(one_chip):
 def test_grouped_products_compile_at_afmoe_widths(one_chip):
     """The worst-case row buffer of one sequence (8 x 8,192 pairs and a
     tile of padding for each of 16 experts) times 16 experts' matrices
-    of 2048 x 1024: the forward kernel, d lhs and d rhs."""
+    of 2048 x 1024: the forward kernel, d lhs and d rhs, at the tiles
+    the rule picks (``gm.gmm_tiles``: an expert's whole matrix) under
+    the VMEM it states."""
     gm = importlib.import_module("ray_tpu.ops.grouped_matmul")
     tiles = 8 * 8192 // 256 + 16
 
@@ -170,7 +172,7 @@ def test_grouped_products_compile_at_afmoe_widths(one_chip):
 
     def grads(lhs, rhs, tile_expert, n_live):
         out, vjp = jax.vjp(lambda a, b: gm._gmm(
-            a, b, tile_expert, n_live, 256, 512, False), lhs, rhs)
+            a, b, tile_expert, n_live, 256, None, False), lhs, rhs)
         return out, vjp(out)
 
     compiled = jax.jit(grads).lower(
@@ -179,13 +181,16 @@ def test_grouped_products_compile_at_afmoe_widths(one_chip):
     assert compiled.as_text().count("tpu_custom_call") == 3
 
 
-#: the three routed cells: experts held, hidden x expert width, choices
-#: a token, tokens a call, matrices an expert (three: gated; two: relu2)
+#: the four routed cells: experts held, hidden x expert width, choices
+#: a token, tokens a call (Mellum: the group's), matrices an expert
+#: (three: gated; two: relu2)
 ROUTED_CELLS = {
     "trinity-mini": (16, 2048, 1024, 8, 8192, 3),
     "kanana-2-30b-a3b": (16, 2048, 768, 6, 16384, 3),
     "nemotron-3-nano-30b-a3b": (8, 2688, 1856, 6, 8192, 2),
+    "mellum2-12b-a2.5b": (16, 2304, 896, 8, 16384, 3),
 }
+
 
 
 @pytest.mark.parametrize("cell", sorted(ROUTED_CELLS))
@@ -195,9 +200,12 @@ def test_fused_expert_products_compile_at_the_cells_widths(one_chip, cell):
     product forming the activation on its lhs tile (1856 wide: the whole
     width, contracted); backward the down product's ``d rhs`` with the
     same prologue, its ``d lhs`` ending in the activation's derivative
-    (two results, gated; under the masked last tile of 384 at 1856), and
-    for gate and up a ``d rhs`` and a ``d lhs``, the second adding the
-    first's tile in place."""
+    (two results, gated), and for gate and up a ``d rhs`` and a ``d
+    lhs``, the second adding the first's tile in place.  Every product
+    at the tiles the rule picks from the shapes (``gm.product_tiles``:
+    an expert's whole matrix a block, 1856 as it lies; Nemotron's ``d
+    rhs`` in three blocks), compiled under the VMEM limit the kernels
+    state: what Mosaic refuses for fast memory it refuses here."""
     gm = importlib.import_module("ray_tpu.ops.grouped_matmul")
     held, hidden, width, top_k, tokens, matrices = ROUTED_CELLS[cell]
     tiles = top_k * tokens // 256 + held
@@ -207,7 +215,7 @@ def test_fused_expert_products_compile_at_the_cells_widths(one_chip, cell):
 
     def grads(rows, weights, tile_expert, n_live):
         out, vjp = jax.vjp(lambda r, w: gm._experts(
-            r, w, tile_expert, n_live, 256, 512, False), rows, weights)
+            r, w, tile_expert, n_live, 256, None, False), rows, weights)
         return out, vjp(out)
 
     weights = (shape((held, hidden, width)),) * (matrices - 1) \

@@ -264,7 +264,11 @@ def test_the_plan_spans_say_what_was_compiled():
         "experts": 8, "held_first": 2, "held": 4, "top_k": 2,
         "row_bound": 64 * 2, "block_rows": 8, "buffer_passes": 0,
         "row_gather": "reach", "gather_reaches": "1/8,1/4,1/2,1/1",
-        "walk_tile": 64, "pairs": 64 * 2, "walked": "table"}
+        "walk_tile": 64, "pairs": 64 * 2, "walked": "table",
+        "product_tiles": "up 32x16:1, down 16x32:1, drhs 32x16:1x1, "
+                         "drhs_down 16x32:1x1",
+        "product_vmem_bytes": gm.product_tiles(8, 32, 16, 4)[
+            "product_vmem_bytes"]}
 
 
 def test_the_scopes_name_the_kernel_call_and_the_up_projection():

@@ -358,7 +358,10 @@ def test_grouped_products_take_a_width_no_tile_divides(k, n):
 @pytest.mark.parametrize("width,block,want", [
     (1856, 512, 384), (1856, 1024, 640), (2688, 512, 384),
     (2688, 1024, 896), (1024, 512, 512), (768, 512, 384), (2048, 1024, 1024),
-    (96, 512, 96), (232, 128, 128)])
+    (96, 512, 96), (232, 128, 128),
+    # Mellum's: 896 = 7 x 128 and 7 is prime, so under any cap below the
+    # whole width the only whole-lane divisor is one register
+    (896, 512, 128), (896, 768, 128), (2304, 512, 384), (2304, 1024, 768)])
 def test_lane_block_keeps_whole_tiles_and_masks_only_what_it_must(
         width, block, want):
     assert gm.lane_block(width, block) == want
@@ -366,6 +369,48 @@ def test_lane_block_keeps_whole_tiles_and_masks_only_what_it_must(
     fit = gm.fit_block(width, block)
     if fit % 128 == 0 or fit == width:
         assert want == fit   # what the tiling chose before: unchanged
+    # under a cap the rule's tile is that one: the widest it may take
+    # (blocks of 1,024 lanes and under always fit the budget)
+    assert gm.lane_tiles(width, block)[0] == want
+    assert gm.gmm_tiles(256, 2048, width, 2, block)[0] == want
+    assert gm.tgmm_tiles(256, 2048, width, 2, block)[1] == want
+    # with no cap the whole width comes first, then whole-lane divisors;
+    # a masked tile is the last resort, where nothing divides
+    free = gm.lane_tiles(width)
+    assert free[0] == width and gm.lane_block(width, 512) in free
+    assert all(width % b == 0 for b in free[:-1])
+    assert width % free[-1] == 0 or free[-1] % 128 == 0
+
+
+@pytest.mark.parametrize("k,n,want,d_rhs", [
+    # the four configurations' products, bfloat16 under 256 rows: an
+    # expert's whole matrix one block, the width as it lies in HBM
+    (2048, 1024, 1024, (2048, 1024)), (1024, 2048, 2048, (1024, 2048)),
+    (2048, 768, 768, (2048, 768)), (768, 2048, 2048, (768, 2048)),
+    (2304, 896, 896, (2304, 896)), (896, 2304, 2304, (896, 2304)),
+    # 1856 whole forward; its d rhs whole is 76 MB of blocks and is split
+    # where the rows cross HBM least: 2688 three ways (whole lanes) and
+    # never 1856, which nothing divides
+    (2688, 1856, 1856, (896, 1856)), (1856, 2688, 2688, (1856, 896)),
+    # what the budget refuses, by whole-lane divisors, widest first; d
+    # rhs in the blocks under which the rows cross HBM least (4 x 4096 +
+    # 2 x 8192 lanes a row)
+    (4096, 8192, 1024, (2048, 2048)),
+    # under 128 lanes (tests' shapes): whole
+    (32, 32, 32, (32, 32))])
+def test_the_rule_takes_the_widest_tile_the_budget_holds(k, n, want, d_rhs):
+    """``gmm_tiles`` and ``tgmm_tiles``, no cap: the whole width first,
+    then whole-lane divisors, by the blocks' own bytes against the
+    module's VMEM budget."""
+    tile, vmem = gm.gmm_tiles(256, k, n, 2)
+    assert tile == want and vmem == gm._gmm_vmem(256, k, want, 2)
+    assert vmem <= gm.VMEM_BUDGET
+    wider = [b for b in gm.lane_tiles(n) if b > tile]
+    assert all(gm._gmm_vmem(256, k, b, 2) > gm.VMEM_BUDGET for b in wider)
+    *blocks, vmem = gm.tgmm_tiles(256, k, n, 2)
+    assert tuple(blocks) == d_rhs and vmem <= gm.VMEM_BUDGET
+    if d_rhs != (k, n):
+        assert gm._tgmm_vmem(256, k, n, 2) > gm.VMEM_BUDGET
 
 
 # ---------------------------------------------------------------------------
@@ -416,6 +461,10 @@ def test_the_plan_spans_say_what_was_compiled():
         "row_bound": 64 * 2, "block_rows": 8, "buffer_passes": 0,
         "row_gather": "reach", "gather_reaches": "1/8,1/4,1/2,1/1",
         "walk_tile": 64, "pairs": 64 * 2, "walked": "table",
+        "product_tiles": "up 32x24:1, down 24x32:1, drhs 32x24:1x1, "
+                         "drhs_down 24x32:1x1",
+        "product_vmem_bytes": gm.product_tiles(8, 32, 24, 4)[
+            "product_vmem_bytes"],
         "form": "relu2"}
 
 
@@ -524,13 +573,13 @@ GOLDEN = {
     "deepseek_v3":
         "e0120260cefb64c7e92794f586fc586100d4d4a5e661b2ed2965010b34c32116",
     "gmm_1024_2048":
-        "b5d6752993cf917f77917231af657799875b593877b802587f43eb837f5144a0",
+        "4ab5cd396489efe5707466e78a6868beed355142b351f4a7f5853f9812edf078",
     "gmm_2048_1024":
-        "6258bfc7d186e55d2fbb09a027b6e1aecce7f8d1b312331b54522618581d4d9f",
+        "a4d348a5b4129d0b32e8fc62b51abf97729913ef9cad79665a33706ad4c50f88",
     "gmm_2048_768":
-        "2eea7f1639c71a0a85f3bf1bf77158b88c8943079b327fbf880ec5b60f47ad14",
+        "7955d35bb3377fa255af5c60cb7f64246cfca54696524c1fcc9f7f3fa36920a9",
     "gmm_768_2048":
-        "421e998f8a1dd7438b018af5084ba9ed683ca9eb7141d900f09a6143cc1bf3be",
+        "f4023cff2bb787fd33436965271a632a584267dffdcd946d793f108d4f39de68",
 }
 
 

@@ -59,18 +59,20 @@ BEFORE = {
     # layer's sums over tokens are kernel calls (``ops/grouped_matmul.py``
     # ``_walk_pallas``) where they were a gather a choice, and
     # ``combine`` rounds its sums itself; the parameter trees are what
-    # they were, and GPT-2 runs none of it
+    # they were, and GPT-2 runs none of it; the three again at PR 52: the
+    # grouped products' kernels take an expert's whole matrix a block
+    # (their grids, blocks and ``vmem_limit_bytes`` are in the text)
     ("afmoe", ""): (
-        "38a0c3242ae259e8589691ca70c0d7498618c7110a5a467ba460f6a2b22cd443",
+        "4178b72c3fa93d5d8c6cb5e3d15c856151a3a1889249dfd46d27b14d08989b22",
         "73a54f273ca8d1cab30e4c5907da0f975cba6a8dcc30a4dda90aad118cc3b47d"),
     ("afmoe", "full"): (
-        "18f70711abfa4da4fe8523d6cc67ea4da421318d8961b2a46d61d8668bd29b2e",
+        "a51ef50a0140c51b48134014958dcd52ca821fb036abfba7fc481d9f4bb575d4",
         "73a54f273ca8d1cab30e4c5907da0f975cba6a8dcc30a4dda90aad118cc3b47d"),
     ("deepseek_v3", ""): (
-        "8f40e4af0b454b125be74d905803a4efd3492218bc9fa42bafa494f87017acba",
+        "fb6f7b446e704a0ab809eb8aa58a77afbd77e952025cce23d49e8012c3655a11",
         "80259fb632cdea7eb743ab44b67d71fd30d782bcfb29589957d50eecca4b4839"),
     ("deepseek_v3", "full"): (
-        "24ffcd5a6b950efccd4fecb64282cee7c749c69cbd8f7c80b4da0ce79250b85f",
+        "82536dabb9454f2cfa1fb624788b7da17fd9a9044676495291213a80ee58fd5d",
         "80259fb632cdea7eb743ab44b67d71fd30d782bcfb29589957d50eecca4b4839"),
     # re-pinned at PR 45 too: the mixer's convolution is two kernel
     # calls (``ops/short_conv.py``) where it was XLA's passes; and at
@@ -78,10 +80,10 @@ BEFORE = {
     # (``_SplitDense``), and the gated norm rounds its own result
     # (``ops/gate_norm.py``; ``tiny``'s groups of 32 take its ``jnp`` form)
     ("nemotron_h", ""): (
-        "9e35f1b30dfe192a0637f1e05dab4c628ef092d15af655d44b0e69a25e74b99e",
+        "8773b94f86659dc3045349558288f3032e7a0ac1d68904e48ee6f9fcea234e40",
         "dc97618ee45dda61ed25a95141a3b81a3c70d427ead3341d15b02ac03fd1409e"),
     ("nemotron_h", "full"): (
-        "60227b7f7aaac727061f8a899a65f96d8078a05ab18e7cad3d8deada2734e28d",
+        "fd2eaf2d807b2dfc5a0c146fb64c62be4e9fc4f9d1df4414db3f84db2fa145ad",
         "dc97618ee45dda61ed25a95141a3b81a3c70d427ead3341d15b02ac03fd1409e"),
 }
 
