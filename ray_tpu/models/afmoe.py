@@ -92,7 +92,8 @@ class AFMoEConfig:
     param_dtype: Any = jnp.float32
     #: scores, top-k and weights; float32 as the source's router
     router_dtype: Any = jnp.float32
-    #: "" | "full": each part of a layer recomputed in the backward pass
+    #: "" | "full": each part of a layer recomputed in the backward
+    #: pass, but for a routed call's decisions (``step.remat``)
     remat: str = ""
 
     @classmethod
@@ -162,7 +163,13 @@ def routed_plan_args(cfg, tokens: int) -> Dict[str, Any]:
     ``product_tiles``: the grouped products' weight blocks and their
     sweeps over the rows, ``product_vmem_bytes`` the most VMEM a kernel
     of theirs is given, both from the function the kernels take their
-    tiles from (``gm.product_tiles``)."""
+    tiles from (``gm.product_tiles``).  ``kept``: what a recompute of
+    the call replays and does not make again (``step.KEPT``: the
+    router's choices and the row plan, made once, in the forward);
+    ``kept_bytes``: their bytes a call, from the shapes."""
+    idx = jax.ShapeDtypeStruct((tokens, cfg.top_k), jnp.int32)
+    plan = jax.eval_shape(lambda i: _tables(gm.plan_rows(
+        i, 0, cfg.experts_held[1], block_m=BLOCK_ROWS)), idx)
     return {"experts": cfg.num_experts, "held_first": cfg.experts_held[0],
             "held": cfg.experts_held[1], "top_k": cfg.top_k,
             "row_bound": tokens * cfg.top_k, "block_rows": BLOCK_ROWS,
@@ -170,6 +177,9 @@ def routed_plan_args(cfg, tokens: int) -> Dict[str, Any]:
             "gather_reaches": ",".join(f"1/{r}" for r in gm.REACHES),
             "walk_tile": gm.walk_tile(tokens, cfg.embed_dim) or 0,
             "pairs": tokens * cfg.top_k, "walked": "table",
+            "kept": ",".join(step.KEPT),
+            "kept_bytes": sum(a.size * a.dtype.itemsize
+                              for a in (idx, *plan.values())),
             **gm.product_tiles(BLOCK_ROWS, cfg.embed_dim, cfg.expert_dim,
                                jnp.dtype(cfg.dtype).itemsize)}
 
@@ -228,17 +238,65 @@ def route(cfg, h: jax.Array, w_router: jax.Array,
     ``h [T, E]`` -> ``(expert ids [T, k], weights [T, k] f32, the
     router's own choice [T, k])``.  ``chosen [T, k]``: a recorded routing
     to replay, in place of the router's own largest (the weights are
-    still its scores')."""
+    still its scores').
+
+    The weights are the scores AT the ids, whoever chose them, and the
+    router's own ids are a decision the call keeps (``step.keep``): a
+    recompute (``step.remat``) reads the forward's ids and weighs them
+    by the recomputed scores, so its ``top_k`` is dead and a weight can
+    lie on no other expert's row than the kept plan's."""
     logits = jnp.dot(h.astype(cfg.router_dtype),
                      w_router.astype(cfg.router_dtype),
                      precision=jax.lax.Precision.HIGHEST)
     scores = _SCORES[getattr(cfg, "score_func", "sigmoid")](logits)
-    top, own = jax.lax.top_k(scores, cfg.top_k)
-    idx = own
-    if chosen is not None:
-        idx, top = chosen, jnp.take_along_axis(scores, chosen, axis=1)
-    top = top.astype(jnp.float32)
+    own = jax.lax.top_k(scores, cfg.top_k)[1]
+    # the kept copy stays in here; ``own`` goes out as it came
+    idx = step.keep("choices", own) if chosen is None else chosen
+    top = _scores_at(scores, idx).astype(jnp.float32)
     return idx, cfg.route_scale * top / top.sum(-1, keepdims=True), own
+
+
+def _scores_at(scores: jax.Array, idx: jax.Array) -> jax.Array:
+    """``scores [T, E]`` at ``idx [T, k]`` (a token's ids distinct):
+    ``take_along_axis`` in every bit, forward and backward, as a select
+    against the ids and a sum over the experts, one nonzero term a
+    choice.  A gather of ``[T, k]`` entries and its scatter backward are
+    serial work on a TPU (0.47 ms a call at Trinity's 8,192 x 8, forward
+    and recomputed: 7.5 ms a step, PERF.md section 6, PR 53); this is a
+    fused pass over ``[T, k, E]`` (compiled for a described v5e,
+    Nemotron's step holds 29 MB more with it than with the gather and
+    Mellum's peaks 0.75 GiB lower).  The
+    barrier keeps it apart from the sum over the chosen that follows: a
+    TPU fuses the two into one reduce, adds a token's weights in another
+    order, and the last bit of a quarter of them moves
+    (``tests/test_chip_compile.py`` holds the text compiled for a v5e to
+    two fusions)."""
+    at = idx[:, :, None] == jnp.arange(scores.shape[1], dtype=idx.dtype)
+    return jax.lax.optimization_barrier(
+        jnp.where(at, scores[:, None, :], 0).sum(-1))
+
+
+def _tables(plan) -> Dict[str, jax.Array]:
+    """The arrays of a ``gm.RowPlan`` that dispatch, the products and
+    combine read, forward and backward; ``sizes`` and ``fits`` are
+    reported, not read."""
+    return {f: getattr(plan, f) for f in (
+        "row_pair", "row_valid", "pair_row", "pair_valid", "tile_expert",
+        "n_live")}
+
+
+def _kept(plan):
+    """``plan`` (``gm.plan_rows``) with the tables that are read stamped
+    as a decision the call keeps (``step.keep``): what reads THIS copy
+    under a recompute (``step.remat``) reads the forward's tables, and
+    no plan is made again.  The caller's ``plan`` stays as it came, for
+    what leaves the call (``sizes``, ``n_live``): a kept value that left
+    a ``shard_map`` body unread would fail the trace (jax 0.9.0,
+    ``partial_eval``: its residual is a ``DropVar``).  Called beside
+    ``plan_rows`` and outside the ``moe.plan`` scope: what takes a kept
+    table's shape again in the backward pass is no plan made again, and
+    stands under no part."""
+    return plan._replace(**step.keep("plan", _tables(plan)))
 
 
 def _landed_sums(cfg, rows: jax.Array, weights: jax.Array, matrices,
@@ -271,7 +329,15 @@ class RoutedExperts(nn.Module):
     that shares the layer: ``experts_held`` is then the group's share,
     the parameters lie over the axis by expert, ``h``'s batch is split
     over it, and the layer runs with its exchange
-    (:meth:`_exchanged`)."""
+    (:meth:`_exchanged`).
+
+    A call DECIDES once: the router's own choices ``[T, k]``
+    (:func:`route`) and the row plan made from them (:func:`_kept`,
+    beside ``plan_rows``) are stamped with the names a block's
+    ``step.remat`` keeps, so the recompute of the part replays them.  A
+    decision has no backward, so making it again bought nothing, and a
+    ``top_k`` taken again from recomputed scores could pick another
+    expert at a near tie: the backward of a routing the loss never ran."""
     config: Any
 
     @nn.compact
@@ -313,12 +379,13 @@ class RoutedExperts(nn.Module):
         with step.scope("moe.plan"):
             # buffers for the worst case: every pair may land here
             plan = gm.plan_rows(idx, first, held, block_m=BLOCK_ROWS)
+        kept = _kept(plan)
         self.sow("intermediates", "expert_load", plan.sizes)
         self.sow("intermediates", "expert_choice", own)
         # of that buffer's tiles the live ones alone are worked on
         self.sow("intermediates", "live_tiles", plan.n_live[0])
         self.sow("intermediates", "buffer_tiles", plan.tile_expert.shape[0])
-        routed = _landed_sums(cfg, flat, weights, (*into, w_down), plan)
+        routed = _landed_sums(cfg, flat, weights, (*into, w_down), kept)
         with step.scope("moe.combine"):
             return routed.reshape(batch, seq, embed)
 
@@ -354,7 +421,8 @@ class RoutedExperts(nn.Module):
                 plan = gm.plan_rows(
                     idx, first + own_held * jax.lax.axis_index(axis),
                     own_held, block_m=BLOCK_ROWS)
-            part = _landed_sums(cfg, rows, weights, matrices, plan)
+            kept = _kept(plan)
+            part = _landed_sums(cfg, rows, weights, matrices, kept)
             with step.scope("moe.exchange"):
                 mine = expert.scatter_sums(part, axis)
             return mine, plan.sizes, own, plan.n_live
@@ -470,10 +538,15 @@ def each_sequence(parts, x: jax.Array,
 
 class AFMoEBlock(nn.Module):
     """One layer: its two parts, each over one sequence at a time and
-    each recomputed on its own in the backward pass under ``remat``: the
-    backward of a part then holds that part's activations for 8,192
-    tokens alone (both parts over a batch of two are 3 GiB, which the
-    training state leaves no room for beside a gradient check)."""
+    each recomputed on its own in the backward pass under ``remat``
+    (``step.remat``): the backward of a part then holds that part's
+    activations for 8,192 tokens alone (both parts over a batch of two
+    are 3 GiB, which the training state leaves no room for beside a
+    gradient check).  What a routed call DECIDED is not recomputed: its
+    choices and its row plan are kept from the forward (under 1 MB a
+    call at the cell's shapes) and the recompute replays them, so the
+    backward is of the routing the loss ran, and ``plan_rows`` and the
+    router's ``top_k`` run once a call."""
     config: AFMoEConfig
     kind: str      # "sliding" | "full"
     routed: bool   # an expert layer, or a leading dense one
@@ -484,7 +557,7 @@ class AFMoEBlock(nn.Module):
         cfg = self.config
         attn, mlp = AttentionPart, MLPPart
         if cfg.remat == "full":
-            attn, mlp = nn.remat(attn), nn.remat(mlp)
+            attn, mlp = step.remat(attn), step.remat(mlp)
         return each_sequence((attn(cfg, self.kind, name="attn"),
                               mlp(cfg, self.routed, name="mlp")), x, chosen)
 

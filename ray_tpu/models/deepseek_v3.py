@@ -27,7 +27,8 @@ kanana-2-30b-a3b.json``):
 The source's selection bias (``e_score_correction_bias``, updated
 outside the gradient, zero at initialisation) is left out, as in
 ``afmoe.py``.  Every layer runs its two parts over one sequence of the
-batch at a time, each recomputed on its own under ``remat``.
+batch at a time, each recomputed on its own under ``remat`` (a routed
+call's choices and row plan kept: ``models/step.py``).
 """
 
 from __future__ import annotations
@@ -215,7 +216,8 @@ class MLPPart(nn.Module):
 class DeepseekV3Block(nn.Module):
     """One layer: its two parts, each over one sequence at a time and
     each recomputed on its own in the backward pass under ``remat``, as
-    ``afmoe.AFMoEBlock``."""
+    ``afmoe.AFMoEBlock``: but for a routed call's choices and row plan,
+    which are kept from the forward (``step.remat``)."""
     config: DeepseekV3Config
     routed: bool   # an expert layer, or a leading dense one
 
@@ -225,7 +227,7 @@ class DeepseekV3Block(nn.Module):
         cfg = self.config
         attn, mlp = AttentionPart, MLPPart
         if cfg.remat == "full":
-            attn, mlp = nn.remat(attn), nn.remat(mlp)
+            attn, mlp = step.remat(attn), step.remat(mlp)
         return each_sequence((attn(cfg, name="attn"),
                               mlp(cfg, self.routed, name="mlp")), x, chosen)
 

@@ -153,7 +153,11 @@ class MellumConfig:
         chips = mesh.shape[self.expert_axis] if mesh is not None else 1
         held = dataclasses.replace(self, experts_held=(
             self.experts_held[0], self.experts_held[1] // chips))
-        return {**afmoe.routed_plan_args(held, chips * tokens),
+        args = afmoe.routed_plan_args(held, chips * tokens)
+        # a chip keeps the choices of the tokens it routes itself, and
+        # the plan over the group's
+        args["kept_bytes"] -= (chips - 1) * tokens * self.top_k * 4
+        return {**args,
                 "router": self.score_func, "window": self.window,
                 "heads": self.num_heads, "kv_heads": self.kv_heads,
                 "layers": ",".join(k[0] for k in self.layer_kinds())}
@@ -294,7 +298,10 @@ class MellumBlock(nn.Module):
     sequence a chip) and each recomputed on its own in the backward pass
     under ``remat``, as ``afmoe.AFMoEBlock``; the routed part, which
     sees a token at a time, over ``routed_tokens`` of the sequence a
-    call (an exchange a call)."""
+    call (an exchange a call).  What a routed call DECIDED is kept, not
+    recomputed (``step.remat``): a chip's own choices and its plan over
+    the GROUP's pairs, 1.46 MB a call at the cell's shapes, so the
+    recompute gathers no choices, sorts nothing and takes no ``top_k``."""
     config: MellumConfig
     kind: str      # "sliding" | "full"
 
@@ -304,7 +311,7 @@ class MellumBlock(nn.Module):
         cfg = self.config
         attn, mlp = AttentionPart, MLPPart
         if cfg.remat == "full":
-            attn, mlp = nn.remat(attn), nn.remat(mlp)
+            attn, mlp = step.remat(attn), step.remat(mlp)
         attn, mlp = attn(cfg, self.kind, name="attn"), mlp(cfg, name="mlp")
         group = sequences_a_call()
         batch, seq, embed = x.shape

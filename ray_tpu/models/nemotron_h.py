@@ -29,7 +29,8 @@ residual, ``x = x + part(norm(x))``:
 The source's selection bias (``e_score_correction_bias``, updated
 outside the gradient, zero at initialisation) is left out, as in
 ``afmoe.py``.  Every layer runs its part over one sequence of the batch
-at a time, recomputed on its own under ``remat``.
+at a time, recomputed on its own under ``remat`` (a routed call's
+choices and row plan kept: ``models/step.py``).
 """
 
 from __future__ import annotations
@@ -360,7 +361,10 @@ PARTS = {"M": (MixerPart, "mixer", "m", ("ssm.in_proj", "ssm.out_proj")),
 
 class HybridBlock(nn.Module):
     """One layer: its one part, over one sequence at a time and
-    recomputed on its own in the backward pass under ``remat``."""
+    recomputed on its own in the backward pass under ``remat``: but for
+    a routed call's choices and row plan, which are kept from the
+    forward (``step.remat``; a mixer or an attention layer names
+    nothing, so nothing of it is kept)."""
     config: NemotronHConfig
     kind: str      # "M" | "E" | "*"
 
@@ -369,7 +373,7 @@ class HybridBlock(nn.Module):
                  chosen: Optional[jax.Array] = None) -> jax.Array:
         part, name, _, around = PARTS[self.kind]
         if self.config.remat == "full":
-            part = nn.remat(part)
+            part = step.remat(part)
         return each_sequence((part(self.config, name=name),), x, chosen,
                              around)
 

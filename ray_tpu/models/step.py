@@ -15,6 +15,16 @@ are ``attn``; ``mla.kv_up`` is a child of whatever part it stands in),
 so it is in at most one.  A scope is a Python context at trace time and
 a string in the instruction's metadata: the jaxpr, the compiled code
 and the step's memory are what they are without it.
+
+**What a block recomputes, said once too.**  A block that recomputes a
+part in the backward pass wraps it in :func:`remat`, and that recomputes
+everything BUT what the part stamped with a name of :data:`KEPT`
+(:func:`keep`): the discrete decisions of a routed call, the router's
+choices ``[T, k]`` and the row plan made from them.  A decision is not
+recomputed: it has no backward, so making it again buys nothing (an
+``argsort`` and a ``top_k`` a layer-call), and a recomputed ``top_k``
+whose near tie falls the other way would take the backward for a
+routing the loss never ran.  A part that stamps nothing keeps nothing.
 """
 
 from __future__ import annotations
@@ -25,6 +35,7 @@ from typing import Any, Callable, Optional
 
 import flax.linen as nn
 import jax
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.core import telemetry
 
@@ -33,6 +44,10 @@ PARTS = ("embed", "attn", "mlp", "moe.route", "moe.exchange", "moe.plan",
          "moe.dispatch", "moe.experts", "moe.combine", "ssm.in_proj",
          "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out_proj", "head",
          "exit", "optimizer")
+
+#: what a recomputed part keeps of its forward: a routed call's decisions
+KEPT = ("choices", "plan")
+_KEEP_THESE = jax.checkpoint_policies.save_only_these_names(*KEPT)
 
 
 def part_of(component: str) -> Optional[str]:
@@ -70,6 +85,29 @@ def named_children():
     """The other half of :func:`names_its_parts`, around the body of
     such a module's ``__call__``."""
     return nn.override_named_call(True)
+
+
+def remat(module):
+    """``module`` (a flax class), recomputed in the backward pass but for
+    what its forward stamped with a name of :data:`KEPT`: the ONE way a
+    block wraps a part for recompute."""
+    return nn.remat(module, policy=_KEEP_THESE)
+
+
+def keep(name: str, tree):
+    """``tree``, every array of it stamped ``name`` (of :data:`KEPT`):
+    under :func:`remat` the backward pass reads the forward's values and
+    what made them is not run again; anywhere else, ``tree`` as it is.
+    An array is kept FLAT and takes its shape again behind the stamp: a
+    TPU lays a last axis out over 128 lanes, so a kept ``[T, 4]`` holds
+    32 times its bytes from the forward to the backward (Mellum's step
+    compiled for a described v5e: a peak of 14.91 GiB with the tables
+    kept in their shape, 14.16 flat; ``tests/test_chip_compile.py``
+    holds it under 14.5)."""
+    if name not in KEPT:
+        raise ValueError(f"{name!r} is not kept under remat: {KEPT}")
+    return jax.tree.map(lambda a: checkpoint_name(
+        a.reshape(-1), name).reshape(a.shape), tree)
 
 
 def make_train_step(loss: Callable[[Any, jax.Array], jax.Array], tx, *,

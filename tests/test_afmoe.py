@@ -592,6 +592,13 @@ def test_moe_plan_span_says_what_was_compiled():
         "gather_reaches": "1/8,1/4,1/2,1/1",
         # the sums over tokens: a sequence of 64 is one tile of the walk
         "walk_tile": 64, "pairs": 64 * 2, "walked": "table",
+        # what a recompute keeps of the call (``step.remat``): the choices
+        # [64, 2] int32 and the plan's tables over 128 / 8 + 4 tiles of 8
+        # rows (``row_pair`` int32 and ``row_valid``, ``pair_row`` int32
+        # and ``pair_valid``, a tile's expert, the live tiles' count)
+        "kept": "choices,plan",
+        "kept_bytes": 64 * 2 * 4 + 160 * (4 + 1) + 128 * (4 + 1) + 20 * 4
+                      + 4,
         # the grouped products: an expert's whole matrix a block (k x
         # tile), one sweep over the rows each
         "product_tiles": "up 32x32:1, down 32x32:1, drhs 32x32:1x1, "

@@ -237,6 +237,47 @@ def test_fused_expert_products_compile_at_the_cells_widths(one_chip, cell):
     assert _passes_over_the_row_buffer(text, tiles * 256) == []
 
 
+#: the four cells' routers: experts published, the scores' function,
+#: tokens a chip routes a call (Mellum: its own quarter of the group's)
+ROUTERS = {
+    "trinity-mini": (128, "sigmoid", 8192),
+    "kanana-2-30b-a3b": (128, "sigmoid", 16384),
+    "nemotron-3-nano-30b-a3b": (128, "sigmoid", 8192),
+    "mellum2-12b-a2.5b": (64, "softmax", 4096),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(ROUTED_CELLS))
+def test_a_routers_weights_are_made_apart_from_their_sum(one_chip, cell):
+    """``afmoe.route`` for a described v5e at the cells' shapes: the
+    scores at the ids (``afmoe._scores_at``: a select against the ids and
+    a sum over the experts) are ONE fusion's one result ``[T, k]``, and
+    the sum over a token's chosen that normalises them is another's.
+    Fused into one reduce (which is what this compiler does without the
+    barrier ``_scores_at`` ends in: a second result ``[T]`` of the same
+    fusion), a token's weights are added in another order than after
+    ``top_k``, and on the chip a quarter of them leave the parent's last
+    bit and the first loss with them (PERF.md section 6, PR 53).  The
+    CPU's tests cannot see that; this holds the compiled text to it."""
+    import types
+
+    afmoe = importlib.import_module("ray_tpu.models.afmoe")
+    _, hidden, _, top_k, _, _ = ROUTED_CELLS[cell]
+    experts, score, tokens = ROUTERS[cell]
+    cfg = types.SimpleNamespace(router_dtype=jnp.float32, top_k=top_k,
+                                route_scale=2.5, score_func=score)
+    text = jax.jit(lambda h, w: afmoe.route(cfg, h, w)[:2]).lower(
+        jax.ShapeDtypeStruct((tokens, hidden), jnp.bfloat16,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((hidden, experts), jnp.float32,
+                             sharding=one_chip)).compile().as_text()
+    results = [line.split(" fusion(")[0].split(" = ", 1)[1]
+               for line in text.splitlines()
+               if " fusion(" in line and "reduce_sum" in line
+               and f"f32[{tokens},{top_k}]" in line.split(" fusion(")[0]]
+    assert results and all(r.startswith("f32[") for r in results), results
+
+
 @pytest.mark.parametrize("cell", sorted(ROUTED_CELLS))
 def test_the_walks_compile_at_the_cells_shapes(one_chip, cell):
     """The routed layer's three sums over tokens as ``RoutedExperts``
@@ -532,6 +573,16 @@ def _compiled_step(module, model, batch, one_chip):
     return compiled.as_text(), params, total
 
 
+def _plans_remade(text):
+    """Instructions of the backward pass's recompute that stand under
+    ``moe.plan``: none, since a routed call's choices and row plan are
+    kept from the forward (``models/step.py`` ``remat``, PR 53), and with
+    them no second ``argsort`` a layer-call."""
+    return [line[:120] for line in text.splitlines()
+            if re.search(r'op_name="[^"]*rematted_computation[^"]*moe\.plan',
+                         line)]
+
+
 def _kernel_calls(text):
     return [line for line in text.splitlines()
             if "tpu_custom_call" in line and " custom-call(" in line]
@@ -607,7 +658,9 @@ def test_gated_share_steps_count_their_kernels_and_pass_no_row_buffer(
     at the published widths: 4 and 5 expert layers x 2 and 1 sequences x
     3 products x (2 forward, d lhs, d rhs) grouped kernels, which is what
     the benchmark's readers count, and around them no pass of XLA over a
-    call's worst-case row buffer."""
+    call's worst-case row buffer.  The recompute makes no plan: a call's
+    choices and tables are kept from the forward, 0.94 MB x 8 calls and
+    1.40 MB x 5 (the ``moe.plan`` span's ``kept_bytes``)."""
     if cell == "trinity-mini":
         module = importlib.import_module("ray_tpu.models.afmoe")
         model = module.AFMoE(module.AFMoEConfig.trinity_mini_share(
@@ -632,12 +685,18 @@ def test_gated_share_steps_count_their_kernels_and_pass_no_row_buffer(
     # PERF.md section 7)
     _walks(kernels, calls // 12, sums=3 if cell == "trinity-mini" else 2)
     assert _kernel_results_of_rows(kernels, rows) == []
+    assert _plans_remade(text) == []
+    assert "moe.plan" in text
     assert total < 0.9 * V5E_HBM_BYTES, f"{total / 2**30:.2f} GiB"
 
 
 def test_nemotron_share_train_step_fits_one_v5e(one_chip):
     """``nemotron-3-nano-30b-a3b.steady``'s step: EMEMEMEM* at the
-    published widths, batch 2 x 8,192, donated state."""
+    published widths, batch 2 x 8,192, donated state; in it the 0.70 MB
+    x 8 calls of choices and row plans the recompute replays (PR 53).
+    The line below had 38 MB of room (11.803 GiB on the parent); the
+    step reads 11.832 with the weights selected at the ids
+    (``afmoe._scores_at``), 8 MB under it."""
     nh, cfg, model = _nemotron_share()
     text, params, total = _compiled_step(nh, model, 2, one_chip)
     assert sum(a.size for a in jax.tree.leaves(params)) == 666_962_944
@@ -673,6 +732,7 @@ def test_nemotron_share_train_step_fits_one_v5e(one_chip):
     assert _passes_over_the_row_buffer(text, 6 * 8192 + 8 * 256) == []
     _walks(calls, 8)
     assert _kernel_results_of_rows(calls, 6 * 8192 + 8 * 256) == []
+    assert _plans_remade(text) == []
     # what it took before the routed layer kept to its live rows (PR 35:
     # 11.83 GiB), and a hundredth of a GiB
     assert total < 11.84 * 2 ** 30, f"{total / 2**30:.3f} GiB"
@@ -870,9 +930,12 @@ def _mellum_step(topo, depth):
 def _exchange_and_placement(compiled, params, specs, depth):
     """What holds of the step at any depth: the exchange is all-gathers
     of the group's rows and reduce-scatters of the parts, never an
-    all-to-all; the experts lie by expert and the state is donated."""
+    all-to-all; the experts lie by expert and the state is donated; the
+    recompute makes no plan (a chip keeps its own choices and its plan
+    over the group's pairs from the forward: 1.46 MB a call, PR 53)."""
     text = compiled.as_text()
     assert text.count(" all-to-all(") == 0
+    assert _plans_remade(text) == [] and "moe.plan" in text
     # a call of a routed layer gathers the four chips' 4,096 rows of
     # 2304 (forward and recomputed forward), and the backward of the
     # scatter gathers as many: 2 sequences x 2 pieces a layer
@@ -912,7 +975,8 @@ def test_mellum_step_exchanges_over_four_v5e(topo):
 @pytest.mark.slow
 def test_mellum_stage_step_fits_four_v5e(topo):
     """The whole period at the cell's batch: 7.91 GiB of state a chip
-    with the gradients, and the step's temporaries beside it."""
+    with the gradients, and the step's temporaries beside it, the 16
+    calls' kept choices and plans (23 MB a chip, PR 53) among them."""
     compiled, params, specs = _mellum_step(topo, 4)
     assert sum(a.size for a in jax.tree.leaves(params)) == 2_123_976_960
     _, mem = _exchange_and_placement(compiled, params, specs, 4)

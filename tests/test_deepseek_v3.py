@@ -265,6 +265,13 @@ def test_the_plan_spans_say_what_was_compiled():
         "row_bound": 64 * 2, "block_rows": 8, "buffer_passes": 0,
         "row_gather": "reach", "gather_reaches": "1/8,1/4,1/2,1/1",
         "walk_tile": 64, "pairs": 64 * 2, "walked": "table",
+        # what a recompute keeps of the call (``step.remat``): the choices
+        # [64, 2] int32 and the plan's tables over 128 / 8 + 4 tiles of 8
+        # rows (``row_pair`` int32 and ``row_valid``, ``pair_row`` int32
+        # and ``pair_valid``, a tile's expert, the live tiles' count)
+        "kept": "choices,plan",
+        "kept_bytes": 64 * 2 * 4 + 160 * (4 + 1) + 128 * (4 + 1) + 20 * 4
+                      + 4,
         "product_tiles": "up 32x16:1, down 16x32:1, drhs 32x16:1x1, "
                          "drhs_down 16x32:1x1",
         "product_vmem_bytes": gm.product_tiles(8, 32, 16, 4)[

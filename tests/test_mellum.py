@@ -302,6 +302,11 @@ def test_plan_spans_say_what_exchange_was_compiled(mesh4):
     moe = rows["moe.plan"]["args"]
     assert (moe["experts"], moe["held"], moe["top_k"], moe["router"],
             moe["pairs"], moe["row_bound"]) == (8, 2, 2, "softmax", 256, 256)
+    # kept for the recompute, a call: a chip's OWN choices [32, 2] and
+    # its plan over the group's 256 pairs (256 / 8 + 2 tiles of 8 rows)
+    assert (moe["kept"], moe["kept_bytes"]) == (
+        "choices,plan",
+        32 * 2 * 4 + 272 * (4 + 1) + 256 * (4 + 1) + 34 * 4 + 4)
     # no mesh: no exchange to speak of
     jax.eval_shape(lambda p: ml.loss_fn(model, p, tokens), params)
     rows = {r["name"]: r for r in telemetry.drain_spans("test")}

@@ -461,6 +461,13 @@ def test_the_plan_spans_say_what_was_compiled():
         "row_bound": 64 * 2, "block_rows": 8, "buffer_passes": 0,
         "row_gather": "reach", "gather_reaches": "1/8,1/4,1/2,1/1",
         "walk_tile": 64, "pairs": 64 * 2, "walked": "table",
+        # what a recompute keeps of the call (``step.remat``): the choices
+        # [64, 2] int32 and the plan's tables over 128 / 8 + 4 tiles of 8
+        # rows (``row_pair`` int32 and ``row_valid``, ``pair_row`` int32
+        # and ``pair_valid``, a tile's expert, the live tiles' count)
+        "kept": "choices,plan",
+        "kept_bytes": 64 * 2 * 4 + 160 * (4 + 1) + 128 * (4 + 1) + 20 * 4
+                      + 4,
         "product_tiles": "up 32x24:1, down 24x32:1, drhs 32x24:1x1, "
                          "drhs_down 24x32:1x1",
         "product_vmem_bytes": gm.product_tiles(8, 32, 24, 4)[
@@ -566,12 +573,16 @@ def _gmm_text(k, n):
 #: tree, which changes all six ON PURPOSE too: ``dispatch`` and
 #: ``combine`` carry the kernels' switch to their backward passes (on a
 #: TPU the sums over tokens walk the landed pairs) and ``combine``
-#: rounds its sums itself; off the TPU the arithmetic is what it was
+#: rounds its sums itself; off the TPU the arithmetic is what it was.
+#: And on PR 53's, the two steps: a routed call's choices and plan carry
+#: the names a recompute keeps them by and ``route`` takes the weights at
+#: the ids (``tests/test_routed_decides_once.py``); the products' four
+#: are what they were
 GOLDEN = {
     "afmoe":
-        "8aee32969af5dd40e27aaaf7bcf37e88e8c773ddaef6c17c8f292582cb239688",
+        "e3c684149e7818869e4e333c865bd0dfbf65f7cd2ea931c30abbb646e674cb4c",
     "deepseek_v3":
-        "e0120260cefb64c7e92794f586fc586100d4d4a5e661b2ed2965010b34c32116",
+        "ffb645d203fb936dfcc9ff576a9ce6b19c1a0e2dac7bb3967ae74e454bb76508",
     "gmm_1024_2048":
         "4ab5cd396489efe5707466e78a6868beed355142b351f4a7f5853f9812edf078",
     "gmm_2048_1024":

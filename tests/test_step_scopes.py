@@ -61,18 +61,23 @@ BEFORE = {
     # ``combine`` rounds its sums itself; the parameter trees are what
     # they were, and GPT-2 runs none of it; the three again at PR 52: the
     # grouped products' kernels take an expert's whole matrix a block
-    # (their grids, blocks and ``vmem_limit_bytes`` are in the text)
+    # (their grids, blocks and ``vmem_limit_bytes`` are in the text); and
+    # at PR 53: a routed call's choices and row plan carry the names a
+    # recompute keeps them by (``step.keep``: a ``name`` equation each),
+    # the parts' ``checkpoint`` has the policy that keeps them, and the
+    # weights are the scores taken at the ids in both branches of
+    # ``route``; GPT-2's two are what they were
     ("afmoe", ""): (
-        "4178b72c3fa93d5d8c6cb5e3d15c856151a3a1889249dfd46d27b14d08989b22",
+        "bf51ff92ddcb04e496df9ecfd8e290880d73381f71061b8d6149d638715e2348",
         "73a54f273ca8d1cab30e4c5907da0f975cba6a8dcc30a4dda90aad118cc3b47d"),
     ("afmoe", "full"): (
-        "a51ef50a0140c51b48134014958dcd52ca821fb036abfba7fc481d9f4bb575d4",
+        "57c0eaa5624553116c4ff343e4c2e3446113ceef0d3345add7cd7e6466fb2798",
         "73a54f273ca8d1cab30e4c5907da0f975cba6a8dcc30a4dda90aad118cc3b47d"),
     ("deepseek_v3", ""): (
-        "fb6f7b446e704a0ab809eb8aa58a77afbd77e952025cce23d49e8012c3655a11",
+        "186a7b3404f356baf4852c90faf6288d8df53ae23cb8374565661e41249beb54",
         "80259fb632cdea7eb743ab44b67d71fd30d782bcfb29589957d50eecca4b4839"),
     ("deepseek_v3", "full"): (
-        "82536dabb9454f2cfa1fb624788b7da17fd9a9044676495291213a80ee58fd5d",
+        "0df51f05dd0edb77ed9b4a37074b620b1ea92bf97febab2ac6862ef2fd9f05dd",
         "80259fb632cdea7eb743ab44b67d71fd30d782bcfb29589957d50eecca4b4839"),
     # re-pinned at PR 45 too: the mixer's convolution is two kernel
     # calls (``ops/short_conv.py``) where it was XLA's passes; and at
@@ -80,10 +85,10 @@ BEFORE = {
     # (``_SplitDense``), and the gated norm rounds its own result
     # (``ops/gate_norm.py``; ``tiny``'s groups of 32 take its ``jnp`` form)
     ("nemotron_h", ""): (
-        "8773b94f86659dc3045349558288f3032e7a0ac1d68904e48ee6f9fcea234e40",
+        "91b05022902fd18bb305f5027aa37d72807290050f8b82f0421360e43451d454",
         "dc97618ee45dda61ed25a95141a3b81a3c70d427ead3341d15b02ac03fd1409e"),
     ("nemotron_h", "full"): (
-        "fd2eaf2d807b2dfc5a0c146fb64c62be4e9fc4f9d1df4414db3f84db2fa145ad",
+        "c5035b4ff18bc3df144616f53bb195b8a93ccfa11b75a4a99191ca6bdb09b1bd",
         "dc97618ee45dda61ed25a95141a3b81a3c70d427ead3341d15b02ac03fd1409e"),
 }
 
@@ -200,8 +205,11 @@ def test_phases_are_told_by_jaxs_own_markers(name):
     assert all("transpose(" in n and "/rematted_computation/" in n
                for n in recomputed)
     # (a part's last op is not run again where no gradient needs it)
+    # (nor is a routed call's plan: a decision is kept, ``step.remat``)
     assert {scopes.part(n, parts) for n in recomputed} >= \
-        SEEN[name] - {"embed", "optimizer", "moe.combine", "ssm.out_proj"}
+        SEEN[name] - {"embed", "optimizer", "moe.combine", "ssm.out_proj",
+                      "moe.plan"}
+    assert "moe.plan" not in {scopes.part(n, parts) for n in recomputed}
     # a backward op never passes for a forward one
     assert not [n for n in by_phase["forward"] if "transpose(" in n]
 
@@ -276,6 +284,8 @@ def test_the_program_is_what_it_was(name, remat):
             jax.ShapeDtypeStruct((2, cfg.max_seq_len), jnp.int32))
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         text = str(jax.make_jaxpr(module.make_train_step(model, tx))(*args))
+    # (a ``checkpoint`` with a policy prints the function's address)
+    text = re.sub(r" at 0x[0-9a-f]+>", ">", text)
     paths = "\n".join(sorted(
         jax.tree_util.keystr(k) + str(v.shape) + str(v.dtype)
         for k, v in jax.tree_util.tree_leaves_with_path(params)))
