@@ -667,6 +667,35 @@ def moe_exchange_bytes(model: str, nbytes: float) -> None:
 
 
 # ---------------------------------------------------------------------------
+# hyper-connections (models/hyper.py)
+# ---------------------------------------------------------------------------
+
+def hyper_connection(model: str, connection: int, offdiag_mass: float,
+                     doubly_stochastic_error: float,
+                     pre_entropy: float) -> None:
+    """What one hyper-connection made of its last observed batch: the
+    mean of ``1 - trace(H_res) / n`` (0 is the plain residual: every
+    lane keeps to itself), the largest ``|column sum - 1|`` the Sinkhorn
+    steps left in ``H_res``, and the mean entropy of ``H_pre`` over the
+    lanes (``log n``: the sub-layer reads all lanes alike)."""
+    if not enabled():
+        return
+    key = (("model", model), ("connection", str(connection)))
+    _gauge("ray_tpu_hc_offdiag_mass",
+           "mean share of a lane that a hyper-connection's residual "
+           "matrix takes from the other lanes (0: the plain residual)",
+           ("model", "connection")).set_key(key, float(offdiag_mass))
+    _gauge("ray_tpu_hc_doubly_stochastic_error",
+           "largest |column sum - 1| of a hyper-connection's residual "
+           "matrix after its Sinkhorn steps",
+           ("model", "connection")).set_key(
+        key, float(doubly_stochastic_error))
+    _gauge("ray_tpu_hc_pre_entropy",
+           "mean entropy over the lanes of what a sub-layer reads",
+           ("model", "connection")).set_key(key, float(pre_entropy))
+
+
+# ---------------------------------------------------------------------------
 # looped stacks (models/ouro.py)
 # ---------------------------------------------------------------------------
 

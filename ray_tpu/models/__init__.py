@@ -7,6 +7,11 @@ from ray_tpu.models.deepseek_v3 import (  # noqa: F401
     DeepseekV3Config,
 )
 from ray_tpu.models.gpt2 import GPT2, GPT2Config  # noqa: F401
+from ray_tpu.models.hyper import (  # noqa: F401
+    Coefficients,
+    Connection,
+    residual,
+)
 from ray_tpu.models.llama import Llama, LlamaConfig  # noqa: F401
 from ray_tpu.models.nemotron_h import (  # noqa: F401
     NemotronH,

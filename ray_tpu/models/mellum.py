@@ -163,25 +163,25 @@ class MellumConfig:
                 "layers": ",".join(k[0] for k in self.layer_kinds())}
 
 
-def yarn_inv_freq(cfg: MellumConfig):
-    """``(inv_freq [head_dim / 2]`` float32, ``low``, ``high)``: YaRN's
-    frequencies.  Pair ``j`` turns ``theta^(-2j/d)`` a position where it
-    makes more than ``beta_fast`` turns over the original context
-    (``j <= low``: extrapolated, as trained), that over ``factor`` where
-    it makes fewer than ``beta_slow`` (``j >= high``: interpolated), and
-    a linear blend between."""
-    d, base = cfg.head_dim, cfg.rope_theta
-
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float):
+    """``(inv_freq [dim / 2]`` float32, ``low``, ``high)``: YaRN's
+    frequencies over a rotary width ``dim`` (this model's and
+    ``models/deepseek_v3.py``'s).  Pair ``j`` turns ``theta^(-2j/dim)`` a
+    position where it makes more than ``beta_fast`` turns over the
+    original context (``j <= low``: extrapolated, as trained), that over
+    ``factor`` where it makes fewer than ``beta_slow`` (``j >= high``:
+    interpolated), and a linear blend between."""
     def pair_of(turns: float) -> float:
-        return d * math.log(cfg.yarn_original_max / (turns * 2 * math.pi)) \
-            / (2 * math.log(base))
+        return dim * math.log(original_max / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
 
-    low = max(math.floor(pair_of(cfg.yarn_beta_fast)), 0)
-    high = min(math.ceil(pair_of(cfg.yarn_beta_slow)), d // 2 - 1)
-    j = jnp.arange(d // 2, dtype=jnp.float32)
-    inv = base ** (-2.0 * j / d)
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim // 2 - 1)
+    j = jnp.arange(dim // 2, dtype=jnp.float32)
+    inv = theta ** (-2.0 * j / dim)
     ramp = jnp.clip((j - low) / max(high - low, 1e-3), 0.0, 1.0)
-    return (1.0 - ramp) * inv + ramp * inv / cfg.yarn_factor, low, high
+    return (1.0 - ramp) * inv + ramp * inv / factor, low, high
 
 
 def rope_table(cfg: MellumConfig, kind: str, seq: int):
@@ -192,7 +192,10 @@ def rope_table(cfg: MellumConfig, kind: str, seq: int):
     (``original_max_position_embeddings`` enters through ``low`` and
     ``high`` alone)."""
     if kind == "full":
-        inv, factor = yarn_inv_freq(cfg)[0], cfg.yarn_attention_factor
+        inv = yarn_inv_freq(cfg.head_dim, cfg.rope_theta, cfg.yarn_factor,
+                            cfg.yarn_original_max, cfg.yarn_beta_fast,
+                            cfg.yarn_beta_slow)[0]
+        factor = cfg.yarn_attention_factor
     else:
         j = jnp.arange(cfg.head_dim // 2, dtype=jnp.float32)
         inv, factor = cfg.rope_theta ** (-2.0 * j / cfg.head_dim), 1.0

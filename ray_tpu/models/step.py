@@ -40,10 +40,11 @@ from jax.ad_checkpoint import checkpoint_name
 from ray_tpu.core import telemetry
 
 #: the parts of a step, in the order a step meets them
-PARTS = ("embed", "attn", "mlp", "moe.route", "moe.exchange", "moe.plan",
-         "moe.dispatch", "moe.experts", "moe.combine", "ssm.in_proj",
-         "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out_proj", "head",
-         "exit", "optimizer")
+PARTS = ("embed", "hc.coef", "hc.mix", "attn", "mlp", "moe.route",
+         "moe.exchange", "moe.plan", "moe.dispatch", "moe.experts",
+         "moe.combine", "ssm.in_proj", "ssm.conv", "ssm.scan",
+         "ssm.gate_norm", "ssm.out_proj", "mtp", "head", "exit",
+         "optimizer")
 
 #: what a recomputed part keeps of its forward: a routed call's decisions
 KEPT = ("choices", "plan")

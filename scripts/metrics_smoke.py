@@ -92,6 +92,11 @@ TRAFFIC_DEPENDENT = {
     # a looped stack's exit gate likewise (models/ouro.py
     # report_exit_stats); what loop was compiled is the `model:loop.plan`
     # span
+    # hyper-connections likewise (models/deepseek_v3.py report_hc_stats);
+    # what was compiled is the `model:hc.plan` span
+    "ray_tpu_hc_offdiag_mass",
+    "ray_tpu_hc_doubly_stochastic_error",
+    "ray_tpu_hc_pre_entropy",
     "ray_tpu_loop_exit_share",
     "ray_tpu_loop_expected_passes",
     "ray_tpu_loop_exit_entropy",

@@ -216,7 +216,9 @@ def test_yarn_table_against_the_closed_form_at_the_published_numbers():
     low = d * math.log(L / (32 * 2 * math.pi)) / (2 * math.log(b))
     high = d * math.log(L / (1 * 2 * math.pi)) / (2 * math.log(b))
     assert (round(low, 2), round(high, 2)) == (18.08, 34.98)
-    inv, got_low, got_high = ml.yarn_inv_freq(cfg)
+    inv, got_low, got_high = ml.yarn_inv_freq(
+        cfg.head_dim, cfg.rope_theta, cfg.yarn_factor, cfg.yarn_original_max,
+        cfg.yarn_beta_fast, cfg.yarn_beta_slow)
     assert (got_low, got_high) == (18, 35)
     plain = [b ** (-2 * j / d) for j in range(64)]
     # extrapolated as trained, blended half way, interpolated by 16
