@@ -443,7 +443,8 @@ class DeepseekV3(nn.Module):
                 "model", "moe.plan", **afmoe.routed_plan_args(cfg, seq)))
             if _many(cfg):
                 spans.enter_context(telemetry.span(
-                    "model", "hc.plan", **hyper.plan_args(cfg, seq)))
+                    "model", "hc.plan",
+                    **hyper.plan_args(cfg, tokens.shape[0], seq)))
             for n in range(cfg.num_dense_layers + cfg.num_layers):
                 i = n - cfg.num_dense_layers
                 block = DeepseekV3Block(

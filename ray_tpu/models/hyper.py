@@ -35,21 +35,34 @@ twenty normalisations then run over whole 128-lane rows of tokens, and
 what their backward keeps of each is ``n x n`` rows of ``T`` and not
 ``T`` tiles of 8 x 128 for sixteen numbers.
 
-Everything here is plain ``jax.numpy``; the twenty Sinkhorn steps are
-ONE ``lax.scan`` a call, which the compiler sees once and whose backward
-is taken through.
+**What runs where.**  The arithmetic on a token's ``n (n + 2)``
+coefficients (the gates, the sigmoids, the norm's ``rsqrt``, the clamp,
+the twenty Sinkhorn steps: ONE ``lax.scan`` a call, which the compiler
+sees once and whose backward is taken through) is plain ``jax.numpy``
+here, whatever runs the passes over the lanes.  Those passes (the
+projection with the squares' sum, :func:`write`, and the backward of the
+lanes) are the kernels of ``ops/lane_mix.py`` on a TPU, where a lane is
+whole 128-column registers, the tokens divide into tiles and the
+coefficients are float32 (``lane_mix.mode``); anywhere else they are the
+``jnp`` forms below.  On the kernels' path a connection is two calls
+(:func:`_open`, :func:`_close`) with the sub-layer between them, each
+with a backward of its own, so that the lanes are read once a pass and
+``d X`` is written once: see :func:`_open`.  :func:`read` stays XLA's
+on both (one fusion that reads four lanes and writes one).
 """
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Any, Dict, NamedTuple
+from typing import Any, Dict, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import step
+from ray_tpu.ops import lane_mix
 
 #: ``H_post = POST_GAIN x sigmoid(.)``: 1 where the sigmoid is at rest
 POST_GAIN = 2.0
@@ -62,8 +75,6 @@ SINKHORN_EPS = 1e-6
 CLAMP = (-30.0, 30.0)
 #: what the coefficients are computed in: float32, as a router's scores
 COEF_DTYPE = jnp.float32
-#: how the mixing runs (the ``hc.plan`` span's ``impl``)
-IMPL = "jnp"
 
 
 class Coefficients(NamedTuple):
@@ -95,9 +106,13 @@ def collapse(x: jax.Array, n: int) -> jax.Array:
 
 def lane_scale(x: jax.Array, dtype: Any) -> jax.Array:
     """``1 / sqrt(mean(vec(X[t])^2) + eps)`` over all ``n x C`` elements
-    of a token, ``[B, T]``."""
+    of a token, ``[B, T]``.  ``x [M, 1]`` (no lane is one column wide)
+    IS the mean square a token, where a kernel has taken it with the
+    projection: what turns it into the scale is still this."""
     x = x.astype(dtype)
-    return jax.lax.rsqrt(jnp.mean(x * x, axis=-1) + LANE_EPS)
+    mean_square = x[..., 0] if x.shape[-1] == 1 \
+        else jnp.mean(x * x, axis=-1)
+    return jax.lax.rsqrt(mean_square + LANE_EPS)
 
 
 def sinkhorn(logits: jax.Array, iters: int, eps: float) -> jax.Array:
@@ -125,7 +140,15 @@ def coefficients(x: jax.Array, phi: jax.Array, bias: jax.Array,
     proj = jax.lax.dot_general(                                  # [m, T]
         phi.astype(dtype), rows.astype(dtype), (((0,), (1,)), ((), ())),
         precision=jax.lax.Precision.HIGHEST)
-    proj = proj * lane_scale(rows, dtype)[None]
+    return _of_projection(proj, lane_scale(rows, dtype), bias, gates, n)
+
+
+def _of_projection(proj: jax.Array, scale: jax.Array, bias: jax.Array,
+                   gates: jax.Array, n: int) -> Coefficients:
+    """The coefficients from ``proj [m, T]`` (``phi^T X^T``, tokens last)
+    and the lanes' ``scale [T]``: all that is a token's own numbers."""
+    dtype = COEF_DTYPE
+    proj = proj.astype(dtype) * scale[None]
     gate = jnp.concatenate([
         jnp.broadcast_to(gates[k].astype(dtype), (count,))
         for k, count in enumerate((n, n, n * n))])
@@ -159,6 +182,87 @@ def write(x: jax.Array, y: jax.Array, coef: Coefficients) -> jax.Array:
         (sum(_a_token(coef.res[i, j], y) * lanes[j] for j in range(n))
          + _a_token(coef.post[i], y) * y).astype(x.dtype)
         for i in range(n)], axis=-1)
+
+
+# ---------------------------------------------------------------------------
+# the kernels' path: a connection as two calls around its sub-layer
+# ---------------------------------------------------------------------------
+
+def _small(n: int, width: int, proj, sumsq, bias, gates) -> Coefficients:
+    """The coefficients from the kernel's two results: ``proj [m, M]``
+    unscaled and the squares' sum a token ``[M]`` over ``width``
+    elements.  Traced anew every call (a ``custom_vjp`` keeps no trace),
+    so the module's names are read as :func:`coefficients` reads them."""
+    mean_square = (sumsq / width).astype(COEF_DTYPE)[:, None]
+    return _of_projection(proj, lane_scale(mean_square, COEF_DTYPE), bias,
+                          gates, n)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _open(x, phi, bias, gates, n: int, interpret: bool):
+    """A connection before its sub-layer, ``x [M, n C]``: ``(coef, u,
+    held)``, the coefficients, what the sub-layer reads, and the lanes
+    again for :func:`_close` ALONE.  ``held`` is ``x``; what comes back
+    for it is not its cotangent but ``d X'`` as :func:`_close` got it,
+    still to be carried through ``res``: this call's backward does that
+    in the one pass that writes ``d X``, with the terms through ``pre``,
+    the projection and the norm (``lane_mix.open_bwd``), so the four
+    are never arrays of their own."""
+    return _open_fwd(x, phi, bias, gates, n, interpret)[0]
+
+
+def _open_fwd(x, phi, bias, gates, n, interpret):
+    with step.scope("hc.coef"):
+        # (kept under ``step.remat``: the recompute starts from these
+        # 25 numbers a token, not from another pass over the lanes)
+        proj, sumsq = step.keep("projection", lane_mix.project(
+            x, phi, n, interpret))
+        coef, pull = jax.vjp(functools.partial(_small, n, x.shape[-1]),
+                             proj, sumsq, bias, gates)
+    with step.scope("hc.mix"):
+        u = read(x, coef.pre)
+    return (coef, u, x), (x, phi, coef, pull)
+
+
+def _open_bwd(n, interpret, kept, cotangents):
+    x, phi, coef, pull = kept
+    dcoef, du, d = cotangents
+    with step.scope("hc.mix"):
+        dpre = lane_mix.read_bwd(x, du, n, interpret)
+    with step.scope("hc.coef"):
+        g, dsumsq, dbias, dgates = pull(dcoef._replace(
+            pre=dcoef.pre + dpre))
+    with step.scope("hc.mix"):
+        dx, dphi = lane_mix.open_bwd(x, d, du, coef.res, coef.pre, phi, g,
+                                     dsumsq, interpret)
+    return dx, dphi.astype(phi.dtype), dbias, dgates
+
+
+_open.defvjp(_open_fwd, _open_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _close(held, y, res, post, interpret: bool):
+    """A connection after its sub-layer: ``X'`` from ``held`` (of
+    :func:`_open`), the sub-layer's ``y [M, C]`` and the coefficients."""
+    return _close_fwd(held, y, res, post, interpret)[0]
+
+
+def _close_fwd(held, y, res, post, interpret):
+    with step.scope("hc.mix"):
+        return lane_mix.write(held, y, res, post, interpret), \
+            (held, y, post)
+
+
+def _close_bwd(interpret, kept, d):
+    held, y, post = kept
+    with step.scope("hc.mix"):
+        dy, dres, dpost = lane_mix.write_bwd(held, d, y, post, interpret)
+    # ``d`` for ``held``: see :func:`_open`
+    return d, dy, dres, dpost
+
+
+_close.defvjp(_close_fwd, _close_bwd)
 
 
 def stats_of(coef: Coefficients) -> Dict[str, jax.Array]:
@@ -197,7 +301,8 @@ class Connection(nn.Module):
     config: Any
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> Coefficients:
+    def connect(self, x: jax.Array) -> "Mix":
+        """The connection of ``x [B, T, n C]`` up to its sub-layer."""
         cfg = self.config
         n = cfg.hc_mult
         m = n * (n + 2)
@@ -210,14 +315,28 @@ class Connection(nn.Module):
         gates = self.param(
             "gates", nn.with_partitioning(nn.initializers.constant(0.01),
                                           (None,)), (3,), cfg.param_dtype)
-        with step.scope("hc.coef"):
-            coef = coefficients(x, phi, bias, gates, n)
-            # for an operator who asks (``hc_stats``): a step collects
-            # nothing, and what nothing collects is not traced
-            if self.is_mutable_collection("intermediates"):
+        kernels = _kernels(x, n)
+        if kernels is None:
+            with step.scope("hc.coef"):
+                coef = coefficients(x, phi, bias, gates, n)
+            mixed = Mix(x, coef)
+        else:
+            coef, u, held = _open(x.reshape(-1, x.shape[-1]), phi, bias,
+                                  gates, n, kernels)
+            mixed = Mix(x, coef, u=u.reshape(*x.shape[:-1], -1),
+                        close=lambda y: _close(
+                            held, y.reshape(-1, y.shape[-1]), coef.res,
+                            coef.post, kernels).reshape(x.shape))
+        # for an operator who asks (``hc_stats``): a step collects
+        # nothing, and what nothing collects is not traced
+        if self.is_mutable_collection("intermediates"):
+            with step.scope("hc.coef"):
                 for name, value in stats_of(coef).items():
                     self.sow("intermediates", name, value)
-        return coef
+        return mixed
+
+    def __call__(self, x: jax.Array) -> Coefficients:
+        return self.connect(x).coef
 
 
 class Sum:
@@ -240,17 +359,24 @@ class Mix:
     """The same three on several lanes under a connection's ``coef``:
     ``u`` is :func:`read`, the terms are summed, and :meth:`out` is
     :func:`write`; both under the step's part ``hc.mix``, so :meth:`out`
-    is called outside the sub-layer's own scope."""
+    is called outside the sub-layer's own scope.  On the kernels' path
+    the connection hands in ``u`` as :func:`_open` read it and ``close``,
+    its :func:`_close`."""
 
-    def __init__(self, x: jax.Array, coef: Coefficients):
-        self.x, self.coef, self.y = x, coef, None
-        with step.scope("hc.mix"):
-            self.u = read(x, coef.pre)
+    def __init__(self, x: jax.Array, coef: Coefficients, u=None,
+                 close=None):
+        self.x, self.coef, self.y, self.close = x, coef, None, close
+        if u is None:
+            with step.scope("hc.mix"):
+                u = read(x, coef.pre)
+        self.u = u
 
     def add(self, y: jax.Array) -> None:
         self.y = y if self.y is None else self.y + y
 
     def out(self) -> jax.Array:
+        if self.close is not None:
+            return self.close(self.y)
         with step.scope("hc.mix"):
             return write(self.x, self.y, self.coef)
 
@@ -262,12 +388,34 @@ def residual(cfg, x: jax.Array):
     part's ``__call__``)."""
     if cfg.hc_mult == 1:
         return Sum(x)
-    return Mix(x, Connection(cfg, name="hc")(x))
+    return Connection(cfg, name="hc").connect(x)
 
 
-def plan_args(cfg, tokens: int) -> Dict[str, Any]:
-    """What was compiled, for the ``hc.plan`` span."""
+def _kernels(x: jax.Array, n: int) -> Optional[bool]:
+    """How the passes over the lanes of ``x [..., T, n C]`` run
+    (``lane_mix.mode``): ``None`` the ``jnp`` forms."""
+    tokens = math.prod(x.shape[:-1])
+    return lane_mix.mode(tokens, n, x.shape[-1] // n, x.dtype, COEF_DTYPE)
+
+
+def plan_args(cfg, batch: int, tokens: int) -> Dict[str, Any]:
+    """What was compiled for a step of ``batch`` sequences of ``tokens``
+    (a connection sees one sequence a call), for the ``hc.plan`` span:
+    ``impl`` says how the passes over the lanes run; on the kernels'
+    path ``x_reads`` says how often a connection's forward (project,
+    ``read``, write), recompute (``read``: the projection is kept) and
+    backward (``lane_mix``'s three) read ``X``, and ``kernel_calls``
+    counts the lanes' kernel calls of a step (five a connection)."""
+    lanes = jax.ShapeDtypeStruct((tokens, cfg.hc_mult * cfg.embed_dim),
+                                 getattr(cfg, "dtype", cfg.param_dtype))
+    kernels = _kernels(lanes, cfg.hc_mult) is not None
+    connections = 2 * batch * sum(getattr(cfg, name, 0) for name in (
+        "num_dense_layers", "num_layers", "num_mtp_layers"))
+    recomputed = 1 if getattr(cfg, "remat", "") else 0
     return {"lanes": cfg.hc_mult, "iters": SINKHORN_ITERS,
             "clamp": ",".join(f"{c:g}" for c in CLAMP),
             "eps": SINKHORN_EPS, "width": cfg.embed_dim, "seq": tokens,
-            "coef_dtype": jnp.dtype(COEF_DTYPE).name, "impl": IMPL}
+            "coef_dtype": jnp.dtype(COEF_DTYPE).name,
+            "impl": "pallas" if kernels else "jnp",
+            "x_reads": f"3,{recomputed},3" if kernels else "",
+            "kernel_calls": 5 * connections if kernels else 0}

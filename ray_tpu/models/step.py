@@ -24,7 +24,10 @@ choices ``[T, k]`` and the row plan made from them.  A decision is not
 recomputed: it has no backward, so making it again buys nothing (an
 ``argsort`` and a ``top_k`` a layer-call), and a recomputed ``top_k``
 whose near tie falls the other way would take the backward for a
-routing the loss never ran.  A part that stamps nothing keeps nothing.
+routing the loss never ran.  Beside the decisions, :data:`KEPT_SUMS`:
+a hyper-connection's projection, ``n (n + 2) + 1`` float32 a token that
+cost a pass over all of the token's lanes (``models/hyper.py``).  A part
+that stamps nothing keeps nothing.
 """
 
 from __future__ import annotations
@@ -48,7 +51,10 @@ PARTS = ("embed", "hc.coef", "hc.mix", "attn", "mlp", "moe.route",
 
 #: what a recomputed part keeps of its forward: a routed call's decisions
 KEPT = ("choices", "plan")
-_KEEP_THESE = jax.checkpoint_policies.save_only_these_names(*KEPT)
+#: and the few sums a token that a whole pass over its lanes made
+KEPT_SUMS = ("projection",)
+_KEEP_THESE = jax.checkpoint_policies.save_only_these_names(
+    *KEPT, *KEPT_SUMS)
 
 
 def part_of(component: str) -> Optional[str]:
@@ -96,7 +102,8 @@ def remat(module):
 
 
 def keep(name: str, tree):
-    """``tree``, every array of it stamped ``name`` (of :data:`KEPT`):
+    """``tree``, every array of it stamped ``name`` (of :data:`KEPT` or
+    :data:`KEPT_SUMS`):
     under :func:`remat` the backward pass reads the forward's values and
     what made them is not run again; anywhere else, ``tree`` as it is.
     An array is kept FLAT and takes its shape again behind the stamp: a
@@ -105,8 +112,9 @@ def keep(name: str, tree):
     compiled for a described v5e: a peak of 14.91 GiB with the tables
     kept in their shape, 14.16 flat; ``tests/test_chip_compile.py``
     holds it under 14.5)."""
-    if name not in KEPT:
-        raise ValueError(f"{name!r} is not kept under remat: {KEPT}")
+    if name not in KEPT + KEPT_SUMS:
+        raise ValueError(
+            f"{name!r} is not kept under remat: {KEPT + KEPT_SUMS}")
     return jax.tree.map(lambda a: checkpoint_name(
         a.reshape(-1), name).reshape(a.shape), tree)
 

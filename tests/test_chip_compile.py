@@ -668,21 +668,25 @@ def _walks(calls, layer_calls, sums=2):
 def _not_moves_between_memory_spaces(found, text):
     """``found`` (:func:`_passes_over_the_row_buffer` of ``text``)
     without the compiler's own asynchronous moves of an array between
-    HBM and its faster memory space: a ``copy-start`` of which exactly
-    ONE of destination and source is laid out in ``S(1)``, and its
-    ``copy-done``.  A copy that stays in one space is a pass over the
-    buffer like any other and is kept, in every cell."""
+    HBM and its faster memory space: a ``copy-start`` (or a
+    ``slice-start``: the same move a slice of the array at a time) of
+    which exactly ONE of destination and source is laid out in ``S(1)``,
+    and its ``-done``.  A copy that stays in one space is a pass over
+    the buffer like any other and is kept, in every cell."""
     full = {m.group(1): line for line in text.splitlines()
             for m in [re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = ", line)] if m}
 
     def moves(line):
         name = line.split(" = ")[0]
-        if name.startswith("%copy-done"):
-            name = re.search(r"copy-done\((%[\w.\-]+)\)", full[name]).group(1)
-        if not name.startswith("%copy-start"):
+        done = re.match(r"%(copy|slice)-done", name)
+        if done:
+            name = re.search(rf"{done.group(1)}-done\((%[\w.\-]+)\)",
+                             full[name]).group(1)
+        start = re.match(r"%(copy|slice)-start", name)
+        if not start:
             return False
         ends = re.findall(r"(?:bf16|f32)\[[\d,]+\]\{[^}]*\}",
-                          full[name].split(" copy-start(")[0])[:2]
+                          full[name].split(f" {start.group(1)}-start(")[0])[:2]
         return len(ends) == 2 and ("S(1)" in ends[0]) != ("S(1)" in ends[1])
 
     return [line for line in found if not moves(line)]
@@ -858,8 +862,12 @@ XING_STATE_BYTES = 759_346_190 * 12
 def test_xing_share_train_step_fits_one_v5e(one_chip):
     """``xing4.0-29b-a4b.steady``'s step: one dense and four expert
     layers at the published widths, four lanes, batch 4 x 2,048, donated
-    state.  8.49 GiB of state and 5.62 of temporaries: 14.11 GiB
-    (13.24 at 2 x 4,096).  Around the kernels the lanes are XLA's."""
+    state.  8.49 GiB of state and 5.48 of temporaries: 13.97 GiB (14.11
+    while the lanes were XLA's, PR 54).  The passes over the lanes are
+    ``ops/lane_mix.py``'s kernels (PR 55): a connection's projection
+    (kept for the recompute), ``write``, and the three of its backward;
+    ``read`` and the arithmetic on the coefficients stay XLA's, and no
+    float32 copy of a sequence's lanes is left in the step."""
     ds, cfg, model = _xing_share()
     text, params, total = _compiled_step(ds, model, 4, one_chip)
     assert sum(a.size for a in jax.tree.leaves(params)) == 759_346_190
@@ -870,6 +878,19 @@ def test_xing_share_train_step_fits_one_v5e(one_chip):
     assert named("flash") == 80
     # 4 expert layers x 4 sequences x 3 products x (2 forward, d lhs, d rhs)
     assert named("grouped_matmul") == 192
+    # 5 layers x 2 connections x 4 sequences, each once: the recompute
+    # starts from the projection the forward kept
+    lanes = {name: named(name) for name in (
+        "lane_project", "lane_write.", "lane_write_bwd", "lane_read_bwd",
+        "lane_open_bwd")}
+    assert lanes == {"lane_project": 40, "lane_write.": 40,
+                     "lane_write_bwd": 40, "lane_read_bwd": 40,
+                     "lane_open_bwd": 40}, lanes
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        plan = ds.hyper.plan_args(cfg, 4, cfg.max_seq_len)
+    assert (plan["impl"], plan["x_reads"], plan["kernel_calls"]) == (
+        "pallas", "3,1,3", sum(lanes.values()))
+    assert not re.search(r"f32\[(?:1,)?2048,14336\]", text)
     rows = 4 * 2048 + 8 * 256
     assert _not_moves_between_memory_spaces(
         _passes_over_the_row_buffer(text, rows), text) == []

@@ -173,6 +173,31 @@ def test_the_identity_like_start():
     assert float(sown["doubly_stochastic_error"][0]) < 1e-4
     assert float(sown["pre_entropy"][0]) == pytest.approx(np.log(4),
                                                           abs=1e-3)
-    assert hyper.plan_args(cfg, 16) == {
+    assert hyper.plan_args(cfg, 2, 16) == {
         "lanes": 4, "iters": 20, "clamp": "-30,30", "eps": 1e-6,
-        "width": 8, "seq": 16, "coef_dtype": "float32", "impl": "jnp"}
+        "width": 8, "seq": 16, "coef_dtype": "float32", "impl": "jnp",
+        "x_reads": "", "kernel_calls": 0}
+
+
+@pytest.mark.parametrize("remat,reads,calls", [("full", "3,1,3", 5),
+                                               ("", "3,0,3", 5)])
+def test_the_plan_says_what_the_kernels_read(remat, reads, calls,
+                                             monkeypatch):
+    """Where the lanes' kernels run (a TPU; here the decision steered as
+    a chip would answer) the plan says so: how often a connection's
+    forward, recompute and backward read ``X`` and a step's kernel
+    calls, two connections a layer and a call a sequence."""
+    from ray_tpu.ops import lane_mix
+
+    monkeypatch.setattr(lane_mix, "kernel_mode", lambda interpret: False)
+    cfg = types.SimpleNamespace(
+        hc_mult=4, param_dtype=jnp.float32, dtype=jnp.bfloat16,
+        embed_dim=256, remat=remat, num_dense_layers=1, num_layers=4,
+        num_mtp_layers=0)
+    args = hyper.plan_args(cfg, 4, 2048)
+    assert (args["impl"], args["x_reads"]) == ("pallas", reads)
+    assert args["kernel_calls"] == 5 * 2 * 4 * calls
+    # shapes the kernels cannot tile, or bfloat16 coefficients: ``jnp``
+    assert hyper.plan_args(cfg, 4, 2040)["impl"] == "jnp"
+    monkeypatch.setattr(hyper, "COEF_DTYPE", jnp.bfloat16)
+    assert hyper.plan_args(cfg, 4, 2048)["kernel_calls"] == 0

@@ -324,7 +324,8 @@ def test_the_plan_span_the_parts_and_the_scopes():
     assert set(rows) == {"mla.plan", "moe.plan", "hc.plan"}
     assert rows["hc.plan"]["args"] == {
         "lanes": 4, "iters": 20, "clamp": "-30,30", "eps": 1e-6,
-        "width": 32, "seq": 64, "coef_dtype": "float32", "impl": "jnp"}
+        "width": 32, "seq": 64, "coef_dtype": "float32", "impl": "jnp",
+        "x_reads": "", "kernel_calls": 0}
     assert rows["mla.plan"]["args"]["q_latent"] == 16
     assert rows["mla.plan"]["args"]["yarn_factor"] == 64.0
     for name in ("hc.coef", "hc.mix", "mla.q_up", "mla.kv_up", "attn.mla",
