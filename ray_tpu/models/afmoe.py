@@ -462,26 +462,35 @@ class AttentionPart(nn.Module):
         heads, kv, dim = cfg.num_heads, cfg.kv_heads, cfg.head_dim
         norm = functools.partial(RMSNorm, cfg.rms_eps)
 
-        h = norm(name="attn_norm")(x)
-        q = _dense(cfg, heads * dim, "wq", ("embed", "heads"))(h)
-        k = _dense(cfg, kv * dim, "wk", ("embed", "kv"))(h)
-        v = _dense(cfg, kv * dim, "wv", ("embed", "kv"))(h)
-        gate = _dense(cfg, heads * dim, "wg", ("embed", "heads"))(h)
-        q = _HeadNorm(cfg.rms_eps, name="q_norm")(
-            q.reshape(batch, seq, heads, dim))
-        k = _HeadNorm(cfg.rms_eps, name="k_norm")(
-            k.reshape(batch, seq, kv, dim))
-        v = v.reshape(batch, seq, kv, dim)
+        # the part's pieces (``step.ATTN_PIECES``); the kernels' call
+        # names its own two inside its kind
+        with step.scope("attn.norm"):
+            h = norm(name="attn_norm")(x)
+        with step.scope("attn.proj"):
+            q = _dense(cfg, heads * dim, "wq", ("embed", "heads"))(h)
+            k = _dense(cfg, kv * dim, "wk", ("embed", "kv"))(h)
+            v = _dense(cfg, kv * dim, "wv", ("embed", "kv"))(h)
+            gate = _dense(cfg, heads * dim, "wg", ("embed", "heads"))(h)
+        with step.scope("attn.norm"):  # (a view by heads moves nothing)
+            q = _HeadNorm(cfg.rms_eps, name="q_norm")(
+                q.reshape(batch, seq, heads, dim))
+            k = _HeadNorm(cfg.rms_eps, name="k_norm")(
+                k.reshape(batch, seq, kv, dim))
+            v = v.reshape(batch, seq, kv, dim)
         sliding = self.kind == "sliding"
         if sliding:
-            q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
+            with step.scope("attn.pos"):
+                q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
         with step.scope("attn." + self.kind):
             attn = flash_attention(
                 q, k, v, causal=True, mesh=get_global_mesh(),
                 window=cfg.window if sliding else None)
-        attn = attn.reshape(batch, seq, heads * dim) * nn.sigmoid(gate)
-        attn = _dense(cfg, cfg.embed_dim, "wo", ("heads", "embed"))(attn)
-        return x + norm(name="attn_post_norm")(attn)
+        with step.scope("attn.gate"):
+            attn = attn.reshape(batch, seq, heads * dim) * nn.sigmoid(gate)
+        with step.scope("attn.proj"):
+            attn = _dense(cfg, cfg.embed_dim, "wo", ("heads", "embed"))(attn)
+        with step.scope("attn.norm"):
+            return x + norm(name="attn_post_norm")(attn)
 
 
 class MLPPart(nn.Module):
@@ -513,14 +522,16 @@ class MLPPart(nn.Module):
 
 def each_sequence(parts, x: jax.Array,
                   chosen: Optional[jax.Array] = None,
-                  around: Tuple[str, str] = ("attn", "mlp")) -> jax.Array:
+                  around: Tuple[str, str] = ("attn.norm", "mlp")
+                  ) -> jax.Array:
     """A layer's ``parts``, one after the other, over ONE sequence of the
     batch at a time (a kernel call a sequence, the activation memory of
     one sequence); ``chosen [B*T, k]``: the routing each sequence's LAST
     part, the routed one, replays.  ``around``: the step's parts
     (``models/step.py``) that taking a sequence out of the batch and
     joining the sequences again are put down to: the layer's first and
-    its last."""
+    its last (of ``attn``, the piece that holds the residual stream's
+    side)."""
     seq = x.shape[1]
     out = []
     for i in range(x.shape[0]):

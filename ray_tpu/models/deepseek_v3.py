@@ -278,10 +278,11 @@ class AttentionPart(nn.Module):
     """Latent attention ``F(N(u))`` and its residual (``hyper.residual``:
     ``x + F(N(x))`` on one lane).  A block names it ``attn``, and on one
     lane flax puts a module's name around its ops: that IS the step's
-    part ``attn`` (``models/step.py``); ``mla.q_up``, ``mla.kv_up`` and
-    ``attn.mla`` are pieces of it.  With several lanes the module names
-    its parts itself (``step.names_its_parts``): ``hc.coef`` and
-    ``hc.mix`` stand BESIDE ``attn``."""
+    part ``attn`` (``models/step.py``), split by ``step.ATTN_PIECES``;
+    ``attn.mla`` is the kind of its kernels' call, ``mla.q_up`` and
+    ``mla.kv_up`` plain names inside ``attn.proj``.  With several lanes
+    the module names its parts itself (``step.names_its_parts``):
+    ``hc.coef`` and ``hc.mix`` stand BESIDE ``attn``."""
     config: DeepseekV3Config
     names_its_parts = property(lambda self: _many(self.config))
 
@@ -297,37 +298,56 @@ class AttentionPart(nn.Module):
             res = hyper.residual(cfg, x)
             with _names_parts(cfg, "attn"):
                 batch, seq = res.u.shape[:2]
-                h = RMSNorm(cfg.rms_eps, name="attn_norm")(res.u)
+                # the part's pieces (``step.ATTN_PIECES``); the kernels'
+                # call names its own two inside its kind; ``mla.q_up``
+                # and ``mla.kv_up`` are plain names inside ``attn.proj``
+                with step.scope("attn.norm"):
+                    h = RMSNorm(cfg.rms_eps, name="attn_norm")(res.u)
                 if cfg.q_lora_rank is None:
-                    q = _dense(cfg, heads * cfg.qk_head_dim, "wq",
-                               ("embed", "heads"))(h)
+                    with step.scope("attn.proj"):
+                        q = _dense(cfg, heads * cfg.qk_head_dim, "wq",
+                                   ("embed", "heads"))(h)
                 else:
-                    c = _QueryNorm(cfg.rms_eps, name="q_norm")(_dense(
-                        cfg, cfg.q_lora_rank, "wq_a", ("embed", None))(h))
-                    with jax.named_scope("mla.q_up"):
+                    with step.scope("attn.proj"):
+                        c = _dense(cfg, cfg.q_lora_rank, "wq_a",
+                                   ("embed", None))(h)
+                    with step.scope("attn.norm"):
+                        c = _QueryNorm(cfg.rms_eps, name="q_norm")(c)
+                    with step.scope("attn.proj"), \
+                            jax.named_scope("mla.q_up"):
                         q = _dense(cfg, heads * cfg.qk_head_dim, "wq_b",
                                    (None, "heads"))(c)
-                q = q.reshape(batch, seq, heads, cfg.qk_head_dim)
-                a = _dense(cfg, rank + rope, "wkv_a", ("embed", None))(h)
-                latent = _HeadNorm(cfg.rms_eps, name="kv_norm")(
-                    a[..., :rank])
-                k_rope = a[..., rank:].reshape(batch, seq, 1, rope)
-                with jax.named_scope("mla.kv_up"):
-                    kv = _dense(cfg, heads * (nope + dim_v), "wkv_b",
-                                (None, "heads"))(latent).reshape(
-                                    batch, seq, heads, nope + dim_v)
-                q = jnp.concatenate(
-                    [q[..., :nope],
-                     rope_interleaved(q[..., nope:], cfg.rope_theta, table)],
-                    axis=-1)
-                k_rope = rope_interleaved(k_rope, cfg.rope_theta, table)
+                with step.scope("attn.proj"):
+                    q = q.reshape(batch, seq, heads, cfg.qk_head_dim)
+                    a = _dense(cfg, rank + rope, "wkv_a",
+                               ("embed", None))(h)
+                    latent = a[..., :rank]
+                with step.scope("attn.norm"):
+                    latent = _HeadNorm(cfg.rms_eps, name="kv_norm")(latent)
+                with step.scope("attn.proj"):
+                    k_rope = a[..., rank:].reshape(batch, seq, 1, rope)
+                    with jax.named_scope("mla.kv_up"):
+                        kv = _dense(cfg, heads * (nope + dim_v), "wkv_b",
+                                    (None, "heads"))(latent).reshape(
+                                        batch, seq, heads, nope + dim_v)
+                with step.scope("attn.pos"):
+                    q = jnp.concatenate(
+                        [q[..., :nope],
+                         rope_interleaved(q[..., nope:], cfg.rope_theta,
+                                          table)], axis=-1)
+                    k_rope = rope_interleaved(k_rope, cfg.rope_theta, table)
+                with step.scope("attn.proj"):
+                    k, v = kv[..., :nope], kv[..., nope:]
                 with step.scope("attn.mla"):
                     attn = flash_attention(
-                        q, kv[..., :nope], kv[..., nope:], k_rope=k_rope,
-                        causal=True, scale=cfg.softmax_scale)
-                attn = attn.reshape(batch, seq, heads * dim_v)
-                res.add(_dense(cfg, cfg.embed_dim, "wo",
-                               ("heads", "embed"))(attn))
+                        q, k, v, k_rope=k_rope, causal=True,
+                        scale=cfg.softmax_scale)
+                with step.scope("attn.proj"):
+                    attn = _dense(cfg, cfg.embed_dim, "wo",
+                                  ("heads", "embed"))(
+                        attn.reshape(batch, seq, heads * dim_v))
+                with step.scope("attn.norm"):  # (one lane: the sum)
+                    res.add(attn)
             return res.out()
 
 

@@ -115,24 +115,32 @@ class Block(nn.Module):
             # along embed: a weight sharded there is gathered for its use
             # and its gradient scattered back.  Said inside the block, so
             # that the recomputed forward of a remat is laid out the same
-            x = constrain_activation(x, "batch", "seq", "embed")
+            with step.scope("attn.norm"):
+                x = constrain_activation(x, "batch", "seq", "embed")
 
-            # block LNs emit cfg.dtype (statistics still accumulate f32
-            # inside flax): an f32 round-trip costs 3x the HBM traffic
-            h = nn.LayerNorm(dtype=cfg.dtype, name="ln_1",
-                             scale_init=nn.with_partitioning(
-                                 nn.initializers.ones, ("embed",)),
-                             bias_init=nn.with_partitioning(
-                                 nn.initializers.zeros, ("embed",)))(x)
-            qkv = _dense(3 * cfg.embed_dim, cfg, "attn_qkv",
-                         ("embed", "heads"))(h)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
+                # block LNs emit cfg.dtype (statistics still accumulate
+                # f32 inside flax): an f32 round-trip costs 3x the HBM
+                # traffic
+                h = nn.LayerNorm(dtype=cfg.dtype, name="ln_1",
+                                 scale_init=nn.with_partitioning(
+                                     nn.initializers.ones, ("embed",)),
+                                 bias_init=nn.with_partitioning(
+                                     nn.initializers.zeros, ("embed",)))(x)
             batch, seq = x.shape[:2]
 
             def heads(t):
                 return t.reshape(batch, seq, cfg.num_heads, head_dim)
 
-            q, k, v = heads(q), heads(k), heads(v)
+            with step.scope("attn.proj"):
+                qkv = _dense(3 * cfg.embed_dim, cfg, "attn_qkv",
+                             ("embed", "heads"))(h)
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                q, k, v = heads(q), heads(k), heads(v)
+            # what attends names its own pieces (``attn.layout``,
+            # ``attn.kernel``: ``ops/flash_attention.py``).  The ring
+            # stands where the kernels would, its chunks' calls naming
+            # theirs inside it; Ulysses' exchanges move data around such
+            # a call (a reader takes the innermost piece)
             if cfg.attn_impl == "ring":
                 from ray_tpu.parallel.ring_attention import ring_attention
 
@@ -140,16 +148,19 @@ class Block(nn.Module):
                 # global mesh (shard_map applied inside ring_attention);
                 # inside a user shard_map the axis is already bound and
                 # mesh is None
-                attn = ring_attention(q, k, v, axis_name=cfg.sp_axis,
-                                      causal=True, mesh=get_global_mesh())
+                with step.scope("attn.kernel"):
+                    attn = ring_attention(
+                        q, k, v, axis_name=cfg.sp_axis, causal=True,
+                        mesh=get_global_mesh())
             elif cfg.attn_impl == "ulysses":
                 from ray_tpu.parallel.ulysses import ulysses_attention
 
                 # same binding rules as "ring": mesh when under plain
                 # jit/GSPMD, already-bound axis inside a user shard_map
-                attn = ulysses_attention(
-                    q, k, v, axis_name=cfg.sp_axis, causal=True,
-                    mesh=get_global_mesh())
+                with step.scope("attn.layout"):
+                    attn = ulysses_attention(
+                        q, k, v, axis_name=cfg.sp_axis, causal=True,
+                        mesh=get_global_mesh())
             elif cfg.attn_impl == "reference":
                 from ray_tpu.ops.flash_attention import _attention_reference
 
@@ -159,10 +170,12 @@ class Block(nn.Module):
                 # the kernel runs per (batch, head) shard
                 attn = flash_attention(q, k, v, causal=True,
                                        mesh=get_global_mesh())
-            attn = attn.reshape(batch, seq, cfg.embed_dim)
-            attn = _dense(cfg.embed_dim, cfg, "attn_proj",
-                          ("heads", "embed"))(attn)
-            x = x + attn
+            with step.scope("attn.proj"):
+                attn = attn.reshape(batch, seq, cfg.embed_dim)
+                attn = _dense(cfg.embed_dim, cfg, "attn_proj",
+                              ("heads", "embed"))(attn)
+            with step.scope("attn.norm"):
+                x = x + attn
 
         with step.scope("mlp"):
             h = nn.LayerNorm(dtype=cfg.dtype, name="ln_2",

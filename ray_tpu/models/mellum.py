@@ -248,21 +248,28 @@ class AttentionPart(nn.Module):
         cfg = self.config
         batch, seq = x.shape[:2]
         heads, kv, dim = cfg.num_heads, cfg.kv_heads, cfg.head_dim
-        h = Norm(cfg.rms_eps, name="attn_norm")(x)
-        q = _dense(cfg, heads * dim, "wq", ("embed", "heads"))(h)
-        k = _dense(cfg, kv * dim, "wk", ("embed", "kv"))(h)
-        v = _dense(cfg, kv * dim, "wv", ("embed", "kv"))(h)
-        table = rope_table(cfg, self.kind, seq)
-        q = rotate(q.reshape(batch, seq, heads, dim), *table)
-        k = rotate(k.reshape(batch, seq, kv, dim), *table)
-        v = v.reshape(batch, seq, kv, dim)
+        # the part's pieces (``step.ATTN_PIECES``); the kernels' call
+        # names its own two inside its kind
+        with step.scope("attn.norm"):
+            h = Norm(cfg.rms_eps, name="attn_norm")(x)
+        with step.scope("attn.proj"):
+            q = _dense(cfg, heads * dim, "wq", ("embed", "heads"))(h)
+            k = _dense(cfg, kv * dim, "wk", ("embed", "kv"))(h)
+            v = _dense(cfg, kv * dim, "wv", ("embed", "kv"))(h)
+        with step.scope("attn.pos"):  # (a view by heads moves nothing)
+            table = rope_table(cfg, self.kind, seq)
+            q = rotate(q.reshape(batch, seq, heads, dim), *table)
+            k = rotate(k.reshape(batch, seq, kv, dim), *table)
+            v = v.reshape(batch, seq, kv, dim)
         with step.scope("attn." + self.kind):
             attn = flash_attention(
                 q, k, v, causal=True, mesh=get_global_mesh(),
                 window=cfg.window if self.kind == "sliding" else None)
-        attn = attn.reshape(batch, seq, heads * dim)
-        return _here(x + _dense(cfg, cfg.embed_dim, "wo",
-                                ("heads", "embed"))(attn))
+        with step.scope("attn.proj"):
+            attn = _dense(cfg, cfg.embed_dim, "wo", ("heads", "embed"))(
+                attn.reshape(batch, seq, heads * dim))
+        with step.scope("attn.norm"):
+            return _here(x + attn)
 
 
 class MLPPart(nn.Module):
@@ -321,7 +328,8 @@ class MellumBlock(nn.Module):
         if batch % group:
             raise ValueError(f"a batch of {batch} does not give each of "
                              f"{group} chips whole sequences")
-        with step.scope("attn"):
+        # (of ``attn``, the piece that holds the residual stream's side)
+        with step.scope("attn.norm"):
             # a chip's sequences are neighbours in the batch
             calls = x.reshape(group, batch // group, seq, embed)
         if chosen is not None:
@@ -329,7 +337,7 @@ class MellumBlock(nn.Module):
         piece = min(cfg.routed_tokens or seq, seq)
         out = []
         for i in range(batch // group):
-            with step.scope("attn"):
+            with step.scope("attn.norm"):
                 h = _here(calls[:, i])
             h = attn(h)
             pieces = []

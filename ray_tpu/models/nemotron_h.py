@@ -316,18 +316,25 @@ class AttentionPart(nn.Module):
         cfg = self.config
         batch, seq = x.shape[:2]
         heads, kv, dim = cfg.num_heads, cfg.kv_heads, cfg.head_dim
-        h = RMSNorm(cfg.rms_eps, name="attn_norm")(x)
-        q = _dense(cfg, heads * dim, "wq", ("embed", "heads"))(h)
-        k = _dense(cfg, kv * dim, "wk", ("embed", "kv"))(h)
-        v = _dense(cfg, kv * dim, "wv", ("embed", "kv"))(h)
+        # the part's pieces (``step.ATTN_PIECES``); the kernels' call
+        # names its own two inside its kind
+        with step.scope("attn.norm"):
+            h = RMSNorm(cfg.rms_eps, name="attn_norm")(x)
+        with step.scope("attn.proj"):
+            q = _dense(cfg, heads * dim, "wq", ("embed", "heads"))(h)
+            k = _dense(cfg, kv * dim, "wk", ("embed", "kv"))(h)
+            v = _dense(cfg, kv * dim, "wv", ("embed", "kv"))(h)
+            q = q.reshape(batch, seq, heads, dim)
+            k = k.reshape(batch, seq, kv, dim)
+            v = v.reshape(batch, seq, kv, dim)
         with step.scope("attn.full"):
-            attn = flash_attention(
-                q.reshape(batch, seq, heads, dim),
-                k.reshape(batch, seq, kv, dim),
-                v.reshape(batch, seq, kv, dim), causal=True,
-                mesh=get_global_mesh())
-        attn = attn.reshape(batch, seq, heads * dim)
-        return x + _dense(cfg, cfg.embed_dim, "wo", ("heads", "embed"))(attn)
+            attn = flash_attention(q, k, v, causal=True,
+                                   mesh=get_global_mesh())
+        with step.scope("attn.proj"):
+            attn = _dense(cfg, cfg.embed_dim, "wo", ("heads", "embed"))(
+                attn.reshape(batch, seq, heads * dim))
+        with step.scope("attn.norm"):
+            return x + attn
 
 
 class ExpertPart(nn.Module):
@@ -356,7 +363,7 @@ class ExpertPart(nn.Module):
 #: the step's parts its first and its last op are of: ``models/step.py``)
 PARTS = {"M": (MixerPart, "mixer", "m", ("ssm.in_proj", "ssm.out_proj")),
          "E": (ExpertPart, "mlp", "h", ("mlp", "mlp")),
-         "*": (AttentionPart, "attn", "a", ("attn", "attn"))}
+         "*": (AttentionPart, "attn", "a", ("attn.norm", "attn.norm"))}
 
 
 class HybridBlock(nn.Module):

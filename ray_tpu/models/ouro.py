@@ -31,6 +31,7 @@ part's saved input for every one of ``passes x layers`` layer-calls.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Dict, List, Tuple
@@ -111,10 +112,20 @@ class _Part(nn.Module):
     config: OuroConfig
 
     def sandwich(self, x: jax.Array, inner, name: str) -> jax.Array:
-        """``x + post_norm(inner(pre_norm(x)))``."""
+        """``x + post_norm(inner(pre_norm(x)))``; of ``attn`` the norms
+        and the sum are the piece ``attn.norm`` (``step.ATTN_PIECES``:
+        ``mlp`` is split no further)."""
         eps = self.config.rms_eps
-        out = inner(RMSNorm(eps, name=name + "_norm")(x))
-        return x + RMSNorm(eps, name=name + "_post_norm")(out)
+
+        def norms():
+            return step.scope("attn.norm") if name == "attn" \
+                else contextlib.nullcontext()
+
+        with norms():
+            h = RMSNorm(eps, name=name + "_norm")(x)
+        out = inner(h)
+        with norms():
+            return x + RMSNorm(eps, name=name + "_post_norm")(out)
 
 
 class AttentionPart(_Part):
@@ -130,16 +141,23 @@ class AttentionPart(_Part):
         heads, dim = cfg.num_heads, cfg.head_dim
 
         def attention(h):
-            positions = jnp.broadcast_to(jnp.arange(seq)[None], (batch, seq))
-            q, k, v = (_dense(cfg, heads * dim, name, ("embed", "heads"))(
-                h).reshape(batch, seq, heads, dim)
-                for name in ("wq", "wk", "wv"))
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+            # the part's pieces; the kernels' call names its own two
+            with step.scope("attn.pos"):
+                positions = jnp.broadcast_to(jnp.arange(seq)[None],
+                                             (batch, seq))
+            with step.scope("attn.proj"):
+                q, k, v = [_dense(cfg, heads * dim, name,
+                                  ("embed", "heads"))(h).reshape(
+                    batch, seq, heads, dim) for name in ("wq", "wk", "wv")]
+            with step.scope("attn.pos"):
+                q = _rope(q, positions, cfg.rope_theta)
+                k = _rope(k, positions, cfg.rope_theta)
             with step.scope("attn.full"):
                 attn = flash_attention(q, k, v, causal=True)
-            return _dense(cfg, cfg.embed_dim, "wo", ("heads", "embed"))(
-                attn.reshape(batch, seq, heads * dim))
+            with step.scope("attn.proj"):
+                return _dense(cfg, cfg.embed_dim, "wo",
+                              ("heads", "embed"))(
+                    attn.reshape(batch, seq, heads * dim))
 
         with jax.named_scope(f"pass{pass_index}"):
             return self.sandwich(x, attention, "attn")

@@ -16,6 +16,14 @@ so it is in at most one.  A scope is a Python context at trace time and
 a string in the instruction's metadata: the jaxpr, the compiled code
 and the step's memory are what they are without it.
 
+**The part ``attn``, one level down.**  :data:`ATTN_PIECES` is the ONE
+list of what an attention half is made of in every model file, opened
+as ``scope("attn.<piece>")`` and said beside the parts in the plan span
+(``attn_pieces``).  An op's piece is the INNERMOST component of its
+name that is ``attn.<piece>`` with ``<piece>`` on the list: a kind
+(:data:`ATTN_KINDS`, around the kernels' call) or a plain name
+(``mla.kv_up``, a flax module's) is no piece and hides none outside it.
+
 **What a block recomputes, said once too.**  A block that recomputes a
 part in the backward pass wraps it in :func:`remat`, and that recomputes
 everything BUT what the part stamped with a name of :data:`KEPT`
@@ -49,6 +57,22 @@ PARTS = ("embed", "hc.coef", "hc.mix", "attn", "mlp", "moe.route",
          "ssm.gate_norm", "ssm.out_proj", "mtp", "head", "exit",
          "optimizer")
 
+#: the pieces of the part ``attn``.  ``norm``: the residual stream's
+#: side, its read (a sequence taken out of the batch), the pre- and
+#: post-norms, the per-head and latent norms, the residual add;
+#: ``proj``: every matrix product with its bias and the split or reshape
+#: of its result; ``pos``: the rotation, tables and application, and the
+#: join of rotated and unrotated halves; ``gate``: the output gate's
+#: sigmoid and product; ``layout``: what ``ops/flash_attention.py`` does
+#: around its kernel calls that is no kernel (transposes, packed
+#: reshapes, ``delta``, the ``shard_map``); ``kernel``: the kernel
+#: calls, or the plain ``jnp`` form that stands for them off a TPU.
+#: ``ops/`` imports no model: it says the last two as literals
+#: (``tests/test_step_scopes.py`` holds them to this list)
+ATTN_PIECES = ("norm", "proj", "pos", "gate", "layout", "kernel")
+#: what a call of the kernels is, around the call: no piece
+ATTN_KINDS = ("sliding", "full", "mla")
+
 #: what a recomputed part keeps of its forward: a routed call's decisions
 KEPT = ("choices", "plan")
 #: and the few sums a token that a whole pass over its lanes made
@@ -68,10 +92,15 @@ def part_of(component: str) -> Optional[str]:
 
 def scope(name: str):
     """``with scope("attn"):`` around the trace of a part's work, or of
-    a named piece of it (``attn.sliding``): ``jax.named_scope``, for the
-    names of :data:`PARTS` alone."""
+    a named piece of it (``attn.proj``): ``jax.named_scope``, for the
+    names of :data:`PARTS` alone; under ``attn.`` for a piece or a kind
+    alone, so that a typo is no unpieced time."""
     if part_of(name) is None:
         raise ValueError(f"{name!r} is no part of a step: {PARTS}")
+    if name.startswith("attn.") \
+            and name[len("attn."):] not in ATTN_PIECES + ATTN_KINDS:
+        raise ValueError(f"{name!r} is no piece of attn {ATTN_PIECES} "
+                         f"and no kind of it {ATTN_KINDS}")
     return jax.named_scope(name)
 
 
@@ -130,12 +159,14 @@ def make_train_step(loss: Callable[[Any, jax.Array], jax.Array], tx, *,
     state per step).  ``plan(params)``: a context around the trace of
     loss and gradients (GPT-2's ``fsdp_plan``).  A trace leaves the span
     ``model:step.scopes`` (``parts``: :data:`PARTS` comma-joined,
-    ``remat``); nothing runs with the step."""
+    ``attn_pieces``: :data:`ATTN_PIECES` likewise, ``remat``); nothing
+    runs with the step."""
     import optax
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def train_step(params, opt_state, tokens):
         with telemetry.span("model", "step.scopes", parts=",".join(PARTS),
+                            attn_pieces=",".join(ATTN_PIECES),
                             remat=remat):
             with plan(params) if plan else contextlib.nullcontext():
                 value, grads = jax.value_and_grad(

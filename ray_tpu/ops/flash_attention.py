@@ -95,6 +95,15 @@ CUT_BLOCKS = 4
 CUT_BLOCKS_FORWARD = 8
 CUT_MIN = 128
 
+#: the two pieces of a step's part ``attn`` that are made HERE
+#: (``models/step.py`` ``ATTN_PIECES``; ``ops/`` imports no model, and a
+#: test holds the two names to that list).  ``attn.kernel``: the kernel
+#: calls, or the plain ``jnp`` form where that stands for them;
+#: ``attn.layout``: what is done around the calls that is no kernel.  A
+#: function under ``traced_once`` is traced once a family, so these cost
+#: a handful of ``with`` statements a step, not a layer
+_LAYOUT, _KERNEL = "attn.layout", "attn.kernel"
+
 
 def _clamp_k_tile(j, i, block_q: int, block_k: int,
                   window: Optional[int] = None):
@@ -410,23 +419,24 @@ def _plan_args(family: str, q, seq_k: int, block_q: int, block_k: int,
 
 def _attention_reference(q, k, v, causal: bool, scale: float,
                          window: Optional[int] = None) -> jax.Array:
-    group = q.shape[2] // k.shape[2]
-    if group > 1:  # query head h reads K/V head h // group
-        k = jnp.repeat(k, group, axis=2)
-        v = jnp.repeat(v, group, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * scale
-    if causal:
-        tq, tk = q.shape[1], k.shape[1]
-        mask = jnp.tril(jnp.ones((tq, tk), bool), tk - tq)
-        if window is not None:
-            mask = jnp.logical_and(
-                mask, jnp.triu(jnp.ones((tq, tk), bool),
-                               tk - tq - (window - 1)))
-        s = jnp.where(mask[None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
-    return out.astype(q.dtype)
+    with jax.named_scope(_KERNEL):  # it stands where the kernels would
+        group = q.shape[2] // k.shape[2]
+        if group > 1:  # query head h reads K/V head h // group
+            k = jnp.repeat(k, group, axis=2)
+            v = jnp.repeat(v, group, axis=2)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                       preferred_element_type=jnp.float32) * scale
+        if causal:
+            tq, tk = q.shape[1], k.shape[1]
+            mask = jnp.tril(jnp.ones((tq, tk), bool), tk - tq)
+            if window is not None:
+                mask = jnp.logical_and(
+                    mask, jnp.triu(jnp.ones((tq, tk), bool),
+                                   tk - tq - (window - 1)))
+            s = jnp.where(mask[None, None], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
+        return out.astype(q.dtype)
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
@@ -509,9 +519,10 @@ def _flash_forward(q, k, v, causal: bool, scale: float,
     seq_k = k.shape[1]
     group = heads // k.shape[2]
     # pallas layout: [B, H, T, D]
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
+    with jax.named_scope(_LAYOUT):
+        qt = q.transpose(0, 2, 1, 3)
+        kt = k.transpose(0, 2, 1, 3)
+        vt = v.transpose(0, 2, 1, 3)
 
     block_q = min(block_q, seq_q)
     block_k = min(block_k, seq_k)
@@ -534,7 +545,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float,
         def kv_idx(b, h, i, j):
             return (b, _kv_head(h, group), j, 0)
 
-    out, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -562,8 +573,11 @@ def _flash_forward(q, k, v, causal: bool, scale: float,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3), lse
+    )
+    with jax.named_scope(_KERNEL):
+        out, lse = call(qt, kt, vt)
+    with jax.named_scope(_LAYOUT):
+        return out.transpose(0, 2, 1, 3), lse
 
 
 def _fa_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -685,16 +699,17 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     block_q = min(block_q, seq_q)
     block_k = min(block_k, seq_k)
     n_q = seq_q // block_q
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    dot = g.transpose(0, 2, 1, 3)
-    if delta is None:
-        # delta_i = rowsum(dO_i * O_i) (FlashAttention-2 eq. for dS);
-        # [B,H,S,1] like lse (TPU blocks need >=2 trailing dims)
-        delta = jnp.sum(dot.astype(jnp.float32)
-                        * out.transpose(0, 2, 1, 3).astype(jnp.float32),
-                        axis=-1, keepdims=True)
+    with jax.named_scope(_LAYOUT):
+        qt = q.transpose(0, 2, 1, 3)
+        kt = k.transpose(0, 2, 1, 3)
+        vt = v.transpose(0, 2, 1, 3)
+        dot = g.transpose(0, 2, 1, 3)
+        if delta is None:
+            # delta_i = rowsum(dO_i * O_i) (FlashAttention-2 eq. for
+            # dS); [B,H,S,1] like lse (TPU blocks need >=2 trailing dims)
+            delta = jnp.sum(dot.astype(jnp.float32)
+                            * out.transpose(0, 2, 1, 3).astype(jnp.float32),
+                            axis=-1, keepdims=True)
 
     seq_params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
@@ -731,7 +746,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                              causal=causal, block_q=block_q,
                              block_k=block_k, window=window,
                              q_tiles=0 if group == 1 else n_q)
-    dk, dv = pl.pallas_call(
+    dkdv_call = pl.pallas_call(
         dkdv,
         grid=(batch, kv_heads, seq_k // block_k, group * n_q),
         in_specs=[tile_q, tile_k_rev, tile_k_rev, tile_q, rows_q_rev,
@@ -743,7 +758,9 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                         pltpu.VMEM((block_k, dim), jnp.float32)],
         compiler_params=seq_params,
         interpret=interpret,
-    )(qt, kt, vt, dot, lse, delta)
+    )
+    with jax.named_scope(_KERNEL):
+        dk, dv = dkdv_call(qt, kt, vt, dot, lse, delta)
 
     tile_q_fwd = pl.BlockSpec((None, None, block_q, dim),
                               lambda b, h, i, j: (b, h, i, 0))
@@ -753,7 +770,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     dq_kernel = functools.partial(_fa_bwd_dq_kernel, scale=scale,
                                   causal=causal, block_q=block_q,
                                   block_k=block_k, window=window)
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         dq_kernel,
         grid=(batch, heads, seq_q // block_q, seq_k // block_k),
         in_specs=[tile_q_fwd, tile_k_fwd, tile_k_fwd, tile_q_fwd,
@@ -763,10 +780,13 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_q, dim), jnp.float32)],
         compiler_params=seq_params,
         interpret=interpret,
-    )(qt, kt, vt, dot, lse, delta)
+    )
+    with jax.named_scope(_KERNEL):
+        dq = dq_call(qt, kt, vt, dot, lse, delta)
 
-    return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
-            dv.transpose(0, 2, 1, 3))
+    with jax.named_scope(_LAYOUT):
+        return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
+                dv.transpose(0, 2, 1, 3))
 
 
 # ---------------------------------------------------------------------------
@@ -925,9 +945,10 @@ def _flash_nl_forward(q, k, v, causal: bool, scale: float,
     # slab h reads K/V slab h // group
     group = heads // k.shape[2]
     # free reshapes: collapse the contiguous minor dims
-    qr = q.reshape(batch, seq_q, h2 * pack * dim)
-    kr = k.reshape(batch, seq_k, k.shape[2] * dim)
-    vr = v.reshape(batch, seq_k, k.shape[2] * dim)
+    with jax.named_scope(_LAYOUT):
+        qr = q.reshape(batch, seq_q, h2 * pack * dim)
+        kr = k.reshape(batch, seq_k, k.shape[2] * dim)
+        vr = v.reshape(batch, seq_k, k.shape[2] * dim)
 
     block_q = min(block_q, seq_q)
     block_k = min(block_k, seq_k)
@@ -948,7 +969,7 @@ def _flash_nl_forward(q, k, v, causal: bool, scale: float,
         def kv_idx(b, h, i, j):
             return (b, j, _kv_head(h, group))
 
-    out, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -976,8 +997,11 @@ def _flash_nl_forward(q, k, v, causal: bool, scale: float,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(qr, kr, vr)
-    return out.reshape(q.shape), lse
+    )
+    with jax.named_scope(_KERNEL):
+        out, lse = call(qr, kr, vr)
+    with jax.named_scope(_LAYOUT):
+        return out.reshape(q.shape), lse
 
 
 def _fa_nl_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -1131,18 +1155,20 @@ def _flash_nl_backward(q, k, v, out, lse, g, causal, scale, block_q,
     block_q = min(block_q, seq_q)
     block_k = min(block_k, seq_k)
     n_q = seq_q // block_q
-    qr = q.reshape(batch, seq_q, heads * dim)
-    kr = k.reshape(batch, seq_k, kv_heads * dim)
-    vr = v.reshape(batch, seq_k, kv_heads * dim)
-    gr = g.reshape(batch, seq_q, heads * dim)
-    if delta is None:
-        # delta_i = rowsum(dO_i * O_i), laid out [B, H2, T, pack] like
-        # lse (T in sublanes so per-head columns broadcast along lanes
-        # without relayout); XLA fuses the product+reduce
-        delta = (jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                         axis=-1)                  # [B, T, H]
-                 .reshape(batch, seq_q, h2, pack)
-                 .transpose(0, 2, 1, 3))           # [B, H2, T, pack]
+    with jax.named_scope(_LAYOUT):
+        qr = q.reshape(batch, seq_q, heads * dim)
+        kr = k.reshape(batch, seq_k, kv_heads * dim)
+        vr = v.reshape(batch, seq_k, kv_heads * dim)
+        gr = g.reshape(batch, seq_q, heads * dim)
+        if delta is None:
+            # delta_i = rowsum(dO_i * O_i), laid out [B, H2, T, pack]
+            # like lse (T in sublanes so per-head columns broadcast along
+            # lanes without relayout); XLA fuses the product+reduce
+            delta = (jnp.sum(g.astype(jnp.float32)
+                             * out.astype(jnp.float32),
+                             axis=-1)                  # [B, T, H]
+                     .reshape(batch, seq_q, h2, pack)
+                     .transpose(0, 2, 1, 3))           # [B, H2, T, pack]
 
     seq_params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
@@ -1188,7 +1214,7 @@ def _flash_nl_backward(q, k, v, out, lse, g, causal, scale, block_q,
                              block_k=block_k, pack=pack, dim=dim,
                              window=window,
                              q_tiles=0 if group == 1 else n_q)
-    dk, dv = pl.pallas_call(
+    dkdv_call = pl.pallas_call(
         dkdv,
         grid=(batch, kv_heads // pack, seq_k // block_k, group * n_q),
         in_specs=[tile_q, tile_k_rev, tile_k_rev, tile_q, rows_q_rev,
@@ -1200,7 +1226,9 @@ def _flash_nl_backward(q, k, v, out, lse, g, causal, scale, block_q,
                         pltpu.VMEM((block_k, slab), jnp.float32)],
         compiler_params=seq_params,
         interpret=interpret,
-    )(qr, kr, vr, gr, lse, delta)
+    )
+    with jax.named_scope(_KERNEL):
+        dk, dv = dkdv_call(qr, kr, vr, gr, lse, delta)
 
     tile_q_fwd = pl.BlockSpec((None, block_q, slab),
                               lambda b, h, i, j: (b, i, h))
@@ -1211,7 +1239,7 @@ def _flash_nl_backward(q, k, v, out, lse, g, causal, scale, block_q,
                                   causal=causal, block_q=block_q,
                                   block_k=block_k, pack=pack, dim=dim,
                                   window=window)
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         dq_kernel,
         grid=(batch, h2, seq_q // block_q, seq_k // block_k),
         in_specs=[tile_q_fwd, tile_k_fwd, tile_k_fwd, tile_q_fwd,
@@ -1221,9 +1249,13 @@ def _flash_nl_backward(q, k, v, out, lse, g, causal, scale, block_q,
         scratch_shapes=[pltpu.VMEM((block_q, slab), jnp.float32)],
         compiler_params=seq_params,
         interpret=interpret,
-    )(qr, kr, vr, gr, lse, delta)
+    )
+    with jax.named_scope(_KERNEL):
+        dq = dq_call(qr, kr, vr, gr, lse, delta)
 
-    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
+    with jax.named_scope(_LAYOUT):
+        return (dq.reshape(q.shape), dk.reshape(k.shape),
+                dv.reshape(v.shape))
 
 
 # ---------------------------------------------------------------------------
@@ -1339,13 +1371,15 @@ def _flash_mla_forward(q, k, k_rope, v, causal: bool, scale: float,
                                 v.shape[3])
     block_q, block_k = _mla_blocks(q, k, block_q, block_k)
     # pallas layout: [B, H, T, D]
-    qt, kt, rt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, k_rope, v))
+    with jax.named_scope(_LAYOUT):
+        qt, kt, rt, vt = (x.transpose(0, 2, 1, 3)
+                          for x in (q, k, k_rope, v))
 
     def k_tile(j, i):
         return _clamp_k_tile(j, i, block_q, block_k) if causal else j
 
     rows_q = lambda b, h, i, j: (b, h, i, 0)  # noqa: E731
-    out, lse = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_fa_mla_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
         grid=(batch, heads, seq_q // block_q, seq_k // block_k),
@@ -1375,8 +1409,11 @@ def _flash_mla_forward(q, k, k_rope, v, causal: bool, scale: float,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(qt, kt, rt, vt)
-    return out.transpose(0, 2, 1, 3), lse
+    )
+    with jax.named_scope(_KERNEL):
+        out, lse = call(qt, kt, rt, vt)
+    with jax.named_scope(_LAYOUT):
+        return out.transpose(0, 2, 1, 3), lse
 
 
 def _mla_probs(q_ref, k_ref, r_ref, lse_ref, scale, keep, part: _Part):
@@ -1496,12 +1533,13 @@ def _flash_mla_backward(q, k, k_rope, v, out, lse, g, causal, scale,
                                 v.shape[3])
     block_q, block_k = _mla_blocks(q, k, block_q, block_k)
     n_q = seq_q // block_q
-    qt, kt, rt, vt, dot = (x.transpose(0, 2, 1, 3)
-                           for x in (q, k, k_rope, v, g))
-    # delta_i = rowsum(dO_i * O_i), [B, H, T, 1] like lse
-    delta = jnp.sum(dot.astype(jnp.float32)
-                    * out.transpose(0, 2, 1, 3).astype(jnp.float32),
-                    axis=-1, keepdims=True)
+    with jax.named_scope(_LAYOUT):
+        qt, kt, rt, vt, dot = (x.transpose(0, 2, 1, 3)
+                               for x in (q, k, k_rope, v, g))
+        # delta_i = rowsum(dO_i * O_i), [B, H, T, 1] like lse
+        delta = jnp.sum(dot.astype(jnp.float32)
+                        * out.transpose(0, 2, 1, 3).astype(jnp.float32),
+                        axis=-1, keepdims=True)
 
     def q_tile(j, i):  # dK/dV grid: i = k tile, j = head * n_q + q tile
         return _clamp_q_tile(j % n_q, i, block_q, block_k) if causal \
@@ -1514,7 +1552,7 @@ def _flash_mla_backward(q, k, k_rope, v, out, lse, g, causal, scale,
     walk_q = lambda b, i, j: (b, j // n_q, q_tile(j, i), 0)  # noqa: E731
     head_k = lambda b, i, j: (b, j // n_q, i, 0)             # noqa: E731
     shared_k = lambda b, i, j: (b, 0, i, 0)                  # noqa: E731
-    dk, dr, dv = pl.pallas_call(
+    dkdv_call = pl.pallas_call(
         functools.partial(_fa_mla_bwd_dkdv_kernel, scale=scale,
                           causal=causal, block_q=block_q, block_k=block_k,
                           q_tiles=n_q),
@@ -1541,10 +1579,12 @@ def _flash_mla_backward(q, k, k_rope, v, out, lse, g, causal, scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(qt, kt, rt, vt, dot, lse, delta)
+    )
+    with jax.named_scope(_KERNEL):
+        dk, dr, dv = dkdv_call(qt, kt, rt, vt, dot, lse, delta)
 
     rows_q = lambda b, h, i, j: (b, h, i, 0)  # noqa: E731
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         functools.partial(_fa_mla_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
         grid=(batch, heads, n_q, seq_k // block_k),
@@ -1567,9 +1607,12 @@ def _flash_mla_backward(q, k, k_rope, v, out, lse, g, causal, scale,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(qt, kt, rt, vt, dot, lse, delta)
+    )
+    with jax.named_scope(_KERNEL):
+        dq = dq_call(qt, kt, rt, vt, dot, lse, delta)
 
-    return tuple(x.transpose(0, 2, 1, 3) for x in (dq, dk, dr, dv))
+    with jax.named_scope(_LAYOUT):
+        return tuple(x.transpose(0, 2, 1, 3) for x in (dq, dk, dr, dv))
 
 
 def _chunk_blocks(seq_q: int, seq_k: int):
@@ -1597,12 +1640,14 @@ def _flash_chunk_fwd(q, k, v, causal: bool, scale: float,
                                      block_k, interpret,
                                      out_dtype=jnp.float32)
         # [B, H2, T, pack] -> [B, T, H]  (head index = h2 * pack + h)
-        lse = lse.transpose(0, 2, 1, 3).reshape(batch, seq_q, heads)
+        with jax.named_scope(_LAYOUT):
+            lse = lse.transpose(0, 2, 1, 3).reshape(batch, seq_q, heads)
     else:
         out, lse = _flash_forward(q, k, v, causal, scale, block_q,
                                   block_k, interpret,
                                   out_dtype=jnp.float32)
-        lse = lse[..., 0].transpose(0, 2, 1)
+        with jax.named_scope(_LAYOUT):
+            lse = lse[..., 0].transpose(0, 2, 1)
     return out, lse
 
 
@@ -1622,19 +1667,22 @@ def _flash_chunk_bwd(q, k, v, out, lse, g, causal: bool, scale: float,
         h2 = heads // pack
 
         def to_nl(x):
-            return x.reshape(batch, seq_q, h2, pack).transpose(0, 2, 1, 3)
+            with jax.named_scope(_LAYOUT):
+                return x.reshape(batch, seq_q, h2, pack) \
+                    .transpose(0, 2, 1, 3)
 
         return _flash_nl_backward(q, k, v, out, to_nl(lse), g, causal,
                                   scale, block_q, block_k, interpret,
                                   grad_dtype=jnp.float32,
                                   delta=None if delta is None
                                   else to_nl(delta))
-    return _flash_backward(q, k, v, out,
-                           lse.transpose(0, 2, 1)[..., None], g, causal,
-                           scale, block_q, block_k, interpret,
-                           grad_dtype=jnp.float32,
-                           delta=None if delta is None
-                           else delta.transpose(0, 2, 1)[..., None])
+    with jax.named_scope(_LAYOUT):
+        lse = lse.transpose(0, 2, 1)[..., None]
+        if delta is not None:
+            delta = delta.transpose(0, 2, 1)[..., None]
+    return _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
+                           block_k, interpret, grad_dtype=jnp.float32,
+                           delta=delta)
 
 
 def kernel_block_for(seq: int, block: int = DEFAULT_BLOCK):
@@ -1748,8 +1796,9 @@ def _latent_attention(q, k, k_rope, v, causal, scale, block_q, block_k,
                          "device, with no window")
     interpret = kernel_mode(interpret)
     if interpret is None:
-        whole = jnp.concatenate(
-            [k, jnp.broadcast_to(k_rope, (*k.shape[:3], rope))], -1)
+        with jax.named_scope(_KERNEL):  # a tile's two key parts, joined
+            whole = jnp.concatenate(
+                [k, jnp.broadcast_to(k_rope, (*k.shape[:3], rope))], -1)
         return _attention_reference(q, whole, v, causal, scale)
     block_q = DEFAULT_BLOCK if block_q is None else block_q
     block_k = DEFAULT_BLOCK if block_k is None else block_k
@@ -1841,8 +1890,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             flash_attention, causal=causal, scale=scale, block_q=block_q,
             block_k=block_k, interpret=interpret, native=native,
             window=window)
-        return jax.shard_map(per_shard, mesh=mesh, in_specs=(spec,) * 3,
-                             out_specs=spec, check_vma=False)(q, k, v)
+        # (what a shard runs names its own pieces inside this one: a
+        # reader takes the innermost)
+        with jax.named_scope(_LAYOUT):
+            return jax.shard_map(per_shard, mesh=mesh, in_specs=(spec,) * 3,
+                                 out_specs=spec, check_vma=False)(q, k, v)
     block_q = DEFAULT_BLOCK if block_q is None else block_q
     block_k = DEFAULT_BLOCK if block_k is None else block_k
     if native is None:

@@ -7,11 +7,15 @@ what a device trace is split by.  Here each of the four model files'
 instruction's ``op_name`` read from the compiled text, with the reader
 the benchmark uses on a chip's trace (``benchmarks/reduce/scopes.py``);
 the list of parts comes from the ``model:step.scopes`` span, as there.
+The part ``attn`` has a second level, its pieces
+(``step.ATTN_PIECES``, reader ``benchmarks/reduce/pieces.py``): held
+here in all six model files, on the step traced as for a TPU.
 Names only: nothing here is a speed.
 """
 
 import functools
 import hashlib
+import importlib
 import re
 from unittest import mock
 
@@ -21,10 +25,14 @@ import optax
 import pytest
 from flax.core import meta
 
-from benchmarks.reduce import scopes
+from benchmarks.reduce import pieces, scopes
 from ray_tpu.core import telemetry
-from ray_tpu.models import afmoe, deepseek_v3, gpt2, nemotron_h, step
+from ray_tpu.models import afmoe, deepseek_v3, gpt2, mellum, nemotron_h, \
+    ouro, step
 from ray_tpu.ops import grouped_matmul as gm
+
+# (``ray_tpu.ops`` exports the function under the module's name)
+fa = importlib.import_module("ray_tpu.ops.flash_attention")
 
 MODELS = {
     "gpt2": (gpt2, gpt2.GPT2Config, gpt2.GPT2),
@@ -151,6 +159,7 @@ def test_the_span_says_the_list_once_a_trace(name):
     instructions, rows = compiled(name)
     assert len(rows) == 1
     assert rows[0]["args"] == {"parts": ",".join(step.PARTS),
+                               "attn_pieces": ",".join(step.ATTN_PIECES),
                                "remat": "full"}
     parts = parts_of(name)
     seen = {scopes.part(n, parts) for _, n in instructions} - {None}
@@ -292,6 +301,184 @@ def test_the_program_is_what_it_was(name, remat):
     assert (hashlib.sha256(text.encode()).hexdigest(),
             hashlib.sha256(paths.encode()).hexdigest()) == \
         BEFORE[name, remat]
+
+
+# --------------------------------------------------------------------------
+# the part ``attn``, one level down: its pieces
+# --------------------------------------------------------------------------
+
+#: every model file's tiny configuration: the four above, Mellum, Ouro,
+#: and ``deepseek_v3`` as Xing runs it (a query latent, YaRN, four lanes)
+TINY = {**{name: (module, config.tiny, cls)
+           for name, (module, config, cls) in MODELS.items()},
+        "mellum": (mellum, mellum.MellumConfig.tiny, mellum.Mellum),
+        "ouro": (ouro, ouro.OuroConfig.tiny, ouro.Ouro),
+        "xing": (deepseek_v3, functools.partial(
+            deepseek_v3.DeepseekV3Config.tiny, q_lora_rank=16,
+            yarn_factor=64.0, rope_theta=1e4, hc_mult=4,
+            num_shared_experts=1, route_scale=2.0),
+            deepseek_v3.DeepseekV3),
+        # GPT-2 with two heads of 64: the native-layout kernels
+        "gpt2_native": (gpt2, functools.partial(gpt2.GPT2Config.tiny,
+                                                embed_dim=128), gpt2.GPT2)}
+PIECED = sorted(set(TINY) - {"gpt2_native"})
+#: a kernel family -> a tiny step that calls it
+FAMILIES = {"native": "gpt2_native", "head_major": "gpt2",
+            "latent": "deepseek_v3"}
+
+
+def _equations(jaxpr, prefix=""):
+    """``(primitive, name)`` of every equation, those of inner jaxprs
+    under the names the lowering would give them: the outer equation's
+    name stack before theirs, ``jit(<name>)`` at an inner ``jit``.  A
+    kernel's body is the kernel's."""
+    for eqn in jaxpr.eqns:
+        prim = eqn.primitive.name
+        name = "/".join(x for x in (
+            prefix, str(eqn.source_info.name_stack)) if x)
+        inner = [] if prim == "pallas_call" else \
+            list(jax.core.jaxprs_in_params(eqn.params))
+        if prim in ("jit", "pjit"):
+            name += f"/jit({eqn.params['name']})"
+        for sub in inner:
+            yield from _equations(getattr(sub, "jaxpr", sub), name)
+        if not inner:
+            yield prim, f"{name}/{prim}"
+
+
+@functools.lru_cache(maxsize=None)
+def traced(name):
+    """``(equations [(primitive, name)], the trace's step.scopes rows)``
+    of the model's tiny step under ``remat="full"``, traced as for a TPU
+    (the kernel calls there, not their ``jnp`` form).  The jaxpr, not
+    its lowering: the Nemotron mixer's kernels do not lower at their
+    tiny shapes, and a name is the trace's to give."""
+    module, tiny, model_cls = TINY[name]
+    cfg = tiny(remat="full")
+    model = model_cls(cfg)
+    tx = optax.adamw(1e-3)
+    params = meta.unbox(jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0), batch=2)))
+    args = (params, jax.eval_shape(tx.init, params),
+            jax.ShapeDtypeStruct((2, cfg.max_seq_len), jnp.int32))
+    telemetry.drain_spans("test")
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        jaxpr = jax.make_jaxpr(module.make_train_step(model, tx))(*args)
+    rows = [r for r in telemetry.drain_spans("test")
+            if (r["cat"], r["name"]) == ("model", "step.scopes")]
+    return list(_equations(jaxpr.jaxpr)), rows
+
+
+def all_pieces(op_name):
+    """EVERY piece among an op's components (the reader takes the
+    innermost: off a ``shard_map`` an op sits under one)."""
+    return {c[len("attn."):] for c in scopes.components(op_name)
+            if c.startswith("attn.")
+            and c[len("attn."):] in step.ATTN_PIECES}
+
+
+def under_attn(instructions):
+    return [(op, n) for op, n in instructions
+            if scopes.part(n, step.PARTS) == "attn"]
+
+
+@pytest.mark.parametrize("name", PIECED)
+def test_the_span_says_the_pieces_beside_the_parts(name):
+    _, rows = traced(name)
+    assert len(rows) == 1
+    assert rows[0]["args"]["attn_pieces"] == ",".join(step.ATTN_PIECES)
+    assert pieces.step_pieces(rows) == list(step.ATTN_PIECES)
+    assert scopes.step_parts(rows) == list(step.PARTS)
+
+
+@pytest.mark.parametrize("name", PIECED)
+def test_every_op_under_attn_sits_under_one_piece(name):
+    said = list(step.ATTN_PIECES)
+    found = [traced(name)[0]]
+    if name in MODELS:  # and in the step the CPU compiled (the jnp form)
+        found.append([(op, n) for op, n in compiled(name)[0]
+                      if op not in _PLUMBING])
+    for instructions in found:
+        work = under_attn(instructions)
+        assert len(work) > 100
+        assert not [n for _, n in work if len(all_pieces(n)) > 1]
+        bare = [n for _, n in work if pieces.piece(n, said) is None]
+        assert len(bare) < 0.02 * len(work), (
+            len(bare), len(work), sorted(set(bare))[:10])
+        seen = {pieces.piece(n, said) for _, n in work} - {None}
+        assert seen >= {"norm", "proj", "kernel"}
+        assert ("gate" in seen) == (name == "afmoe")
+        assert ("pos" in seen) == (name not in ("gpt2", "nemotron_h"))
+    # what the kernels' file does around its calls: where they are called
+    assert "layout" in {pieces.piece(n, said)
+                        for _, n in under_attn(found[0])}
+    # no piece outside the part: ``hc.*`` stay beside ``attn``
+    assert not [n for _, n in found[0] if all_pieces(n)
+                and scopes.part(n, step.PARTS) != "attn"]
+
+
+@pytest.mark.parametrize("name", PIECED)
+def test_every_product_under_attn_is_a_projection_or_the_kernels(name):
+    said = list(step.ATTN_PIECES)
+    products = [n for op, n in under_attn(traced(name)[0])
+                if op == "dot_general"]
+    assert len(products) >= 10
+    assert {pieces.piece(n, said) for n in products} == {"proj"}
+    if name in MODELS:  # the jnp form stands for the kernels
+        products = [n for op, n in under_attn(compiled(name)[0])
+                    if op in ("dot", "convolution")]
+        assert {pieces.piece(n, said) for n in products} <= {"proj",
+                                                              "kernel"}
+        assert [n for n in products if "/attn.proj/" in n]
+    # a latent projection's plain name stands inside the piece
+    latent = [n for _, n in traced(name)[0] if "/mla." in n]
+    assert bool(latent) == (name in ("deepseek_v3", "xing"))
+    assert {pieces.piece(n, said) for n in latent} <= {"proj"}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_a_kernel_call_is_kernel_and_what_is_around_it_layout(family):
+    assert (fa._LAYOUT, fa._KERNEL) == ("attn.layout", "attn.kernel")
+    assert {"layout", "kernel"} <= set(step.ATTN_PIECES)
+    said = list(step.ATTN_PIECES)
+    made_here = [(op, n) for op, n in under_attn(
+        traced(FAMILIES[family])[0]) if "/jit(_flash_" in n]
+    builders = {c for _, n in made_here for c in scopes.components(n)
+                if c.startswith("jit(_flash_")}
+    assert builders == {
+        "native": {"jit(_flash_nl_forward)", "jit(_flash_nl_backward)"},
+        "head_major": {"jit(_flash_forward)", "jit(_flash_backward)"},
+        "latent": {"jit(_flash_mla_forward)", "jit(_flash_mla_backward)"},
+    }[family]
+    calls = [n for op, n in made_here if op == "pallas_call"]
+    # a call of a layer: forward, recomputed forward, dK/dV and dQ
+    by_phase = {}
+    for n in calls:
+        by_phase[scopes.phase(n)] = by_phase.get(scopes.phase(n), 0) + 1
+    forward = by_phase["forward"]
+    assert forward >= 2 and by_phase == {
+        "forward": forward, "recompute": forward, "backward": 2 * forward}
+    assert {pieces.piece(n, said) for n in calls} == {"kernel"}
+    moved = [n for op, n in made_here if op == "transpose"]
+    assert bool(moved)
+    assert {pieces.piece(n, said) for n in moved} == {"layout"}
+    # and nothing made here is anything else
+    assert {pieces.piece(n, said) for _, n in made_here} == {"layout",
+                                                             "kernel"}
+    # any other kernel call under the part is a fused norm's
+    other = [n for op, n in under_attn(traced(FAMILIES[family])[0])
+             if op == "pallas_call" and n not in calls]
+    assert {pieces.piece(n, said) for n in other} <= {"norm"}
+
+
+def test_a_scope_under_attn_is_a_piece_or_a_kind():
+    with pytest.raises(ValueError):
+        step.scope("attn.rotate")
+    with step.scope("attn.pos"), step.scope("attn.mla"):
+        pass
+    for piece in step.ATTN_PIECES:
+        assert step.part_of("attn." + piece) == "attn"
+    assert not set(step.ATTN_PIECES) & set(step.ATTN_KINDS)
 
 
 def test_a_scope_that_is_no_part_is_refused():
