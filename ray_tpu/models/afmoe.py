@@ -644,7 +644,9 @@ def loss_fn(model: nn.Module, params, tokens: jax.Array,
                       mutable=["intermediates"] if with_choices else False)
     (x, head), state = out if with_choices else (out, None)
     compute = jnp.bfloat16 if model.config.dtype == jnp.bfloat16 else None
-    with step.scope("head"):  # the scan's body inherits it
+    # the scan's body inherits the name, and the gradient's two products
+    # with it: they run in the forward's scan (``ops/fused.py``)
+    with step.scope("head"):
         loss = chunked_lm_loss(x[:, :-1], head, tokens[:, 1:],
                                chunk=head_chunk, compute_dtype=compute,
                                logits_dtype=head_logits_dtype)

@@ -542,11 +542,13 @@ def loss_fn(model: nn.Module, params, tokens: jax.Array,
     kw = dict(chunk=head_chunk, logits_dtype=head_logits_dtype,
               compute_dtype=jnp.bfloat16 if cfg.dtype == jnp.bfloat16
               else None)
-    with step.scope("head"):  # the scan's body inherits it
+    # the scan's body inherits the name, and the gradient's two products
+    # with it: they run in the forward's scan (``ops/fused.py``)
+    with step.scope("head"):
         loss = chunked_lm_loss(x[:, :-1], head, tokens[:, 1:], **kw)
     with step.scope("mtp"):
-        loss = loss + MTP_WEIGHT * chunked_lm_loss(
-            mtp[:, :-2], head, tokens[:, 2:], **kw)
+        loss = loss + chunked_lm_loss(
+            mtp[:, :-2], head, tokens[:, 2:], weight=MTP_WEIGHT, **kw)
     return (loss, _own_choices(model, state)) if with_choices else loss
 
 

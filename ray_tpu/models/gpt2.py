@@ -273,14 +273,18 @@ def loss_fn(model: GPT2, params, tokens: jax.Array,
     The LM head + softmax run in token chunks (``chunked_lm_loss``):
     full [B,T,V] f32 logits would be the single largest HBM tensor *and*
     the dominant bandwidth consumer at small model sizes (2 x 6 GiB at
-    batch 32 — the profile that motivated this)."""
+    batch 32 — the profile that motivated this).  A chunk's logits are
+    made once: under a gradient the same scan step takes ``d hidden``
+    and ``d wte`` from them (``ops/fused.py`` ``weighted_token_loss``),
+    and the backward pass has no head left to run."""
     from ray_tpu.ops.fused import chunked_lm_loss
 
     x, wte = model.apply({"params": params}, tokens, method=GPT2.hidden)
     # bf16-activation models run the head matmuls on the MXU in bf16;
     # logits accumulate and are stored in f32
     compute = jnp.bfloat16 if model.config.dtype == jnp.bfloat16 else None
-    with step.scope("head"):  # the scan's body inherits it
+    # the scan's body inherits the name, the gradient's products with it
+    with step.scope("head"):
         return chunked_lm_loss(x[:, :-1], wte, tokens[:, 1:],
                                chunk=head_chunk, compute_dtype=compute,
                                mesh=get_global_mesh())

@@ -45,7 +45,7 @@ from ray_tpu.models import step
 from ray_tpu.models.afmoe import _dense, _swiglu, each_sequence
 from ray_tpu.models.llama import RMSNorm, _rope
 from ray_tpu.ops.flash_attention import flash_attention
-from ray_tpu.ops.fused import chunked_token_loss
+from ray_tpu.ops.fused import chunked_token_loss, weighted_token_loss
 
 
 @dataclasses.dataclass(frozen=True)
@@ -292,6 +292,19 @@ def exit_loss(ce: jax.Array, gate: jax.Array, beta: float) -> jax.Array:
     return jnp.sum(jnp.exp(log_p) * (ce + beta * log_p), axis=0)
 
 
+def _exits(model: nn.Module, params, tokens: jax.Array):
+    """``(hidden [R x B, T-1, E], head [V, E], labels [R x B, T-1], gate
+    [R-1, B, T-1])``: the exits' states STACKED for one call of the
+    head (one scan, one rounded copy of the head and one accumulator of
+    its gradient, where a call an exit keeps four of each alive: 1.5
+    GiB more; PERF.md, PR 47), every exit's labels, and the gate's
+    logits.  The last position has no label and is left out."""
+    states, head, gate = model.apply({"params": params}, tokens,
+                                     method=type(model).hidden)
+    return (jnp.concatenate([x[:, :-1] for x in states]), head,
+            jnp.tile(tokens[:, 1:], (len(states), 1)), gate[:, :, :-1])
+
+
 def exit_terms(model: nn.Module, params, tokens: jax.Array,
                head_chunk: int = 1024, head_logits_dtype: Any = None
                ) -> Tuple[jax.Array, jax.Array]:
@@ -299,21 +312,19 @@ def exit_terms(model: nn.Module, params, tokens: jax.Array,
     cross entropy at every exit, through the chunked head that gives a
     loss a token (float32 logits unless ``head_logits_dtype`` says
     otherwise, the ``[chunk, V]`` block alive in one scan step), and its
-    gate logits.  The last position has no label and is left out."""
-    states, head, gate = model.apply({"params": params}, tokens,
-                                     method=type(model).hidden)
+    gate logits.  For a reader of the exits and for a loss that weighs
+    them its own way (``benchmarks/controls/ouro.py``); the training
+    loss, :func:`loss_fn`, does not come through here: a cotangent a
+    token is known only in the backward pass, so this head recomputes
+    its logits there."""
+    hidden, head, labels, gate = _exits(model, params, tokens)
     compute = jnp.bfloat16 if model.config.dtype == jnp.bfloat16 else None
-    passes, (batch, seq) = len(states), tokens.shape
-    # ONE call over the exits' states stacked: one scan, one rounded copy
-    # of the head and one accumulator of its gradient, where a call an
-    # exit keeps four of each alive (1.5 GiB more; PERF.md, PR 47); the
-    # scan's body inherits the part's name and cannot carry a pass
+    # the scan's body inherits the part's name and cannot carry a pass
     with step.scope("head"):
-        ce = chunked_token_loss(
-            jnp.concatenate([x[:, :-1] for x in states]), head,
-            jnp.tile(tokens[:, 1:], (passes, 1)), chunk=head_chunk,
-            compute_dtype=compute, logits_dtype=head_logits_dtype)
-    return ce.reshape(passes, batch, seq - 1), gate[:, :, :-1]
+        ce = chunked_token_loss(hidden, head, labels, chunk=head_chunk,
+                                compute_dtype=compute,
+                                logits_dtype=head_logits_dtype)
+    return ce.reshape(-1, *gate.shape[1:]), gate
 
 
 def loss_fn(model: nn.Module, params, tokens: jax.Array,
@@ -321,11 +332,23 @@ def loss_fn(model: nn.Module, params, tokens: jax.Array,
             ) -> jax.Array:
     """Mean over tokens of ``sum_t p_t CE_t - beta H(p)``: the loss read
     at every exit, weighted by the exit distribution the gate computes,
-    which takes gradients itself."""
-    ce, gate = exit_terms(model, params, tokens, head_chunk,
-                          head_logits_dtype)
+    which takes gradients itself.  The head weighs every (exit, token)
+    by ``p_t / n`` (``weighted_token_loss``: loss and gradient in one
+    scan over the logits, the gate's gradient through the weights, whose
+    cotangent is ``CE``); the entropy term stands beside it."""
+    hidden, head, labels, gate = _exits(model, params, tokens)
+    compute = jnp.bfloat16 if model.config.dtype == jnp.bfloat16 else None
+    n = gate[0].size
     with step.scope("exit"):
-        return exit_loss(ce, gate, model.config.exit_beta).mean()
+        log_p = exit_log_p(gate)
+        p = jnp.exp(log_p)
+    with step.scope("head"):
+        loss = weighted_token_loss(
+            hidden.reshape(-1, hidden.shape[-1]), head, labels.reshape(-1),
+            (p / n).reshape(-1), chunk=head_chunk, compute_dtype=compute,
+            logits_dtype=head_logits_dtype)
+    with step.scope("exit"):
+        return loss + model.config.exit_beta * jnp.sum(p * log_p) / n
 
 
 def make_train_step(model: nn.Module, tx):

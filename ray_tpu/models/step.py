@@ -49,6 +49,7 @@ import jax
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.core import telemetry
+from ray_tpu.ops.fused import HEAD_GRADIENT
 
 #: the parts of a step, in the order a step meets them
 PARTS = ("embed", "hc.coef", "hc.mix", "attn", "mlp", "moe.route",
@@ -159,15 +160,16 @@ def make_train_step(loss: Callable[[Any, jax.Array], jax.Array], tx, *,
     state per step).  ``plan(params)``: a context around the trace of
     loss and gradients (GPT-2's ``fsdp_plan``).  A trace leaves the span
     ``model:step.scopes`` (``parts``: :data:`PARTS` comma-joined,
-    ``attn_pieces``: :data:`ATTN_PIECES` likewise, ``remat``); nothing
-    runs with the step."""
+    ``attn_pieces``: :data:`ATTN_PIECES` likewise, ``remat``, ``head``:
+    how the chunked head comes by its gradient, ``ops/fused.py``'s
+    ``HEAD_GRADIENT``); nothing runs with the step."""
     import optax
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def train_step(params, opt_state, tokens):
         with telemetry.span("model", "step.scopes", parts=",".join(PARTS),
                             attn_pieces=",".join(ATTN_PIECES),
-                            remat=remat):
+                            remat=remat, head=HEAD_GRADIENT):
             with plan(params) if plan else contextlib.nullcontext():
                 value, grads = jax.value_and_grad(
                     lambda p: loss(p, tokens))(params)

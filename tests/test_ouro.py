@@ -81,6 +81,35 @@ def test_loss_and_every_gradient_match_the_reference(remat):
                                    err_msg=name)
 
 
+@pytest.mark.parametrize("leaf", ["kernel", "bias"])
+@pytest.mark.parametrize("chunk", [32, 1024],
+                         ids=["padded_tail", "one_chunk"])
+def test_the_gate_learns_through_the_head_s_weights(leaf, chunk):
+    """The head weighs every (exit, token) by ``p_t / n`` and hands the
+    weights ``CE`` for a cotangent: the gate's gradient is the
+    reference's, and the loss a reader puts together from
+    ``exit_terms`` (the head that recomputes) is the training loss."""
+    cfg, model, params, tokens, sizes = _setup()
+    loss, got = jax.value_and_grad(
+        lambda p: ouro.loss_fn(model, p, tokens, head_chunk=chunk))(params)
+    want = jax.grad(
+        lambda p: ref.loss(p, tokens, **sizes, **REF_KW))(params)
+    g = want["exit_gate"][leaf]
+    scale = float(jnp.abs(g).max())
+    assert scale > 0
+    np.testing.assert_allclose(got["exit_gate"][leaf], g, atol=2e-5 * scale)
+
+    def by_terms(p):
+        ce, gate = ouro.exit_terms(model, p, tokens, head_chunk=chunk)
+        return ouro.exit_loss(ce, gate, cfg.exit_beta).mean()
+
+    terms, g_terms = jax.value_and_grad(by_terms)(params)
+    assert float(loss) == pytest.approx(float(terms), rel=2e-6)
+    np.testing.assert_allclose(got["exit_gate"][leaf],
+                               g_terms["exit_gate"][leaf],
+                               atol=2e-5 * scale)
+
+
 def test_both_remat_forms_give_one_value():
     values = []
     for remat in ("", "full"):

@@ -451,10 +451,12 @@ def test_chunked_lm_loss_under_the_mesh_is_the_unsharded_call(chunk):
 #: in PR 34 for what ``ops/flash_attention.py`` changed under it on purpose
 #: (the functions that build the kernel calls under an inner ``jit``, the
 #: head-major kernels' tile classification; 09b991c8... and 4882aa21...
+#: before), and in PR 57 for the chunked head (its scan makes the
+#: gradient with the loss, ``ops/fused.py``; 19bff496... and cb0e2585...
 #: before): what the step says about placement is as it was, nothing
 STEP_BEFORE = {
-    "": "19bff496e0e7c944a324556660bcc7abd42fd9a9a5315b1ca6c8cea13029a006",
-    "full": "cb0e2585ab16211d33165d64d3819403386dbe88b4cd73583c18b567603cfa2e",
+    "": "8b2f3a7d5c8af7ff3512381f40cd7d645f8baca91176a9a03f946dd5e81f2bfb",
+    "full": "c881340e3daa18159cdf80040b0c7920438ea541782d99981db7ac46d978f085",
 }
 
 

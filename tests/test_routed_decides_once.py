@@ -132,12 +132,15 @@ def test_the_recompute_makes_no_plan_and_takes_no_top_k(name):
 #: (loss as a hex float, sha256 of every gradient leaf's bytes in the
 #: tree's order) at ``remat=""``, computed by :func:`_digest` on the tree
 #: BEFORE the weights were taken at the ids (commit 6eb95be, whose
-#: ``route`` took ``top_k``'s values)
+#: ``route`` took ``top_k``'s values).  PR 57: Mellum's LOSS moved by one
+#: unit in the last place (``...310`` -> ``...30e``: the head weighs a
+#: token ``1 / n`` inside its scan where it divided the sum by ``n``);
+#: every gradient leaf of all four is to the byte what it was
 PINNED = {
     "afmoe": ("0x1.62e31a0000000p+2", "9b0116038d5cb0df"),
     "deepseek_v3": ("0x1.62e84e0000000p+2", "e8121434fa8dd02d"),
     "nemotron_h": ("0x1.63a4060000000p+2", "3b1e922cf5a65d0e"),
-    "mellum": ("0x1.6343100000000p+2", "396968ca4e0e6398"),
+    "mellum": ("0x1.63430e0000000p+2", "396968ca4e0e6398"),
 }
 
 #: the most a leaf of the ``remat="full"`` gradients may lie from the

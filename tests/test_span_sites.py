@@ -25,9 +25,16 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.fixture
 def clean_spans():
+    # a test that ran a cluster earlier in this worker process has left
+    # its offset against that cluster's clock (a millisecond either way),
+    # and which files share a worker changes with every file added:
+    # these spans are read on this process's own clock
+    was = telemetry.clock_offset()
+    telemetry.set_clock_offset(0.0)
     telemetry.drain_spans("test")
     yield
     telemetry.drain_spans("test")
+    telemetry.set_clock_offset(was)
 
 
 def _by_name(rows):
