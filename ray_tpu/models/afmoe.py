@@ -653,11 +653,18 @@ def loss_fn(model: nn.Module, params, tokens: jax.Array,
     return (loss, _own_choices(model, state)) if with_choices else loss
 
 
+def _expert_layers(cfg) -> int:
+    """Expert layers ``h0 ..`` of a stack: ``num_layers`` unless the
+    configuration counts them apart (``models/qwen3_next.py``, whose
+    depth argument counts one kind of its layers)."""
+    return getattr(cfg, "num_expert_layers", cfg.num_layers)
+
+
 def _own_choices(model: nn.Module, state) -> List[jax.Array]:
     # sown once a call, and a call sees one sequence
     return [jnp.concatenate(
         state["intermediates"][f"h{i}"]["mlp"]["moe"]["expert_choice"])
-        for i in range(model.config.num_layers)]
+        for i in range(_expert_layers(model.config))]
 
 
 def make_train_step(model: nn.Module, tx):
@@ -690,9 +697,9 @@ def router_stats(model: nn.Module, params, tokens: jax.Array
     # sown once a call, and a call sees one sequence
     load = jnp.stack([
         sum(layers[f"h{i}"]["mlp"]["moe"]["expert_load"])
-        for i in range(cfg.num_layers)]).astype(jnp.float32)
+        for i in range(_expert_layers(cfg))]).astype(jnp.float32)
     tiles = {k: jnp.stack([sum(layers[f"h{i}"]["mlp"]["moe"][k])
-                           for i in range(cfg.num_layers)])
+                           for i in range(_expert_layers(cfg))])
              for k in ("live_tiles", "buffer_tiles")}
     pairs = tokens.shape[0] * tokens.shape[1] * cfg.top_k
     return {"load": load, "landed_share": load.sum(-1) / pairs,

@@ -1896,7 +1896,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             return jax.shard_map(per_shard, mesh=mesh, in_specs=(spec,) * 3,
                                  out_specs=spec, check_vma=False)(q, k, v)
     block_q = DEFAULT_BLOCK if block_q is None else block_q
-    block_k = DEFAULT_BLOCK if block_k is None else block_k
+    if block_k is None:
+        # a head wider than one 128-lane slab (256: two): inside a step
+        # the dK/dV kernel's tiles at 1024 x 1024 pass its 16 MiB of
+        # scoped VMEM (by 20 KB at width 256); the forward and dQ stream
+        # K/V along this axis, so their traffic stays what it is
+        block_k = DEFAULT_BLOCK if q.shape[-1] <= 128 else \
+            DEFAULT_BLOCK // 2
     if native is None:
         native = _nl_eligible(q, k, v)
     with telemetry.span("ops", "flash.plan", **_plan_args(

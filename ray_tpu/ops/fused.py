@@ -21,21 +21,28 @@ import jax.numpy as jnp
 from ray_tpu.ops._kernel import kernel_mode
 
 
-def _rmsnorm_ref(x, weight, eps):
+def _scale(weight, offset: float):
+    """``offset + weight`` in float32; an offset of 0 adds nothing to the
+    trace (the norms whose scale is the weight itself)."""
+    weight = weight.astype(jnp.float32)
+    return weight + offset if offset else weight
+
+
+def _rmsnorm_ref(x, weight, eps, offset: float = 0.0):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     y = x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
-    return (y * weight.astype(jnp.float32)).astype(x.dtype)
+    return (y * _scale(weight, offset)).astype(x.dtype)
 
 
-def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
+def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float, offset: float):
     x = x_ref[:].astype(jnp.float32)
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     y = x * jax.lax.rsqrt(var + eps)
-    o_ref[:] = (y * w_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
+    o_ref[:] = (y * _scale(w_ref[:], offset)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _rmsnorm(x, weight, eps, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _rmsnorm(x, weight, eps, interpret, offset=0.0):
     from jax.experimental import pallas as pl
 
     rows = x.shape[0] * (x.shape[1] if x.ndim == 3 else 1)
@@ -53,7 +60,7 @@ def _rmsnorm(x, weight, eps, interpret):
         block //= 2
     block = max(block, 1)
     out = pl.pallas_call(
-        functools.partial(_rmsnorm_kernel, eps=eps),
+        functools.partial(_rmsnorm_kernel, eps=eps, offset=offset),
         grid=(rows // block,),
         in_specs=[
             pl.BlockSpec((block, x.shape[-1]), lambda i: (i, 0)),
@@ -66,13 +73,14 @@ def _rmsnorm(x, weight, eps, interpret):
     return out.reshape(x.shape)
 
 
-def _rmsnorm_fwd(x, weight, eps, interpret):
-    return _rmsnorm(x, weight, eps, interpret), (x, weight)
+def _rmsnorm_fwd(x, weight, eps, interpret, offset):
+    return _rmsnorm(x, weight, eps, interpret, offset), (x, weight)
 
 
-def _rmsnorm_bwd(eps, interpret, res, g):
+def _rmsnorm_bwd(eps, interpret, offset, res, g):
     x, weight = res
-    _, vjp = jax.vjp(lambda x_, w_: _rmsnorm_ref(x_, w_, eps), x, weight)
+    _, vjp = jax.vjp(lambda x_, w_: _rmsnorm_ref(x_, w_, eps, offset), x,
+                     weight)
     return vjp(g)
 
 
@@ -81,24 +89,29 @@ _rmsnorm.defvjp(_rmsnorm_fwd, _rmsnorm_bwd)
 
 def fused_rmsnorm(x: jax.Array, weight: jax.Array, *, eps: float = 1e-6,
                   interpret: Optional[bool] = None,
-                  mesh: Optional[jax.sharding.Mesh] = None) -> jax.Array:
-    """``mesh`` (models under a mesh pass ``get_global_mesh()``): GSPMD
+                  mesh: Optional[jax.sharding.Mesh] = None,
+                  offset: float = 0.0) -> jax.Array:
+    """``offset``: the scale is ``offset + weight`` (1 for a norm whose
+    weights are zero-centred, ``models/qwen3_next.py``); 0 traces the
+    body as it is without the argument.
+
+    ``mesh`` (models under a mesh pass ``get_global_mesh()``): GSPMD
     cannot partition a Mosaic kernel, so over several devices the
     kernel runs on each device's own rows of ``x [B, T, E]``, split as
     the activations are, with the whole ``weight``."""
     interpret = kernel_mode(interpret)
     if interpret is None:
-        return _rmsnorm_ref(x, weight, eps)
+        return _rmsnorm_ref(x, weight, eps, offset)
     if mesh is not None and mesh.size > 1:
         from ray_tpu.parallel.sharding import MESH_RULES, P
 
         spec = MESH_RULES.activation_spec("batch", "seq", "embed",
                                           mesh=mesh, shape=x.shape)
         return jax.shard_map(
-            lambda a, w: _rmsnorm(a, w, eps, interpret), mesh=mesh,
+            lambda a, w: _rmsnorm(a, w, eps, interpret, offset), mesh=mesh,
             in_specs=(spec, P()), out_specs=spec,
             check_vma=False)(x, weight)
-    return _rmsnorm(x, weight, eps, interpret)
+    return _rmsnorm(x, weight, eps, interpret, offset)
 
 
 def fused_softmax_cross_entropy(logits: jax.Array,

@@ -323,12 +323,17 @@ def _short_conv_bwd(tile, interpret, res, g):
 _short_conv.defvjp(_short_conv_fwd, _short_conv_bwd)
 
 
-def short_conv(u: jax.Array, w: jax.Array, bias: jax.Array, *,
+def short_conv(u: jax.Array, w: jax.Array,
+               bias: Optional[jax.Array] = None, *,
                interpret: Optional[bool] = None) -> jax.Array:
     """``silu(bias + sum_j w[j] * u[t - (taps - 1) + j])`` over ``u [B,
     T, C]`` in ``u``'s dtype: the float32 sum rounded once.  ``w [taps,
     C]`` and ``bias [C]``; taps, bias, ``silu`` and the gradients of
-    ``w`` and ``bias`` are float32 whatever ``u`` is."""
+    ``w`` and ``bias`` are float32 whatever ``u`` is.  No ``bias``
+    (``models/qwen3_next.py``): a constant zero that is no parameter,
+    through the same kernels."""
+    if bias is None:
+        bias = jnp.zeros((u.shape[-1],), jnp.float32)
     tile = tiles(u, w.shape[0])
     interpret = kernel_mode(interpret)
     if tile is None or interpret is None:

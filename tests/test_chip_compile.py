@@ -1206,3 +1206,131 @@ def test_mellum_gradient_check_fits_beside_the_training_state(topo):
         f"{MELLUM_STATE_BYTES_A_CHIP / 2**30:.2f} GiB of state"
     print(f"mellum gradient check: peak "
           f"{mem.peak_memory_in_bytes / 2**30:.3f} GiB")
+
+
+# --------------------------------------------------------------------------
+# Qwen3-Next-80B-A3B: heads of 256, the gated delta rule's scan
+# --------------------------------------------------------------------------
+
+#: bytes of ``qwen3-next-80b-a3b.steady``'s training state: 625,667,136
+#: parameters, f32 weights and AdamW's two moments
+QWEN3_NEXT_STATE_BYTES = 625_667_136 * 12
+
+
+def _qwen3_next_share(**kw):
+    qn = importlib.import_module("ray_tpu.models.qwen3_next")
+    cfg = qn.Qwen3NextConfig.qwen3_next_80b_a3b_share(remat="full", **kw)
+    return qn, cfg, qn.Qwen3Next(cfg)
+
+
+def test_flash_compiles_at_the_qwen3_next_cells_shapes(one_chip):
+    """One sequence of 4,096 (and of 8,192), 16 query on 2 K/V heads of
+    256, as ``models/qwen3_next.py`` calls it: the first head of two lane
+    slabs, so the head-major family (the native one takes 64 and 128),
+    a group of 8, full causal: forward, dK/dV and dQ, at the blocks
+    ``flash_attention`` picks for a head wider than 128 (1024 queries x
+    512 keys: inside the cell's step the dK/dV kernel's tiles at 1024 x
+    1024 passed its 16 MiB of scoped VMEM by 20 KB)."""
+    for seq in (4096, 8192):
+        q = jax.ShapeDtypeStruct((1, seq, 16, 256), jnp.bfloat16,
+                                 sharding=one_chip)
+        k = jax.ShapeDtypeStruct((1, seq, 2, 256), jnp.bfloat16,
+                                 sharding=one_chip)
+        assert not fa._nl_eligible(q, k, k)
+
+        def grads(q, k, v):
+            with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+                return jax.grad(lambda q, k, v: fa.flash_attention(
+                    q, k, v, causal=True).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+
+        text = jax.jit(grads).lower(q, k, k).compile().as_text()
+        assert text.count("tpu_custom_call") == 3
+        # the key block: dK/dV's grid walks seq / 512 tiles of keys
+        assert f"bf16[1,2,{seq},256]" in text
+
+
+def test_the_gated_delta_scan_compiles_at_the_cells_shapes(one_chip):
+    """``ops/gated_delta.py`` forward and backward at one sequence of the
+    cell: 16 key heads serving 32 value heads of 128, chunks of 64, plain
+    ``jnp`` under XLA: two loops over the 64 chunks (the carry, and its
+    backward), a float32 state, no kernel, and the backward's temporaries
+    (1.11 GiB here; 2.15 at 8,192, which with the reference's own is what
+    the gradient check has no room for)."""
+    gd = importlib.import_module("ray_tpu.ops.gated_delta")
+    q = jax.ShapeDtypeStruct((1, 4096, 16, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 4096, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    g = jax.ShapeDtypeStruct((1, 4096, 32), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v, g, b: gd.gated_delta(q, k, v, g, b).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))).lower(
+                q, q, v, g, g).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert len(re.findall(r" while\(", text)) == 2
+    assert "f32[64,1,16,2,128,128]" in text   # the states that entered
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.3 * 2 ** 30
+
+
+@pytest.mark.slow
+def test_qwen3_next_share_train_step_fits_one_v5e(one_chip):
+    """``qwen3-next-80b-a3b.steady``'s step: L L L F at the published
+    widths, batch 4 x 4,096, donated state: 12.03 GiB (14.17 at 2 x
+    8,192).  Marked slow: three minutes of compiling in the suite's
+    longest file, and the benchmark reads the same number on the chip
+    (``step_hbm_gib``)."""
+    qn, cfg, model = _qwen3_next_share()
+    text, params, total = _compiled_step(qn, model, 4, one_chip)
+    assert sum(a.size for a in jax.tree.leaves(params)) == 625_667_136
+    calls = _kernel_calls(text)
+    named = lambda name: sum(  # noqa: E731
+        name in _called(line) for line in calls)
+    # 3 mixers x 4 sequences: the convolution forward twice (remat),
+    # backward once, with no bias to learn
+    assert named("short_conv_bwd") == 12
+    assert named("short_conv") - named("short_conv_bwd") == 24
+    # 4 layers x 4 sequences x 3 products x (2 forward, d lhs, d rhs)
+    assert named("grouped_matmul") == 192
+    flash = [_op_name(line) for line in calls]
+    assert sum("/jit(_flash_forward)/" in c for c in flash) == 8
+    assert sum("/jit(_flash_backward)/" in c for c in flash) == 8
+    assert _plans_remade(text) == []
+    assert total < 12.2 * 2 ** 30, f"{total / 2**30:.3f} GiB"
+    print(f"qwen3-next step: {total / 2**30:.3f} GiB")
+
+
+@pytest.mark.slow
+def test_qwen3_next_gradient_check_fits_beside_the_training_state(one_chip):
+    """The harness's check: depth 2, ``L L F``, two sequences of 4,096,
+    the program's paired loss and the step-by-step reference, BOTH
+    gradients in one program, beside the training state: 7.75 GiB beside
+    6.99.  At 8,192 it reads 12.25: why the cell runs 4 x 4,096."""
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    reference = importlib.import_module("benchmarks.reference.qwen3_next")
+    paired = importlib.import_module(
+        "benchmarks.reference.qwen3_next_paired")
+    _, cfg, model = _qwen3_next_share(num_layers=2)
+    params = _abstract_params(model, one_chip, 2)
+    tokens = jax.ShapeDtypeStruct((2, cfg.max_seq_len), jnp.int32,
+                                  sharding=one_chip)
+    sizes = {"n_layer": 2, "n_head": cfg.num_heads, "ln_eps": cfg.rms_eps}
+
+    def error(p, t):
+        return reference.grad_error(
+            jax.grad(lambda q: paired.program_loss(model, q, t))(p),
+            jax.grad(lambda q: reference.loss(q, t, **sizes))(p))
+
+    compiled = _lower_as_on_tpu(jax.jit(error), (params, tokens)).compile()
+    assert "short_conv_bwd" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    check = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert check + QWEN3_NEXT_STATE_BYTES < 0.97 * V5E_HBM_BYTES, \
+        f"{check / 2**30:.2f} GiB beside " \
+        f"{QWEN3_NEXT_STATE_BYTES / 2**30:.2f} GiB of state"
+    print(f"qwen3-next gradient check: {check / 2**30:.3f} GiB")

@@ -28,7 +28,7 @@ from flax.core import meta
 from benchmarks.reduce import pieces, scopes
 from ray_tpu.core import telemetry
 from ray_tpu.models import afmoe, deepseek_v3, gpt2, mellum, nemotron_h, \
-    ouro, step
+    ouro, qwen3_next, step
 from ray_tpu.ops import fused
 from ray_tpu.ops import grouped_matmul as gm
 
@@ -42,6 +42,8 @@ MODELS = {
                     deepseek_v3.DeepseekV3),
     "nemotron_h": (nemotron_h, nemotron_h.NemotronHConfig,
                    nemotron_h.NemotronH),
+    "qwen3_next": (qwen3_next, qwen3_next.Qwen3NextConfig,
+                   qwen3_next.Qwen3Next),
 }
 ROUTED = ("moe.route", "moe.plan", "moe.dispatch", "moe.experts",
           "moe.combine")
@@ -50,6 +52,11 @@ SEEN = {
     "afmoe": {"embed", "attn", "mlp", "head", "optimizer", *ROUTED},
     "deepseek_v3": {"embed", "attn", "mlp", "head", "optimizer", *ROUTED},
     "nemotron_h": {"embed", "attn", "mlp", "head", "optimizer", *ROUTED,
+                   "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm",
+                   "ssm.out_proj"},
+    # a Gated DeltaNet mixer's work under the five parts a Mamba-2
+    # mixer's stands under (PR 58): ``PARTS`` is what it was
+    "qwen3_next": {"embed", "attn", "mlp", "head", "optimizer", *ROUTED,
                    "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm",
                    "ssm.out_proj"},
 }
@@ -104,6 +111,24 @@ BEFORE = {
     ("nemotron_h", "full"): (
         "c6f1fbc9edb2fbbe41dc0ca5447cc0036d02f5091953c801ef59e336f2b5cfc2",
         "dc97618ee45dda61ed25a95141a3b81a3c70d427ead3341d15b02ac03fd1409e"),
+    # Mellum and Xing (``TINY`` below) pinned at PR 58, taken at its
+    # PARENT (f9d52de): that PR gave ``fused_rmsnorm`` an offset,
+    # ``short_conv`` a call without bias, the routed models a count of
+    # expert layers apart from their depth argument and the flash
+    # kernels a narrower key block for heads wider than 128, and none of
+    # it may reach a model that asks for none of it
+    ("mellum", ""): (
+        "38e915539769072f645e97eec15f7234ebdb02b5bc12600000fc555a9486b18b",
+        "1d016bbb7db2f4c6809d6af7d6dc444d95d85988192687da423def682d25531a"),
+    ("mellum", "full"): (
+        "4a4d04a044c2a10d1c11a49695082c37cf61a6b3b8ef1f530408d9046ca192ed",
+        "1d016bbb7db2f4c6809d6af7d6dc444d95d85988192687da423def682d25531a"),
+    ("xing", ""): (
+        "14988363e09b83234e21b9d070c46ea7bc3973f5d7bc287f6b30318c49d47f47",
+        "161d9e86a1b94fa03254ce3a232d9e728bfeb11661576488b2e4d18d10d760f3"),
+    ("xing", "full"): (
+        "885210fcf12b9151bdc75b1b98fd7b41f55e912d4ae06cd99aa40a84d1ba769a",
+        "161d9e86a1b94fa03254ce3a232d9e728bfeb11661576488b2e4d18d10d760f3"),
 }
 
 _INSTRUCTION = re.compile(
@@ -300,8 +325,8 @@ def test_every_op_of_the_walk_sits_under_combine_or_dispatch(name):
 def test_the_program_is_what_it_was(name, remat):
     """Parameter paths and the jaxpr as before the scopes (GPT-2 at
     another size: ``tests/test_parallel.py`` ``STEP_BEFORE``)."""
-    module, config, model_cls = MODELS[name]
-    cfg = config.tiny(remat=remat)
+    module, tiny, model_cls = TINY[name]
+    cfg = tiny(remat=remat)
     model = model_cls(cfg)
     tx = optax.adamw(1e-3)
     params = meta.unbox(jax.eval_shape(
@@ -424,7 +449,7 @@ def test_every_op_under_attn_sits_under_one_piece(name):
             len(bare), len(work), sorted(set(bare))[:10])
         seen = {pieces.piece(n, said) for _, n in work} - {None}
         assert seen >= {"norm", "proj", "kernel"}
-        assert ("gate" in seen) == (name == "afmoe")
+        assert ("gate" in seen) == (name in ("afmoe", "qwen3_next"))
         assert ("pos" in seen) == (name not in ("gpt2", "nemotron_h"))
     # what the kernels' file does around its calls: where they are called
     assert "layout" in {pieces.piece(n, said)
