@@ -1250,28 +1250,42 @@ def test_flash_compiles_at_the_qwen3_next_cells_shapes(one_chip):
         assert f"bf16[1,2,{seq},256]" in text
 
 
-def test_the_gated_delta_scan_compiles_at_the_cells_shapes(one_chip):
+@pytest.mark.parametrize("chunk_math", ["xla", "pallas"])
+def test_the_gated_delta_scan_compiles_at_the_cells_shapes(one_chip,
+                                                           chunk_math):
     """``ops/gated_delta.py`` forward and backward at one sequence of the
-    cell: 16 key heads serving 32 value heads of 128, chunks of 64, plain
-    ``jnp`` under XLA: two loops over the 64 chunks (the carry, and its
-    backward), a float32 state, no kernel, and the backward's temporaries
-    (1.11 GiB here; 2.15 at 8,192, which with the reference's own is what
-    the gradient check has no room for)."""
+    cell: 16 key heads serving 32 value heads of 128, chunks of 64.  In
+    both forms two loops over the 64 chunks (the carry, and its backward:
+    what ``gdn_roofline``'s reader counts) and a float32 state.  ``xla``,
+    the public wrapper's choice off the TPU: plain ``jnp``, no kernel,
+    and the backward's temporaries (0.77 GiB here; 1.11 before PR 59,
+    2.15 then at 8,192, which with the reference's own is what the
+    gradient check had no room for).  ``pallas`` (``interpret=False``), what the chip runs: Mosaic
+    accepts the kernels before and after the carry and their backward,
+    no ``[.., 64, 64]`` float32 matrix of all chunks is left in the
+    program, and the temporaries are 0.53 GiB."""
     gd = importlib.import_module("ray_tpu.ops.gated_delta")
+    kernels = chunk_math == "pallas"
     q = jax.ShapeDtypeStruct((1, 4096, 16, 128), jnp.bfloat16,
                              sharding=one_chip)
     v = jax.ShapeDtypeStruct((1, 4096, 32, 128), jnp.bfloat16,
                              sharding=one_chip)
     g = jax.ShapeDtypeStruct((1, 4096, 32), jnp.float32, sharding=one_chip)
     compiled = jax.jit(jax.grad(
-        lambda q, k, v, g, b: gd.gated_delta(q, k, v, g, b).astype(
-            jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))).lower(
-                q, q, v, g, g).compile()
+        lambda q, k, v, g, b: gd.gated_delta(
+            q, k, v, g, b, interpret=False if kernels else None).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))).lower(
+                    q, q, v, g, g).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text
+    # the forward before the carry, and the two backward kernels (the
+    # read-out's forward feeds nothing a gradient needs)
+    assert text.count("tpu_custom_call") == (3 if kernels else 0)
     assert len(re.findall(r" while\(", text)) == 2
     assert "f32[64,1,16,2,128,128]" in text   # the states that entered
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.3 * 2 ** 30
+    # a slab's C x C matrices side by side, of all 64 chunks x 16 heads
+    assert bool(re.search(r"f32\[(1,)?64,16,64,128\]", text)) != kernels
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < (0.6 if kernels else 1.3) * 2 ** 30
 
 
 @pytest.mark.slow

@@ -437,4 +437,6 @@ def test_the_plan_spans_say_what_was_compiled():
     assert len(scans) == 4
     assert scans[0]["chunk"] == 16 and scans[0]["chunks"] == 4
     assert scans[0]["key_heads"] == 2 and scans[0]["value_heads"] == 4
-    assert scans[0]["saved"] == "chunk_states"
+    assert scans[0]["saved"] == "chunk_states,carry_operands"
+    # heads of 8 lanes: a shape the kernels cannot tile
+    assert (scans[0]["chunk_math"], scans[0]["carry"]) == ("xla", "xla")
